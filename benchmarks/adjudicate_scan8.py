@@ -27,12 +27,10 @@ import numpy as np
 
 def main():
   import jax
-  from glt_tpu.utils.backend import force_backend
+  from glt_tpu.utils.backend import (configure_compile_cache,
+                                     force_backend)
   force_backend()
-  cache = os.path.join(os.path.dirname(os.path.dirname(
-      os.path.abspath(__file__))), '.jax_cache')
-  jax.config.update('jax_compilation_cache_dir', cache)
-  jax.config.update('jax_persistent_cache_min_compile_time_secs', 1.0)
+  configure_compile_cache()
   import jax.numpy as jnp
   from glt_tpu.data import Topology
   from glt_tpu.ops.pipeline import (make_dedup_tables, multihop_sample_many,

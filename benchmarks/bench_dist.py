@@ -23,9 +23,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 import numpy as np
 
-_CACHE_DIR = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), '.jax_cache')
-
 
 def run_mesh(n_dev, root_by_p, num_nodes, fanout, batch, iters, warmup):
   import jax
@@ -70,9 +67,10 @@ def main():
       'XLA_FLAGS',
       f'--xla_force_host_platform_device_count={max(sizes)}')
   import jax
-  from glt_tpu.utils.backend import force_backend
+  from glt_tpu.utils.backend import (configure_compile_cache,
+                                     force_backend)
   force_backend()
-  jax.config.update('jax_compilation_cache_dir', _CACHE_DIR)
+  configure_compile_cache()
   from glt_tpu.partition import RandomPartitioner
 
   n = args.num_nodes

@@ -35,9 +35,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 import numpy as np
 
-_CACHE_DIR = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), '.jax_cache')
-
 
 def main():
   ap = argparse.ArgumentParser()
@@ -58,10 +55,10 @@ def main():
   args = ap.parse_args()
 
   import jax
-  from glt_tpu.utils.backend import force_backend
+  from glt_tpu.utils.backend import (configure_compile_cache,
+                                     force_backend)
   force_backend()
-  jax.config.update('jax_compilation_cache_dir', _CACHE_DIR)
-  jax.config.update('jax_persistent_cache_min_compile_time_secs', 1.0)
+  configure_compile_cache()
   import jax.numpy as jnp
   import optax
   from glt_tpu.data import Dataset

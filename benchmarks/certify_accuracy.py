@@ -34,9 +34,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 import numpy as np
 
-_CACHE_DIR = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), '.jax_cache')
-
 
 def mean_aggregate(src, dst, feats, num_nodes, chunk=2_000_000):
   """(A_mean f)_i = mean of feats[dst] over out-edges of i, chunked."""
@@ -126,10 +123,10 @@ def main():
   args = ap.parse_args()
 
   import jax
-  from glt_tpu.utils.backend import force_backend
+  from glt_tpu.utils.backend import (configure_compile_cache,
+                                     force_backend)
   force_backend()
-  jax.config.update('jax_compilation_cache_dir', _CACHE_DIR)
-  jax.config.update('jax_persistent_cache_min_compile_time_secs', 1.0)
+  configure_compile_cache()
   from glt_tpu.data import Dataset
 
   rng = np.random.default_rng(0)

@@ -36,9 +36,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
 
 import numpy as np
 
-_CACHE_DIR = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), '.jax_cache')
-
 
 def timed(fn, iters, warmup, sync):
   import jax
@@ -75,10 +72,11 @@ def main():
         os.environ.get('XLA_FLAGS', '') +
         f' --xla_force_host_platform_device_count={args.num_devices}')
   import jax
+  from glt_tpu.utils.backend import (configure_compile_cache,
+                                     force_backend)
   if args.cpu_mesh:
-    from glt_tpu.utils.backend import force_backend
     force_backend('cpu')
-  jax.config.update('jax_compilation_cache_dir', _CACHE_DIR)
+  configure_compile_cache()
   import jax.numpy as jnp
   import optax
   from glt_tpu.distributed import (

@@ -22,9 +22,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 import numpy as np
 
-_CACHE_DIR = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), '.jax_cache')
-
 N = 2_450_000
 E = 62_000_000
 F = 153_600
@@ -47,10 +44,10 @@ def timed(fn, *args, iters=20, warmup=3):
 
 def main():
   import jax
-  from glt_tpu.utils.backend import force_backend
+  from glt_tpu.utils.backend import (configure_compile_cache,
+                                     force_backend)
   force_backend()
-  jax.config.update('jax_compilation_cache_dir', _CACHE_DIR)
-  jax.config.update('jax_persistent_cache_min_compile_time_secs', 1.0)
+  configure_compile_cache()
   import jax.numpy as jnp
   from jax import lax
 

@@ -6,11 +6,11 @@ from __future__ import annotations
 
 import os
 
-from glt_tpu.utils.backend import force_backend
+from glt_tpu.utils.backend import configure_compile_cache, force_backend
 
-# honor GLT_PLATFORM/GLT_BENCH_PLATFORM even where the TPU plugin
-# overrides JAX_PLATFORMS (must run before backend init)
+# honor GLT_PLATFORM/GLT_BENCH_PLATFORM (must run before backend init)
 force_backend()
+configure_compile_cache()
 
 import numpy as np
 

@@ -8,10 +8,6 @@ collectives, and Pallas kernels on the hot paths.
 
 __version__ = '0.1.0'
 
-from .utils import compat as _compat  # noqa: E402
-
-_compat.install()  # backfill jax.shard_map / jax.memory on older jax
-
 from . import typing  # noqa: F401
 from . import utils  # noqa: F401
 from . import obs  # noqa: F401

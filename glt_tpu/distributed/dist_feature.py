@@ -293,7 +293,7 @@ class DistFeature:
     zeros_feat = jnp.zeros((b, self.feature_dim), feat_shard.dtype)
     zeros = ((zeros_feat, jnp.zeros((b,), bool)) if two_outputs
              else zeros_feat)
-    return capped_drain(round_serve, meta, n, eff_cap, b, ax, zeros)
+    return capped_drain(round_serve, meta, n, eff_cap, ax, zeros)
 
   def lookup(self, ids, valid=None) -> jax.Array:
     """Whole-mesh lookup: ids [P * B] shard-major.

@@ -22,6 +22,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.pipeline import multihop_sample_hetero
 from ..ops.pipeline import make_dedup_tables
+from ..parallel.mesh import replicate
 from ..typing import EdgeType, NodeType, reverse_edge_type
 from ..utils import as_numpy
 from ..utils.rng import RandomSeedManager
@@ -578,7 +579,7 @@ class DistHeteroTrainStep:
 
   def init_params(self, key):
     params = self.model.init(key, self.dummy_batch())
-    return jax.device_put(params, NamedSharding(self.mesh, P()))
+    return replicate(params, self.mesh)
 
   def _assembly(self):
     """Shared device-batch assembly for the train and eval programs:
@@ -835,6 +836,7 @@ class DistHeteroTrainStep:
             len(seeds_stack), -1), jnp.int32), sh)
     nv = jax.device_put(jnp.asarray(n_valid_stack, jnp.int32), sh)
     keys = jax.device_put(keys, sh)
+    params, opt_state = replicate((params, opt_state), self.mesh)
     from ..obs import get_registry, get_tracer
     tracer = get_tracer()
     _synced = {}
@@ -857,6 +859,7 @@ class DistHeteroTrainStep:
     nv = jax.device_put(jnp.asarray(n_valid_per_device, jnp.int32),
                         shard)
     keys = jax.random.split(key, n_dev)
+    params, opt_state = replicate((params, opt_state), self.mesh)
     params, opt_state, self.sampler.tables, loss = self._step_fn(
         params, opt_state, self.sampler.tables, seeds, nv, keys)
     return params, opt_state, loss

@@ -22,6 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..loader.transform import Batch
 from ..ops.pipeline import edge_hop_offsets, multihop_sample
 from ..ops.pipeline import make_dedup_tables
+from ..parallel.mesh import replicate
 from .dist_feature import DistFeature
 from .dist_graph import DistGraph
 from .dist_neighbor_sampler import make_dist_one_hop
@@ -90,7 +91,7 @@ class DistTrainStep:
 
   def init_params(self, key):
     params = self.model.init(key, self._dummy_batch())
-    return jax.device_put(params, NamedSharding(self.mesh, P()))
+    return replicate(params, self.mesh)
 
   def _build(self):
     g, f, ef = self.g, self.f, self.ef
@@ -196,6 +197,7 @@ class DistTrainStep:
     nv = jax.device_put(
         jnp.asarray(n_valid_per_device, jnp.int32), shard)
     keys = jax.random.split(key, n_dev)
+    params, opt_state = replicate((params, opt_state), self.mesh)
     params, opt_state, self.tables, self.scratches, loss = self._step_fn(
         params, opt_state, self.tables, self.scratches, seeds, nv, keys)
     return params, opt_state, loss

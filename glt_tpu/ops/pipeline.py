@@ -104,13 +104,6 @@ def fused_walk_mode() -> str:
   return mode
 
 
-#: env-level fallback events already counted this process — hop_engine()
-#: is read per hop per trace, and a per-read count would report one
-#: configuration event hops x traces times (sampler-level reasons
-#: dedupe per sampler instance via their own sets)
-_COUNTED_ENV_FALLBACKS = set()
-
-
 def count_engine_fallback(requested: str, resolved: str,
                           reason: str) -> None:
   """Record an engine-fallback event on the metrics registry
@@ -119,9 +112,8 @@ def count_engine_fallback(requested: str, resolved: str,
   weaker one is an operational fact worth a counter, not just a log
   line — dashboards can alert on a fleet that quietly lost its fused
   kernels. Counted once per resolution event — a sampler gating a
-  shape it can't fuse (callers dedupe per instance) or a process whose
-  env requests an unimportable engine — never per sample call or per
-  trace-time env read."""
+  shape it can't fuse (callers dedupe per instance) — never per sample
+  call or per trace-time env read."""
   import logging
   logging.getLogger(__name__).warning(
       'GLT_HOP_ENGINE=%s resolved to %r (%s)', requested, resolved,
@@ -160,21 +152,22 @@ def hop_engine() -> str:
     overlays, table-overflow budgets) fall back to ``pallas`` with a
     counted ``hop_engine_fallbacks_total`` event.
 
-  ``GLT_HOP_ENGINE`` selects; ``auto`` (the default) resolves PER
-  BACKEND: on CPU it stays ``element`` (the r5 microbench measured
-  XLA's element gather fastest there, and interpret-mode kernels are a
-  correctness harness, not a perf path); on TPU it resolves to the
-  best servable fused engine — ``pallas_fused`` — gated on a one-time
-  probe compile of the kernel family on the real backend
-  (``pallas_kernels.auto_probe_ok``), demoting to ``element`` with a
-  counted fallback if the probe fails. It deliberately never resolves
-  to ``window``: the XLA window gather measured 437 ms for 153k x 96
-  rows on a v5e (benchmarks/tpu_runs/microbench_prims_tpu2.json) — the
-  window read is only viable as a Pallas DMA. The resolution is
-  recorded once per process via
-  ``hop_engine_fallbacks_total{requested="auto",...}`` so the flip is
-  observable from a registry snapshot; ``GLT_HOP_ENGINE_AUTO=0`` is
-  the escape hatch pinning the legacy (element-everywhere) auto.
+  ``GLT_HOP_ENGINE`` selects. ``auto`` (the default) is ``element`` on
+  every backend: a fixed answer, reached without compiling or probing
+  anything. On a TPU that makes the sampler the XLA ``sort+fused`` path
+  (:func:`dedup_engine` and :func:`fused_hops` resolve to it there), the
+  one engine with a driver-recorded chip number (BENCH_r05.json). A
+  Pallas family becomes a TPU default only after it has compiled on the
+  chip at the widths its samplers run and matched ``sort+fused`` bit
+  for bit there (``benchmarks/probe_pallas_compile.py`` rungs 8-10). On
+  the v5e with jax 0.9.0 none does: Mosaic tiles a 1-D int32 HBM
+  operand in 1024-element tiles and refuses the ``W``-wide window slice
+  at an arbitrary edge offset that ``pallas``, ``pallas_fused`` (per-hop
+  and cross-hop) and the hetero type plane are all built on. They stay
+  reachable by an explicit ``GLT_HOP_ENGINE=``, where a compile failure
+  raises. ``window`` is never a default either: the XLA window gather
+  measured 437 ms for 153k x 96 rows on a v5e
+  (benchmarks/tpu_runs/microbench_prims_tpu2.json).
 
   All engines draw offsets from the same ``jax.random`` stream, so
   results are bit-identical (ops/sample.py; ``pallas_fused`` is
@@ -185,31 +178,7 @@ def hop_engine() -> str:
     raise ValueError(
         f'GLT_HOP_ENGINE={mode!r}: expected '
         'auto|element|window|pallas|pallas_fused')
-  if mode == 'auto':
-    if not knob('GLT_HOP_ENGINE_AUTO', True):
-      return 'element'
-    if jax.default_backend() != 'tpu':
-      return 'element'
-    from .pallas_kernels import auto_probe_ok, pallas_available
-    if not pallas_available():
-      key = ('auto', 'element', 'pallas_unimportable')
-    elif not auto_probe_ok():
-      key = ('auto', 'element', 'auto_probe_failed')
-    else:
-      key = ('auto', 'pallas_fused', 'auto_backend_tpu')
-    if key not in _COUNTED_ENV_FALLBACKS:  # one config event per
-      _COUNTED_ENV_FALLBACKS.add(key)      # process, not per read
-      count_engine_fallback(*key)
-    return key[1]
-  if mode in ('pallas', 'pallas_fused'):
-    from .pallas_kernels import pallas_available
-    if not pallas_available():
-      key = (mode, 'window', 'pallas_unimportable')
-      if key not in _COUNTED_ENV_FALLBACKS:  # one config event, not
-        _COUNTED_ENV_FALLBACKS.add(key)      # one per env read
-        count_engine_fallback(*key)
-      return 'window'
-  return mode
+  return 'element' if mode == 'auto' else mode
 
 
 def checksum_outputs(out: Dict[str, jax.Array]) -> jax.Array:

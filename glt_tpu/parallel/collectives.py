@@ -86,7 +86,7 @@ def drain_rounds(meta: BucketMeta, n_shards: int, cap: int,
 
 
 def capped_drain(round_out, meta: 'BucketMeta', n_shards: int, cap: int,
-                 b: int, axis_name: str, zeros):
+                 axis_name: str, zeros):
   """Accumulate ``round_out(base)`` over however many capped-exchange
   rounds serve every request (see :func:`drain_rounds`).
 
@@ -96,31 +96,18 @@ def capped_drain(round_out, meta: 'BucketMeta', n_shards: int, cap: int,
   zeros/False. ``zeros`` is the matching all-zero pytree. Bool leaves
   merge with ``|``, everything else with ``+``.
 
-  On modern jax the round count is a pmax'd traced scalar driving a
-  ``lax.while_loop`` (typical skew: one round). Legacy 0.4.x jax
-  MISCOMPILES collectives under a traced while_loop inside shard_map
-  (wrong values, not an error), so there the drain unrolls statically
-  to its worst case ceil(b/cap) — value-identical, always paying the
-  full exchange count. One implementation for every capped lookup path
-  (parallel + distributed feature stores).
+  The round count is a pmax'd traced scalar driving one
+  ``lax.while_loop`` (typical skew: one round). One implementation for
+  every capped lookup path (parallel + distributed feature stores).
   """
-  from jax import tree_util  # jax.tree.map is younger than the 0.4.x
-  #                            targets the legacy branch exists for
-
   def merge(a, o):
     return a | o if a.dtype == jnp.bool_ else a + o
 
-  from ..utils import compat
-  if compat.LEGACY_JAX:
-    acc = zeros
-    for k in range(-(-b // cap)):
-      acc = tree_util.tree_map(merge, acc, round_out(k * cap))
-    return acc
   rounds = drain_rounds(meta, n_shards, cap, axis_name)
 
   def body(state):
     k, acc = state
-    return k + 1, tree_util.tree_map(merge, acc, round_out(k * cap))
+    return k + 1, jax.tree.map(merge, acc, round_out(k * cap))
 
   _, acc = jax.lax.while_loop(lambda s: s[0] < rounds, body,
                               (jnp.zeros((), jnp.int32), zeros))

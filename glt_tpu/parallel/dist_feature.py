@@ -284,7 +284,7 @@ class ShardedFeature:
     if cap >= b:
       return round_out(0)  # a single uncapped round serves everything
     return capped_drain(
-        round_out, meta, n_shards, cap, b, ax,
+        round_out, meta, n_shards, cap, ax,
         jnp.zeros((b, self.feature_dim), local_shard.dtype))
 
   def _cold_values_host(self, nodes: np.ndarray, valid: np.ndarray):

@@ -32,3 +32,13 @@ def replicated(mesh: Mesh) -> NamedSharding:
 
 def row_sharded(mesh: Mesh, axis: str = 'data') -> NamedSharding:
   return NamedSharding(mesh, P(axis))
+
+
+def replicate(tree, mesh: Mesh):
+  """Place every leaf of ``tree`` replicated over ``mesh``; a leaf that
+  already is comes back as it is. The train steps pass params and
+  optimizer state through this on entry: jax keys its trace cache on
+  the mesh a value was placed with, so the state ``tx.init`` builds
+  (its step count is a scalar made without a mesh) and the state a step
+  returns would otherwise compile the same program twice."""
+  return jax.device_put(tree, replicated(mesh))

@@ -227,9 +227,7 @@ class NeighborSampler(BaseSampler):
     """Once-per-(sampler, reason) engine-fallback accounting — the
     event is a property of the sampler's configuration, so repeating it
     per hop/call would just inflate the counter. The ``requested``
-    label carries what the operator actually asked for (``auto`` when
-    the backend-aware default resolved to the fused engine), so a
-    dashboard can tell a deliberate engine request from a default."""
+    label carries what the operator actually asked for."""
     if reason not in self._fallbacks_counted:
       self._fallbacks_counted.add(reason)
       requested = knob('GLT_HOP_ENGINE', 'auto')

@@ -918,7 +918,7 @@ def test_fused_walk_mode_knob(monkeypatch):
 def test_hop_engine_knob_accepts_pallas_fused(monkeypatch):
   from glt_tpu.ops.pipeline import dedup_engine, hop_engine
   monkeypatch.setenv('GLT_HOP_ENGINE', 'pallas_fused')
-  assert hop_engine() in ('pallas_fused', 'window')
+  assert hop_engine() == 'pallas_fused'
   # the fused engine implies the sort dedup contract under auto
   monkeypatch.delenv('GLT_DEDUP', raising=False)
   assert dedup_engine() == 'sort'

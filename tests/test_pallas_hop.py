@@ -232,6 +232,6 @@ def test_hop_engine_knob_validation(monkeypatch):
   with pytest.raises(ValueError):
     hop_engine()
   monkeypatch.setenv('GLT_HOP_ENGINE', 'pallas')
-  assert hop_engine() in ('pallas', 'window')  # window iff no pallas
+  assert hop_engine() == 'pallas'
   monkeypatch.delenv('GLT_HOP_ENGINE')
   assert hop_engine() == 'element'
