@@ -1,0 +1,1 @@
+"""The benchmark: see BENCHMARK.json at the root and PERF.md."""
