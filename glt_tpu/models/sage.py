@@ -54,11 +54,14 @@ class GraphSAGE(nn.Module):
         r, c, m = row[:end], col[:end], mask[:end]
       else:
         r, c, m = row, col, mask
-      x = _CONVS[self.conv](dim, i)(x, r, c, m)
-      if i < self.num_layers - 1:
-        x = nn.relu(x)
-        if self.dropout > 0:
-          x = nn.Dropout(self.dropout, deterministic=not train)(x)
+      # one scope a layer, its activation included, so that a device
+      # trace tells the layers apart (obs/device.py reads the labels)
+      with jax.named_scope(f'conv{i}'):
+        x = _CONVS[self.conv](dim, i)(x, r, c, m)
+        if i < self.num_layers - 1:
+          x = nn.relu(x)
+          if self.dropout > 0:
+            x = nn.Dropout(self.dropout, deterministic=not train)(x)
     if return_all:
       return x
     return x[:batch.batch_size]

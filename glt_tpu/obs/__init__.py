@@ -27,7 +27,13 @@ observations stop (plain registry counters keep counting — exposition
 is independent of the tracing knob); the tier-1 overhead test pins the
 no-op path below 2% of a sampled epoch.
 
-Two further pieces ride the same registry/tracer surfaces:
+Three further pieces ride the same registry/tracer surfaces:
+
+  * :mod:`device` — device time by layer from inside a compiled step:
+    the layer-named ``jax.named_scope`` convention (``LAYERS``,
+    ``scope``), ``reduce_scopes`` from a profiler trace to ms a step by
+    layer and stage, and ``scope_profile``, the session that takes the
+    trace around a step program's own calls.
 
   * :mod:`perf` — XLA cost accounting (``compiles_total{fn}``,
     ``xla_flops``/``xla_bytes_accessed``/``xla_peak_bytes`` via the

@@ -11,9 +11,10 @@ file").
 Three bridges make the host spans useful on an accelerator machine:
 
   * **device annotation** — every span also enters
-    ``jax.profiler.TraceAnnotation`` (the :func:`glt_tpu.utils.profile.
-    annotate` region), so when an XLA profiler trace is active the host
-    stages line up against the device timeline;
+    ``jax.profiler.TraceAnnotation``, so when an XLA profiler trace is
+    active the host stages line up against the device timeline
+    (:func:`glt_tpu.obs.device.scope_profile` names a device's idle
+    gaps after them);
   * **device-sync sampling** — JAX dispatch is async, so a host span
     around a jitted call measures dispatch, not compute. A span given
     ``sync=<arrays>`` calls ``jax.block_until_ready`` on exit for a

@@ -310,9 +310,10 @@ def multihop_sample(one_hop: OneHopFn,
   for hop_idx, fanout in enumerate(fanouts):
     width = abs(fanout)  # negative = full-neighborhood hop, window |k|
     key, sub = jax.random.split(key)
-    # named_scope: trace-time-only labels so device profiler traces
-    # (jax.profiler / xprof) break the fused program down by pipeline
-    # stage — the in-jit counterpart of the host-side obs spans
+    # named_scope: trace-time-only labels, kept as the op_name of every
+    # HLO instruction. A step program nests them under its ``sampler``
+    # scope, and obs/device.py::reduce_scopes sums a device trace by
+    # them (``sampler/sample_hop0``, ``sampler/dedup0``, ...)
     with jax.named_scope(f'sample_hop{hop_idx}'):
       out = one_hop(frontier_ids, fanout, sub, frontier_mask)
     prev_count = state.count

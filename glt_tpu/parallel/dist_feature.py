@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..obs.device import scope
 from ..utils import as_numpy
 
 
@@ -216,28 +217,25 @@ class ShardedFeature:
     ax = axis_name or self.axis
     n_shards = self.mesh.shape[self.axis]
     b = ids.shape[0]
-    owner = jnp.clip(ids // self.rows_per_shard, 0, n_shards - 1)
-    owner = jnp.where(valid, owner, n_shards)  # pads sort last
-    order = jnp.argsort(owner, stable=True)    # group requests by owner
-    owner_sorted = jnp.take(owner, order)
-    counts = jnp.bincount(jnp.minimum(owner_sorted, n_shards),
-                          length=n_shards + 1)[:n_shards]
-    offsets = jnp.cumsum(counts) - counts
-    pos_in_bucket = jnp.arange(b) - jnp.take(
-        offsets, jnp.minimum(owner_sorted, n_shards - 1))
-    meta = BucketMeta(order, owner_sorted, pos_in_bucket)
+    store = lambda stage: scope('feature_store', stage)
+    with store('bucket'):
+      owner = jnp.clip(ids // self.rows_per_shard, 0, n_shards - 1)
+      owner = jnp.where(valid, owner, n_shards)  # pads sort last
+      order = jnp.argsort(owner, stable=True)    # group requests by owner
+      owner_sorted = jnp.take(owner, order)
+      counts = jnp.bincount(jnp.minimum(owner_sorted, n_shards),
+                            length=n_shards + 1)[:n_shards]
+      offsets = jnp.cumsum(counts) - counts
+      pos_in_bucket = jnp.arange(b) - jnp.take(
+          offsets, jnp.minimum(owner_sorted, n_shards - 1))
+      meta = BucketMeta(order, owner_sorted, pos_in_bucket)
     # fixed-capacity request buckets [n_shards, C] (C = B by default)
     cap = (self.bucket_cap if 0 < self.bucket_cap < b else b)
 
-    def round_out(base):
-      """One bucket-exchange-serve-unbucket pass over the requests
-      ranked [base, base+cap) per bucket; other lanes come back 0."""
-      req = bucket_payload(ids, meta, n_shards, fill_value=-1,
-                           capacity=cap, round_offset=base)
-      # exchange requests: row p of the result = what peer p asked us
-      req_in = all_to_all(req, ax)
-      # serve from the local block (hot rows only when spilling; cold
-      # lanes return zero and the host phase in lookup() fills them)
+    def serve(req_in):
+      """Rows of the local block for the requests peers sent (hot rows
+      only when spilling; cold lanes return zero and the host phase in
+      lookup() fills them)."""
       my_index = jax.lax.axis_index(ax)
       local_rows = req_in - my_index * self.rows_per_shard
       ok = (local_rows >= 0) & (local_rows < self.hot_count) & \
@@ -275,11 +273,26 @@ class ShardedFeature:
                 cold_rows_idx.shape + (self.feature_dim,))
         served = jnp.where(cold_ok[..., None],
                            cold_out.astype(served.dtype), served)
-      # responses back; row p now holds our requests served by peer p
-      resp = all_to_all(served, ax)
-      resp = resp.reshape(n_shards, cap, self.feature_dim)
-      # positional stitch back to request order
-      return unbucket(resp, meta, n_shards, round_offset=base)
+      return served
+
+    def round_out(base):
+      """One bucket-exchange-serve-unbucket pass over the requests
+      ranked [base, base+cap) per bucket; other lanes come back 0."""
+      with store('bucket'):
+        req = bucket_payload(ids, meta, n_shards, fill_value=-1,
+                             capacity=cap, round_offset=base)
+      with store('exchange'):
+        # exchange requests: row p of the result = what peer p asked us
+        req_in = all_to_all(req, ax)
+      with store('serve'):
+        served = serve(req_in)
+      with store('exchange'):
+        # responses back; row p now holds our requests served by peer p
+        resp = all_to_all(served, ax)
+      with store('unbucket'):
+        resp = resp.reshape(n_shards, cap, self.feature_dim)
+        # positional stitch back to request order
+        return unbucket(resp, meta, n_shards, round_offset=base)
 
     if cap >= b:
       return round_out(0)  # a single uncapped round serves everything

@@ -50,6 +50,7 @@ from ..ops.sample import sample_neighbors
 from ..ops.pipeline import make_dedup_tables
 from ..ops.superstep import scan_consume, superstep as build_superstep
 from ..loader.transform import Batch
+from ..obs.device import register_step_program, scope
 from .mesh import replicate
 
 
@@ -58,19 +59,23 @@ def _sage_update(model, tx, axis, bs, params, opt_state, batch, n_valid):
   the training tail shared by the per-batch, fused-superstep and
   streaming-consume bodies (identical op sequence = loss parity)."""
   def loss_fn(p):
-    logits = model.apply(p, batch)
-    mask = jnp.arange(bs) < n_valid
-    losses = optax.softmax_cross_entropy_with_integer_labels(
-        logits, batch.y)
-    return (jnp.where(mask, losses, 0).sum()
-            / jnp.maximum(mask.sum(), 1))
+    with jax.named_scope('forward'):
+      logits = model.apply(p, batch)
+      mask = jnp.arange(bs) < n_valid
+      losses = optax.softmax_cross_entropy_with_integer_labels(
+          logits, batch.y)
+      return (jnp.where(mask, losses, 0).sum()
+              / jnp.maximum(mask.sum(), 1))
 
-  loss, grads = jax.value_and_grad(loss_fn)(params)
-  # DDP allreduce (mean over devices), riding ICI
-  grads = jax.lax.pmean(grads, axis)
-  loss = jax.lax.pmean(loss, axis)
-  updates, opt_state = tx.update(grads, opt_state, params)
-  params = optax.apply_updates(params, updates)
+  with scope('model_step'):
+    loss, grads = jax.value_and_grad(loss_fn)(params)
+  with scope('collectives', 'grad_sync'):
+    # DDP allreduce (mean over devices), riding ICI
+    grads = jax.lax.pmean(grads, axis)
+    loss = jax.lax.pmean(loss, axis)
+  with scope('model_step', 'update'):
+    updates, opt_state = tx.update(grads, opt_state, params)
+    params = optax.apply_updates(params, updates)
   return params, opt_state, loss
 
 
@@ -148,6 +153,7 @@ class SPMDSageTrainStep:
     if self._streaming:
       self._sample_fn = self._build_sample_superstep()
       self._consume_fn = self._build_consume_superstep()
+    register_step_program(self)
 
   def init_params(self, key) -> dict:
     batch = self._dummy_batch()
@@ -185,15 +191,17 @@ class SPMDSageTrainStep:
         indptr, indices, ids, fanout, k, seed_mask=mask)
 
     def body(params, opt_state, table, scratch, seeds, n_valid, key):
-      key = jax.random.fold_in(key[0], jax.lax.axis_index(axis))
-      out, table, scratch = multihop_sample(
-          one_hop, seeds, n_valid[0], fanouts, key, table, scratch,
-          with_edge=with_edge)
-      node_valid = jnp.arange(out['node'].shape[0]) < out['node_count']
-      x = feature.lookup_local(
-          feat_shard, jnp.maximum(out['node'], 0), node_valid,
-          axis_name=axis, cold_shard=cold_shard)
-      y = jnp.take(labels, jnp.maximum(out['batch'], 0)[:bs])
+      with scope('sampler'):
+        key = jax.random.fold_in(key[0], jax.lax.axis_index(axis))
+        out, table, scratch = multihop_sample(
+            one_hop, seeds, n_valid[0], fanouts, key, table, scratch,
+            with_edge=with_edge)
+      with scope('feature_store'):
+        node_valid = jnp.arange(out['node'].shape[0]) < out['node_count']
+        x = feature.lookup_local(
+            feat_shard, jnp.maximum(out['node'], 0), node_valid,
+            axis_name=axis, cold_shard=cold_shard)
+        y = jnp.take(labels, jnp.maximum(out['batch'], 0)[:bs])
       batch = Batch(
           x=x, row=out['row'], col=out['col'], edge_mask=out['edge_mask'],
           node=out['node'], node_count=out['node_count'], y=y,
@@ -363,10 +371,11 @@ class SPMDSageTrainStep:
       def body(carry, x):
         table, scratch = carry
         seeds, n_valid, key = x
-        key = jax.random.fold_in(key[0], jax.lax.axis_index(axis))
-        out, table, scratch = multihop_sample(
-            one_hop, seeds, n_valid[0], fanouts, key, table, scratch,
-            with_edge=with_edge)
+        with scope('sampler'):
+          key = jax.random.fold_in(key[0], jax.lax.axis_index(axis))
+          out, table, scratch = multihop_sample(
+              one_hop, seeds, n_valid[0], fanouts, key, table, scratch,
+              with_edge=with_edge)
         keep = dict(node=out['node'], node_count=out['node_count'][None],
                     row=out['row'], col=out['col'],
                     edge_mask=out['edge_mask'])
@@ -415,12 +424,13 @@ class SPMDSageTrainStep:
         params, opt_state = carry
         out, cold_t, n_valid = x
         node_count = out['node_count'][0]
-        node_valid = jnp.arange(budget) < node_count
-        xh = feature.lookup_local(
-            feat_shard, jnp.maximum(out['node'], 0), node_valid,
-            axis_name=axis)
-        x_feat = xh + cold_t.astype(xh.dtype)
-        y = jnp.take(labels, jnp.maximum(out['node'], 0)[:bs])
+        with scope('feature_store'):
+          node_valid = jnp.arange(budget) < node_count
+          xh = feature.lookup_local(
+              feat_shard, jnp.maximum(out['node'], 0), node_valid,
+              axis_name=axis)
+          x_feat = xh + cold_t.astype(xh.dtype)
+          y = jnp.take(labels, jnp.maximum(out['node'], 0)[:bs])
         batch = Batch(
             x=x_feat, row=out['row'], col=out['col'],
             edge_mask=out['edge_mask'], node=out['node'],
@@ -545,26 +555,39 @@ class SPMDSageTrainStep:
           'cold_streaming stores run through superstep()/run_epoch(); '
           'the per-batch step cannot resolve host-spilled rows '
           'in-program')
-    n_dev = self.mesh.shape[self.axis]
-    seeds = jax.device_put(
-        jnp.asarray(seeds, jnp.int32),
-        NamedSharding(self.mesh, P(self.axis)))
-    n_valid = jax.device_put(
-        jnp.asarray(n_valid_per_device, jnp.int32),
-        NamedSharding(self.mesh, P(self.axis)))
-    params, opt_state = replicate((params, opt_state), self.mesh)
-    extra = ((self.feature.cold_array,)
-             if self.feature.cold_array is not None else ())
     from ..obs import get_registry, get_tracer
     tracer = get_tracer()
     _synced = {}
+    # the children are where the host spends a step's time; an idle gap
+    # of the device is named after the one that covers it
     with tracer.span('train.step', sync=lambda: _synced.get('loss')):
-      (params, opt_state, self.tables, self.scratches,
-       loss) = self._step_fn(
-           params, opt_state, self.tables, self.scratches, seeds,
-           n_valid, keys, self.feature.array, self.labels, self._indptr,
-           self._indices, *extra)
+      with tracer.span('train.step/put'):
+        seeds = jax.device_put(
+            jnp.asarray(seeds, jnp.int32),
+            NamedSharding(self.mesh, P(self.axis)))
+        n_valid = jax.device_put(
+            jnp.asarray(n_valid_per_device, jnp.int32),
+            NamedSharding(self.mesh, P(self.axis)))
+        params, opt_state = replicate((params, opt_state), self.mesh)
+      extra = ((self.feature.cold_array,)
+               if self.feature.cold_array is not None else ())
+      with tracer.span('train.step/dispatch'):
+        (params, opt_state, self.tables, self.scratches,
+         loss) = self._step_fn(
+             params, opt_state, self.tables, self.scratches, seeds,
+             n_valid, keys, self.feature.array, self.labels,
+             self._indptr, self._indices, *extra)
       _synced['loss'] = loss
     if tracer.enabled:
       get_registry().set('train_step_traces', float(self.step_traces))
     return params, opt_state, loss
+
+  def scope_profile(self, params, opt_state, batches) -> dict:
+    """Device time by layer of the per-batch step, from a profiler
+    session of its own: drives ``self(params, opt_state, *batch)`` over
+    ``batches`` (an iterable of ``(seeds, n_valid, keys)``) and returns
+    what ``obs.device.reduce_scopes`` makes of the trace. The state it
+    is given is stepped and thrown away."""
+    from ..obs.device import scope_profile
+    return scope_profile(self, params, opt_state, batches,
+                         step_program='jit_step')

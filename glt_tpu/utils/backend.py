@@ -83,9 +83,16 @@ def configure_compile_cache() -> str:
   and nothing is set here, so the directory can be placed from outside
   the program. Otherwise the cache lives at ``<checkout>/.jax_cache``.
   The path is part of the cache key, so it is never a temporary name.
+  So is a program's metadata (see below).
   """
   import jax
   if not raw_env('JAX_COMPILATION_CACHE_DIR'):
     jax.config.update('jax_compilation_cache_dir',
                       os.path.join(_CHECKOUT, '.jax_cache'))
+  # JAX strips locations and scope names before it hashes a program, so
+  # a cached executable would keep the ``op_name``s it was compiled
+  # with, and ``obs/device.py`` would read the scopes of an older tree
+  # out of a trace. Keyed on the metadata too, an edit that moves a
+  # scope or a line compiles once more.
+  jax.config.update('jax_compilation_cache_include_metadata_in_key', True)
   return jax.config.jax_compilation_cache_dir

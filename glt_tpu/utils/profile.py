@@ -3,12 +3,12 @@
 The reference measures throughput with manual time.time() +
 cuda.synchronize in bench scripts (SURVEY.md §5.1) and has no built-in
 tracer. Here timing hooks are first-class: a ThroughputMeter for the
-sampled-edges/sec north-star metric, a device-synchronizing Timer, and a
-context manager around the XLA profiler for real traces.
+sampled-edges/sec north-star metric and a device-synchronizing Timer.
+Traces are ``glt_tpu.obs``'s: host spans by ``obs.Tracer``, device time by
+layer by ``obs.device.scope_profile``.
 """
 from __future__ import annotations
 
-import contextlib
 import time
 from typing import Optional
 
@@ -86,20 +86,3 @@ class ThroughputMeter:
     if r >= 1e3:
       return f'{r / 1e3:.2f}K {self.unit}/s'
     return f'{r:.2f} {self.unit}/s'
-
-
-@contextlib.contextmanager
-def trace(log_dir: str):
-  """XLA profiler trace (view with tensorboard / xprof)."""
-  jax.profiler.start_trace(log_dir)
-  try:
-    yield
-  finally:
-    jax.profiler.stop_trace()
-
-
-@contextlib.contextmanager
-def annotate(name: str):
-  """Named region inside a trace."""
-  with jax.profiler.TraceAnnotation(name):
-    yield

@@ -18,15 +18,18 @@ def test_compile_cache_dir_is_fixed_or_left_to_the_environment(
   monkeypatch.setattr(jax.config, 'update',
                       lambda name, value: updates.append((name, value)))
   monkeypatch.delenv('JAX_COMPILATION_CACHE_DIR', raising=False)
+  # scope names are part of the key wherever the cache lives: a cached
+  # executable must not carry an older tree's (obs/device.py reads them)
+  keyed = ('jax_compilation_cache_include_metadata_in_key', True)
   configure_compile_cache()
   assert updates == [('jax_compilation_cache_dir',
-                      os.path.join(REPO, '.jax_cache'))]
+                      os.path.join(REPO, '.jax_cache')), keyed]
   # with the variable set, JAX has read it itself at start-up and the
   # helper sets no directory in code
   del updates[:]
   monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', '/somewhere/else')
   configure_compile_cache()
-  assert updates == []
+  assert updates == [keyed]
 
 
 def test_chip_smoke_refuses_to_run_without_a_tpu():
