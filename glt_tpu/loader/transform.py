@@ -38,6 +38,12 @@ class Batch:
   batch_size: int = flax.struct.field(pytree_node=False, default=0)
   edge_hop_offsets: Optional[tuple] = flax.struct.field(
       pytree_node=False, default=None)
+  #: static; ``node_hop_offsets[h]`` leading node slots hold every node
+  #: within h hops of a seed (ops.pipeline.node_hop_offsets). A promise
+  #: of the producer that labels are hop-compact: models trim the nodes
+  #: by it as they trim the edges by edge_hop_offsets. None: no promise.
+  node_hop_offsets: Optional[tuple] = flax.struct.field(
+      pytree_node=False, default=None)
 
   @property
   def edge_index(self) -> jax.Array:
@@ -101,6 +107,8 @@ def to_batch(out: SamplerOutput,
       else (out.batch.shape[0] if out.batch is not None else 0),
       edge_hop_offsets=tuple(out.edge_hop_offsets)
       if out.edge_hop_offsets else None,
+      node_hop_offsets=tuple(out.node_hop_offsets)
+      if out.node_hop_offsets else None,
   )
 
 

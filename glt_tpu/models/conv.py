@@ -6,6 +6,12 @@ implementations of the same math, designed for the framework's padded
 static-shape batches: invalid edge slots are routed to a sacrificial
 segment so aggregation is one masked segment_sum — no dynamic shapes, and
 the feature matmuls stay dense on the MXU.
+
+Every convolution takes ``num_out``, a static int: it computes output rows
+for the first ``num_out`` nodes only (``None``: all of ``x``). Children are
+still read from every row of ``x``; an edge whose parent lies beyond
+``num_out`` is masked. models/sage.py passes the hop prefix a later layer
+reads (``Batch.node_hop_offsets``).
 """
 from __future__ import annotations
 
@@ -56,17 +62,19 @@ class SAGEConv(nn.Module):
 
   @nn.compact
   def __call__(self, x: jax.Array, row: jax.Array, col: jax.Array,
-               edge_mask: jax.Array) -> jax.Array:
+               edge_mask: jax.Array, num_out=None) -> jax.Array:
     n = x.shape[0]
+    m = n if num_out is None else num_out
     safe_row = jnp.clip(row, 0, n - 1)
     msgs = jnp.take(x, safe_row, axis=0)
-    agg = _AGGRS[self.aggr](msgs, jnp.clip(col, 0, n - 1),
-                            edge_mask & (row >= 0) & (col >= 0), n)
+    agg = _AGGRS[self.aggr](
+        msgs, jnp.clip(col, 0, m - 1),
+        edge_mask & (row >= 0) & (col >= 0) & (col < m), m)
     lin_nbr = nn.Dense(self.out_features, use_bias=False,
                        param_dtype=self.param_dtype, name='lin_nbr')
     lin_root = nn.Dense(self.out_features, use_bias=self.use_bias,
                         param_dtype=self.param_dtype, name='lin_root')
-    return lin_root(x) + lin_nbr(agg)
+    return lin_root(x[:m]) + lin_nbr(agg)
 
 
 class GATConv(nn.Module):
@@ -79,10 +87,12 @@ class GATConv(nn.Module):
   param_dtype: jnp.dtype = jnp.float32
 
   @nn.compact
-  def __call__(self, x, row, col, edge_mask):
+  def __call__(self, x, row, col, edge_mask, num_out=None):
     n = x.shape[0]
+    m = n if num_out is None else num_out
     h, f = self.heads, self.out_features
-    ok = edge_mask & (row >= 0) & (col >= 0)
+    ok = edge_mask & (row >= 0) & (col >= 0) & (col < m)
+    # children are read from the projection, so it covers every row
     proj = nn.Dense(h * f, use_bias=False, param_dtype=self.param_dtype,
                     name='proj')(x).reshape(n, h, f)
     att_src = self.param('att_src', nn.initializers.glorot_uniform(),
@@ -94,19 +104,19 @@ class GATConv(nn.Module):
     logit = nn.leaky_relu(
         (src * att_src).sum(-1) + (dst * att_dst).sum(-1),
         negative_slope=self.negative_slope)                 # [E, h]
-    seg = jnp.where(ok, col, n)
+    seg = jnp.where(ok, col, m)
     # numerically-stable masked segment softmax over each parent
     seg_max = jax.ops.segment_max(
-        jnp.where(ok[:, None], logit, -jnp.inf), seg, n + 1)
+        jnp.where(ok[:, None], logit, -jnp.inf), seg, m + 1)
     seg_max = jnp.where(jnp.isfinite(seg_max), seg_max, 0.0)
-    z = jnp.exp(logit - seg_max[jnp.clip(seg, 0, n)])
+    z = jnp.exp(logit - seg_max[jnp.clip(seg, 0, m)])
     z = jnp.where(ok[:, None], z, 0.0)
-    denom = jax.ops.segment_sum(z, seg, n + 1)
-    alpha = z / jnp.maximum(denom[jnp.clip(seg, 0, n)], 1e-16)  # [E, h]
+    denom = jax.ops.segment_sum(z, seg, m + 1)
+    alpha = z / jnp.maximum(denom[jnp.clip(seg, 0, m)], 1e-16)  # [E, h]
     out = jax.ops.segment_sum(
-        src * alpha[:, :, None], seg, n + 1)[:n]            # [n, h, f]
+        src * alpha[:, :, None], seg, m + 1)[:m]            # [m, h, f]
     if self.concat:
-      return out.reshape(n, h * f)
+      return out.reshape(m, h * f)
     return out.mean(axis=1)
 
 
@@ -118,25 +128,30 @@ class GCNConv(nn.Module):
   param_dtype: jnp.dtype = jnp.float32
 
   @nn.compact
-  def __call__(self, x, row, col, edge_mask):
+  def __call__(self, x, row, col, edge_mask, num_out=None):
     n = x.shape[0]
-    ok = edge_mask & (row >= 0) & (col >= 0)
+    m = n if num_out is None else num_out
+    ok = edge_mask & (row >= 0) & (col >= 0) & (col < m)
+    # children are read from h, so it covers every row
     h = nn.Dense(self.out_features, use_bias=False,
                  param_dtype=self.param_dtype, name='lin')(x)
     ones = ok.astype(h.dtype)
-    seg_in = jnp.where(ok, col, n)
+    seg_in = jnp.where(ok, col, m)
     # PyG GCN semantics: both endpoints are normalized by the in-degree
     # of the self-loop-augmented graph (deg_in includes the +1 loop), and
     # the self-loop term below uses 1/deg_in — models ported from the
     # reference match numerically.
-    deg_in = jax.ops.segment_sum(ones, seg_in, n + 1)[:n] + 1.0
-    norm = (jnp.take(deg_in, jnp.clip(row, 0, n - 1)) ** -0.5
-            * jnp.take(deg_in, jnp.clip(col, 0, n - 1)) ** -0.5)
+    deg_in = jax.ops.segment_sum(ones, seg_in, m + 1)[:m] + 1.0
+    # a child beyond num_out is the parent of no edge: its self-loop's 1
+    deg_row = jnp.where(row < m,
+                        jnp.take(deg_in, jnp.clip(row, 0, m - 1)), 1.0)
+    norm = (deg_row ** -0.5
+            * jnp.take(deg_in, jnp.clip(col, 0, m - 1)) ** -0.5)
     msgs = jnp.take(h, jnp.clip(row, 0, n - 1), axis=0) * norm[:, None]
     agg = jax.ops.segment_sum(
-        jnp.where(ok[:, None], msgs, 0.0), seg_in, n + 1)[:n]
+        jnp.where(ok[:, None], msgs, 0.0), seg_in, m + 1)[:m]
     # self-loop term with its own normalization
-    agg = agg + h / deg_in[:, None]
+    agg = agg + h[:m] / deg_in[:, None]
     if self.use_bias:
       agg = agg + self.param('bias', nn.initializers.zeros,
                              (self.out_features,), self.param_dtype)

@@ -3,9 +3,19 @@
 Reference workloads: examples/train_sage_ogbn_products.py (supervised
 SAGE), examples/graph_sage_unsup_ppi.py (unsupervised link-pred SAGE).
 Hop-trimming (`trim_to_layer`, examples/train_sage_prod_with_trim.py) is
-built in: with ``trim=True`` layer l only processes the edge slots of the
-hops it still needs — a *static* slice thanks to edge_hop_offsets, so
-trimming costs zero recompilation and shrinks every matmul.
+built in. With ``trim=True`` layer l only processes the edge slots of the
+hops it still needs, a *static* slice thanks to ``edge_hop_offsets``. Where
+the batch also carries ``node_hop_offsets`` (the producer's promise that
+labels are hop-compact) and only the seed rows are asked for, layer l also
+computes output rows only for the nodes a later layer reads: the static
+prefix within ``num_layers - 1 - l`` hops of a seed, which cuts the
+aggregation target and the layer's matmuls (GCN's and GAT's input
+projection stays over every row, since children are read from it). Both
+slices are static, so trimming costs no recompilation. On the benchmark's
+cells (3 layers, fanout 15,10,5, 1024 seeds a chip) the node trim took the
+model's device time a step from 111.2 ms to 51.7 ms and a step from 217.1 ms
+to 156.2 ms on one chip (my chip runs, PR 26; the driver's numbers are
+PERF_LEDGER.jsonl's PR 26 lines, the split is in PERF.md, section 5).
 """
 from __future__ import annotations
 
@@ -42,6 +52,7 @@ class GraphSAGE(nn.Module):
     row, col, mask = batch.row, batch.col, batch.edge_mask
     offsets = batch.edge_hop_offsets
     num_hops = len(offsets) - 1 if offsets else self.num_layers
+    rows = self.layer_rows(batch, return_all)
     for i in range(self.num_layers):
       dim = (self.hidden_features if i < self.num_layers - 1
              else self.out_features)
@@ -57,7 +68,7 @@ class GraphSAGE(nn.Module):
       # one scope a layer, its activation included, so that a device
       # trace tells the layers apart (obs/device.py reads the labels)
       with jax.named_scope(f'conv{i}'):
-        x = _CONVS[self.conv](dim, i)(x, r, c, m)
+        x = _CONVS[self.conv](dim, i)(x, r, c, m, num_out=rows[i])
         if i < self.num_layers - 1:
           x = nn.relu(x)
           if self.dropout > 0:
@@ -65,6 +76,20 @@ class GraphSAGE(nn.Module):
     if return_all:
       return x
     return x[:batch.batch_size]
+
+  @nn.nowrap
+  def layer_rows(self, batch: Batch, return_all: bool = False) -> tuple:
+    """Output rows each layer computes, static ints. Layer i's output is
+    read by num_layers-1-i later propagations, so only for the nodes
+    within that many hops of a seed: ``node_hop_offsets`` makes them a
+    prefix. Every row where the batch does not carry both offset tuples,
+    ``trim`` is off or all rows are asked for."""
+    offsets, node_offsets = batch.edge_hop_offsets, batch.node_hop_offsets
+    if not (self.trim and offsets and node_offsets) or return_all:
+      return (batch.x.shape[0],) * self.num_layers
+    num_hops = len(offsets) - 1
+    return tuple(node_offsets[min(num_hops, self.num_layers - 1 - i)]
+                 for i in range(self.num_layers))
 
   def embed(self, batch: Batch, train: bool = False) -> jax.Array:
     """Embeddings for ALL sampled nodes (link/unsupervised tasks index

@@ -63,6 +63,21 @@ def count_compile(fn: str,
     pass
 
 
+def gauge_layer_rows(fn: str, rows,
+                     registry: Optional[MetricsRegistry] = None) -> None:
+  """Trace-time hook beside :func:`count_compile`: publish the output
+  rows each layer of program ``fn``'s model computes, as
+  ``model_layer_rows{fn=..., layer=i}``. The node trim of
+  models/sage.py engages when a program is traced, so its counter is
+  static too: set once a trace, no host work a step."""
+  try:
+    reg = registry or get_registry()
+    for i, n in enumerate(rows):
+      reg.set('model_layer_rows', float(n), fn=str(fn), layer=str(i))
+  except Exception:  # accounting must never break a trace
+    pass
+
+
 def compile_counts(registry: Optional[MetricsRegistry] = None) -> dict:
   """{fn: count} view over ``compiles_total`` — the assertable surface
   (tests pin a label's count flat across steady-state traffic)."""

@@ -246,6 +246,17 @@ def edge_hop_offsets(batch_size: int, fanouts: Sequence[int]) -> List[int]:
   return offs
 
 
+def node_hop_offsets(batch_size: int, fanouts: Sequence[int]) -> List[int]:
+  """``node_hop_offsets[h]`` leading node slots hold every node within
+  ``h`` hops of a seed. Every engine below hands labels out in order of
+  first appearance, hop by hop, so a node first seen at hop ``h`` has a
+  label under the budget of the first ``h`` hops: a static prefix of the
+  node buffer, as :func:`edge_hop_offsets` is of the edge slots
+  (tests/test_node_trim.py pins it for each engine)."""
+  return [sample_budget(batch_size, fanouts[:h])
+          for h in range(len(fanouts) + 1)]
+
+
 def multihop_sample(one_hop: OneHopFn,
                     seeds: jax.Array,
                     n_valid: jax.Array,

@@ -45,7 +45,8 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..data import Graph
-from ..ops.pipeline import edge_hop_offsets, multihop_sample, sample_budget
+from ..ops.pipeline import edge_hop_offsets, multihop_sample, \
+    node_hop_offsets, sample_budget
 from ..ops.sample import sample_neighbors
 from ..ops.pipeline import make_dedup_tables
 from ..ops.superstep import scan_consume, superstep as build_superstep
@@ -123,6 +124,13 @@ class SPMDSageTrainStep:
     self.bs = batch_size_per_device
     self.axis = axis
     self.with_edge = bool(with_edge)
+    #: the static fields of every Batch the step builds: the hop
+    #: boundaries of the edge slots and of the node labels (the sampler
+    #: hands labels out hop by hop), by which the model trims both
+    self._batch_static = dict(
+        batch_size=self.bs,
+        edge_hop_offsets=tuple(edge_hop_offsets(self.bs, self.fanouts)),
+        node_hop_offsets=tuple(node_hop_offsets(self.bs, self.fanouts)))
     graph.lazy_init()
     self.labels = jax.device_put(labels, NamedSharding(mesh, P()))
     # one-time replication of the topology over the mesh: these ride
@@ -148,6 +156,10 @@ class SPMDSageTrainStep:
     #: once more by design.
     self.step_traces = 0
     self.superstep_traces = 0
+    #: output rows each layer of the model computes in the step, filled
+    #: when a program is traced (static: the node trim engages at trace
+    #: time); None before, and for a model that does not say
+    self.layer_rows = None
     self._step_fn = self._build()
     self._superstep_fn = self._build_superstep()
     if self._streaming:
@@ -162,7 +174,7 @@ class SPMDSageTrainStep:
 
   def _dummy_batch(self) -> Batch:
     budget = sample_budget(self.bs, self.fanouts)
-    ecap = edge_hop_offsets(self.bs, self.fanouts)[-1]
+    ecap = self._batch_static['edge_hop_offsets'][-1]
     return Batch(
         x=jnp.zeros((budget, self.feature.feature_dim)),
         row=jnp.zeros((ecap,), jnp.int32),
@@ -170,10 +182,17 @@ class SPMDSageTrainStep:
         edge_mask=jnp.zeros((ecap,), bool),
         node=jnp.zeros((budget,), jnp.int32),
         node_count=jnp.zeros((), jnp.int32),
-        y=jnp.zeros((self.bs,), jnp.int32),
-        batch_size=self.bs,
-        edge_hop_offsets=tuple(edge_hop_offsets(self.bs, self.fanouts)),
-    )
+        y=jnp.zeros((self.bs,), jnp.int32), **self._batch_static)
+
+  def _note_layer_rows(self, batch: Batch) -> None:
+    """Trace-time side effect, as ``step_traces``: what the node trim
+    leaves each layer to compute, on the attribute and as the gauge
+    ``model_layer_rows{fn="train.step", layer=i}``."""
+    rows_of = getattr(self.model, 'layer_rows', None)
+    if rows_of is not None:
+      from ..obs.perf import gauge_layer_rows
+      self.layer_rows = rows_of(batch)
+      gauge_layer_rows('train.step', self.layer_rows)
 
   # -- shared per-batch body ----------------------------------------------
 
@@ -185,7 +204,6 @@ class SPMDSageTrainStep:
     engines stay bit-identical."""
     feature, model, tx, axis = self.feature, self.model, self.tx, self.axis
     fanouts, bs = self.fanouts, self.bs
-    offs = tuple(edge_hop_offsets(bs, fanouts))
     with_edge = self.with_edge
     one_hop = lambda ids, fanout, k, mask: sample_neighbors(
         indptr, indices, ids, fanout, k, seed_mask=mask)
@@ -205,8 +223,8 @@ class SPMDSageTrainStep:
       batch = Batch(
           x=x, row=out['row'], col=out['col'], edge_mask=out['edge_mask'],
           node=out['node'], node_count=out['node_count'], y=y,
-          edge=out.get('edge'),
-          batch_size=bs, edge_hop_offsets=offs)
+          edge=out.get('edge'), **self._batch_static)
+      self._note_layer_rows(batch)
       params, opt_state, loss = _sage_update(
           model, tx, axis, bs, params, opt_state, batch, n_valid[0])
       return params, opt_state, table, scratch, loss
@@ -414,7 +432,6 @@ class SPMDSageTrainStep:
     ShardedFeature._resolve_cold_sharded's host merge."""
     feature, model, tx = self.feature, self.model, self.tx
     axis, bs = self.axis, self.bs
-    offs = tuple(edge_hop_offsets(bs, self.fanouts))
     budget = sample_budget(bs, self.fanouts)
     with_edge = self.with_edge
 
@@ -435,7 +452,8 @@ class SPMDSageTrainStep:
             x=x_feat, row=out['row'], col=out['col'],
             edge_mask=out['edge_mask'], node=out['node'],
             node_count=node_count, y=y, edge=out.get('edge'),
-            batch_size=bs, edge_hop_offsets=offs)
+            **self._batch_static)
+        self._note_layer_rows(batch)
         params, opt_state, loss = _sage_update(
             model, tx, axis, bs, params, opt_state, batch, n_valid[0])
         return (params, opt_state), loss

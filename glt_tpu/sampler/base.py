@@ -128,6 +128,9 @@ class SamplerOutput:
   #: per-hop static slot boundaries (python ints; hop h edges occupy
   #: slots [edge_hop_offsets[h], edge_hop_offsets[h+1]) of row/col)
   edge_hop_offsets: Optional[List[int]] = None
+  #: per-hop static node prefixes (python ints; nodes within h hops of a
+  #: seed hold labels below node_hop_offsets[h]); None where the producer
+  #: does not promise hop-compact labels
   node_hop_offsets: Optional[List[int]] = None
   metadata: Optional[Dict] = None
 

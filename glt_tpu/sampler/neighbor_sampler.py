@@ -29,7 +29,7 @@ from ..data import Graph
 from ..ops.pipeline import count_engine_fallback, dedup_engine, \
     edge_hop_offsets, hetero_edge_hop_offsets, hop_engine, \
     make_dedup_tables, multihop_sample, multihop_sample_hetero, \
-    sample_budget
+    node_hop_offsets, sample_budget
 from ..ops.sample import (
     neighbor_probs, sample_full_neighbors, sample_neighbors,
     sample_neighbors_weighted,
@@ -561,6 +561,7 @@ class NeighborSampler(BaseSampler):
         num_sampled_nodes=out['num_sampled_nodes'],
         num_sampled_edges=out['num_sampled_edges'],
         edge_hop_offsets=self._edge_hop_offsets(batch_size),
+        node_hop_offsets=node_hop_offsets(batch_size, self.num_neighbors),
         metadata=metadata,
     )
 
@@ -774,6 +775,9 @@ class NeighborSampler(BaseSampler):
       inverse = out.metadata['seed_labels'][input_type[0]]
     else:
       out = self.sample_from_nodes(seeds, key=key, **kwargs)
+      # a link batch reads the embedding of every endpoint and its batch
+      # size is not the seed count: the nodes are not trimmed
+      out.node_hop_offsets = None
       inverse = out.metadata['seed_labels']
     meta = dict(out.metadata or {})
     if neg is None or neg.is_binary():
