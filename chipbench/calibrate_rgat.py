@@ -1,0 +1,62 @@
+"""Reads the numbers that ``correct`` compares in the typed cell, on
+several seeds in one process: the program against the reference (the lower
+reading of each limit), and the reference put in the program's place on the
+same batches, computed in bfloat16 (the control) or with a fault planted
+(the upper readings). ``chipbench/calibrate.py``'s pattern, in another
+order: the reference needs the memory that the table holds, so every seed
+is started first (its own weights, batches and keys on the one graph), then
+the trainer is freed and the references follow. ``PERF.md`` holds what it
+printed and the limits set from it. Not part of a benchmark run.
+
+  python3 chipbench/calibrate_rgat.py --workload rgat-igbh-c1.fused --seeds 4
+"""
+import argparse
+import gc
+import importlib
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+FAULTS = ('half_batch', 'no_attention')
+
+
+def main(argv=None):
+  ap = argparse.ArgumentParser()
+  ap.add_argument('--workload', required=True)
+  ap.add_argument('--seeds', type=int, default=4)
+  ap.add_argument('--controls', type=int, default=1)
+  ap.add_argument('--first-seed', type=int, default=2_700_000_001)
+  args = ap.parse_args(argv)
+  import jax.numpy as jnp
+  from chipbench import reference_rgat, run
+  _, cell, cfg, traffic = run.load_cell(args.workload)
+  run.require_chips(cell['chips'])
+  run.place_compile_cache()
+  driver = importlib.import_module('chipbench.drivers.' + traffic['driver'])
+  s = driver.build(cfg, traffic, cell['chips'], args.first_seed)
+  started = []
+  for i in range(args.seeds):
+    seed = args.first_seed + 7919 * i
+    if i:
+      driver.start(s, seed)
+    started.append((seed, s.program, s.params0, s.sampled))
+  s.trainer = s.params = s.opt = None
+  gc.collect()
+  for i, (seed, program, s.params0, s.sampled) in enumerate(started):
+    follow = lambda **kw: reference_rgat.follow(
+        s.params0, driver.reference_batches(s), cfg['num_layers'],
+        cfg['heads'], cfg['learning_rate'], **kw)[0]
+    ref = follow()
+    out = {'seed': seed, 'program': reference_rgat.compare(program, ref)}
+    if i < args.controls:
+      out['bf16'] = reference_rgat.compare(follow(dtype=jnp.bfloat16), ref)
+      for fault in FAULTS:
+        out[fault] = reference_rgat.compare(follow(fault=fault), ref)
+    print(json.dumps(out), flush=True)
+
+
+if __name__ == '__main__':
+  main()

@@ -232,37 +232,60 @@ class DistFeature:
     ax = axis_name or self.axis
     n = self.num_partitions
     b = ids.shape[0]
-    owner = jnp.take(pb, jnp.clip(ids, 0, self.num_ids - 1), mode='clip')
-    owner = jnp.where(valid, owner, n)
-    cap = (self.bucket_cap if 0 < self.bucket_cap < b else 0)
-    _, meta = bucket_by_owner(ids, owner, n, capacity=cap)
+    if n == 1 and not self._spill:
+      # one partition holds every row: the rows are read in request
+      # order, with nothing to bucket, exchange or stitch; the result is
+      # the bucketed path's bit for bit
+      with jax.named_scope('serve'):
+        rows = jnp.take(map_shard, jnp.clip(ids, 0, self.num_ids - 1),
+                        mode='clip')
+        ok = valid & (ids >= 0) & (rows >= 0)
+        from ..ops.pallas_kernels import resolve_row_gather
+        gather = resolve_row_gather(self._row_gather)
+        safe_rows = jnp.clip(rows, 0, self.hot_max - 1)
+        rows_out = (gather(feat_shard, safe_rows) if gather is not None
+                    else jnp.take(feat_shard, safe_rows, axis=0))
+        return jnp.where(ok[:, None], rows_out, 0)
+    # stages as parallel/dist_feature.py names them, below the caller's
+    # ``feature_store`` scope
+    with jax.named_scope('bucket'):
+      owner = jnp.take(pb, jnp.clip(ids, 0, self.num_ids - 1),
+                       mode='clip')
+      owner = jnp.where(valid, owner, n)
+      cap = (self.bucket_cap if 0 < self.bucket_cap < b else 0)
+      _, meta = bucket_by_owner(ids, owner, n, capacity=cap)
     eff_cap = cap if cap else b
     two_outputs = self._spill and cold_shard is None
 
     def round_serve(base):
-      req = bucket_payload(ids, meta, n, fill_value=-1,
-                           capacity=eff_cap, round_offset=base)
-      req_in = all_to_all(req, ax)                      # [P, C]
-      flat = req_in.reshape(-1)
-      rows = jnp.take(map_shard, jnp.clip(flat, 0, self.num_ids - 1),
-                      mode='clip')
-      ok = (flat >= 0) & (rows >= 0)
-      if self._spill:
-        my_hot = jnp.take(self._hot_counts_dev, jax.lax.axis_index(ax))
-        cold = ok & (rows >= my_hot)
-        ok = ok & (rows < my_hot)
-      safe_rows = jnp.clip(rows, 0, self.hot_max - 1)
-      from ..ops.pallas_kernels import resolve_row_gather
-      gather = resolve_row_gather(self._row_gather)
-      if gather is not None:   # per-row DMA serving gather (see
-        #                        parallel.ShardedFeature.lookup_local)
-        rows_out = gather(feat_shard, safe_rows)
-      else:
-        rows_out = jnp.take(feat_shard, safe_rows, axis=0)
-      served = jnp.where(ok[:, None], rows_out, 0)
+      with jax.named_scope('bucket'):
+        req = bucket_payload(ids, meta, n, fill_value=-1,
+                             capacity=eff_cap, round_offset=base)
+      with jax.named_scope('exchange'):
+        req_in = all_to_all(req, ax)                    # [P, C]
+      with jax.named_scope('serve'):
+        flat = req_in.reshape(-1)
+        rows = jnp.take(map_shard, jnp.clip(flat, 0, self.num_ids - 1),
+                        mode='clip')
+        ok = (flat >= 0) & (rows >= 0)
+        if self._spill:
+          my_hot = jnp.take(self._hot_counts_dev, jax.lax.axis_index(ax))
+          cold = ok & (rows >= my_hot)
+          ok = ok & (rows < my_hot)
+        safe_rows = jnp.clip(rows, 0, self.hot_max - 1)
+        from ..ops.pallas_kernels import resolve_row_gather
+        gather = resolve_row_gather(self._row_gather)
+        if gather is not None:   # per-row DMA serving gather (see
+          #                        parallel.ShardedFeature.lookup_local)
+          rows_out = gather(feat_shard, safe_rows)
+        else:
+          rows_out = jnp.take(feat_shard, safe_rows, axis=0)
+        served = jnp.where(ok[:, None], rows_out, 0)
       if not self._spill:
-        resp = all_to_all(served.reshape(n, -1, self.feature_dim), ax)
-        return unbucket(resp, meta, n, round_offset=base)
+        with jax.named_scope('exchange'):
+          resp = all_to_all(served.reshape(n, -1, self.feature_dim), ax)
+        with jax.named_scope('unbucket'):
+          return unbucket(resp, meta, n, round_offset=base)
       if cold_shard is not None:
         # serve the owner's spilled rows from pinned host memory
         # without leaving the program: index arithmetic stays on

@@ -20,10 +20,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..ops.pipeline import multihop_sample_hetero
-from ..ops.pipeline import make_dedup_tables
+from ..obs.device import register_step_program, scope
+from ..ops.pipeline import (hetero_edge_hop_offsets, make_dedup_tables,
+                            multihop_sample_hetero)
 from ..parallel.mesh import replicate
-from ..typing import EdgeType, NodeType, reverse_edge_type
+from ..typing import EdgeType, NodeType, as_str, reverse_edge_type
 from ..utils import as_numpy
 from ..utils.rng import RandomSeedManager
 from .dist_graph import DistGraph
@@ -115,6 +116,42 @@ class DistHeteroGraph:
     store.local_row = jax.device_put(np.stack(locals_l), shard)
     store.node_pb = jax.device_put(_pb_dense(node_pb, num_rows_global),
                                    repl)
+
+  @classmethod
+  def from_csr(cls, mesh: Mesh, node_counts: Dict[NodeType, int],
+               csr: Dict[EdgeType, tuple], edge_dir: str = 'out',
+               axis: str = 'data'):
+    """One partition on a mesh of one device, from per-edge-type
+    ``(indptr [n_row + 1], indices [E])`` taken as given (ascending
+    within rows, pre-oriented row -> col): every row is kept, with or
+    without edges, so the stores' shapes follow from the counts alone
+    and one compiled program serves every graph of these sizes. No sort,
+    and edge ids are positions."""
+    assert mesh.shape[axis] == 1, 'from_csr builds a single partition'
+    out = cls.__new__(cls)
+    out.mesh, out.axis, out.edge_dir = mesh, axis, edge_dir
+    out.node_counts = dict(node_counts)
+    out.num_partitions = 1
+    out.graphs = {}
+    shard = NamedSharding(mesh, P(axis))
+    for etype, (indptr, indices) in csr.items():
+      row_t = etype[0] if edge_dir == 'out' else etype[2]
+      n_rows, n_edges = node_counts[row_t], int(indices.shape[0])
+      assert indptr.shape[0] == n_rows + 1 and indptr[-1] == n_edges
+      store = DistGraph.__new__(DistGraph)
+      store._finish_init(mesh, axis, n_rows, 'out', 1, n_rows,
+                         max(n_edges, 1),
+                         max(int(np.diff(indptr).max(initial=0)), 1))
+      put = lambda a: jax.device_put(np.asarray(a, np.int32)[None], shard)
+      store.indptr = put(indptr)
+      store.indices = put(indices if n_edges else np.zeros(1, np.int32))
+      store.edge_ids = put(np.arange(max(n_edges, 1), dtype=np.int32))
+      store.edge_weights = None
+      store.local_row = put(np.arange(n_rows, dtype=np.int32))
+      store.node_pb = jax.device_put(np.zeros(n_rows, np.int32),
+                                     NamedSharding(mesh, P()))
+      out.graphs[etype] = store
+    return out
 
   @classmethod
   def from_dataset_partitions(cls, mesh: Mesh, root_dir: str,
@@ -485,6 +522,32 @@ class DistHeteroNeighborSampler:
     return out
 
 
+def _hetero_update(model, tx, axis, bs, params, opt_state, batch, y,
+                   n_valid):
+  """Forward/backward + gradient pmean + optimizer update for one typed
+  batch: the training tail shared by the per-batch step and the
+  superstep scan (identical op sequence = loss parity), under the layer
+  scopes of parallel/train.py::_sage_update."""
+  import optax
+
+  def loss_fn(p):
+    with jax.named_scope('forward'):
+      logits = model.apply(p, batch)
+      mask = jnp.arange(bs) < n_valid
+      l = optax.softmax_cross_entropy_with_integer_labels(logits, y)
+      return jnp.where(mask, l, 0).sum() / jnp.maximum(mask.sum(), 1)
+
+  with scope('model_step'):
+    loss, grads = jax.value_and_grad(loss_fn)(params)
+  with scope('collectives', 'grad_sync'):
+    grads = jax.lax.pmean(grads, axis)
+    loss = jax.lax.pmean(loss, axis)
+  with scope('model_step', 'update'):
+    updates, opt_state = tx.update(grads, opt_state, params)
+    params = optax.apply_updates(params, updates)
+  return params, opt_state, loss
+
+
 class DistHeteroTrainStep:
   """One-program hetero distributed training (the IGBH deployment shape,
   examples/igbh/dist_train_rgnn.py): hetero collective sampling +
@@ -499,14 +562,18 @@ class DistHeteroTrainStep:
                seed_type: NodeType, seed: Optional[int] = None,
                edge_features: Optional[Dict[EdgeType, object]] = None,
                with_weight: bool = False,
-               max_weighted_degree: Optional[int] = None):
+               max_weighted_degree: Optional[int] = None,
+               keep_sample: bool = False):
     """``edge_features`` maps *traversal* edge types to edge-id-space
     DistFeatures; when given, sampling emits eids and the batch carries
     ``edge_attr_dict`` (reference efeat collate,
     dist_neighbor_sampler.py:689-807). ``with_weight`` enables the
     weighted per-etype collective one-hop (reference
-    neighbor_sampler.py:96-144 hetero weighted loops)."""
-    import optax
+    neighbor_sampler.py:96-144 hetero weighted loops). ``keep_sample``
+    has the per-batch step return the structure it sampled and trained
+    on, kept as ``last_sample`` until the next call: what a check of the
+    sample, or a reference's own step on it, reads, with no second
+    program."""
     from ..parallel.dist_feature import require_device_resident
     for t, st in features.items():
       require_device_resident(st, f'DistHeteroTrainStep features[{t!r}]')
@@ -529,34 +596,63 @@ class DistHeteroTrainStep:
     self.labels = {t: jax.device_put(as_numpy(v),
                                      NamedSharding(self.mesh, P()))
                    for t, v in labels.items()}
-    self._optax = optax
     #: times each program was TRACED (trace-time side effects;
     #: executions never bump these) — the zero-steady-state-recompile
     #: assertions on the hetero train path read them
     self.step_traces = 0
     self.superstep_traces = 0
+    self.keep_sample = bool(keep_sample)
+    #: with ``keep_sample``, the last ``__call__``'s batch as
+    #: ``sampler.sample_from_nodes`` lays one out (``node``,
+    #: ``node_count`` by type; ``row``, ``col``, ``edge_mask`` by
+    #: message-flow relation; a leading axis of devices), on the device
+    self.last_sample = None
+    _, caps, budgets, active = self.sampler._make_device_core(
+        self.bs, seed_type)
+    trav = {e: tc for e, tc in self.sampler._trav().items()
+            if e in active}
+    #: static counters of the step, in slots: rows of each type's padded
+    #: node budget and edge slots of each relation (message-flow keys),
+    #: known when the step is built
+    edge_offsets = {
+        self._final_key(e): tuple(v)
+        for e, v in hetero_edge_hop_offsets(
+            caps, trav, self.sampler.num_neighbors,
+            self.sampler.num_hops).items()}
+    self.node_budget = dict(budgets)
+    self.edge_budget = {e: v[-1] for e, v in edge_offsets.items()}
+    #: output rows each layer of the model computes for each type, filled
+    #: when a program is traced (the node trim engages at trace time);
+    #: None before, and for a model that does not say
+    self.layer_rows = None
+    #: what the producer promises of a batch's labels, per type and per
+    #: relation: the static hop prefixes that models/rgnn.py trims by
+    self._batch_static = dict(
+        edge_hop_offsets_dict=edge_offsets,
+        node_hop_offsets_dict={
+            t: tuple(int(x) for x in np.cumsum([c[t] for c in caps]))
+            for t in budgets})
+    from ..obs.perf import gauge_budgets
+    gauge_budgets('train.hetero_step', self.node_budget, self.edge_budget)
     self._step_fn = self._build()
     self._superstep_fn = None  # built lazily on first superstep call
     self._eval_fn = None  # built lazily on first eval_step call
+    register_step_program(self)
 
   def _final_key(self, e):
     return reverse_edge_type(e) if self.g.edge_dir == 'out' else e
 
   def dummy_batch(self):
     from ..loader.transform import HeteroBatch
-    _, caps, budgets, active = self.sampler._make_device_core(
-        self.bs, self.seed_type)
-    trav = {e: tc for e, tc in self.sampler._trav().items()
-            if e in active}
+    budgets = self.node_budget
     x_dict = {t: jnp.zeros((budgets[t], self.features[t].feature_dim))
               for t in self.features}
-    from ..ops.pipeline import hetero_edge_capacities
-    ecaps = hetero_edge_capacities(caps, trav, self.sampler.num_neighbors,
-                                   self.sampler.num_hops)
     row_d, col_d, mask_d, eattr_d, eid_d = {}, {}, {}, {}, {}
-    for e in trav:
-      ecap = max(ecaps[e], 1)
+    for e in self.sampler.edge_types:
       k = self._final_key(e)
+      if k not in self.edge_budget:   # no frontier ever reaches it
+        continue
+      ecap = max(self.edge_budget[k], 1)
       row_d[k] = jnp.zeros((ecap,), jnp.int32)
       col_d[k] = jnp.zeros((ecap,), jnp.int32)
       mask_d[k] = jnp.zeros((ecap,), bool)
@@ -575,7 +671,18 @@ class DistHeteroTrainStep:
         node_count_dict={t: jnp.zeros((), jnp.int32)
                          for t in self.features},
         y_dict={self.seed_type: jnp.zeros((self.bs,), jnp.int32)},
-        input_type=self.seed_type, batch_size=self.bs)
+        input_type=self.seed_type, batch_size=self.bs,
+        **self._batch_static)
+
+  def _note_layer_rows(self, batch) -> None:
+    """Trace-time side effect, as ``step_traces``: what the node trim
+    leaves each layer to compute for each type, on the attribute and as
+    the gauge ``model_layer_rows{fn, layer, type}``."""
+    rows_of = getattr(self.model, 'layer_rows', None)
+    if rows_of is not None:
+      from ..obs.perf import gauge_layer_rows
+      self.layer_rows = rows_of(batch)
+      gauge_layer_rows('train.hetero_step', self.layer_rows)
 
   def init_params(self, key):
     params = self.model.init(key, self.dummy_batch())
@@ -615,30 +722,34 @@ class DistHeteroTrainStep:
       my_key = jax.random.fold_in(key[0], jax.lax.axis_index(axis))
       flat_tables = {t: (tables[t][0][0], tables[t][1][0])
                      for t in tables}
-      out, out_tables = device_core(shards_in, seeds, n_valid[0], my_key,
-                                    flat_tables)
+      with scope('sampler'):
+        out, out_tables = device_core(shards_in, seeds, n_valid[0],
+                                      my_key, flat_tables)
       x_dict = {}
       for t in types:
         fs = feat_shards[t]
-        valid = (jnp.arange(out['node'][t].shape[0])
-                 < out['node_count'][t])
-        x_dict[t] = feats[t].lookup_local(
-            fs['array'][0], fs['id2index'][0], fs['feat_pb'][0],
-            jnp.maximum(out['node'][t], 0), valid, axis_name=axis,
-            cold_shard=fs['cold'][0] if 'cold' in fs else None)
-      y = jnp.take(labels[seed_type],
-                   jnp.maximum(out['batch'], 0)[:bs])
+        with scope('feature_store', 'gather', t):
+          valid = (jnp.arange(out['node'][t].shape[0])
+                   < out['node_count'][t])
+          x_dict[t] = feats[t].lookup_local(
+              fs['array'][0], fs['id2index'][0], fs['feat_pb'][0],
+              jnp.maximum(out['node'][t], 0), valid, axis_name=axis,
+              cold_shard=fs['cold'][0] if 'cold' in fs else None)
+      with scope('feature_store'):
+        y = jnp.take(labels[seed_type],
+                     jnp.maximum(out['batch'], 0)[:bs])
       fk = self._final_key
       edge_attr_dict = None
       if efeats:
         edge_attr_dict = {}
         for e in efeats:
           fs = efeat_shards[e]
-          edge_attr_dict[fk(e)] = efeats[e].lookup_local(
-              fs['array'][0], fs['id2index'][0], fs['feat_pb'][0],
-              jnp.maximum(out['edge'][e], 0), out['edge_mask'][e],
-              axis_name=axis,
-              cold_shard=fs['cold'][0] if 'cold' in fs else None)
+          with scope('feature_store', 'gather', as_str(fk(e))):
+            edge_attr_dict[fk(e)] = efeats[e].lookup_local(
+                fs['array'][0], fs['id2index'][0], fs['feat_pb'][0],
+                jnp.maximum(out['edge'][e], 0), out['edge_mask'][e],
+                axis_name=axis,
+                cold_shard=fs['cold'][0] if 'cold' in fs else None)
       batch = HeteroBatch(
           x_dict=x_dict,
           row_dict={fk(e): out['col'][e] for e in etypes},
@@ -648,7 +759,9 @@ class DistHeteroTrainStep:
           edge_dict=({fk(e): out['edge'][e] for e in etypes}
                      if 'edge' in out else None),
           node_dict=out['node'], node_count_dict=out['node_count'],
-          y_dict={seed_type: y}, input_type=seed_type, batch_size=bs)
+          y_dict={seed_type: y}, input_type=seed_type, batch_size=bs,
+          **self._batch_static)
+      self._note_layer_rows(batch)
       out_tables = {t: (tb[None], sc[None])
                     for t, (tb, sc) in out_tables.items()}
       return batch, y, out_tables
@@ -696,7 +809,6 @@ class DistHeteroTrainStep:
     return device_batch, specs, payloads
 
   def _build(self):
-    optax = self._optax
     model, tx, axis, bs = self.model, self.tx, self.axis, self.bs
     device_batch, specs, payloads = self._assembly()
 
@@ -705,27 +817,25 @@ class DistHeteroTrainStep:
       batch, y, out_tables = device_batch(
           shards, feat_shards, efeat_shards, labels, seeds, n_valid,
           key, tables)
-
-      def loss_fn(p):
-        logits = model.apply(p, batch)
-        mask = jnp.arange(bs) < n_valid[0]
-        l = optax.softmax_cross_entropy_with_integer_labels(logits, y)
-        return jnp.where(mask, l, 0).sum() / jnp.maximum(mask.sum(), 1)
-
-      loss, grads = jax.value_and_grad(loss_fn)(params)
-      grads = jax.lax.pmean(grads, axis)
-      loss = jax.lax.pmean(loss, axis)
-      updates, opt_state = tx.update(grads, opt_state, params)
-      params = optax.apply_updates(params, updates)
-      return params, opt_state, out_tables, loss[None]
+      params, opt_state, loss = _hetero_update(
+          model, tx, axis, bs, params, opt_state, batch, y, n_valid[0])
+      out = (params, opt_state, out_tables, loss[None])
+      if self.keep_sample:
+        out += (jax.tree_util.tree_map(lambda a: a[None], dict(
+            node=batch.node_dict, node_count=batch.node_count_dict,
+            row=batch.row_dict, col=batch.col_dict,
+            edge_mask=batch.edge_mask_dict)),)
+      return out
 
     sp = specs['sp']
+    out_specs = (P(), P(), specs['tables'], sp)
     fn = jax.shard_map(
         device_step, mesh=self.mesh,
         in_specs=(P(), P(), specs['shards'], specs['feats'],
                   specs['efeats'], specs['labels'], sp, sp, sp,
                   specs['tables']),
-        out_specs=(P(), P(), specs['tables'], sp), check_vma=False)
+        out_specs=out_specs + ((sp,) if self.keep_sample else ()),
+        check_vma=False)
 
     import functools
     @functools.partial(jax.jit, donate_argnums=(9,))
@@ -742,6 +852,7 @@ class DistHeteroTrainStep:
       return step(params, opt_state, shards, feat_shards, efeat_shards,
                   self.labels, seeds, n_valid, keys, tables)
 
+    run.jitted = step   # the compiled program, for its cache's size
     return run
 
   # -- superstep: K hetero batches per donated dispatch ------------------
@@ -757,7 +868,6 @@ class DistHeteroTrainStep:
     transfer, and dispatch latency amortize 1/K — exactly the homo
     superstep's collapse (parallel/train.py), now on the per-edge-type
     dispatch train VERDICT round 5 measured at 174 seeds/s."""
-    optax = self._optax
     model, tx, axis, bs = self.model, self.tx, self.axis, self.bs
     device_batch, specs, payloads = self._assembly()
     from ..ops.superstep import superstep_hetero
@@ -769,19 +879,8 @@ class DistHeteroTrainStep:
         batch, y, out_tables = device_batch(
             shards, feat_shards, efeat_shards, labels, seeds, n_valid,
             key, tables)
-
-        def loss_fn(p):
-          logits = model.apply(p, batch)
-          mask = jnp.arange(bs) < n_valid[0]
-          l = optax.softmax_cross_entropy_with_integer_labels(logits, y)
-          return jnp.where(mask, l, 0).sum() / jnp.maximum(mask.sum(),
-                                                           1)
-
-        loss, grads = jax.value_and_grad(loss_fn)(params)
-        grads = jax.lax.pmean(grads, axis)
-        loss = jax.lax.pmean(loss, axis)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        params, opt_state, loss = _hetero_update(
+            model, tx, axis, bs, params, opt_state, batch, y, n_valid[0])
         return params, opt_state, out_tables, loss[None]
 
       run = superstep_hetero(body)
@@ -854,15 +953,38 @@ class DistHeteroTrainStep:
   def __call__(self, params, opt_state, seeds, n_valid_per_device, key):
     n_dev = self.mesh.shape[self.axis]
     shard = NamedSharding(self.mesh, P(self.axis))
-    seeds = jax.device_put(
-        jnp.asarray(np.asarray(seeds).reshape(-1), jnp.int32), shard)
-    nv = jax.device_put(jnp.asarray(n_valid_per_device, jnp.int32),
-                        shard)
-    keys = jax.random.split(key, n_dev)
-    params, opt_state = replicate((params, opt_state), self.mesh)
-    params, opt_state, self.sampler.tables, loss = self._step_fn(
-        params, opt_state, self.sampler.tables, seeds, nv, keys)
+    from ..obs import get_tracer
+    tracer = get_tracer()
+    _synced = {}
+    # the spans of SPMDSageTrainStep.__call__: an idle gap of the device
+    # is named after the child that covers it
+    with tracer.span('train.step', sync=lambda: _synced.get('loss')):
+      with tracer.span('train.step/put'):
+        seeds = jax.device_put(
+            jnp.asarray(np.asarray(seeds).reshape(-1), jnp.int32), shard)
+        nv = jax.device_put(jnp.asarray(n_valid_per_device, jnp.int32),
+                            shard)
+        keys = jax.random.split(key, n_dev)
+        params, opt_state = replicate((params, opt_state), self.mesh)
+      with tracer.span('train.step/dispatch'):
+        out = self._step_fn(params, opt_state, self.sampler.tables,
+                            seeds, nv, keys)
+        params, opt_state, self.sampler.tables, loss = out[:4]
+        if self.keep_sample:
+          self.last_sample = out[4]
+      _synced['loss'] = loss
     return params, opt_state, loss
+
+  def scope_profile(self, params, opt_state, batches) -> dict:
+    """Device time by layer of the per-batch step, from a profiler
+    session of its own: drives ``self(params, opt_state, *batch)`` over
+    ``batches`` (an iterable of ``(seeds, n_valid, key)``) and returns
+    what ``obs.device.reduce_scopes`` makes of the trace: the contract of
+    ``SPMDSageTrainStep.scope_profile``. The state it is given is stepped
+    and thrown away."""
+    from ..obs.device import scope_profile
+    return scope_profile(self, params, opt_state, batches,
+                         step_program='jit_step')
 
   # -- evaluation (reference dist_train_rgnn.py evaluate loop) -----------
 

@@ -55,12 +55,19 @@ def make_dist_one_hop(graph_shards: Dict[str, jax.Array], num_nodes: int,
   def one_hop(ids, fanout, key, mask):
     f = ids.shape[0]
     width = abs(fanout)  # negative = full-neighborhood hop, window |k|
-    owner = jnp.take(node_pb, jnp.clip(ids, 0, num_nodes - 1),
-                     mode='clip')
-    owner = jnp.where(mask, owner, n_parts)
-    req, meta = bucket_by_owner(ids.astype(jnp.int32), owner, n_parts)
-    req_in = all_to_all(req, axis)                       # [P, F]
-    flat = req_in.reshape(-1)
+    if n_parts == 1:
+      # one partition owns every row: each request is served in its own
+      # slot, with nothing to bucket, exchange or stitch (the bucketing
+      # is a stable sort and three scatters a hop, and most of what the
+      # typed step's program takes to compile)
+      flat = jnp.where(mask, ids.astype(jnp.int32), -1)
+    else:
+      owner = jnp.take(node_pb, jnp.clip(ids, 0, num_nodes - 1),
+                       mode='clip')
+      owner = jnp.where(mask, owner, n_parts)
+      req, meta = bucket_by_owner(ids.astype(jnp.int32), owner, n_parts)
+      req_in = all_to_all(req, axis)                     # [P, F]
+      flat = req_in.reshape(-1)
     lrow = jnp.take(local_row, jnp.clip(flat, 0, num_nodes - 1),
                     mode='clip')
     ok = (flat >= 0) & (lrow >= 0)
@@ -83,13 +90,16 @@ def make_dist_one_hop(graph_shards: Dict[str, jax.Array], num_nodes: int,
       out = sample_neighbors(indptr, indices,
                              jnp.clip(lrow, 0, rows_max - 1), fanout,
                              serve_key, seed_mask=ok, edge_ids=eids)
+    from ..ops.sample import NeighborOutput
+    if n_parts == 1:
+      return NeighborOutput(nbrs=out.nbrs, mask=out.mask & mask[:, None],
+                            eids=out.eids)
     resp_nbrs = all_to_all(out.nbrs.reshape(n_parts, f, width), axis)
     resp_mask = all_to_all(out.mask.reshape(n_parts, f, width), axis)
     resp_eids = all_to_all(out.eids.reshape(n_parts, f, width), axis)
     nbrs = unbucket(resp_nbrs, meta, n_parts)
     nmask = unbucket(resp_mask, meta, n_parts, invalid_value=False)
     out_eids = unbucket(resp_eids, meta, n_parts, invalid_value=-1)
-    from ..ops.sample import NeighborOutput
     return NeighborOutput(nbrs=nbrs, mask=nmask & mask[:, None],
                           eids=out_eids)
 

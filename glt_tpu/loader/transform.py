@@ -80,6 +80,12 @@ class HeteroBatch:
   #: per-layer trimming, reference trim_to_layer); Dict[etype, tuple]
   edge_hop_offsets_dict: Optional[Dict] = flax.struct.field(
       pytree_node=False, default=None)
+  #: static per-type ``Dict[ntype, tuple]``: ``[h]`` leading node slots of
+  #: a type hold every node of it within h hops of a seed. A promise of
+  #: the producer that labels are hop-compact per type; where it is
+  #: ``None`` models/rgnn.py computes every row.
+  node_hop_offsets_dict: Optional[Dict] = flax.struct.field(
+      pytree_node=False, default=None)
 
   def edge_index_dict(self) -> Dict[EdgeType, jax.Array]:
     return {k: jnp.stack([self.row_dict[k], self.col_dict[k]])

@@ -62,9 +62,11 @@ class SAGEConv(nn.Module):
 
   @nn.compact
   def __call__(self, x: jax.Array, row: jax.Array, col: jax.Array,
-               edge_mask: jax.Array, num_out=None) -> jax.Array:
+               edge_mask: jax.Array, num_out=None,
+               x_dst=None) -> jax.Array:
     n = x.shape[0]
-    m = n if num_out is None else num_out
+    x_dst = x if x_dst is None else x_dst   # parents of another node type
+    m = x_dst.shape[0] if num_out is None else num_out
     safe_row = jnp.clip(row, 0, n - 1)
     msgs = jnp.take(x, safe_row, axis=0)
     agg = _AGGRS[self.aggr](
@@ -74,12 +76,19 @@ class SAGEConv(nn.Module):
                        param_dtype=self.param_dtype, name='lin_nbr')
     lin_root = nn.Dense(self.out_features, use_bias=self.use_bias,
                         param_dtype=self.param_dtype, name='lin_root')
-    return lin_root(x[:m]) + lin_nbr(agg)
+    return lin_root(x_dst[:m]) + lin_nbr(agg)
 
 
 class GATConv(nn.Module):
   """Graph attention (GATv1): per-edge attention logits softmax-normalized
-  over each parent's incoming sampled edges, multi-head."""
+  over each parent's incoming sampled edges, multi-head.
+
+  ``x`` holds the rows that edges read as children; ``x_dst`` the parents'
+  rows where they are another node type's (a typed relation; ``None``: the
+  parents are rows of ``x``). Only children are projected to ``heads x
+  out``: a parent's logit is ``x_dst @ (W . att_dst)``, an ``[m, heads]``
+  product, which equals ``((x_dst @ W) * att_dst).sum(-1)``.
+  """
   out_features: int
   heads: int = 1
   concat: bool = True
@@ -87,34 +96,42 @@ class GATConv(nn.Module):
   param_dtype: jnp.dtype = jnp.float32
 
   @nn.compact
-  def __call__(self, x, row, col, edge_mask, num_out=None):
+  def __call__(self, x, row, col, edge_mask, num_out=None, x_dst=None):
     n = x.shape[0]
-    m = n if num_out is None else num_out
+    x_dst = x if x_dst is None else x_dst
+    m = x_dst.shape[0] if num_out is None else num_out
     h, f = self.heads, self.out_features
-    ok = edge_mask & (row >= 0) & (col >= 0) & (col < m)
+    ok = edge_mask & (row >= 0) & (row < n) & (col >= 0) & (col < m)
     # children are read from the projection, so it covers every row
-    proj = nn.Dense(h * f, use_bias=False, param_dtype=self.param_dtype,
-                    name='proj')(x).reshape(n, h, f)
+    dense = nn.Dense(h * f, use_bias=False, param_dtype=self.param_dtype,
+                     name='proj')
+    proj = dense(x).reshape(n, h, f)
     att_src = self.param('att_src', nn.initializers.glorot_uniform(),
                          (h, f), self.param_dtype)
     att_dst = self.param('att_dst', nn.initializers.glorot_uniform(),
                          (h, f), self.param_dtype)
-    src = jnp.take(proj, jnp.clip(row, 0, n - 1), axis=0)   # [E, h, f]
-    dst = jnp.take(proj, jnp.clip(col, 0, n - 1), axis=0)
-    logit = nn.leaky_relu(
-        (src * att_src).sum(-1) + (dst * att_dst).sum(-1),
-        negative_slope=self.negative_slope)                 # [E, h]
-    seg = jnp.where(ok, col, m)
-    # numerically-stable masked segment softmax over each parent
-    seg_max = jax.ops.segment_max(
-        jnp.where(ok[:, None], logit, -jnp.inf), seg, m + 1)
-    seg_max = jnp.where(jnp.isfinite(seg_max), seg_max, 0.0)
-    z = jnp.exp(logit - seg_max[jnp.clip(seg, 0, m)])
-    z = jnp.where(ok[:, None], z, 0.0)
-    denom = jax.ops.segment_sum(z, seg, m + 1)
-    alpha = z / jnp.maximum(denom[jnp.clip(seg, 0, m)], 1e-16)  # [E, h]
-    out = jax.ops.segment_sum(
-        src * alpha[:, :, None], seg, m + 1)[:m]            # [m, h, f]
+    with jax.named_scope('attention'):
+      kernel = dense.variables['params']['kernel'].reshape(-1, h, f)
+      w_dst = (kernel * att_dst).sum(-1)                    # [in, h]
+      logit_dst = x_dst[:m].astype(proj.dtype) @ w_dst        # [m, h]
+      logit_src = (proj * att_src).sum(-1)                  # [n, h]
+      seg = jnp.where(ok, col, m)
+      logit = nn.leaky_relu(
+          jnp.take(logit_src, jnp.clip(row, 0, n - 1), axis=0)
+          + jnp.take(logit_dst, jnp.clip(col, 0, m - 1), axis=0),
+          negative_slope=self.negative_slope)               # [E, h]
+      # numerically-stable masked segment softmax over each parent
+      seg_max = jax.ops.segment_max(
+          jnp.where(ok[:, None], logit, -jnp.inf), seg, m + 1)
+      seg_max = jnp.where(jnp.isfinite(seg_max), seg_max, 0.0)
+      z = jnp.exp(logit - seg_max[jnp.clip(seg, 0, m)])
+      z = jnp.where(ok[:, None], z, 0.0)
+      denom = jax.ops.segment_sum(z, seg, m + 1)
+      alpha = z / jnp.maximum(denom[jnp.clip(seg, 0, m)], 1e-16)  # [E, h]
+    with jax.named_scope('aggregate'):
+      src = jnp.take(proj, jnp.clip(row, 0, n - 1), axis=0)   # [E, h, f]
+      out = jax.ops.segment_sum(
+          src * alpha[:, :, None], seg, m + 1)[:m]          # [m, h, f]
     if self.concat:
       return out.reshape(m, h * f)
     return out.mean(axis=1)

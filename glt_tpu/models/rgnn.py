@@ -7,14 +7,18 @@ per-destination-type aggregation of the relation outputs.
 
 Batch contract: HeteroBatch edge keys (s, r, d) carry row = s-type child
 labels, col = d-type parent labels (message-flow orientation).
+
+A relation's convolution reads its children from the source type's rows
+and its parents from the destination type's (``x_dst`` of models/conv.py):
+a node type's rows are projected once per relation that reads them as
+children, never as parents, and no ``[src || dst]`` view is stacked.
 """
 from __future__ import annotations
 
-from typing import Dict, Sequence
+from typing import Dict, Optional, Sequence
 
 import flax.linen as nn
 import jax
-import jax.numpy as jnp
 
 from ..loader.transform import HeteroBatch
 from ..typing import EdgeType, NodeType, as_str
@@ -22,47 +26,58 @@ from .conv import GATConv, SAGEConv
 
 
 class HeteroConvLayer(nn.Module):
-  """Applies a per-edge-type conv and sums relation outputs per dst type."""
+  """Applies a per-edge-type conv and sums relation outputs per dst type.
+
+  ``concat`` (attention only): the heads are ``out_features // heads``
+  wide and concatenated (PyG's ``GATConv(in, out // heads, heads)``, the
+  reference's rgnn.py); else each head is ``out_features`` wide and the
+  heads are averaged. ``remat``: a relation's convolution is computed
+  again in the backward pass instead of keeping its projected rows.
+  """
   edge_types: Sequence[EdgeType]
   out_features: int
   conv: str = 'sage'       # 'sage' | 'gat'
   heads: int = 1
+  concat: bool = False
+  remat: bool = False
 
   def _make(self, etype):
-    name = as_str(etype)
+    cls, width, kw = SAGEConv, self.out_features, {}
     if self.conv == 'gat':
-      return GATConv(self.out_features, heads=self.heads, concat=False,
-                     name=f'conv_{name}')
-    return SAGEConv(self.out_features, name=f'conv_{name}')
+      cls, kw = GATConv, dict(heads=self.heads, concat=self.concat)
+      if self.concat:
+        assert width % self.heads == 0, (width, self.heads)
+        width //= self.heads
+    if self.remat:   # num_out is static: argument 5, the module being 0
+      cls = nn.remat(cls, static_argnums=(5,))
+    return cls(width, name=f'conv_{as_str(etype)}', **kw)
 
   @nn.compact
   def __call__(self, x_dict: Dict[NodeType, jax.Array],
-               row_dict, col_dict, mask_dict):
+               row_dict, col_dict, mask_dict,
+               num_out: Optional[Dict[NodeType, int]] = None):
+    """``num_out[t]``: output rows to compute for type ``t`` (static;
+    ``None`` or a type left out: every row of ``x_dict[t]``)."""
+    rows_of = lambda t: (num_out or {}).get(t, x_dict[t].shape[0])
     out: Dict[NodeType, jax.Array] = {}
     for etype in self.edge_types:
-      key = etype
-      if key not in row_dict:
+      if etype not in row_dict:
         continue
       src_t, _, dst_t = etype
       if src_t not in x_dict or dst_t not in x_dict:
         continue
-      n_dst = x_dict[dst_t].shape[0]
-      n_src = x_dict[src_t].shape[0]
-      conv = self._make(etype)
-      # bipartite message passing: gather from src space, aggregate into
-      # dst space. Reuse the homo convs by building a stacked view:
-      # [src || dst] with offset labels.
-      x_cat = jnp.concatenate([x_dict[src_t], x_dict[dst_t]], axis=0) \
-          if src_t != dst_t else x_dict[src_t]
-      row = row_dict[key]
-      col = col_dict[key] + (n_src if src_t != dst_t else 0)
-      h = conv(x_cat, row, col, mask_dict[key])
-      h_dst = h[n_src:] if src_t != dst_t else h
-      out[dst_t] = out.get(dst_t, 0) + h_dst
+      # bipartite message passing: children from the src type's rows,
+      # parents (and the output rows) from the dst type's
+      h = self._make(etype)(
+          x_dict[src_t], row_dict[etype], col_dict[etype],
+          mask_dict[etype], rows_of(dst_t),
+          None if src_t == dst_t else x_dict[dst_t])
+      out[dst_t] = out.get(dst_t, 0) + h
     # types with no incoming relation keep a transformed self-embedding
     for t, x in x_dict.items():
       if t not in out:
-        out[t] = nn.Dense(self.out_features, name=f'self_{t}')(x)
+        out[t] = nn.Dense(self.out_features,
+                          name=f'self_{t}')(x[:rows_of(t)])
     return out
 
 
@@ -74,6 +89,15 @@ class RGNN(nn.Module):
   batches do), layers trim hierarchically: layer i only reads the edge
   slots of hops [0, num_hops - i) per edge type — the reference's
   trim_to_layer (examples/hetero/hierarchical_sage.py), as static slices.
+  When it also carries ``node_hop_offsets_dict`` (the producer's promise
+  that labels are hop-compact per type), layer i computes output rows only
+  for the nodes a later layer reads, as models/sage.py does for one type.
+
+  ``head``: every layer is ``hidden_features`` wide (attention heads
+  concatenated, ``hidden_features // heads`` each) and a linear layer maps
+  the seeds' rows to ``out_features``: IGB's own R-GAT and the MLPerf
+  recipe. Without it the last layer is ``out_features`` wide and the
+  attention heads are averaged.
   """
   edge_types: Sequence[EdgeType]
   hidden_features: int
@@ -83,41 +107,67 @@ class RGNN(nn.Module):
   heads: int = 4
   dropout: float = 0.0
   trim: bool = True
+  head: bool = False
+  remat: bool = False
+
+  def layer_plan(self, batch: HeteroBatch, return_all: bool = False):
+    """Per layer ``(edge_ends, rows)``: ``edge_ends[e]`` leading edge
+    slots are read (``None``: all), ``rows[t]`` output rows are computed
+    (``None``: every row of the input). Static, from the batch's hop
+    offsets alone."""
+    offs = batch.edge_hop_offsets_dict if self.trim else None
+    noffs = (batch.node_hop_offsets_dict
+             if offs and not return_all else None)
+    num_hops = (max(len(v) for v in offs.values()) - 1) if offs else 0
+    plan = []
+    for i in range(self.num_layers):
+      if not offs:
+        plan.append((None, None))
+        continue
+      # layer i still feeds num_layers-1-i later propagations, so hop
+      # h is useful iff h <= num_layers - i (clamped to sampled hops)
+      keep = max(min(num_hops, self.num_layers - i), 1)
+      ends = {e: max(v[min(keep, len(v) - 1)], 1)   # non-empty for XLA
+              for e, v in offs.items()}
+      rows = None
+      if noffs:
+        out_hops = min(num_hops, self.num_layers - 1 - i)
+        rows = {t: max(v[min(out_hops, len(v) - 1)], 1)
+                for t, v in noffs.items()}
+      plan.append((ends, rows))
+    return plan
+
+  def layer_rows(self, batch: HeteroBatch, return_all: bool = False):
+    """``[{type: output rows}]`` a layer, as the step's counter reads."""
+    return [rows if rows is not None else
+            {t: x.shape[0] for t, x in batch.x_dict.items()}
+            for _, rows in self.layer_plan(batch, return_all)]
 
   @nn.compact
   def __call__(self, batch: HeteroBatch, train: bool = False,
                return_all: bool = False):
     conv_kind = 'gat' if self.conv == 'rgat' else 'sage'
     x_dict = dict(batch.x_dict)
-    offs = batch.edge_hop_offsets_dict if self.trim else None
-    num_hops = (max(len(v) for v in offs.values()) - 1) if offs else 0
-    for i in range(self.num_layers):
-      dim = (self.hidden_features if i < self.num_layers - 1
-             else self.out_features)
-      if offs is not None:
-        # layer i still feeds num_layers-1-i later propagations, so hop
-        # h is useful iff h <= num_layers - i (clamped to sampled hops)
-        keep = max(min(num_hops, self.num_layers - i), 1)
-        row_d, col_d, mask_d = {}, {}, {}
-        for e, v in batch.row_dict.items():
-          end = offs[e][min(keep, len(offs[e]) - 1)] \
-              if e in offs else v.shape[0]
-          end = max(end, 1)  # keep shapes non-empty for XLA
-          row_d[e] = v[:end]
-          col_d[e] = batch.col_dict[e][:end]
-          mask_d[e] = batch.edge_mask_dict[e][:end]
-      else:
-        row_d, col_d, mask_d = (batch.row_dict, batch.col_dict,
-                                batch.edge_mask_dict)
+    for i, (ends, rows) in enumerate(self.layer_plan(batch, return_all)):
+      last = i == self.num_layers - 1
+      dim = (self.out_features if last and not self.head
+             else self.hidden_features)
+      cut = lambda d: d if ends is None else {
+          e: v[:ends[e]] if e in ends else v for e, v in d.items()}
       x_dict = HeteroConvLayer(
           edge_types=list(self.edge_types), out_features=dim,
-          conv=conv_kind, heads=self.heads, name=f'layer{i}')(
-              x_dict, row_d, col_d, mask_d)
-      if i < self.num_layers - 1:
+          conv=conv_kind, heads=self.heads, concat=self.head,
+          remat=self.remat, name=f'layer{i}')(
+              x_dict, cut(batch.row_dict), cut(batch.col_dict),
+              cut(batch.edge_mask_dict), rows)
+      if not last or self.head:
         x_dict = {t: nn.relu(v) for t, v in x_dict.items()}
-        if self.dropout > 0:
-          drop = nn.Dropout(self.dropout, deterministic=not train)
-          x_dict = {t: drop(v) for t, v in x_dict.items()}
+      if not last and self.dropout > 0:
+        drop = nn.Dropout(self.dropout, deterministic=not train)
+        x_dict = {t: drop(v) for t, v in x_dict.items()}
     if return_all:
       return x_dict
-    return x_dict[batch.input_type][:batch.batch_size]
+    seeds = x_dict[batch.input_type][:batch.batch_size]
+    if self.head:
+      return nn.Dense(self.out_features, name='head')(seeds)
+    return seeds

@@ -41,6 +41,7 @@ import os
 import time
 from typing import Optional
 
+from ..typing import as_str
 from ..utils.env import knob
 from .registry import MetricsRegistry, get_registry
 
@@ -73,8 +74,29 @@ def gauge_layer_rows(fn: str, rows,
   try:
     reg = registry or get_registry()
     for i, n in enumerate(rows):
-      reg.set('model_layer_rows', float(n), fn=str(fn), layer=str(i))
+      if isinstance(n, dict):   # a typed model: rows per node type
+        for t, m in n.items():
+          reg.set('model_layer_rows', float(m), fn=str(fn), layer=str(i),
+                  type=str(t))
+      else:
+        reg.set('model_layer_rows', float(n), fn=str(fn), layer=str(i))
   except Exception:  # accounting must never break a trace
+    pass
+
+
+def gauge_budgets(fn: str, node_budget: dict, edge_budget: dict,
+                  registry: Optional[MetricsRegistry] = None) -> None:
+  """Build-time hook of a typed step program ``fn``: the static padded
+  budgets it steps over, as ``node_budget{fn, type}`` (rows of a node
+  type's slots) and ``edge_budget{fn, relation}`` (edge slots of a
+  relation)."""
+  try:
+    reg = registry or get_registry()
+    for t, n in node_budget.items():
+      reg.set('node_budget', float(n), fn=str(fn), type=str(t))
+    for e, n in edge_budget.items():
+      reg.set('edge_budget', float(n), fn=str(fn), relation=as_str(e))
+  except Exception:  # accounting must never break a build
     pass
 
 

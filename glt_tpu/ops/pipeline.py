@@ -13,6 +13,7 @@ from typing import Callable, Dict, List, Sequence
 import jax
 import jax.numpy as jnp
 
+from ..typing import as_str
 from ..utils.env import knob
 from .sample import NeighborOutput
 from .unique import (dense_assign, dense_init, dense_reset,
@@ -808,8 +809,9 @@ def multihop_sample_hetero(one_hops, trav, num_neighbors, num_hops,
         continue
       width = abs(k)  # negative = full-neighborhood hop, window |k|
       f_ids, f_labels, f_mask = frontier[row_t]
-      key, sub = jax.random.split(key)
-      out = one_hops[e](f_ids, k, sub, f_mask)
+      with jax.named_scope(f'sample_hop{h}'), jax.named_scope(as_str(e)):
+        key, sub = jax.random.split(key)
+        out = one_hops[e](f_ids, k, sub, f_mask)
       per_type_nbrs[col_t].append(
           (out.nbrs.reshape(-1), out.mask.reshape(-1)))
       per_meta.append((e, col_t, jnp.repeat(f_labels, width),
@@ -821,9 +823,10 @@ def multihop_sample_hetero(one_hops, trav, num_neighbors, num_hops,
     for t, chunks in per_type_nbrs.items():
       if not chunks:
         continue
-      ids = jnp.concatenate([c[0] for c in chunks])
-      ok = jnp.concatenate([c[1] for c in chunks])
-      states[t], labels = dense_assign(states[t], ids, ok)
+      with jax.named_scope(f'dedup{h}'), jax.named_scope(t):
+        ids = jnp.concatenate([c[0] for c in chunks])
+        ok = jnp.concatenate([c[1] for c in chunks])
+        states[t], labels = dense_assign(states[t], ids, ok)
       labels_by_type[t] = labels
     cursor = {t: 0 for t in types}
     for e, col_t, rows_parent, mask, eids, width in per_meta:
@@ -907,8 +910,9 @@ def _multihop_sample_hetero_sorted(one_hops, trav, num_neighbors,
         continue
       width = abs(k)
       f_ids, f_labels, f_mask = frontier[row_t]
-      key, sub = jax.random.split(key)
-      out = one_hops[e](f_ids, k, sub, f_mask)
+      with jax.named_scope(f'sample_hop{h}'), jax.named_scope(as_str(e)):
+        key, sub = jax.random.split(key)
+        out = one_hops[e](f_ids, k, sub, f_mask)
       mflat = out.mask.reshape(-1)
       per_type[col_t].append((out.nbrs.reshape(-1), mflat))
       per_meta.append((e, col_t, jnp.repeat(f_labels, width), mflat,
@@ -923,25 +927,29 @@ def _multihop_sample_hetero_sorted(one_hops, trav, num_neighbors,
                        jnp.zeros((cap_next,), bool))
         hop_nodes[t].append(jnp.zeros((), jnp.int32))
         continue
-      ids = jnp.concatenate([c[0] for c in chunks])
-      ok = jnp.concatenate([c[1] for c in chunks])
-      if fused_hops():
-        # single-sort assign already returns slot order — the
-        # per-(type, hop) un-permuting sort below disappears too
-        d = sorted_hop_dedup_fused(*seen[t], ids, ok)
-        labels_by_type[t] = d['labels3']
-        frontier[t] = (jnp.where(d['new_head3'], ids.astype(jnp.int32),
-                                 jnp.iinfo(jnp.int32).max),
-                       d['labels3'], d['new_head3'])
-      else:
-        # rows/mask/eids are NOT threaded through the sorts here: the
-        # hop's edge buffers are rebuilt in slot order below (per_meta),
-        # so the dedup sorts stay as narrow as possible
-        d = sorted_hop_dedup(*seen[t], ids, ok)
-        # slot-order labels: cols for this hop's edge buffers
-        labels_by_type[t] = jax.lax.sort([d['pos3'], d['labels3']],
-                                         num_keys=1)[1]
-        frontier[t] = (d['ids3'], d['labels3'], d['new_head3'])
+      with jax.named_scope(f'dedup{h}'), jax.named_scope(t):
+        ids = jnp.concatenate([c[0] for c in chunks])
+        ok = jnp.concatenate([c[1] for c in chunks])
+        if fused_hops():
+          # single-sort assign already returns slot order — the
+          # per-(type, hop) un-permuting sort below disappears too
+          # a typed program holds one such dedup a type and hop: the
+          # forms that compile quickly, same outputs
+          d = sorted_hop_dedup_fused(*seen[t], ids, ok,
+                                     fast_compile=True)
+          labels_by_type[t] = d['labels3']
+          frontier[t] = (jnp.where(d['new_head3'], ids.astype(jnp.int32),
+                                   jnp.iinfo(jnp.int32).max),
+                         d['labels3'], d['new_head3'])
+        else:
+          # rows/mask/eids are NOT threaded through the sorts here: the
+          # hop's edge buffers are rebuilt in slot order below
+          # (per_meta), so the dedup sorts stay as narrow as possible
+          d = sorted_hop_dedup(*seen[t], ids, ok)
+          # slot-order labels: cols for this hop's edge buffers
+          labels_by_type[t] = jax.lax.sort([d['pos3'], d['labels3']],
+                                           num_keys=1)[1]
+          frontier[t] = (d['ids3'], d['labels3'], d['new_head3'])
       seen[t] = (d['u_ids2'], d['u_labs2'], d['count2'])
       hop_nodes[t].append(d['new_count'])
     cursor = {t: 0 for t in types}
@@ -956,7 +964,8 @@ def _multihop_sample_hetero_sorted(one_hops, trav, num_neighbors,
         eid_d.setdefault(e, []).append(eids)
       hop_edges.setdefault(e, []).append(mask.sum().astype(jnp.int32))
 
-  nodes = {t: sorted_nodes_by_label(*seen[t], budgets[t]) for t in types}
+  nodes = {t: sorted_nodes_by_label(*seen[t], budgets[t],
+                                    fast_compile=True) for t in types}
   result = dict(
       node=nodes,
       node_count={t: seen[t][2] for t in types},

@@ -37,3 +37,22 @@ def cumsum_i32(x: jax.Array) -> jax.Array:
   carry = jnp.cumsum(block_tot) - block_tot                    # exclusive
   out = within + carry[:, None]
   return out.reshape(-1)[:m]
+
+
+def cummax_i32(x: jax.Array) -> jax.Array:
+  """Inclusive running maximum of a 1-D int32 array, blocked like
+  :func:`cumsum_i32`: a cumulative maximum along rows of ``_BLOCK`` and a
+  carry level over the rows. Exact for any values. XLA's one-dimensional
+  ``lax.cummax`` gives the same numbers; the TPU's compiler takes five
+  times as long over it at half a million elements."""
+  m = x.shape[0]
+  if m <= _BLOCK:
+    return jax.lax.cummax(x.astype(jnp.int32))
+  low = jnp.iinfo(jnp.int32).min
+  pad = (-m) % _BLOCK
+  x2 = jnp.pad(x.astype(jnp.int32), (0, pad),
+               constant_values=low).reshape(-1, _BLOCK)
+  within = jax.lax.cummax(x2, axis=1)                          # [nb, b]
+  carry = jax.lax.cummax(within[:, -1])                        # [nb]
+  carry = jnp.concatenate([jnp.full((1,), low, jnp.int32), carry[:-1]])
+  return jnp.maximum(within, carry[:, None]).reshape(-1)[:m]

@@ -1,0 +1,305 @@
+"""R-GAT on a typed graph through ``DistHeteroTrainStep`` against the plain
+reference (``glt_tpu/models/reference/rgat.py``), the node trim per type
+against the untrimmed model, and the restructured ``HeteroConvLayer``
+against the form it replaced, on the same parameters. Small sizes: three
+node types, four relations, hidden 16, two heads."""
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from glt_tpu.distributed import (DistFeature, DistHeteroGraph,
+                                 DistHeteroTrainStep)
+from glt_tpu.models import RGNN
+from glt_tpu.models.conv import GATConv, SAGEConv, segment_mean
+from glt_tpu.models.reference import rgat as reference
+from glt_tpu.models.rgnn import HeteroConvLayer
+from glt_tpu.parallel import make_mesh
+from glt_tpu.typing import GraphPartitionData, as_str, reverse_edge_type
+
+COUNTS = {'a': 40, 'b': 30, 'c': 5}
+# traversal relations (edge_dir 'out'); messages flow along the reverses,
+# and every type receives some
+RELATIONS = [('a', 'aa', 'a'), ('a', 'ab', 'b'), ('b', 'bc', 'c'),
+             ('c', 'ca', 'a')]
+DIM, HIDDEN, HEADS, CLASSES, BATCH = 12, 16, 2, 7, 4
+FANOUT = [3, 2, 2]
+
+
+def typed_graph(seed=0):
+  rng = np.random.default_rng(seed)
+  edges = {}
+  for s, r, d in RELATIONS:
+    deg = rng.integers(0, 6, COUNTS[s])   # some rows have no edge
+    src = np.repeat(np.arange(COUNTS[s]), deg)
+    edges[(s, r, d)] = np.stack([src, rng.integers(0, COUNTS[d],
+                                                   src.shape[0])])
+  feats = {t: rng.standard_normal((n, DIM)).astype(np.float32)
+           for t, n in COUNTS.items()}
+  labels = {'a': rng.integers(0, CLASSES, COUNTS['a']).astype(np.int32)}
+  return edges, feats, labels
+
+
+def build_step(edges, feats, labels, layers, hops, **model_kw):
+  mesh = make_mesh(1)
+  book = {t: np.zeros(n, np.int32) for t, n in COUNTS.items()}
+  graph = DistHeteroGraph(
+      mesh, COUNTS,
+      {e: [GraphPartitionData(ei, np.arange(ei.shape[1]))]
+       for e, ei in edges.items()}, book)
+  stores = {t: DistFeature(mesh, [(f, np.arange(f.shape[0]))], book[t],
+                           f.shape[0]) for t, f in feats.items()}
+  model = RGNN(edge_types=[reverse_edge_type(e) for e in RELATIONS],
+               hidden_features=HIDDEN, out_features=CLASSES,
+               num_layers=layers, conv='rgat', heads=HEADS, **model_kw)
+  tx = optax.adam(1e-3)
+  step = DistHeteroTrainStep(
+      graph, stores, model, tx, labels, {e: FANOUT[:hops] for e in edges},
+      batch_size_per_device=BATCH, seed_type='a', seed=0)
+  return step, tx
+
+
+def feed(t):
+  rng = np.random.default_rng([7, t])
+  return (rng.permutation(COUNTS['a'])[:BATCH].astype(np.int32),
+          jax.random.key(100 + t))
+
+
+def train(step, tx, params, steps=3):
+  """Losses, first gradient (from Adam's mu) and the parameters after
+  ``steps`` steps through ``DistHeteroTrainStep.__call__``."""
+  opt, losses, first = tx.init(params), [], None
+  for t in range(steps):
+    seeds, key = feed(t)
+    params, opt, loss = step(params, opt, seeds, np.full(1, BATCH), key)
+    losses.append(float(np.asarray(loss)[0]))
+    if first is None:
+      first = jax.tree.map(lambda m: np.asarray(m) / (1 - reference.B1),
+                           opt[0].mu)
+  return losses, first, jax.tree.map(np.asarray, params)
+
+
+def sampled_batch(step, feats, labels, t):
+  """The reference's batch for step ``t``: what the step's own sampler
+  draws on the step's key, every row and edge real."""
+  seeds, key = feed(t)
+  out = step.sampler.sample_from_nodes('a', seeds, key=key)
+  count = {k: int(np.asarray(v)[0]) for k, v in out['node_count'].items()}
+  nodes = {k: np.asarray(v)[0][:count[k]] for k, v in out['node'].items()}
+  np.testing.assert_array_equal(nodes['a'][:BATCH], seeds)
+  edges = {}
+  for e in out['row']:
+    ok = np.asarray(out['edge_mask'][e])[0]
+    edges[e] = (np.asarray(out['row'][e])[0][ok],
+                np.asarray(out['col'][e])[0][ok])
+  return {'x': {k: feats[k][v] for k, v in nodes.items()}, 'edges': edges,
+          'y': labels['a'][seeds], 'seed_type': 'a'}
+
+
+@pytest.fixture(params=['table', 'sort+fused'])
+def dedup_engine(request, monkeypatch):
+  """Both inducers: the CPU's default and what ``auto`` is on a TPU."""
+  if request.param == 'sort+fused':
+    monkeypatch.setenv('GLT_DEDUP', 'sort')
+    monkeypatch.setenv('GLT_FUSED_HOP', '1')
+  else:
+    monkeypatch.setenv('GLT_DEDUP', 'table')
+  return request.param
+
+
+@pytest.mark.parametrize('layers', [2, 3])
+def test_step_matches_the_reference(layers, dedup_engine):
+  edges, feats, labels = typed_graph()
+  step, tx = build_step(edges, feats, labels, layers, layers, head=True)
+  params0 = step.init_params(jax.random.key(3))
+  losses, first, params = train(step, tx, params0)
+  ref, ref_params, ref_first = reference.follow(
+      params0, (sampled_batch(step, feats, labels, t) for t in range(3)),
+      layers, HEADS, 1e-3)
+  np.testing.assert_allclose(losses, ref['loss'], rtol=2e-5)
+  flat = lambda tree: {jax.tree_util.keystr(k): np.asarray(v) for k, v in
+                       jax.tree_util.tree_leaves_with_path(tree)}
+  got, want = flat(first), flat(ref_first)
+  assert set(got) == set(want) and len(got) >= 3 * 3 * layers + 2
+  scale = max(np.abs(v).max() for v in want.values())
+  for k in want:
+    np.testing.assert_allclose(got[k], want[k], rtol=1e-3,
+                               atol=2e-6 * scale, err_msg=k)
+  got, want = flat(params), flat(ref_params)
+  for k in want:   # three Adam steps of 1e-3 move an element by 3e-3
+    np.testing.assert_allclose(got[k], want[k], atol=1e-4, err_msg=k)
+  program = reference.readings(losses, first, jax.tree.map(
+      np.asarray, params0), params)
+  gaps = reference.compare(program, ref)
+  assert max(gaps.values()) < 1e-3, gaps
+  # the control: the same equations in bfloat16 are told apart
+  low, _, _ = reference.follow(
+      params0, (sampled_batch(step, feats, labels, t) for t in range(3)),
+      layers, HEADS, 1e-3, dtype=jnp.bfloat16)
+  assert max(reference.compare(low, ref).values()) > 10 * max(
+      gaps.values())
+
+
+@pytest.mark.parametrize('fault', ['half_batch', 'no_attention'])
+def test_a_planted_fault_is_told_apart(fault):
+  edges, feats, labels = typed_graph()
+  step, tx = build_step(edges, feats, labels, 2, 2, head=True)
+  params0 = step.init_params(jax.random.key(3))
+  batches = [sampled_batch(step, feats, labels, t) for t in range(3)]
+  ref, _, _ = reference.follow(params0, batches, 2, HEADS, 1e-3)
+  bad, _, _ = reference.follow(params0, batches, 2, HEADS, 1e-3,
+                               fault=fault)
+  assert reference.compare(bad, ref)['grad_gap'] > 0.02
+
+
+@pytest.mark.parametrize('layers,hops', [(2, 2), (3, 3), (3, 2), (2, 3)])
+def test_node_trim_matches_untrimmed(layers, hops, dedup_engine):
+  """Labels are hop-compact per type, so computing only the rows a later
+  layer reads changes neither the loss nor any gradient."""
+  edges, feats, labels = typed_graph(1)
+  out = {}
+  for trim in (True, False):
+    step, tx = build_step(edges, feats, labels, layers, hops, head=True,
+                          trim=trim)
+    out[trim] = train(step, tx, step.init_params(jax.random.key(5)),
+                      steps=2) + (step,)
+  (l1, g1, p1, trimmed), (l0, g0, p0, full) = out[True], out[False]
+  np.testing.assert_allclose(l1, l0, rtol=1e-5)
+  for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g0)):
+    np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-7)
+  assert (jax.tree.structure(p1) == jax.tree.structure(p0))
+  # the counter says what was computed: the seeds' type shrinks to the
+  # batch in the last layer, the untrimmed model computes every slot
+  assert trimmed.layer_rows[-1]['a'] == BATCH
+  assert full.layer_rows[-1] == full.node_budget
+  assert trimmed.layer_rows[0]['a'] <= trimmed.node_budget['a']
+  assert sum(trimmed.layer_rows[-1].values()) < sum(
+      full.layer_rows[-1].values())
+
+
+def test_budgets_are_the_sum_of_slots():
+  edges, feats, labels = typed_graph()
+  step, _ = build_step(edges, feats, labels, 3, 3, head=True)
+  # frontiers by hand, fanout 3, 2, 2: a = 4, 12, 24, 48 + 48 (from c);
+  # b = 0, 12, 24, 48; c = 0, 0, 24, 48
+  assert step.node_budget == {'a': 4 + 12 + 24 + 96, 'b': 12 + 24 + 48,
+                              'c': 24 + 48}
+  fk = reverse_edge_type
+  assert step.edge_budget[fk(('a', 'aa', 'a'))] == 12 + 24 + 48
+  assert step.edge_budget[fk(('c', 'ca', 'a'))] == 0 + 0 + 48
+  assert step.layer_rows is None   # nothing traced yet
+
+
+# -- the form that HeteroConvLayer replaced, kept here for the comparison --
+
+class _OldGATConv(nn.Module):
+  out_features: int
+  heads: int = 1
+  concat: bool = True
+
+  @nn.compact
+  def __call__(self, x, row, col, edge_mask):
+    n, h, f = x.shape[0], self.heads, self.out_features
+    ok = edge_mask & (row >= 0) & (col >= 0)
+    proj = nn.Dense(h * f, use_bias=False, name='proj')(x).reshape(n, h, f)
+    att_src = self.param('att_src', nn.initializers.glorot_uniform(),
+                         (h, f), jnp.float32)
+    att_dst = self.param('att_dst', nn.initializers.glorot_uniform(),
+                         (h, f), jnp.float32)
+    src = jnp.take(proj, jnp.clip(row, 0, n - 1), axis=0)
+    dst = jnp.take(proj, jnp.clip(col, 0, n - 1), axis=0)
+    logit = nn.leaky_relu((src * att_src).sum(-1) + (dst * att_dst).sum(-1),
+                          negative_slope=0.2)
+    seg = jnp.where(ok, col, n)
+    seg_max = jax.ops.segment_max(
+        jnp.where(ok[:, None], logit, -jnp.inf), seg, n + 1)
+    seg_max = jnp.where(jnp.isfinite(seg_max), seg_max, 0.0)
+    z = jnp.where(ok[:, None], jnp.exp(logit - seg_max[seg]), 0.0)
+    denom = jax.ops.segment_sum(z, seg, n + 1)
+    alpha = z / jnp.maximum(denom[seg], 1e-16)
+    out = jax.ops.segment_sum(src * alpha[:, :, None], seg, n + 1)[:n]
+    return out.reshape(n, h * f) if self.concat else out.mean(axis=1)
+
+
+class _OldSAGEConv(nn.Module):
+  out_features: int
+
+  @nn.compact
+  def __call__(self, x, row, col, edge_mask):
+    n = x.shape[0]
+    agg = segment_mean(jnp.take(x, jnp.clip(row, 0, n - 1), axis=0),
+                       jnp.clip(col, 0, n - 1),
+                       edge_mask & (row >= 0) & (col >= 0), n)
+    return (nn.Dense(self.out_features, name='lin_root')(x)
+            + nn.Dense(self.out_features, use_bias=False,
+                       name='lin_nbr')(agg))
+
+
+class _OldHeteroConvLayer(nn.Module):
+  """``[src || dst]`` stacked, both projected, the parents sliced off."""
+  edge_types: tuple
+  out_features: int
+  conv: str = 'sage'
+  heads: int = 1
+
+  @nn.compact
+  def __call__(self, x_dict, row_dict, col_dict, mask_dict):
+    out = {}
+    for etype in self.edge_types:
+      src_t, _, dst_t = etype
+      n_src = x_dict[src_t].shape[0]
+      name = f'conv_{as_str(etype)}'
+      conv = (_OldGATConv(self.out_features, heads=self.heads, concat=False,
+                          name=name) if self.conv == 'gat'
+              else _OldSAGEConv(self.out_features, name=name))
+      same = src_t == dst_t
+      x_cat = x_dict[src_t] if same else jnp.concatenate(
+          [x_dict[src_t], x_dict[dst_t]], axis=0)
+      h = conv(x_cat, row_dict[etype],
+               col_dict[etype] + (0 if same else n_src), mask_dict[etype])
+      out[dst_t] = out.get(dst_t, 0) + (h if same else h[n_src:])
+    return out
+
+
+@pytest.mark.parametrize('conv', ['sage', 'gat'])
+def test_layer_matches_the_stacked_form_it_replaced(conv):
+  rng = np.random.default_rng(2)
+  etypes = (('a', 'aa', 'a'), ('b', 'ba', 'a'), ('a', 'ab', 'b'),
+            ('c', 'cb', 'b'), ('b', 'bc', 'c'))
+  x = {t: jnp.asarray(rng.standard_normal((n, DIM)), jnp.float32)
+       for t, n in COUNTS.items()}
+  row, col, mask = {}, {}, {}
+  for s, r, d in etypes:
+    row[(s, r, d)] = jnp.asarray(rng.integers(0, COUNTS[s], 50), jnp.int32)
+    col[(s, r, d)] = jnp.asarray(rng.integers(0, COUNTS[d], 50), jnp.int32)
+    mask[(s, r, d)] = jnp.asarray(rng.random(50) < 0.8)
+  old = _OldHeteroConvLayer(etypes, HIDDEN, conv=conv, heads=HEADS)
+  new = HeteroConvLayer(etypes, HIDDEN, conv=conv, heads=HEADS)
+  params = old.init(jax.random.key(0), x, row, col, mask)
+  assert (jax.tree.structure(params) == jax.tree.structure(
+      new.init(jax.random.key(0), x, row, col, mask)))
+  want = old.apply(params, x, row, col, mask)
+  got = new.apply(params, x, row, col, mask)
+  assert set(got) == set(want) == {'a', 'b', 'c'}
+  for t in want:
+    np.testing.assert_allclose(got[t], want[t], rtol=1e-5, atol=1e-6)
+  grad = lambda m: jax.grad(lambda p: sum(
+      (v ** 2).sum() for t, v in m.apply(p, x, row, col, mask).items()
+      if t in want))(params)
+  for a, b in zip(jax.tree.leaves(grad(new)), jax.tree.leaves(grad(old))):
+    np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+def test_remat_changes_nothing():
+  edges, feats, labels = typed_graph()
+  runs = []
+  for remat in (False, True):
+    step, tx = build_step(edges, feats, labels, 2, 2, head=True,
+                          remat=remat)
+    runs.append(train(step, tx, step.init_params(jax.random.key(3)),
+                      steps=2))
+  np.testing.assert_allclose(runs[0][0], runs[1][0], rtol=1e-6)
+  for a, b in zip(jax.tree.leaves(runs[0][1]), jax.tree.leaves(runs[1][1])):
+    np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-8)
