@@ -100,6 +100,20 @@ def gauge_budgets(fn: str, node_budget: dict, edge_budget: dict,
     pass
 
 
+def gauge_in_place(fn: str, in_place: bool,
+                   registry: Optional[MetricsRegistry] = None) -> None:
+  """Trace-time hook of a feature store's ``lookup_local``: publish
+  ``feature_store_in_place{fn}``, 1 when ``fn`` traced the in-place form
+  (one shard owns every row: nothing bucketed, exchanged or stitched), 0
+  when it traced the exchange. The choice follows from the mesh, so the
+  gauge is static like :func:`gauge_layer_rows`: set once a trace."""
+  try:
+    (registry or get_registry()).set('feature_store_in_place',
+                                     float(in_place), fn=str(fn))
+  except Exception:  # accounting must never break a trace
+    pass
+
+
 def compile_counts(registry: Optional[MetricsRegistry] = None) -> dict:
   """{fn: count} view over ``compiles_total`` — the assertable surface
   (tests pin a label's count flat across steady-state traffic)."""

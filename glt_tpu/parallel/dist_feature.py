@@ -12,6 +12,9 @@ id // rows_per_shard), and lookup inside shard_map is
     -> positional un-bucket (the stitch, stitch_sample_results.cu analog)
 
 with fixed-capacity buckets so shapes stay static. Collectives ride ICI.
+On a mesh of one shard there is one owner and the exchange would be the
+identity: lookup_local then serves the requests in place (the local
+gather alone), with the same rows bit for bit.
 """
 from __future__ import annotations
 
@@ -204,17 +207,86 @@ class ShardedFeature:
 
     Returns [B, D]; invalid slots are zero.
 
-    With ``bucket_cap`` set the overflow drain runs IN-PROGRAM: the
-    round count is the mesh-wide max bucket occupancy over the cap
-    (pmax — identical everywhere, so the collectives inside the
-    lax.while_loop stay aligned) and round k ships the requests ranked
-    [k*cap, (k+1)*cap) within each bucket. No host replay, no
+    On a mesh of one shard the owner of every row is this device, so the
+    requests are served IN PLACE, in request order: no bucketing by
+    owner, no exchange, no stitch, and ``bucket_cap`` has no rounds to
+    drain (it is ignored; ``lookup()`` still pins it). The rows are the
+    exchange's bit for bit, in every form (resident, hot-only spill with
+    cold lanes zero, ``cold_shard``); a capped exchange differs in one
+    bit only: its drain adds rounds up, which turns a stored -0.0 into
+    +0.0. The choice follows from the mesh at trace time; the gauge
+    ``feature_store_in_place{fn="ShardedFeature.lookup_local"}`` says
+    which form the last trace took.
+
+    On more shards, with ``bucket_cap`` set the overflow drain runs
+    IN-PROGRAM: the round count is the mesh-wide max bucket occupancy
+    over the cap (pmax — identical everywhere, so the collectives inside
+    the lax.while_loop stay aligned) and round k ships the requests
+    ranked [k*cap, (k+1)*cap) within each bucket. No host replay, no
     cross-process agreement round — fused SPMD train steps can use
     capped stores directly.
     """
+    from ..obs.perf import gauge_in_place
+    ax = axis_name or self.axis
+    in_place = self.mesh.shape[self.axis] == 1
+    gauge_in_place('ShardedFeature.lookup_local', in_place)
+    if not in_place:
+      return self._lookup_exchange(local_shard, ids, valid, ax, cold_shard)
+    with scope('feature_store', 'serve'):
+      return self._serve(local_shard, jnp.where(valid, ids, -1), ax,
+                         cold_shard)
+
+  def _serve(self, local_shard, req_in, ax, cold_shard):
+    """Rows of the local block for the requests ``req_in`` (any shape;
+    -1 = no request): hot rows only when spilling without a
+    ``cold_shard`` (cold lanes return zero and the host phase in
+    lookup(), or the superstep's staged rows, fill them)."""
+    my_index = jax.lax.axis_index(ax)
+    local_rows = req_in - my_index * self.rows_per_shard
+    ok = (local_rows >= 0) & (local_rows < self.hot_count) & \
+        (req_in >= 0)
+    safe_rows = jnp.clip(local_rows, 0, self.hot_count - 1)
+    # one DMA descriptor per served row instead of XLA's
+    # per-output-element gather (the UnifiedTensor GatherTensorKernel
+    # analogue, done the TPU way), when enabled
+    from ..ops.pallas_kernels import resolve_row_gather
+    gather = resolve_row_gather(self._row_gather)
+    if gather is not None:
+      rows_out = gather(local_shard, safe_rows.reshape(-1)).reshape(
+          safe_rows.shape + (self.feature_dim,))
+    else:
+      rows_out = jnp.take(local_shard, safe_rows, axis=0)
+    served = jnp.where(ok[..., None], rows_out, 0)
+    if cold_shard is not None and self._spill:
+      # serve the owner's SPILLED rows from pinned host memory
+      # without leaving the program: index arithmetic stays on
+      # device, the gather itself runs host-side (raw indexing —
+      # bounds logic would materialize device-space constants inside
+      # the host region)
+      from jax.experimental import compute_on
+      cold_count = self.rows_per_shard - self.hot_count
+      cold_ok = (local_rows >= self.hot_count) & \
+          (local_rows < self.rows_per_shard) & (req_in >= 0)
+      cold_rows_idx = jnp.clip(local_rows - self.hot_count, 0,
+                               cold_count - 1)
+      idx_h = jax.device_put(cold_rows_idx.reshape(-1),
+                             jax.memory.Space.Host)
+      with compute_on.compute_on('device_host'):
+        cold_out = cold_shard[idx_h]
+      cold_out = jax.device_put(
+          cold_out, jax.memory.Space.Device).reshape(
+              cold_rows_idx.shape + (self.feature_dim,))
+      served = jnp.where(cold_ok[..., None],
+                         cold_out.astype(served.dtype), served)
+    return served
+
+  def _lookup_exchange(self, local_shard, ids, valid, ax, cold_shard):
+    """``lookup_local`` over more than one shard: bucket the requests by
+    owner, all_to_all, serve, all_to_all back, stitch to request order.
+    Correct on one shard too (the exchange is then the identity), which
+    is how tests hold the in-place form to it."""
     from .collectives import (BucketMeta, all_to_all, bucket_payload,
                               capped_drain, unbucket)
-    ax = axis_name or self.axis
     n_shards = self.mesh.shape[self.axis]
     b = ids.shape[0]
     store = lambda stage: scope('feature_store', stage)
@@ -232,49 +304,6 @@ class ShardedFeature:
     # fixed-capacity request buckets [n_shards, C] (C = B by default)
     cap = (self.bucket_cap if 0 < self.bucket_cap < b else b)
 
-    def serve(req_in):
-      """Rows of the local block for the requests peers sent (hot rows
-      only when spilling; cold lanes return zero and the host phase in
-      lookup() fills them)."""
-      my_index = jax.lax.axis_index(ax)
-      local_rows = req_in - my_index * self.rows_per_shard
-      ok = (local_rows >= 0) & (local_rows < self.hot_count) & \
-          (req_in >= 0)
-      safe_rows = jnp.clip(local_rows, 0, self.hot_count - 1)
-      # one DMA descriptor per served row instead of XLA's
-      # per-output-element gather (the UnifiedTensor GatherTensorKernel
-      # analogue, done the TPU way), when enabled
-      from ..ops.pallas_kernels import resolve_row_gather
-      gather = resolve_row_gather(self._row_gather)
-      if gather is not None:
-        rows_out = gather(local_shard, safe_rows.reshape(-1)).reshape(
-            safe_rows.shape + (self.feature_dim,))
-      else:
-        rows_out = jnp.take(local_shard, safe_rows, axis=0)
-      served = jnp.where(ok[..., None], rows_out, 0)
-      if cold_shard is not None and self._spill:
-        # serve the owner's SPILLED rows from pinned host memory
-        # without leaving the program: index arithmetic stays on
-        # device, the gather itself runs host-side (raw indexing —
-        # bounds logic would materialize device-space constants inside
-        # the host region)
-        from jax.experimental import compute_on
-        cold_count = self.rows_per_shard - self.hot_count
-        cold_ok = (local_rows >= self.hot_count) & \
-            (local_rows < self.rows_per_shard) & (req_in >= 0)
-        cold_rows_idx = jnp.clip(local_rows - self.hot_count, 0,
-                                 cold_count - 1)
-        idx_h = jax.device_put(cold_rows_idx.reshape(-1),
-                               jax.memory.Space.Host)
-        with compute_on.compute_on('device_host'):
-          cold_out = cold_shard[idx_h]
-        cold_out = jax.device_put(
-            cold_out, jax.memory.Space.Device).reshape(
-                cold_rows_idx.shape + (self.feature_dim,))
-        served = jnp.where(cold_ok[..., None],
-                           cold_out.astype(served.dtype), served)
-      return served
-
     def round_out(base):
       """One bucket-exchange-serve-unbucket pass over the requests
       ranked [base, base+cap) per bucket; other lanes come back 0."""
@@ -285,7 +314,7 @@ class ShardedFeature:
         # exchange requests: row p of the result = what peer p asked us
         req_in = all_to_all(req, ax)
       with store('serve'):
-        served = serve(req_in)
+        served = self._serve(local_shard, req_in, ax, cold_shard)
       with store('exchange'):
         # responses back; row p now holds our requests served by peer p
         resp = all_to_all(served, ax)

@@ -319,3 +319,101 @@ def test_sharded_feature_bucket_cap_with_spill(mesh):
   ids = rng.integers(0, n, size=8 * 16)
   out = np.asarray(st.lookup(ids))
   np.testing.assert_allclose(out, feats[ids])
+
+
+# -- one shard: requests served in place ----------------------------------
+
+def _in_place_gauge():
+  from glt_tpu.obs import get_registry
+  return get_registry().get('feature_store_in_place', default=-1.0,
+                            fn='ShardedFeature.lookup_local')
+
+
+def _both_forms(sf, ids, valid):
+  """(in place, exchanged) [B, D] rows of a one-shard store, each
+  through its own jitted shard_map."""
+  from jax.sharding import PartitionSpec as P
+  cold = () if sf.cold_array is None else (sf.cold_array,)
+
+  def run(form):
+    fn = jax.jit(jax.shard_map(
+        form, mesh=sf.mesh, in_specs=(P(sf.axis),) * (3 + len(cold)),
+        out_specs=P(sf.axis), check_vma=False))
+    return np.asarray(fn(sf.array, jnp.asarray(ids), jnp.asarray(valid),
+                         *cold))
+
+  return (run(lambda shard, i, v, c=None: sf.lookup_local(
+              shard, i, v, cold_shard=c)),
+          run(lambda shard, i, v, c=None: sf._lookup_exchange(
+              shard, i, v, sf.axis, c)))
+
+
+_IN_PLACE_CASES = {
+    'resident': dict(),
+    'invalid_negative_out_of_range': dict(bad_ids=True),
+    'bucket_cap': dict(store=dict(bucket_cap=5), bad_ids=True),
+    'hot_only_spill': dict(store=dict(split_ratio=0.3,
+                                      host_offload=False), hot_only=True),
+    'hot_only_spill_bucket_cap': dict(
+        store=dict(split_ratio=0.3, host_offload=False, bucket_cap=7),
+        bad_ids=True, hot_only=True),
+    'cold_shard': dict(store=dict(split_ratio=0.3), pinned=True),
+}
+
+
+@pytest.mark.parametrize('case', list(_IN_PLACE_CASES))
+def test_one_shard_lookup_in_place_equals_the_exchange_bit_for_bit(case):
+  cfg = _IN_PLACE_CASES[case]
+  if cfg.get('pinned'):
+    from fixtures import skip_unless_pinned_host
+    skip_unless_pinned_host()
+  n, d, b = 100, 8, 64
+  rng = np.random.default_rng(31)
+  feats = rng.normal(size=(n, d)).astype(np.float32)
+  sf = ShardedFeature(feats, make_mesh(1), **cfg.get('store', {}))
+  if cfg.get('pinned'):
+    assert sf.cold_array is not None
+  ids = rng.integers(0, n, size=b)
+  valid = rng.random(b) < 0.8
+  if cfg.get('bad_ids'):
+    ids[::7] = -3
+    ids[3::7] = n + 5
+    ids[5::11] = np.iinfo(np.int32).max
+    valid[:4] = [True, False, True, True]   # a bad id on a valid lane too
+  ids = ids.astype(np.int32)
+  in_place, exchanged = _both_forms(sf, ids, valid)
+  assert in_place.dtype == exchanged.dtype == np.float32
+  np.testing.assert_array_equal(in_place.view(np.uint32),
+                                exchanged.view(np.uint32))
+  assert _in_place_gauge() == 1.0
+  asked = valid & (ids >= 0) & (ids < n)
+  rows = feats[np.clip(ids, 0, n - 1)]
+  served = asked
+  if cfg.get('hot_only'):
+    # cold lanes come back zero: the superstep's staged rows and
+    # lookup()'s host phase add theirs
+    assert sf._spill and sf.hot_count < n
+    served = asked & (ids < sf.hot_count)
+  np.testing.assert_array_equal(in_place,
+                                np.where(served[:, None], rows, 0))
+  # the host-side API goes through the same form
+  np.testing.assert_array_equal(
+      np.asarray(sf.lookup(ids, jnp.asarray(valid))),
+      np.where(asked[:, None], rows, 0))
+
+
+@pytest.mark.parametrize('chips', [1, 2, 8])
+def test_lookup_local_exchanges_only_over_more_than_one_shard(chips):
+  from jax.sharding import PartitionSpec as P
+  n, d, b = 96, 4, 16
+  feats = np.arange(n * d, dtype=np.float32).reshape(n, d)
+  sf = ShardedFeature(feats, make_mesh(chips))
+  fn = jax.jit(jax.shard_map(
+      sf.lookup_local, mesh=sf.mesh, in_specs=(P(sf.axis),) * 3,
+      out_specs=P(sf.axis), check_vma=False))
+  ids = jnp.asarray(np.arange(chips * b, dtype=np.int32) % n)
+  text = fn.lower(sf.array, ids, jnp.ones(ids.shape, bool)).as_text()
+  in_place = chips == 1
+  assert text.count('all_to_all') == (0 if in_place else 2), text
+  assert ('stablehlo.sort' in text) != in_place   # the owner argsort
+  assert _in_place_gauge() == float(in_place)
