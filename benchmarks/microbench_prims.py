@@ -146,10 +146,10 @@ def main():
   rec('sorted_hop_dedup_h2',
       timed(dedup_full, u_ids, u_labs, idx_m, ok_m, rows_m))
 
-  # -- windowed gather: XLA slice-gather vs Pallas per-row DMA ---------
+  # -- windowed gather -------------------------------------------------
   # the weighted / full-neighborhood samplers read a [S, W] neighbor
   # window per seed; feature lookup reads [S, D] rows. XLA charges per
-  # output element; the Pallas kernel pays one DMA descriptor per row.
+  # output element.
   W = 96
   starts_f = jnp.asarray(rng.integers(0, E - W, F).astype(np.int32))
 
@@ -160,15 +160,6 @@ def main():
 
   rec(f'window_gather_xla_{F//1000}kx{W}', timed(xla_windows, big,
                                                  starts_f))
-  try:
-    from glt_tpu.ops.pallas_kernels import gather_windows
-    if jax.default_backend() == 'tpu':
-      for blk in (8, 32):
-        rec(f'window_gather_dma_{F//1000}kx{W}_blk{blk}',
-            timed(jax.jit(lambda a, st, _b=blk: gather_windows(
-                a, st, W, block=_b)), big, starts_f))
-  except Exception as exc:
-    print(f'# pallas window gather unavailable: {exc}', file=sys.stderr)
 
   # -- PRNG implementation A/B (threefry default vs rbg) ---------------
   try:

@@ -48,12 +48,10 @@ def check(ok, *why):
 
 
 def engines():
-  """The engine knobs as they resolve in this process, read the way the
+  """The dedup knobs as they resolve in this process, read the way the
   samplers read them at trace time."""
-  from glt_tpu.ops.pipeline import (dedup_engine, fused_hops,
-                                    fused_walk_mode)
-  return {'dedup_engine': dedup_engine(), 'fused_hops': fused_hops(),
-          'fused_walk_mode': fused_walk_mode()}
+  from glt_tpu.ops.pipeline import dedup_engine, fused_hops
+  return {'dedup_engine': dedup_engine(), 'fused_hops': fused_hops()}
 
 
 def bytes_in_use():
@@ -138,8 +136,7 @@ def loader_phase(ds, num_classes):
   check((sampler.num_compiled_fns, step._cache_size()) == compiled,
         'recompiled after step 1', sampler.num_compiled_fns,
         step._cache_size())
-  say(phase='loader', steps=LOADER_STEPS,
-      hop_engine=sampler._resolved_hop_engine(), **engines(),
+  say(phase='loader', steps=LOADER_STEPS, **engines(),
       first_dispatch_s=round(first_s, 1), **steady,
       loss_first5=round(float(losses[:5].mean()), 4),
       loss_last5=round(float(losses[-5:].mean()), 4),
@@ -234,7 +231,7 @@ def fused_phase(ds, feats, num_classes):
 
   say(phase='fused', n_dev=n_dev, batch_size_per_device=BATCH,
       # parallel/train.py reads hops through sample_neighbors directly
-      hop_read='element (hard-wired in SPMDSageTrainStep)', **engines(),
+      **engines(),
       per_batch=dict(steps=FUSED_STEPS,
                      first_dispatch_s=round(pb_first_s, 1), **pb_steady),
       superstep=dict(k=SUPERSTEP_K, supersteps=SUPERSTEPS,
@@ -268,8 +265,6 @@ def main():
   sys.path.insert(0, os.path.join(root, 'examples'))
   import importlib.metadata as md
   import numpy as np
-  from glt_tpu.obs import get_registry
-  from glt_tpu.ops.pallas_kernels import interpret_default
   from glt_tpu.utils.backend import configure_compile_cache
   from common import synthetic_products
 
@@ -280,9 +275,7 @@ def main():
   say(phase='start', device=device,
       local_device_count=jax.local_device_count(),
       versions={p: md.version(p) for p in ('jax', 'jaxlib', 'libtpu')},
-      compile_cache_dir=configure_compile_cache(),
-      interpret=interpret_default())
-  check(not interpret_default(), 'Pallas interpret mode is forced on')
+      compile_cache_dir=configure_compile_cache())
 
   t0 = time.perf_counter()
   ds, num_classes = synthetic_products(num_nodes=NUM_NODES)
@@ -301,13 +294,7 @@ def main():
   say(phase='handover', bytes_in_use_per_device=bytes_in_use())
   fused_phase(ds, feats, num_classes)
 
-  # a demotion is a failure here, not a log line: `auto` is a fixed
-  # answer, so this run may have recorded no engine-resolution event
-  fallbacks = {k: v for k, v in get_registry().snapshot()['counters']
-               .items() if k.startswith('hop_engine_fallbacks_total')}
-  check(not fallbacks, fallbacks)
-  say(phase='done', hop_engine_fallbacks=fallbacks,
-      total_s=round(time.perf_counter() - t_start, 1))
+  say(phase='done', total_s=round(time.perf_counter() - t_start, 1))
   print(json.dumps({'ok': True, 'device': device}), flush=True)
 
 

@@ -1,9 +1,9 @@
 """glt_tpu — a TPU-native graph-learning framework.
 
-A from-scratch JAX/XLA/Pallas re-design with the capabilities of
+A from-scratch JAX/XLA re-design with the capabilities of
 GraphLearn-for-PyTorch (graph sampling, unified feature store, distributed
-sampling/training), built for TPU: static shapes, SPMD meshes, XLA
-collectives, and Pallas kernels on the hot paths.
+sampling/training), built for TPU: static shapes, SPMD meshes and XLA
+collectives; every hot path is a compiled XLA program.
 """
 
 __version__ = '0.1.0'

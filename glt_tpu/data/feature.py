@@ -22,7 +22,6 @@ array is addressable from every chip, and the distributed feature store
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
@@ -32,17 +31,14 @@ import numpy as np
 from ..utils import as_numpy
 
 
-@functools.partial(jax.jit, static_argnames=('row_gather',))
+@jax.jit
 def _mixed_gather(hot: jax.Array, cold: jax.Array,
-                  rows: jax.Array, row_gather=None) -> jax.Array:
+                  rows: jax.Array) -> jax.Array:
   """hot [H, D] device block; cold [C, D] pinned-host block; rows [B]
   absolute row indices (cold row r lives at cold[r - H]). Index
   arithmetic stays on device; the cold read runs host-side via raw
   indexing (bounds ops would materialize device-space constants inside
-  the host region). ``row_gather`` (static: keyed by identity in the
-  jit cache) overrides the HOT-block gather kernel — the same seam as
-  Feature.device_gather, so an injected kernel covers offloaded stores
-  too."""
+  the host region)."""
   from jax.experimental import compute_on
   h = hot.shape[0]
   cold_idx = jnp.clip(rows - h, 0, cold.shape[0] - 1)
@@ -53,8 +49,7 @@ def _mixed_gather(hot: jax.Array, cold: jax.Array,
   if h == 0:  # static shape: the whole table is cold
     return c
   safe = jnp.where(rows < h, rows, 0)
-  x = (row_gather(hot, safe) if row_gather is not None
-       else jnp.take(hot, safe, axis=0))
+  x = jnp.take(hot, safe, axis=0)
   return jnp.where((rows >= h)[:, None], c.astype(x.dtype), x)
 
 
@@ -93,18 +88,11 @@ class Feature:
   def __init__(self, feats, split_ratio: float = 1.0,
                id2index: Optional[np.ndarray] = None,
                device: Optional[jax.Device] = None,
-               dtype=None, host_offload: Optional[bool] = None,
-               row_gather=None):
+               dtype=None, host_offload: Optional[bool] = None):
     feats = as_numpy(feats)
     if feats.ndim == 1:
       feats = feats[:, None]
     self._host_full = feats
-    # optional (table [N, D], rows [B]) -> [B, D] override for the
-    # device-resident gather — the same injection seam the sharded
-    # stores expose (parallel/dist_feature.py): tests pass the
-    # interpret-mode Pallas kernel, deployments can pin a tuned one.
-    # Resolved through ops.pallas_kernels.resolve_row_gather.
-    self.row_gather = row_gather
     self.split_ratio = float(split_ratio)
     self.hot_count = int(round(feats.shape[0] * self.split_ratio))
     self.device = device
@@ -205,59 +193,21 @@ class Feature:
     self.lazy_init()
     return jnp.take(self._id2index_dev, ids, mode='clip')
 
-  def device_gather(self, rows: jax.Array,
-                    row_gather=None) -> jax.Array:
+  def device_gather(self, rows: jax.Array) -> jax.Array:
     """Jit-safe gather; only valid when fully device resident (hot==all).
-    ``rows`` are post-id2index row indices. Gather selection follows
-    ``resolve_row_gather``: an explicit ``row_gather`` (call-site or the
-    store's own) wins, else the Pallas row-gather kernel when
-    GLT_USE_PALLAS=1 on a TPU backend, else ``jnp.take``."""
+    ``rows`` are post-id2index row indices."""
     self.lazy_init()
-    from ..ops.pallas_kernels import resolve_row_gather
-    fn = resolve_row_gather(row_gather if row_gather is not None
-                            else self.row_gather)
-    if fn is not None:
-      return fn(self._hot, rows.reshape(-1)).reshape(
-          rows.shape + (self._hot.shape[1],))
     return jnp.take(self._hot, rows, axis=0, mode='clip')
 
-  def gather_mixed(self, rows: jax.Array,
-                   row_gather=None) -> jax.Array:
+  def gather_mixed(self, rows: jax.Array) -> jax.Array:
     """Jit-served gather over BOTH residency classes: hot rows from the
     device block, cold rows from the pinned-host block via a
     compute_on('device_host') gather — one compiled program, no host
     phase between batches. Requires the offloaded cold block
-    (``cold_array``); loaders fall back to gather_cold_host otherwise.
-    ``row_gather`` (call-site, else the store's own) overrides the
-    hot-block gather kernel; unlike ``device_gather`` the env default
-    (GLT_USE_PALLAS) does not apply here — only explicit injections."""
+    (``cold_array``); loaders fall back to gather_cold_host otherwise."""
     self.lazy_init()
     assert self.cold_array is not None, 'host offload inactive'
-    fn = row_gather if row_gather is not None else self.row_gather
-    return _mixed_gather(self._hot, self.cold_array, rows,
-                         row_gather=fn)
-
-  def fused_gather_fn(self, row_gather=None):
-    """Jit-safe ``ids [m] -> rows [m, D]`` closure for the in-walk
-    (``pallas_fused``) feature gather: identical op chain to
-    :func:`gather_features` on a fully-resident store — ``map_ids``
-    (clip semantics included) then :meth:`device_gather` through the
-    ``resolve_row_gather`` seam — so the assembled ``node_feats`` block
-    is bit-identical to the post-hoc gather, padded lanes included.
-    The returned closure captures this store's device buffers as
-    compile-time constants (the same trade the samplers make with the
-    graph arrays): swap the store, rebuild the sampler."""
-    self.lazy_init()
-    assert self.fully_device_resident, (
-        'the fused in-walk gather serves device-resident stores only; '
-        'spilled/offloaded rows keep the post-hoc gather_features path')
-    fn = row_gather if row_gather is not None else self.row_gather
-
-    def gather(ids):
-      rows = self.map_ids(ids.astype(jnp.int32))
-      return self.device_gather(rows, row_gather=fn)
-
-    return gather
+    return _mixed_gather(self._hot, self.cold_array, rows)
 
   def cold_block_numpy(self) -> np.ndarray:
     """The whole cold block as numpy, whichever residency holds it
@@ -374,51 +324,35 @@ class Feature:
     return out
 
 
-def gather_features(feat: Optional[Feature], node,
-                    row_gather=None, fused=None) -> Optional[jax.Array]:
+def gather_features(feat: Optional[Feature], node) -> Optional[jax.Array]:
   """Batch gather over a Feature across BOTH residency classes — the
   single collate-time gather path shared by the training loaders
   (loader.node_loader) and the online serving engine (serving.engine).
   Hot rows stay on device; cold rows ride the pinned-host block
-  (gather_mixed) when offloaded, else the host phase. ``row_gather``
-  overrides the device-resident gather kernel at the call site (see
-  :meth:`Feature.device_gather`) — it survives feature swaps (e.g.
-  stream snapshot updates) because it rides the call, not the store.
-
-  ``fused``: a feature block the sampler already assembled IN-WALK (the
-  ``pallas_fused`` engine's ``node_feats`` metadata, bit-identical to
-  what this function would gather) — passed through as the result, so
-  every call site keeps one uniform entry point whichever engine ran.
-  The ``gather.features`` span still opens (recording ~0 self time):
-  per-stage breakdowns then show the gather cost moving INTO the fused
-  sample stage rather than silently vanishing."""
+  (gather_mixed) when offloaded, else the host phase."""
   if feat is None:
     return None
   from ..obs import get_tracer
   tracer = get_tracer()
   if tracer.enabled:
     _out = {}
-    with tracer.span('gather.features', sync=lambda: _out.get('x'),
-                     fused=fused is not None):
-      _out['x'] = x = (fused if fused is not None
-                       else _gather_features(feat, node, row_gather))
+    with tracer.span('gather.features', sync=lambda: _out.get('x')):
+      _out['x'] = x = _gather_features(feat, node)
     return x
-  if fused is not None:
-    return fused
-  return _gather_features(feat, node, row_gather)
+  return _gather_features(feat, node)
 
 
-def _gather_features(feat: Feature, node, row_gather):
+def _gather_features(feat: Feature, node):
   rows = feat.map_ids(node)
   if feat.fully_device_resident:
-    return feat.device_gather(rows, row_gather=row_gather)
+    return feat.device_gather(rows)
   feat.lazy_init()  # offload is decided at placement time
   if feat.cold_array is not None:
     # host-offloaded cold block: one jitted program serves both
     # residency classes (compute_on host gather inside) — no host
     # phase between batches at all (jnp.asarray is a no-op for rows
     # already on device)
-    return feat.gather_mixed(jnp.asarray(rows), row_gather=row_gather)
+    return feat.gather_mixed(jnp.asarray(rows))
   # legacy mixed residency (host_offload=False): hot rows stay on
   # device end-to-end; only the cold slice crosses host->device (the
   # UVA-read analogue). The previous design pulled the hot gather D2H
@@ -432,7 +366,7 @@ def _gather_features(feat: Feature, node, row_gather):
                        .astype(feat.dtype))
   rows_dev = jnp.asarray(rows_np)
   hot = jnp.where(rows_dev < feat.hot_count, rows_dev, 0)
-  x = feat.device_gather(hot, row_gather=row_gather)  # cold lanes junk
+  x = feat.device_gather(hot)  # cold lanes junk
   cold_idx = np.nonzero(rows_np >= feat.hot_count)[0]
   if cold_idx.size:
     cold_vals = feat.gather_cold_host(rows_np[cold_idx]) \

@@ -16,7 +16,6 @@ share-via-handle machinery is unnecessary by design.
 """
 from __future__ import annotations
 
-import threading
 from typing import Optional
 
 import jax
@@ -46,27 +45,18 @@ class Graph:
     self._edge_ids = None
     self._edge_weights = None
     self._initialized = False
-    self._window_cache = {}   # field -> (padded_width, array)
-    self._window_lock = threading.Lock()
 
-  # threading.Lock is unpicklable; producers currently ship a
-  # dataset_builder callable rather than Graph objects, but mp channel
-  # payloads / checkpoints may pickle a Graph directly. Device arrays and
-  # the window cache are dropped too: they are lazily rebuilt, and a
-  # fresh process must re-place them on its own devices anyway.
+  # producers currently ship a dataset_builder callable rather than
+  # Graph objects, but mp channel payloads / checkpoints may pickle a
+  # Graph directly. Device arrays are dropped: they are lazily rebuilt,
+  # and a fresh process must re-place them on its own devices anyway.
   def __getstate__(self):
     state = self.__dict__.copy()
-    state['_window_lock'] = None
-    state['_window_cache'] = {}
     if self.mode == GraphMode.HBM:
       state['_indptr'] = state['_indices'] = None
       state['_edge_ids'] = state['_edge_weights'] = None
       state['_initialized'] = False
     return state
-
-  def __setstate__(self, state):
-    self.__dict__.update(state)
-    self._window_lock = threading.Lock()
 
   # -- lazy init ---------------------------------------------------------
 
@@ -89,15 +79,6 @@ class Graph:
     self._edge_weights = put(self.topo.edge_weights)
     self._initialized = True
 
-  # NOTE on edge-array length: after any windowed sample has called
-  # ``window_arrays``, the edge arrays below may carry a sentinel-padded
-  # tail (indices/edge_ids = -1, edge_weights = 0.0) — the padded copy
-  # supersedes the original so only ONE resident copy exists (see
-  # window_arrays). The LOGICAL edge list is always ``[:num_edges]``;
-  # ``shape[0] == num_edges`` is NOT an invariant of these properties.
-  # Kernels are insensitive (gathers clip into the logical prefix);
-  # code iterating a full array must slice to ``num_edges`` first.
-
   @property
   def indptr(self):
     self.lazy_init()
@@ -105,117 +86,18 @@ class Graph:
 
   @property
   def indices(self):
-    """Neighbor ids; may be sentinel-padded past ``num_edges`` (see
-    class note above)."""
     self.lazy_init()
     return self._indices
 
   @property
   def edge_ids(self):
-    """Edge ids; may be sentinel-padded past ``num_edges`` (see class
-    note above)."""
     self.lazy_init()
     return self._edge_ids
 
   @property
   def edge_weights(self):
-    """Edge weights; may be sentinel-padded past ``num_edges`` (see
-    class note above)."""
     self.lazy_init()
     return self._edge_weights
-
-  def window_arrays(self, width: int, fields=('indices', 'edge_ids',
-                                              'edge_weights')):
-    """Edge arrays padded by ``width`` trailing sentinel elements — the
-    precondition of the Pallas window-DMA gather
-    (ops/pallas_kernels.py::gather_windows): every [start, start+width)
-    window of a real row then lies fully inside the array. The padded
-    copy SUPERSEDES the original device array (``self._<field>`` is
-    rebound to it and the original freed): row gathers address the same
-    logical prefix and the clip bounds only loosen, so one resident copy
-    serves both the window-DMA and XLA-gather paths — at papers100M
-    scale a duplicate edge array would cost ~GBs of HBM. Peak transient
-    HBM during the rebind is ~2x the field (concatenate reads old,
-    writes new), same as the old steady state. Callers name only the
-    fields they read (the weighted path needs just ``edge_weights``);
-    entries are cached per (width, field), grown to the max width ever
-    asked, and are None where the source array is None.
-    """
-    if self.mode != GraphMode.HBM:
-      # jnp.concatenate below would silently device-place a HOST-mode
-      # (beyond-HBM) edge array, defeating the residency mode; the
-      # window-DMA path requires device-resident topology, so samplers
-      # fall back to the XLA gather when this returns None fields.
-      return {f: None for f in fields}
-    self.lazy_init()
-    import jax.numpy as jnp
-    fills = {'indices': -1, 'edge_ids': -1, 'edge_weights': 0.0}
-    out = {}
-    with self._window_lock:
-      for f in fields:
-        have = self._window_cache.get(f)
-        # one padded copy per FIELD, grown to the max width ever asked:
-        # containment (start + w <= len) holds for every w <= padded
-        # width, so distinct hop widths share the copy instead of each
-        # materializing another full-edge-array duplicate
-        if have is None or have[0] < width:
-          a = getattr(self, '_' + f)
-          if a is None:
-            have = (width, None)
-          else:
-            # logical prefix: when growing an existing padded copy the
-            # stored array already carries the previous width's tail.
-            # Samplers call this at TRACE time (one_hop closures), so
-            # the pad must evaluate eagerly — a staged concatenate
-            # would rebind self._<f> to a tracer that leaks into the
-            # next compiled program (multi-bucket serving traces the
-            # same graph more than once).
-            with jax.ensure_compile_time_eval():
-              a = jnp.asarray(a)[:self.num_edges]
-              padded = jnp.concatenate(
-                  [a, jnp.full((width,), fills[f], a.dtype)])
-            setattr(self, '_' + f, padded)  # supersede: one HBM copy
-            have = (width, padded)
-          self._window_cache[f] = have
-        out[f] = have[1]
-    return out
-
-  def indptr_pad(self):
-    """The CSR offsets with ONE trailing ``num_edges`` sentinel
-    (``[N + 2]`` int32) — the cross-hop walk kernel's row-window source
-    (ops/pallas_kernels.py::sample_walk_dedup): a clamped 2-wide read
-    at row ``min(id, N)`` then reproduces the element path's
-    per-element ``take(..., mode='clip')`` start/degree semantics for
-    masked frontier rows. Built eagerly once and cached (the sampler
-    builds one FusedHopPlan per compiled batch shape — multi-bucket
-    serving must not materialize one padded copy per bucket)."""
-    self.lazy_init()
-    with self._window_lock:
-      have = self._window_cache.get('indptr_pad')
-      if have is None:
-        import jax.numpy as jnp
-        with jax.ensure_compile_time_eval():
-          have = jnp.concatenate(
-              [jnp.asarray(self.indptr, jnp.int32),
-               jnp.full((1,), int(self.num_edges), jnp.int32)])
-        self._window_cache['indptr_pad'] = have
-      return have
-
-  def hub_count(self, width: int) -> int:
-    """Number of rows with degree > ``width`` — the exact hub capacity
-    ``H`` of the windowed sampling paths (``sample_neighbors``'s
-    ``window=(W, H)``): derived host-side from the true degree
-    distribution, once per width, so the bit-identical window/pallas
-    guarantee is unconditional. Cached alongside the window arrays
-    (same lock; cheap per-width recompute on unpickle)."""
-    with self._window_lock:
-      key = ('hub_count', int(width))
-      have = self._window_cache.get(key)
-      if have is None:
-        deg = np.diff(self.topo.indptr)
-        have = int((deg > int(width)).sum())
-        self._window_cache[key] = have
-      return have
 
   # -- probes (reference graph.cu:30-48 LookupDegreeKernel) ---------------
 

@@ -59,7 +59,7 @@ class DistFeature:
 
   def __init__(self, mesh: Mesh, parts: Sequence, feat_pb,
                num_ids: int, axis: str = 'data', dtype=None,
-               row_gather=None, split_ratio: float = 1.0,
+               split_ratio: float = 1.0,
                hot_counts: Optional[Sequence[int]] = None,
                cold_fetcher=None, bucket_cap: int = 0,
                host_offload: Optional[bool] = None):
@@ -71,7 +71,7 @@ class DistFeature:
                     for f, _ in parts]
     spill = any(h < f.shape[0] for h, (f, _) in zip(hot_counts, parts))
     self._finish_init(mesh, axis, num_ids, parts[0][0].shape[1],
-                      rows_max, n_parts, row_gather=row_gather,
+                      rows_max, n_parts,
                       hot_counts=hot_counts, cold_fetcher=cold_fetcher,
                       spill=spill, bucket_cap=bucket_cap)
     if not isinstance(feat_pb, (list, tuple)):
@@ -136,16 +136,12 @@ class DistFeature:
 
   def _finish_init(self, mesh: Mesh, axis: str, num_ids: int,
                    feat_dim: int, rows_max: int, n_parts: int,
-                   row_gather=None, hot_counts=None, cold_fetcher=None,
+                   hot_counts=None, cold_fetcher=None,
                    spill=None, bucket_cap: int = 0):
     """Non-array state shared by __init__ and every alternate builder.
     ANY new scalar/config field must be set here, so a builder that
     assembles the arrays differently (e.g. the multihost
     process-local path) can never miss it."""
-    # row_gather: optional serving-gather override (see
-    # parallel.ShardedFeature); must be set before the first lookup —
-    # the jitted shard_map traces it in on first call
-    self._row_gather = row_gather
     self.mesh = mesh
     self.axis = axis
     self.num_ids = int(num_ids)
@@ -240,11 +236,8 @@ class DistFeature:
         rows = jnp.take(map_shard, jnp.clip(ids, 0, self.num_ids - 1),
                         mode='clip')
         ok = valid & (ids >= 0) & (rows >= 0)
-        from ..ops.pallas_kernels import resolve_row_gather
-        gather = resolve_row_gather(self._row_gather)
         safe_rows = jnp.clip(rows, 0, self.hot_max - 1)
-        rows_out = (gather(feat_shard, safe_rows) if gather is not None
-                    else jnp.take(feat_shard, safe_rows, axis=0))
+        rows_out = jnp.take(feat_shard, safe_rows, axis=0)
         return jnp.where(ok[:, None], rows_out, 0)
     # stages as parallel/dist_feature.py names them, below the caller's
     # ``feature_store`` scope
@@ -273,13 +266,7 @@ class DistFeature:
           cold = ok & (rows >= my_hot)
           ok = ok & (rows < my_hot)
         safe_rows = jnp.clip(rows, 0, self.hot_max - 1)
-        from ..ops.pallas_kernels import resolve_row_gather
-        gather = resolve_row_gather(self._row_gather)
-        if gather is not None:   # per-row DMA serving gather (see
-          #                        parallel.ShardedFeature.lookup_local)
-          rows_out = gather(feat_shard, safe_rows)
-        else:
-          rows_out = jnp.take(feat_shard, safe_rows, axis=0)
+        rows_out = jnp.take(feat_shard, safe_rows, axis=0)
         served = jnp.where(ok[:, None], rows_out, 0)
       if not self._spill:
         with jax.named_scope('exchange'):
@@ -439,7 +426,7 @@ class DistFeature:
   @classmethod
   def from_dist_datasets(cls, mesh: Mesh, datasets, ntype=None,
                          axis: str = 'data', dtype=None,
-                         kind: str = 'node', row_gather=None,
+                         kind: str = 'node',
                          cold_fetcher=None, split_ratio=None,
                          bucket_cap: int = 0,
                          host_offload: Optional[bool] = None):
@@ -482,7 +469,7 @@ class DistFeature:
                   else int(round(block.shape[0] * float(split_ratio))))
       parts.append((block, feat._id2index))
     return cls(mesh, parts, pbs, num_ids, axis=axis, dtype=dtype,
-               row_gather=row_gather, hot_counts=hots,
+               hot_counts=hots,
                cold_fetcher=cold_fetcher, bucket_cap=bucket_cap,
                host_offload=host_offload)
 
@@ -537,7 +524,6 @@ def dist_feature_from_partitions_multihost(mesh, root_dir: str,
                                            ntype=None, axis: str = 'data',
                                            dtype=None,
                                            kind: str = 'node',
-                                           row_gather=None,
                                            split_ratio: float = 1.0,
                                            cold_fetcher=None,
                                            bucket_cap: int = 0,
@@ -625,7 +611,7 @@ def dist_feature_from_partitions_multihost(mesh, root_dir: str,
 
   store = DistFeature.__new__(DistFeature)
   store._finish_init(mesh, axis, num_ids, feat_dim, rows_max, n_parts,
-                     row_gather=row_gather, hot_counts=hot_counts,
+                     hot_counts=hot_counts,
                      cold_fetcher=cold_fetcher, spill=spill,
                      bucket_cap=bucket_cap)
 

@@ -8,7 +8,7 @@ into collectives (SURVEY.md §7 "One SPMD program instead of rpc actors"):
 
     owner = node_pb[frontier]            # the PB routing
     all_to_all(requests)                 # the rpc fan-out
-    local Pallas/XLA sample on each owner
+    local XLA sample on each owner
     all_to_all(responses)                # the rpc returns
     positional unbucket                  # the stitch
 

@@ -717,6 +717,9 @@ class _Fabric:
     self._clients: Dict[int, RpcClient] = {}
     self._lock = threading.Lock()
     self._seq: Dict[str, int] = {}
+    # set by shutdown_rpc once the final barrier has passed: every peer
+    # is then on its way to hanging up on the master
+    self.drained = False
 
   def client(self, dst: int) -> RpcClient:
     # self-requests go through the socket too: one code path
@@ -749,6 +752,18 @@ class _Fabric:
     self.master.close()
     self.server.stop()
     if self.master_server is not None:
+      if self.drained:
+        # the final barrier releases every rank's serve thread at once,
+        # and rank 0 gets here while a peer's reply may still be in its
+        # serve thread's hands: stop() would close that socket under it
+        # ("peer closed" on the peer). Each peer hangs up on the master
+        # as it closes, so wait for that, bounded for a peer that died
+        deadline = time.monotonic() + 30.0
+        while time.monotonic() < deadline:
+          with self.master_server._lock:
+            if not self.master_server._conns:
+              break
+          time.sleep(0.01)
       self.master_server.stop()
 
 
@@ -817,6 +832,7 @@ def shutdown_rpc(graceful: bool = True) -> None:
   try:
     if graceful:
       global_barrier()
+      fab.drained = True
   finally:
     del _fabric['ctx']
     fab.close()

@@ -85,8 +85,7 @@ class LinkLoader(NodeLoader):
   def _collate_homo_link(self, out, n_valid) -> Batch:
     x = None
     if self.collect_features and self.data.node_features is not None:
-      x = gather_features(self.data.get_node_feature(), out.node,
-                          fused=(out.metadata or {}).get('node_feats'))
+      x = gather_features(self.data.get_node_feature(), out.node)
     batch = to_batch(out, x=x, batch_size=self.batch_size)
     meta = dict(batch.metadata or {})
     meta['n_valid'] = n_valid

@@ -154,10 +154,7 @@ class NodeLoader:
   def _collate_homo(self, out: SamplerOutput, seeds, n_valid) -> Batch:
     x = None
     if self.collect_features and self.data.node_features is not None:
-      # pallas_fused samplers with an in-walk gather hand the block
-      # back through metadata; gather_features passes it through
-      x = gather_features(self.data.get_node_feature(), out.node,
-                          fused=(out.metadata or {}).get('node_feats'))
+      x = gather_features(self.data.get_node_feature(), out.node)
     y = None
     if self.data.node_labels is not None:
       y = jnp.asarray(self.data.get_node_label()[seeds])

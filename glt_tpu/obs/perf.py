@@ -25,8 +25,8 @@ once per device kind, caches the result as JSON
 (``GLT_ROOFLINE_CACHE``), and publishes
 ``roofline_hbm_bytes_per_sec`` / ``roofline_flops_per_sec`` gauges.
 :func:`roofline_report` then restates any items/s headline as % of the
-*measured* ceilings plus bytes-per-item and FLOPs-per-item —
-``bench.py`` emits one such cell per raced engine contender.
+*measured* ceilings plus bytes-per-item and FLOPs-per-item. Nothing
+in BENCHMARK.json reads this half (ROADMAP D3).
 
 Everything here is host-side and allocation-free in steady state;
 nothing touches traced code paths except the deliberate trace-time
@@ -170,7 +170,7 @@ def instrument_compiled(fn_name: str, stage=None, *args,
   ``Lowered.cost_analysis()`` counts the PRE-optimization HLO (every
   unfused intermediate reads as memory traffic), while the Compiled
   analysis reflects the optimized executable and unlocks
-  ``memory_analysis()`` — callers quoting roofline evidence (bench.py)
+  ``memory_analysis()`` — callers quoting roofline evidence
   pay the compile (cheap when the persistent compilation cache already
   holds the program); ambient instrumentation stays lower-only.
 
@@ -190,27 +190,7 @@ def instrument_compiled(fn_name: str, stage=None, *args,
   try:
     if callable(getattr(stage, 'lower', None)) \
         and not hasattr(stage, 'cost_analysis'):
-      # trace-time launch accounting rides the lower: the delta of the
-      # pallas module's per-trace counter around this re-trace is the
-      # number of kernel entries in the program — the ground truth the
-      # lowered text confirms on TPU (custom-call count) and the only
-      # signal in interpret mode, where kernels inline into plain HLO
-      from ..ops.pallas_kernels import kernel_launch_count
-      before = kernel_launch_count()
       stage = stage.lower(*args, **kwargs)
-      traced_launches = kernel_launch_count() - before
-    else:
-      traced_launches = None
-    hlo_launches = None
-    try:
-      txt = stage.as_text() if callable(getattr(stage, 'as_text',
-                                                None)) else ''
-      if txt:
-        # count ONLY Mosaic kernel entries — a generic custom_call
-        # count would pick up RNG/sort library calls on some backends
-        hlo_launches = txt.count('tpu_custom_call')
-    except Exception:
-      pass
     if aot_compile and callable(getattr(stage, 'compile', None)):
       try:
         stage = stage.compile()
@@ -220,21 +200,6 @@ def instrument_compiled(fn_name: str, stage=None, *args,
     compiled = stage
     cost = _flatten_cost(compiled.cost_analysis())
     out = {}
-    # kernel launches per dispatch: the HLO custom-call count when the
-    # program actually embeds kernels as custom calls (TPU), else the
-    # trace-time pallas_call count (interpret mode). A traced delta of
-    # ZERO is not evidence of "no kernels" — the inner jit wrappers may
-    # have hit the jaxpr cache from an earlier trace of the same
-    # shapes (kernel_launch_count's documented caveat) — so only a
-    # POSITIVE count is ever recorded; absence means "not measurable
-    # here", never "zero kernels"
-    if hlo_launches:
-      out['kernel_launches'] = int(hlo_launches)
-    elif traced_launches:
-      out['kernel_launches'] = int(traced_launches)
-    if 'kernel_launches' in out:
-      reg.set('xla_kernel_launches', float(out['kernel_launches']),
-              fn=str(fn_name))
     if 'flops' in cost:
       out['flops'] = float(cost['flops'])
       reg.set('xla_flops', out['flops'], fn=str(fn_name))

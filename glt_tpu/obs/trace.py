@@ -184,8 +184,7 @@ class Tracer:
       65536); oldest spans drop first.
     registry: a :class:`MetricsRegistry` that also receives every
       finished span's duration as a ``stage_seconds{stage=<name>}``
-      histogram observation (None = the process-global registry) — the
-      per-stage breakdown bench.py reports rides these.
+      histogram observation (None = the process-global registry).
   """
 
   def __init__(self, enabled: Optional[bool] = None,

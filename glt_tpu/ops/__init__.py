@@ -1,6 +1,6 @@
 from .sample import (
-    FusedHopPlan, NeighborOutput, sample_neighbors,
-    sample_neighbors_fused, sample_neighbors_weighted, neighbor_probs,
+    NeighborOutput, sample_neighbors, sample_neighbors_weighted,
+    neighbor_probs,
 )
 from .unique import ordered_unique, InducerState, init_node, induce_next
 from .negative import edge_in_csr, random_negative_sample, NegativeOutput
@@ -10,8 +10,7 @@ from .superstep import superstep, scan_consume
 from .delta import delta_one_hop, tombstone_mask
 
 __all__ = [
-    'FusedHopPlan', 'NeighborOutput', 'sample_neighbors',
-    'sample_neighbors_fused', 'sample_neighbors_weighted',
+    'NeighborOutput', 'sample_neighbors', 'sample_neighbors_weighted',
     'neighbor_probs',
     'ordered_unique', 'InducerState', 'init_node', 'induce_next',
     'edge_in_csr', 'random_negative_sample', 'NegativeOutput',

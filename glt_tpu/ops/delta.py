@@ -69,10 +69,6 @@ def delta_one_hop(
     ins_window: int,
     del_window: int,
     replace: bool = False,
-    base_window: Optional[tuple] = None,
-    indices_win: Optional[jax.Array] = None,
-    engine: Optional[str] = None,
-    interpret: bool = False,
 ) -> NeighborOutput:
   """One delta-merged hop; output width ``abs(fanout) + ins_window``.
 
@@ -95,23 +91,13 @@ def delta_one_hop(
   Edge ids are slot-encoded (with_edge consumers are unsupported on the
   stream path — delta edges have no stable compressed slot until
   compaction).
-
-  ``base_window``/``indices_win``/``engine``/``interpret`` route the
-  BASE uniform hop through a windowed read engine (``window`` or
-  ``pallas`` — see ops/pipeline.py::hop_engine); the delta overlays
-  keep their fixed ``ins_window``/``del_window`` full-neighborhood
-  reads regardless. The snapshot's capacity-padded indices array
-  doubles as ``indices_win`` whenever its padding slack covers the
-  window width (StreamSampler checks per snapshot).
   """
   if fanout < 0:
     base = sample_full_neighbors(indptr, indices, frontier, -fanout,
                                  seed_mask=seed_mask)
   else:
     base = sample_neighbors(indptr, indices, frontier, fanout, key,
-                            seed_mask=seed_mask, replace=replace,
-                            window=base_window, indices_win=indices_win,
-                            engine=engine, interpret=interpret)
+                            seed_mask=seed_mask, replace=replace)
   keep = base.mask
   if del_window > 0:
     dels = sample_full_neighbors(del_indptr, del_indices, frontier,

@@ -75,65 +75,14 @@ def _floyd_offsets(deg: jax.Array, u: jax.Array, fanout: int) -> jax.Array:
 
 
 def _hop_degrees(indptr, seeds, seed_mask):
-  """Window start + masked degree per frontier row — the shared prefix
-  of every engine's draw. Factored out so the cross-hop walk's XLA-side
-  mask recomputation (ops/pipeline.py::_multihop_sample_walk) uses the
-  LITERAL same clip/mask semantics as the draw it mirrors."""
+  """Row start and masked degree of each frontier row: the prefix the
+  uniform, full-neighbourhood and weighted hops share."""
   start = jnp.take(indptr, seeds, mode='clip')
   end = jnp.take(indptr, seeds + 1, mode='clip')
   deg = (end - start).astype(jnp.int32)
   if seed_mask is not None:
     deg = jnp.where(seed_mask, deg, 0)
   return start, deg
-
-
-def hop_valid_mask(indptr, seeds, fanout, seed_mask, replace):
-  """The draw's validity mask WITHOUT the offset draw: [S, K] lanes
-  valid exactly where :func:`_draw_hop` would mark them. The cross-hop
-  walk kernel computes its masks on-chip from the same degree formula;
-  this recomputation (two [S] gathers) is what the XLA side uses for
-  ``edge_mask`` so both derive from one definition."""
-  seeds = seeds.astype(indptr.dtype)
-  _, deg = _hop_degrees(indptr, seeds, seed_mask)
-  if replace:
-    return jnp.broadcast_to(deg[:, None] > 0, (seeds.shape[0], fanout))
-  iota = jnp.arange(fanout, dtype=jnp.int32)[None, :]
-  return iota < jnp.minimum(deg, fanout)[:, None]
-
-
-def _draw_hop(indptr, seeds, fanout, key, seed_mask, replace):
-  """The one uniform-hop offset draw shared by EVERY hop engine: degree
-  window, Floyd/replace offsets, validity mask, absolute edge slots.
-  Keeping this in one place is what makes the engines bit-identical —
-  they differ only in WHERE neighbor values are read from."""
-  start, deg = _hop_degrees(indptr, seeds, seed_mask)
-  iota = jnp.arange(fanout, dtype=jnp.int32)[None, :]    # [1, K]
-  if replace:
-    u = jax.random.uniform(key, (seeds.shape[0], fanout))
-    offsets = jnp.minimum((u * deg[:, None]).astype(jnp.int32),
-                          jnp.maximum(deg[:, None] - 1, 0))
-    mask = jnp.broadcast_to(deg[:, None] > 0, offsets.shape)
-  else:
-    u = jax.random.uniform(key, (fanout, seeds.shape[0]))
-    sampled = _floyd_offsets(deg, u, fanout)
-    exhaustive = jnp.broadcast_to(iota, sampled.shape)
-    offsets = jnp.where((deg <= fanout)[:, None], exhaustive, sampled)
-    mask = iota < jnp.minimum(deg, fanout)[:, None]
-  return start, deg, offsets, mask
-
-
-def _hub_fixup_inputs(deg, slots, w_width, n_hub, fanout, s):
-  """Hub row indices + exact edge slots for the Pallas kernels' tail
-  pass (shared by the ``pallas`` and ``pallas_fused`` engines)."""
-  if n_hub > 0 and s > 0:
-    hub_idx = jnp.nonzero(deg > w_width, size=n_hub,
-                          fill_value=-1)[0].astype(jnp.int32)
-    hub_slots = jnp.take(slots, jnp.maximum(hub_idx, 0),
-                         axis=0).astype(jnp.int32)           # [H, K]
-  else:  # static dummy row: -1 never matches a block
-    hub_idx = jnp.full((1,), -1, jnp.int32)
-    hub_slots = jnp.zeros((1, fanout), jnp.int32)
-  return hub_idx, hub_slots
 
 
 def _slots_i32(start, offsets, num_edges):
@@ -150,27 +99,6 @@ def _slots_i32(start, offsets, num_edges):
                   0, max(num_edges - 1, 0)).astype(jnp.int32)
 
 
-def _gather_row_windows(src: jax.Array, start: jax.Array,
-                        width: int) -> jax.Array:
-  """[S, width] contiguous slice per row: win[s, j] = src[start[s] + j].
-
-  One gather descriptor per ROW instead of per element — on TPU this
-  lowers to per-row DMA of a contiguous run, the memory-access shape the
-  hardware is good at (vs the per-element random access of
-  ``jnp.take(src, slots)``). ``src`` must carry >= width slots of
-  padding past the last real element: CLIP mode clamps the *start* of an
-  out-of-range slice, which would silently shift tail windows on an
-  unpadded array (same contract as ops/pallas_kernels.py).
-  """
-  import jax.lax as lax
-  return lax.gather(
-      src, start[:, None].astype(jnp.int32),
-      lax.GatherDimensionNumbers(
-          offset_dims=(1,), collapsed_slice_dims=(),
-          start_index_map=(0,)),
-      slice_sizes=(width,), mode=lax.GatherScatterMode.CLIP)
-
-
 def sample_neighbors(
     indptr: jax.Array,
     indices: jax.Array,
@@ -180,11 +108,6 @@ def sample_neighbors(
     seed_mask: Optional[jax.Array] = None,
     edge_ids: Optional[jax.Array] = None,
     replace: bool = False,
-    window: Optional[tuple] = None,
-    indices_win: Optional[jax.Array] = None,
-    edge_ids_win: Optional[jax.Array] = None,
-    engine: Optional[str] = None,
-    interpret: bool = False,
 ) -> NeighborOutput:
   """Uniformly sample up to ``fanout`` neighbors per seed from a CSR/CSC.
 
@@ -195,402 +118,38 @@ def sample_neighbors(
   <= fanout the sample is exhaustive and in adjacency order (which makes
   tiny-graph tests exact, the reference test strategy SURVEY.md §4).
 
-  ``window=(W, H)`` enables the TPU window read path: neighbor values
-  are read from a [S, W] contiguous per-row window (one DMA per row —
-  see :func:`_gather_row_windows`) instead of a [S, fanout] per-element
-  random gather, with the up-to-``H`` hub rows (degree > W) fixed up by
-  an exact [H, fanout] element gather. Offsets are drawn identically in
-  both paths, so results are BIT-IDENTICAL to the element path provided
-  ``H >= number of hub ROWS in the frontier`` (a hub node occurring
-  twice needs two fix-up slots) — the samplers derive H from the
-  graph's true hub count (host-side, once), which bounds the row count
-  because their internal frontiers are deduplicated/masked, so the
-  guarantee is unconditional there; direct callers passing frontiers
-  with duplicate hub ids must size H for the duplicates. An EAGER call
-  (concrete arrays, outside jit) with an
-  undersized H raises ValueError, while traced calls keep the
-  documented confinement (only unfixed hub rows deviate). Requires
-  ``indices_win``: the same indices array with >= W trailing padding
-  slots (Graph.window_arrays / a one-time host pad); ``edge_ids_win``
-  likewise when ``edge_ids`` is passed.
-
-  ``engine`` picks the window-read implementation (see
-  ops/pipeline.py::hop_engine): ``'window'`` (default when ``window``
-  is given) keeps the XLA slice-gather path; ``'pallas'`` routes the
-  window read + offset pick + hub fix-up through the fused one-hop
-  megakernel (ops/pallas_kernels.py::sample_hop, ``interpret`` for
-  off-TPU parity runs); ``'element'`` ignores ``window``. Offsets come
-  from the same draw in every engine, so outputs stay bit-identical.
+  Neighbor values are read by a [S, fanout] per-element gather from
+  ``indices``: the one hop read in the tree, and the one every line of
+  PERF_LEDGER.jsonl was produced by.
   """
   assert fanout > 0, 'fanout must be a static positive int'
-  if engine is None:
-    engine = 'window' if window is not None else 'element'
-  if engine == 'pallas_fused':
-    # the dedup fusion only engages through the pipeline entry point
-    # (FusedHopPlan / multihop_sample); a plain NeighborOutput call
-    # reads windows through the same megakernel machinery as 'pallas'
-    engine = 'pallas'
-  assert engine in ('element', 'window', 'pallas'), engine
-  if engine == 'element':
-    window = None
-  else:
-    assert window is not None, f"engine={engine!r} needs window=(W, H)"
   seeds = seeds.astype(indptr.dtype)
   num_edges = indices.shape[0]
   if num_edges == 0:  # legitimately empty (e.g. a rare-etype partition)
     return _empty_output(seeds.shape[0], fanout, indices, edge_ids,
                          indptr)
-  start, deg, offsets, mask = _draw_hop(indptr, seeds, fanout, key,
-                                        seed_mask, replace)
+  start, deg = _hop_degrees(indptr, seeds, seed_mask)
+  iota = jnp.arange(fanout, dtype=jnp.int32)[None, :]    # [1, K]
+  if replace:
+    u = jax.random.uniform(key, (seeds.shape[0], fanout))
+    offsets = jnp.minimum((u * deg[:, None]).astype(jnp.int32),
+                          jnp.maximum(deg[:, None] - 1, 0))
+    mask = jnp.broadcast_to(deg[:, None] > 0, offsets.shape)
+  else:
+    u = jax.random.uniform(key, (fanout, seeds.shape[0]))
+    sampled = _floyd_offsets(deg, u, fanout)
+    exhaustive = jnp.broadcast_to(iota, sampled.shape)
+    offsets = jnp.where((deg <= fanout)[:, None], exhaustive, sampled)
+    mask = iota < jnp.minimum(deg, fanout)[:, None]
   # int32 everywhere edge slots flow: a shard's edge count fits int32
   # by construction in this stack (the partitioner splits well before
   # 2^31 edges/shard), so an int64 indptr must not widen the [S, K]
   # slot/eid planes it feeds — half the index bytes on the hot path
   slots = _slots_i32(start, offsets, num_edges)
-  if window is not None:
-    w_width, n_hub = window
-    assert indices_win is not None, (
-        'window read path needs indices_win (W-padded indices); pass '
-        'Graph.window_arrays()["indices"] or pad host-side once')
-    if not isinstance(deg, jax.core.Tracer):
-      # eager call: the docstring guarantee is checkable — fail loudly
-      # instead of silently truncating hub rows past the H capacity
-      true_hubs = int((deg > w_width).sum())
-      if true_hubs > n_hub:
-        raise ValueError(
-            f'window=(W={w_width}, H={n_hub}) underestimates the '
-            f'frontier hub count: {true_hubs} ROWS have degree > W '
-            '(a repeated hub seed counts once per occurrence). '
-            'Graph.hub_count(W) bounds this for deduplicated/masked '
-            'frontiers — the samplers\' internal hops; raise H to the '
-            'frontier size for duplicate-bearing eager calls.')
-    if engine == 'pallas':
-      from .pallas_kernels import sample_hop
-      assert edge_ids is None or edge_ids_win is not None, (
-          'pallas engine with edge_ids needs edge_ids_win (the W-padded '
-          'edge-id array, Graph.window_arrays()["edge_ids"])')
-      hub_idx, hub_slots = _hub_fixup_inputs(deg, slots, w_width, n_hub,
-                                             fanout, seeds.shape[0])
-      nbrs, eid_picks = sample_hop(
-          indices_win, edge_ids_win if edge_ids is not None else None,
-          start.astype(jnp.int32), offsets, hub_idx, hub_slots,
-          width=w_width, interpret=interpret)
-      eids = eid_picks if edge_ids is not None else slots
-      return NeighborOutput(nbrs=nbrs, mask=mask, eids=eids)
-    win = _gather_row_windows(indices_win, start, w_width)   # [S, W]
-    woff = jnp.minimum(offsets, w_width - 1)
-    nbrs = jnp.take_along_axis(win, woff, axis=1)
-    if edge_ids is not None:
-      ewin = _gather_row_windows(edge_ids_win, start, w_width)
-      eids = jnp.take_along_axis(ewin, woff, axis=1)
-    else:
-      eids = slots
-    if n_hub > 0 and seeds.shape[0] > 0:
-      # exact fix-up: element-gather only the hub rows
-      hub_idx = jnp.nonzero(deg > w_width, size=n_hub,
-                            fill_value=0)[0]                 # [H]
-      hub_ok = jnp.take(deg, hub_idx) > w_width              # fill rows F
-      hub_slots = jnp.take(slots, hub_idx, axis=0)           # [H, K]
-      hub_vals = jnp.take(indices, hub_slots, mode='clip')
-      nbrs = nbrs.at[hub_idx].set(
-          jnp.where(hub_ok[:, None], hub_vals,
-                    jnp.take(nbrs, hub_idx, axis=0)))
-      if edge_ids is not None:
-        hub_eids = jnp.take(edge_ids, hub_slots, mode='clip')
-        eids = eids.at[hub_idx].set(
-            jnp.where(hub_ok[:, None], hub_eids,
-                      jnp.take(eids, hub_idx, axis=0)))
-    return NeighborOutput(nbrs=nbrs, mask=mask, eids=eids)
   nbrs = jnp.take(indices, slots, mode='clip')
   eids = jnp.take(edge_ids, slots, mode='clip') if edge_ids is not None \
       else slots
   return NeighborOutput(nbrs=nbrs, mask=mask, eids=eids)
-
-
-_BIG_I32 = jnp.iinfo(jnp.int32).max
-
-
-def walk_hop_uniforms(key, batch_size, fanouts, replace, block=8):
-  """Per-hop uniform draws for the cross-hop walk kernel, from the SAME
-  key sequence as the per-hop loop (``key, sub = split(key)`` per hop,
-  ``uniform(sub, (K, S))`` for Floyd / ``(S, K)`` for replace — see
-  :func:`_draw_hop`). The draws are data-independent, which is what
-  lets the whole walk's randomness be staged up front while the
-  frontier itself is produced on-chip. Returned in the kernel's
-  [S_pad, K] row-major orientation (Floyd draws transposed), rows
-  block-padded with zeros."""
-  from .pallas_kernels import walk_geometry
-  hops, _ = walk_geometry(batch_size, fanouts, block)
-  us = []
-  for h in hops:
-    key, sub = jax.random.split(key)
-    if replace:
-      u = jax.random.uniform(sub, (h['s'], h['k']))
-    else:
-      u = jax.random.uniform(sub, (h['k'], h['s'])).T
-    us.append(jnp.pad(u, ((0, h['s_pad'] - h['s']), (0, 0))))
-  return tuple(us)
-
-
-def _value_order_ranks(ids_flat, new_head, prov_rank, m):
-  """The value-order relabel core shared by the per-hop fused wrapper
-  and the cross-hop walk: given a hop's fresh-id heads (``new_head``),
-  their within-hop first-occurrence ranks (``prov_rank``) and ids,
-  return ``(sorted_ids, val_rank)`` where ``sorted_ids`` is the fresh
-  unique ids ascending (_BIG padded — the fused feature gather consumes
-  these directly) and ``val_rank[first_occurrence_rank] = value rank``.
-  One 2-operand sort over [M] — the only sort in a fused hop."""
-  first_rank = jnp.where(new_head, prov_rank, m)        # pads -> sink
-  new_by_rank = jnp.full((m + 1,), _BIG_I32, jnp.int32).at[
-      first_rank].set(jnp.where(new_head, ids_flat, _BIG_I32))[:m]
-  iota = jnp.arange(m, dtype=jnp.int32)
-  sorted_ids, sorted_rank = jax.lax.sort([new_by_rank, iota],
-                                         num_keys=1)
-  val_rank = jnp.zeros((m + 1,), jnp.int32).at[
-      jnp.where(sorted_ids < _BIG_I32, sorted_rank, m)].set(iota)[:m]
-  return sorted_ids, val_rank
-
-
-def sample_neighbors_fused(
-    indptr: jax.Array,
-    indices: jax.Array,
-    seeds: jax.Array,
-    fanout: int,
-    key: jax.Array,
-    tab_ids: jax.Array,
-    tab_labs: jax.Array,
-    count: jax.Array,
-    seed_mask: Optional[jax.Array] = None,
-    edge_ids: Optional[jax.Array] = None,
-    replace: bool = False,
-    window: Optional[tuple] = None,
-    indices_win: Optional[jax.Array] = None,
-    edge_ids_win: Optional[jax.Array] = None,
-    interpret: bool = False,
-):
-  """One FUSED hop: sample + dedup/relabel in a single kernel pass (the
-  ``pallas_fused`` engine, ops/pipeline.py::hop_engine).
-
-  Sampling offsets come from :func:`_draw_hop` — the same draw as every
-  other engine — and the picks, the ``[S, W]`` windows, and the dedup
-  probes all stay inside ``sample_hop_dedup``'s VMEM. The kernel emits
-  PROVISIONAL labels (first-occurrence order); this wrapper restores
-  the exact :func:`glt_tpu.ops.unique.sorted_hop_dedup_fused` contract
-  — new ids labeled ``count..count+n-1`` in within-hop VALUE order,
-  seen ids keeping their labels — with one single-payload sort over the
-  fresh unique ids, and rewrites the table's labels to match so the
-  NEXT hop's probes return final labels.
-
-  Returns ``(out, d, (tab_ids', tab_labs'))`` where ``out`` is the
-  usual :class:`NeighborOutput` and ``d`` carries (all slot-order,
-  shapes ``[S*K]`` unless noted):
-
-    labels3 / new_head3 / count2 / new_count : exactly
-      ``sorted_hop_dedup_fused``'s fields;
-    sorted_new_ids : [S*K] the fresh unique ids ASCENDING (= label
-      order ``count..count+new_count-1``), _BIG padded — the fused
-      feature gather consumes these directly.
-  """
-  assert fanout > 0, 'fanout must be a static positive int'
-  assert window is not None and indices_win is not None, (
-      'the fused engine always reads through windows; pass window=(W, '
-      'H) and the W-padded indices (Graph.window_arrays)')
-  from .pallas_kernels import sample_hop_dedup
-  w_width, n_hub = window
-  seeds = seeds.astype(indptr.dtype)
-  s = seeds.shape[0]
-  m = s * fanout
-  num_edges = indices.shape[0]
-  if num_edges == 0:  # legitimately empty graph: nothing dedups
-    out = _empty_output(s, fanout, indices, edge_ids, indptr)
-    d = dict(labels3=jnp.full((m,), -1, jnp.int32),
-             new_head3=jnp.zeros((m,), bool),
-             count2=count, new_count=jnp.zeros((), jnp.int32),
-             sorted_new_ids=jnp.full((m,), _BIG_I32, jnp.int32))
-    return out, d, (tab_ids, tab_labs)
-  start, deg, offsets, mask = _draw_hop(indptr, seeds, fanout, key,
-                                        seed_mask, replace)
-  slots = _slots_i32(start, offsets, num_edges)
-  assert edge_ids is None or edge_ids_win is not None, (
-      'fused engine with edge_ids needs edge_ids_win (the W-padded '
-      'edge-id array, Graph.window_arrays()["edge_ids"])')
-  hub_idx, hub_slots = _hub_fixup_inputs(deg, slots, w_width, n_hub,
-                                         fanout, s)
-  picks, eid_picks, prov, new_head, tab_ids, tab_labs = \
-      sample_hop_dedup(
-          indices_win, edge_ids_win if edge_ids is not None else None,
-          start.astype(jnp.int32), offsets, mask, hub_idx, hub_slots,
-          tab_ids, tab_labs, count, width=w_width, interpret=interpret)
-  eids = eid_picks if edge_ids is not None else slots
-  out = NeighborOutput(nbrs=picks, mask=mask, eids=eids)
-
-  # value-order relabel: kernel labels are first-occurrence ranks; the
-  # sorted_hop_dedup_fused contract ranks fresh ids by VALUE. One
-  # 2-operand sort over [M] — narrower than the engine it replaces
-  # (3 operands over [C+M]) and the only sort left in the fused hop.
-  ids_flat = picks.reshape(-1).astype(jnp.int32)
-  m_flat = mask.reshape(-1)
-  prov_flat = prov.reshape(-1)
-  nh = new_head.reshape(-1) != 0
-  sorted_ids, val_rank = _value_order_ranks(ids_flat, nh,
-                                            prov_flat - count, m)
-  is_new_el = m_flat & (prov_flat >= count)
-  labels3 = jnp.where(
-      is_new_el,
-      count + jnp.take(val_rank, jnp.clip(prov_flat - count, 0, m - 1)),
-      prov_flat)
-  new_count = nh.sum(dtype=jnp.int32)
-  # table fix-up: this hop's inserts carry provisional labels >= count;
-  # map them through the same rank table so the next hop probes final
-  tab_labs = jnp.where(
-      (tab_ids >= 0) & (tab_labs >= count),
-      count + jnp.take(val_rank, jnp.clip(tab_labs - count, 0, m - 1)),
-      tab_labs)
-  d = dict(labels3=labels3, new_head3=nh, count2=count + new_count,
-           new_count=new_count, sorted_new_ids=sorted_ids)
-  return out, d, (tab_ids, tab_labs)
-
-
-class FusedHopPlan:
-  """Trace-time bundle for the ``pallas_fused`` engine: the graph's
-  window-padded edge arrays, the static window/hub/table geometry, and
-  (optionally) the fused feature-gather closure. Built once per
-  compiled multihop program (sampler/neighbor_sampler.py, bench.py) and
-  consumed by :func:`glt_tpu.ops.pipeline.multihop_sample` — the plan
-  is what routes the hop loop through :func:`sample_neighbors_fused`
-  instead of the ``one_hop`` + sort-dedup pair.
-
-  Args:
-    indptr / indices: the CSR (device-resident).
-    indices_win: W-padded indices (Graph.window_arrays contract).
-    width: window width W.
-    hub_count: the graph's true hub-row count for W (Graph.hub_count) —
-      clamped per hop to the frontier size, like the other engines.
-    table_slots: dedup-table capacity in id slots
-      (pallas_kernels.fused_table_slots(budget); must exceed the walk's
-      node budget so probes terminate).
-    edge_ids / edge_ids_win: optional edge-id plane.
-    gather_fn: optional ``ids [m] -> rows [m, D]`` feature row gather
-      (Feature.fused_gather_fn) — set, the pipeline gathers each hop's
-      fresh rows while the walk is still running and emits
-      ``node_feats`` alongside the sample.
-    feat_dim / feat_dtype: static output geometry for ``gather_fn``.
-      ``feat_dtype`` may NARROW the store dtype (the opt-in bf16 gather
-      plane, ``GLT_FUSED_FEAT_DTYPE=bfloat16``): the in-walk plane and
-      the emitted ``node_feats`` then carry the narrow dtype, halving
-      the gather's HBM write traffic — parity with the post-hoc
-      ``gather_features`` holds after casting the reference (documented
-      precision trade, default off).
-    indptr_pad: optional [N + 2] int32 CSR offsets with a trailing
-      ``num_edges`` sentinel — the cross-hop walk kernel's row-window
-      source (see ``sample_walk_dedup``). Built eagerly here when not
-      passed (plans are constructed outside jit, so the pad is a
-      one-time host/device op, never a leaked tracer).
-  """
-
-  def __init__(self, indptr, indices, indices_win, width, hub_count,
-               table_slots, edge_ids=None, edge_ids_win=None,
-               replace=False, interpret=False, gather_fn=None,
-               feat_dim=None, feat_dtype=None, indptr_pad=None):
-    self.indptr = indptr
-    self.indices = indices
-    self.indices_win = indices_win
-    self.width = int(width)
-    self.hub_count = int(hub_count)
-    self.table_slots = int(table_slots)
-    self.edge_ids = edge_ids
-    self.edge_ids_win = edge_ids_win
-    self.replace = bool(replace)
-    self.interpret = bool(interpret)
-    self.gather_fn = gather_fn
-    self.feat_dim = feat_dim
-    self.feat_dtype = feat_dtype
-    if indptr_pad is None:
-      num_edges = int(indices.shape[0])
-      indptr_pad = jnp.concatenate(
-          [jnp.asarray(indptr, jnp.int32),
-           jnp.full((1,), num_edges, jnp.int32)])
-    self.indptr_pad = indptr_pad
-
-  def init_table(self, ids, labs, valid):
-    """Fresh table planes seeded with the exact-dedup'd seed hop."""
-    from .pallas_kernels import dedup_table_insert, make_dedup_table
-    tab_ids, tab_labs = make_dedup_table(self.table_slots)
-    return dedup_table_insert(tab_ids, tab_labs, ids, labs, valid,
-                              interpret=self.interpret)
-
-  def __call__(self, frontier_ids, fanout, key, mask, table, count):
-    tab_ids, tab_labs = table
-    out, d, table = sample_neighbors_fused(
-        self.indptr, self.indices, frontier_ids, fanout, key,
-        tab_ids, tab_labs, count, seed_mask=mask,
-        edge_ids=self.edge_ids, replace=self.replace,
-        window=(self.width, min(self.hub_count, frontier_ids.shape[0])),
-        indices_win=self.indices_win, edge_ids_win=self.edge_ids_win,
-        interpret=self.interpret)
-    return out, d, table
-
-
-class HeteroFusedPlan:
-  """Trace-time bundle for the ``pallas_fused`` engine over a HETERO
-  graph: the flat multi-edge-type window geometry (the kernel family's
-  edge-type plane, :func:`glt_tpu.ops.pallas_kernels.build_type_plane`)
-  plus per-etype CSR handles and static hub/table sizing. Built once
-  per compiled hetero multihop program (sampler/neighbor_sampler.py,
-  bench.py) and consumed by
-  :func:`glt_tpu.ops.pipeline.multihop_sample_hetero` — the plan routes
-  each hop's per-edge-type sampling into ONE padded multi-edge-type
-  ``sample_hop_dedup`` invocation: one concatenated frontier whose
-  per-segment ``starts`` address the flat plane, per-type fanouts as
-  [S, K_max] offset/validity lanes, and per-type dedup namespaces via
-  the type-tagged global id space.
-
-  Args:
-    etypes: traversal-order edge types (= the reference hop loop's
-      iteration order).
-    trav: Dict[EdgeType, (expand_from_type, neighbor_type)].
-    node_counts: Dict[NodeType, int].
-    parts: Dict[EdgeType, dict(indptr, indices_win, num_edges,
-      hub_count, edge_ids_win=None)] — ``indices_win`` per the
-      Graph.window_arrays contract (W trailing pad slots).
-    width: window width W (shared across edge types).
-    table_slots: VMEM dedup-table capacity in id slots; must exceed the
-      walk's TOTAL node budget across types (probe termination).
-    budget_total: sum of per-type node budgets — sizes the provisional
-      label remap of the XLA epilogue.
-  """
-
-  def __init__(self, etypes, trav, node_counts, parts, width,
-               table_slots, budget_total, replace=False,
-               interpret=False):
-    from .pallas_kernels import build_type_plane
-    self.etypes = list(etypes)
-    self.trav = dict(trav)
-    self.width = int(width)
-    self.table_slots = int(table_slots)
-    self.budget_total = int(budget_total)
-    self.replace = bool(replace)
-    self.interpret = bool(interpret)
-    self.indptr = {e: parts[e]['indptr'] for e in self.etypes}
-    self.num_edges = {e: int(parts[e]['num_edges'])
-                      for e in self.etypes}
-    self.hub_count = {e: int(parts[e].get('hub_count', 0))
-                      for e in self.etypes}
-    plane = build_type_plane(self.etypes, self.trav, node_counts,
-                             parts, self.width)
-    self.type_base = plane['type_base']
-    self.edge_base = plane['edge_base']
-    self.indices_flat = plane['indices_flat']
-    self.eids_flat = plane['eids_flat']
-    self.has_eids = plane['has_eids']
-
-  def init_table(self, ids, labs, valid):
-    """Fresh table planes seeded with the exact-dedup'd multi-type seed
-    hop (ids already type-tagged, labels provisional-global)."""
-    from .pallas_kernels import dedup_table_insert, make_dedup_table
-    tab_ids, tab_labs = make_dedup_table(self.table_slots)
-    return dedup_table_insert(tab_ids, tab_labs, ids, labs, valid,
-                              interpret=self.interpret)
 
 
 def sample_full_neighbors(
@@ -600,8 +159,6 @@ def sample_full_neighbors(
     max_degree: int,
     seed_mask: Optional[jax.Array] = None,
     edge_ids: Optional[jax.Array] = None,
-    window_gather=None,
-    window_sources: Optional[dict] = None,
 ) -> NeighborOutput:
   """Full-neighborhood expansion — the reference's ``fanout = -1``
   (csrc/cpu/random_sampler.cc FullSample path; examples/seal_link_pred.py
@@ -609,14 +166,6 @@ def sample_full_neighbors(
   inside a static ``[S, max_degree]`` window; callers pass
   ``max_degree >= graph max degree`` for exact semantics (NeighborSampler
   resolves this automatically). Degrees above the window are truncated.
-
-  ``window_gather``/``window_sources``: optional fast path for the
-  [S, max_degree] window reads (one DMA descriptor per row instead of a
-  per-element slice-gather — ops/pallas_kernels.py::gather_windows).
-  ``window_sources`` must hold the SAME edge arrays padded by
-  ``max_degree`` trailing sentinels (Graph.window_arrays provides them);
-  masked lanes read sentinel values exactly like the XLA path reads
-  clipped garbage.
   """
   assert max_degree > 0
   seeds = seeds.astype(indptr.dtype)
@@ -624,21 +173,10 @@ def sample_full_neighbors(
   if num_edges == 0:
     return _empty_output(seeds.shape[0], max_degree, indices, edge_ids,
                          indptr)
-  start = jnp.take(indptr, seeds, mode='clip')
-  end = jnp.take(indptr, seeds + 1, mode='clip')
-  deg = (end - start).astype(jnp.int32)
-  if seed_mask is not None:
-    deg = jnp.where(seed_mask, deg, 0)
+  start, deg = _hop_degrees(indptr, seeds, seed_mask)
   deg = jnp.minimum(deg, max_degree)
   win = jnp.arange(max_degree, dtype=jnp.int32)[None, :]   # [1, D]
   mask = win < deg[:, None]
-  if window_gather is not None:
-    nbrs = window_gather(window_sources['indices'], start, max_degree)
-    if edge_ids is not None:
-      eids = window_gather(window_sources['edge_ids'], start, max_degree)
-    else:
-      eids = _slots_i32(start, win, num_edges)
-    return NeighborOutput(nbrs=nbrs, mask=mask, eids=eids)
   slots = _slots_i32(start, win, num_edges)
   nbrs = jnp.take(indices, slots, mode='clip')
   eids = jnp.take(edge_ids, slots, mode='clip') if edge_ids is not None \
@@ -656,8 +194,6 @@ def sample_neighbors_weighted(
     max_degree: int,
     seed_mask: Optional[jax.Array] = None,
     edge_ids: Optional[jax.Array] = None,
-    window_gather=None,
-    window_sources: Optional[dict] = None,
 ) -> NeighborOutput:
   """Weight-proportional sampling without replacement via Gumbel-top-k.
 
@@ -665,9 +201,6 @@ def sample_neighbors_weighted(
   hub nodes with more neighbors only the first ``max_degree`` (in
   adjacency order) participate. Pass ``max_degree >= topo.max_degree``
   for exact semantics.
-
-  ``window_gather``/``window_sources``: optional DMA fast path for the
-  [S, max_degree] weight-window read (see sample_full_neighbors).
   """
   assert fanout > 0
   assert fanout <= max_degree, (
@@ -678,22 +211,14 @@ def sample_neighbors_weighted(
   if num_edges == 0:
     return _empty_output(seeds.shape[0], fanout, indices, edge_ids,
                          indptr)
-  start = jnp.take(indptr, seeds, mode='clip')
-  end = jnp.take(indptr, seeds + 1, mode='clip')
-  deg = (end - start).astype(jnp.int32)
-  if seed_mask is not None:
-    deg = jnp.where(seed_mask, deg, 0)
+  start, deg = _hop_degrees(indptr, seeds, seed_mask)
   deg = jnp.minimum(deg, max_degree)
 
   win = jnp.arange(max_degree, dtype=jnp.int32)[None, :]  # [1, D]
   valid = win < deg[:, None]                               # [S, D]
-  if window_gather is not None:
-    w = window_gather(window_sources['edge_weights'], start,
-                      max_degree).astype(jnp.float32)
-  else:
-    slots = jnp.clip(start[:, None] + win.astype(start.dtype),
-                     0, max(num_edges - 1, 0))
-    w = jnp.take(weights, slots, mode='clip').astype(jnp.float32)
+  slots = jnp.clip(start[:, None] + win.astype(start.dtype),
+                   0, max(num_edges - 1, 0))
+  w = jnp.take(weights, slots, mode='clip').astype(jnp.float32)
   w = jnp.where(valid & (w > 0), w, 0.0)
   g = -jnp.log(-jnp.log(
       jax.random.uniform(key, w.shape, minval=1e-20, maxval=1.0)))
@@ -735,9 +260,9 @@ def neighbor_probs(
                      0.0)
   contrib_per_src = seed_probs * rate                     # [N]
   # expand to edges: edge e has src = row(e). ``indices`` may carry a
-  # sentinel-padded tail (Graph.window_arrays supersedes the original
-  # with the window-padded copy); positions at/after indptr[-1] are not
-  # edges — zero their contribution and clamp the sentinel (-1) ids.
+  # padded tail (a stream snapshot's capacity padding); positions
+  # at/after indptr[-1] are not edges — zero their contribution and
+  # clamp the sentinel (-1) ids.
   pos = jnp.arange(indices.shape[0], dtype=indptr.dtype)
   rows = jnp.searchsorted(indptr, pos, side='right') - 1
   contrib = jnp.take(contrib_per_src, rows, mode='clip')

@@ -96,12 +96,8 @@ class ShardedFeature:
   """
 
   def __init__(self, feats, mesh: Mesh, axis: str = 'data', dtype=None,
-               row_gather=None, split_ratio: float = 1.0,
-               bucket_cap: int = 0, host_offload: Optional[bool] = None):
-    # row_gather: optional (shard [R, D], rows [M]) -> [M, D] override
-    # for the serving gather — tests inject the interpret-mode Pallas
-    # kernel; on TPU GLT_USE_PALLAS=1 selects it automatically
-    self._row_gather = row_gather
+               split_ratio: float = 1.0, bucket_cap: int = 0,
+               host_offload: Optional[bool] = None):
     feats = as_numpy(feats)
     self.mesh = mesh
     self.axis = axis
@@ -246,16 +242,7 @@ class ShardedFeature:
     ok = (local_rows >= 0) & (local_rows < self.hot_count) & \
         (req_in >= 0)
     safe_rows = jnp.clip(local_rows, 0, self.hot_count - 1)
-    # one DMA descriptor per served row instead of XLA's
-    # per-output-element gather (the UnifiedTensor GatherTensorKernel
-    # analogue, done the TPU way), when enabled
-    from ..ops.pallas_kernels import resolve_row_gather
-    gather = resolve_row_gather(self._row_gather)
-    if gather is not None:
-      rows_out = gather(local_shard, safe_rows.reshape(-1)).reshape(
-          safe_rows.shape + (self.feature_dim,))
-    else:
-      rows_out = jnp.take(local_shard, safe_rows, axis=0)
+    rows_out = jnp.take(local_shard, safe_rows, axis=0)
     served = jnp.where(ok[..., None], rows_out, 0)
     if cold_shard is not None and self._spill:
       # serve the owner's SPILLED rows from pinned host memory

@@ -18,7 +18,6 @@ traversal type itself.
 """
 from __future__ import annotations
 
-import logging
 from typing import Dict, List, Optional, Union
 
 import jax
@@ -26,10 +25,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..data import Graph
-from ..ops.pipeline import count_engine_fallback, dedup_engine, \
-    edge_hop_offsets, hetero_edge_hop_offsets, hop_engine, \
-    make_dedup_tables, multihop_sample, multihop_sample_hetero, \
-    node_hop_offsets, sample_budget
+from ..ops.pipeline import dedup_engine, edge_hop_offsets, \
+    hetero_edge_hop_offsets, make_dedup_tables, multihop_sample, \
+    multihop_sample_hetero, node_hop_offsets
 from ..ops.sample import (
     neighbor_probs, sample_full_neighbors, sample_neighbors,
     sample_neighbors_weighted,
@@ -38,25 +36,14 @@ from ..obs import get_tracer
 from ..ops.subgraph import induced_subgraph
 from ..typing import EdgeType, NodeType, reverse_edge_type
 from ..utils import as_numpy
-from ..utils.env import knob
 from ..utils.rng import RandomSeedManager
 from .base import (
     BaseSampler, HeteroSamplerOutput, NodeSamplerInput, SamplerOutput,
 )
 
-logger = logging.getLogger(__name__)
-
 #: above this column-space size the dense label table is considered too
 #: expensive (2 × 4 bytes per node in HBM)
 DENSE_TABLE_NODE_LIMIT = 256_000_000
-
-
-def _window_width() -> int:
-  """Window width W of the windowed hop engines (`GLT_WINDOW_W`,
-  default 96, floored at 8) — ONE definition so the homo plan, the
-  hetero plan, and the demoted per-hop window read can never disagree
-  on the geometry they share."""
-  return max(knob('GLT_WINDOW_W', 96), 8)
 
 
 class NeighborSampler(BaseSampler):
@@ -79,21 +66,6 @@ class NeighborSampler(BaseSampler):
       path; defaults to the graph's max degree.
     full_neighbor_cap: static neighbor-window bound for ``-1`` hops.
     seed: RNG seed; defaults to the process RandomSeedManager.
-    fused_feature: optional fully-device-resident
-      :class:`~glt_tpu.data.feature.Feature` for the ``pallas_fused``
-      engine's in-walk feature gather: each hop's FRESH unique rows are
-      gathered while the walk runs (through the existing
-      ``gather_rows``/``row_gather`` path) and the assembled
-      ``[budget, D]`` block lands in ``SamplerOutput.metadata
-      ['node_feats']`` — bit-identical to ``gather_features(feat,
-      out.node)``, which downstream call sites short-circuit through
-      (``gather_features(..., fused=)``). The feature block is a
-      compile-time constant of the sampler's programs, so swapping the
-      store (stream snapshot updates) requires a fresh sampler — the
-      stream path therefore never enables this.
-    row_gather: optional gather-kernel override for ``fused_feature``
-      (the ``resolve_row_gather`` seam, same contract as
-      ``Feature.device_gather``).
   """
 
   def __init__(
@@ -108,8 +80,6 @@ class NeighborSampler(BaseSampler):
       seed: Optional[int] = None,
       max_weighted_degree: Optional[int] = None,
       full_neighbor_cap: Optional[int] = None,
-      fused_feature=None,
-      row_gather=None,
   ):
     assert edge_dir in ('out', 'in')
     self.graph = graph
@@ -121,9 +91,6 @@ class NeighborSampler(BaseSampler):
     self.device = device
     self.max_weighted_degree = max_weighted_degree
     self.full_neighbor_cap = full_neighbor_cap
-    self.fused_feature = fused_feature
-    self.row_gather = row_gather
-    self._fallbacks_counted = set()
     from ..utils.rng import make_key
     self._base_key = make_key(
         seed if seed is not None
@@ -207,290 +174,22 @@ class NeighborSampler(BaseSampler):
       self._tables[ntype] = make_dedup_tables(num_nodes)
     return self._tables[ntype]
 
-  def _window_kwargs(self, g: Graph, width: int, fields):
-    """Opt-in Pallas DMA window-gather plumbing for the [S, width]
-    window reads of the full/weighted paths (GLT_USE_PALLAS=1 on TPU;
-    tests inject an interpret-mode gather via ``_window_gather_fn``)."""
-    fn = getattr(self, '_window_gather_fn', None)
-    if fn is None:
-      from ..ops.pallas_kernels import gather_windows, use_pallas_default
-      if not use_pallas_default():
-        return {}
-      fn = gather_windows
-    sources = g.window_arrays(width, fields)
-    if any(sources.get(f) is None for f in fields):
-      return {}  # HOST-mode (or missing) edge arrays: XLA fallback
-    return dict(window_gather=lambda arr, st, w: fn(arr, st, width=w),
-                window_sources=sources)
-
-  def _count_fallback(self, reason: str, resolved: str = 'pallas'):
-    """Once-per-(sampler, reason) engine-fallback accounting — the
-    event is a property of the sampler's configuration, so repeating it
-    per hop/call would just inflate the counter. The ``requested``
-    label carries what the operator actually asked for."""
-    if reason not in self._fallbacks_counted:
-      self._fallbacks_counted.add(reason)
-      requested = knob('GLT_HOP_ENGINE', 'auto')
-      if getattr(self, '_hop_engine_override', None):
-        requested = self._hop_engine_override
-      count_engine_fallback(requested, resolved, reason)
-
-  def _resolved_hop_engine(self) -> str:
-    """The engine this sampler ACTUALLY runs: ``pallas_fused`` demotes
-    to ``pallas`` (counted, ``hop_engine_fallbacks_total``) for the hop
-    shapes the fusion does not serve — weighted and full-neighborhood
-    hops (no uniform offset pick to fuse) and a forced dense dedup
-    engine (the fused kernel IS the sort-contract inducer). Hetero
-    traversals are SERVED by the fused family (one padded
-    multi-edge-type invocation per hop over the edge-type plane,
-    :class:`~glt_tpu.ops.sample.HeteroFusedPlan`); the ``hetero``
-    fallback reason fires only for genuinely unservable hetero shapes
-    — a type-tagged key space past int32 (``_hetero_fused_plan``) —
-    never for hetero as such."""
-    eng = getattr(self, '_hop_engine_override', None) or hop_engine()
-    if eng != 'pallas_fused':
-      return eng
-    if self.with_weight:
-      self._count_fallback('weighted')
-      return 'pallas'
-    fanouts = (sum(self.num_neighbors.values(), []) if self.is_hetero
-               else self.num_neighbors)
-    if any(f < 0 for f in fanouts):
-      self._count_fallback('full_neighborhood')
-      return 'pallas'
-    if knob('GLT_DEDUP', '') == 'table':
-      self._count_fallback('dense_dedup_forced')
-      return 'pallas'
-    return eng
-
-  def _fused_plan(self, batch_size: int):
-    """Build the :class:`~glt_tpu.ops.sample.FusedHopPlan` for one
-    compiled multihop program, or None with a counted fallback when the
-    fused engine cannot engage at this shape (HOST-mode edge arrays; a
-    node budget whose dedup table would blow the VMEM sizing knob,
-    ``GLT_FUSED_TABLE_SLOTS``)."""
-    if self._resolved_hop_engine() != 'pallas_fused':
-      return None
-    from ..ops.pallas_kernels import (fused_table_max_slots,
-                                      fused_table_slots,
-                                      interpret_default)
-    from ..ops.sample import FusedHopPlan
-    g: Graph = self.graph
-    width = _window_width()
-    fields = ('indices', 'edge_ids') if (
-        self.with_edge and g.topo.edge_ids is not None) else ('indices',)
-    # window_arrays BEFORE touching g.indices/edge_ids — the padded
-    # copy supersedes the originals (one-resident-copy rule)
-    sources = g.window_arrays(width, fields)
-    if any(sources.get(f) is None for f in fields):
-      # HOST-mode graphs have no device window arrays at all, so the
-      # demoted hop read lands on the ELEMENT path (the same guard in
-      # _uniform_hop_kwargs returns {})
-      self._count_fallback('host_mode_arrays', resolved='element')
-      return None
-    budget = sample_budget(batch_size, self.num_neighbors)
-    slots = fused_table_slots(budget)
-    # geometry gauges BEFORE the overflow gate: an over-knob walk is
-    # exactly the one whose chosen-slots-vs-knob distance matters
-    self._publish_table_geometry(slots)
-    if slots > fused_table_max_slots():
-      self._count_fallback('table_overflow')
-      return None
-    gather_fn = feat_dim = feat_dtype = None
-    feat = self.fused_feature
-    if feat is not None and feat.fully_device_resident:
-      gather_fn = feat.fused_gather_fn(row_gather=self.row_gather)
-      feat_dim = feat.feature_dim
-      feat_dtype = feat.device_part.dtype
-      # opt-in narrow gather plane: the in-walk feature block (and the
-      # emitted node_feats) carry this dtype, halving the gather's HBM
-      # write traffic for float32 stores. A widening request is
-      # ignored — the plane never up-converts.
-      narrow = knob('GLT_FUSED_FEAT_DTYPE', None)
-      if narrow:
-        narrow = jnp.dtype(narrow)
-        if narrow.itemsize < jnp.dtype(feat_dtype).itemsize:
-          feat_dtype = narrow
-    self._table_slots = slots
-    return FusedHopPlan(
-        g.indptr, g.indices, sources['indices'], width,
-        g.hub_count(width), slots,
-        edge_ids=g.edge_ids if self.with_edge else None,
-        edge_ids_win=sources.get('edge_ids'), replace=self.replace,
-        interpret=interpret_default(), gather_fn=gather_fn,
-        feat_dim=feat_dim, feat_dtype=feat_dtype,
-        indptr_pad=g.indptr_pad())
-
-  def _hetero_fused_plan(self, batch_sizes: Dict[NodeType, int]):
-    """Build the :class:`~glt_tpu.ops.sample.HeteroFusedPlan` for one
-    compiled hetero multihop program, or None with a counted fallback
-    when the fused engine cannot engage at this shape. Fallback reasons
-    stay SPECIFIC — ``host_mode_arrays`` (no device window arrays),
-    ``table_overflow`` (total cross-type budget past the VMEM knob) —
-    and the bare ``hetero`` reason is reserved for the genuinely
-    unservable hetero shapes: a type-tagged global id space or flat
-    edge plane past int32 (build_type_plane raises)."""
-    if self._resolved_hop_engine() != 'pallas_fused':
-      return None
-    from ..ops.pallas_kernels import (fused_table_max_slots,
-                                      fused_table_slots,
-                                      interpret_default)
-    from ..ops.sample import HeteroFusedPlan
-    width = _window_width()
-    parts = {}
-    for e in self.edge_types:
-      g: Graph = self.graph[e]
-      fields = ('indices', 'edge_ids') if (
-          self.with_edge and g.topo.edge_ids is not None) \
-          else ('indices',)
-      # window_arrays BEFORE touching g.indptr — the padded copy
-      # supersedes the originals (one-resident-copy rule)
-      sources = g.window_arrays(width, fields)
-      if any(sources.get(f) is None for f in fields):
-        self._count_fallback('host_mode_arrays', resolved='element')
-        return None
-      parts[e] = dict(indptr=g.indptr, indices_win=sources['indices'],
-                      num_edges=g.num_edges,
-                      hub_count=g.hub_count(width),
-                      edge_ids_win=sources.get('edge_ids'))
-    caps, budgets = self._hetero_caps(batch_sizes)
-    budget_total = sum(budgets.values())
-    slots = fused_table_slots(budget_total)
-    # geometry gauges BEFORE the overflow gate (same rationale as homo)
-    self._publish_table_geometry(slots)
-    if slots > fused_table_max_slots():
-      self._count_fallback('table_overflow')
-      return None
-    try:
-      plan = HeteroFusedPlan(
-          self.edge_types, self._traversal_types(), self._node_counts,
-          parts, width, slots, budget_total, replace=self.replace,
-          interpret=interpret_default())
-    except ValueError as e:
-      # int32 type-tagged key space exceeded: the one hetero shape the
-      # fused family genuinely cannot serve
-      logger.warning('hetero fused plan unavailable: %s', e)
-      self._count_fallback('hetero')
-      return None
-    self._table_slots = slots
-    return plan
-
-  def _publish_table_geometry(self, slots: int) -> None:
-    """Registry gauges for the fused dedup table's static geometry —
-    chosen slot count and VMEM bytes (both planes) — so a
-    ``table_overflow`` demotion is diagnosable from a registry snapshot
-    (how close was the walk to the knob?) instead of only a fallback
-    counter."""
-    try:
-      from ..obs import get_registry
-      from ..ops.pallas_kernels import fused_table_max_slots
-      reg = get_registry()
-      reg.gauge('fused_table_slots').set(float(slots))
-      reg.gauge('fused_table_vmem_bytes').set(float(2 * slots * 4))
-      reg.gauge('fused_table_max_slots').set(
-          float(fused_table_max_slots()))
-    except Exception:  # metrics must never break sampling
-      pass
-
-  def _update_table_occupancy(self, out) -> None:
-    """Occupancy high-water gauge for the fused table: the walk's
-    distinct-node count over the table's slot capacity. Reading the
-    count forces a device sync, so this only runs when the tracer is
-    already sampling syncs (GLT_OBS_TRACE_SAMPLE) or under the explicit
-    ``GLT_OBS_TABLE_OCCUPANCY=1`` opt-in — steady-state sampling stays
-    fully async."""
-    slots = getattr(self, '_table_slots', None)
-    if not slots:
-      return
-    try:
-      from ..obs import get_registry, get_tracer
-      if not knob('GLT_OBS_TABLE_OCCUPANCY', False):
-        t = get_tracer()
-        # mirror the tracer's own probabilistic sync draw: reading the
-        # count blocks on the walk, so it must happen on the SAMPLED
-        # FRACTION of calls, not on every call while sampling is on
-        import random
-        if not (t.enabled and t._sample > 0
-                and random.random() < t._sample):
-          return
-      occ = out['node_count']
-      # hetero: the table is shared across types (type-tagged keys), so
-      # occupancy is the cross-type distinct total
-      occ = (sum(int(c) for c in occ.values())
-             if isinstance(occ, dict) else int(occ))
-      hwm = max(getattr(self, '_table_occ_hwm', 0), occ)
-      self._table_occ_hwm = hwm
-      reg = get_registry()
-      reg.gauge('fused_table_occupancy_hwm').set(float(hwm))
-      reg.gauge('fused_table_occupancy_ratio_hwm').set(
-          float(hwm) / float(slots))
-    except Exception:  # metrics must never break sampling
-      pass
-
-  def _uniform_hop_kwargs(self, g: Graph, frontier_size: int):
-    """Windowed-engine plumbing for the UNIFORM hop read
-    (ops/pipeline.py::hop_engine, read at trace time): resolves the
-    window width (``GLT_WINDOW_W``, default 96, floored at 8), the
-    exact hub capacity from the graph's true degree distribution
-    (:meth:`Graph.hub_count` — host-side, once per width), and the
-    W-padded edge arrays. Returns {} on the element engine or when the
-    padded arrays are unavailable (HOST-mode graphs). Tests inject an
-    engine/interpret override via ``_hop_engine_override``. A
-    ``pallas_fused`` request reaching THIS path (a hop shape outside
-    the fused plan — hetero, weighted/full companions, plan fallback)
-    reads windows through the plain ``pallas`` megakernel."""
-    eng = self._resolved_hop_engine()
-    if eng == 'pallas_fused':
-      eng = 'pallas'
-    if eng == 'element':
-      return {}
-    width = _window_width()
-    fields = ('indices', 'edge_ids') if (
-        self.with_edge and g.topo.edge_ids is not None) else ('indices',)
-    sources = g.window_arrays(width, fields)
-    if any(sources.get(f) is None for f in fields):
-      return {}  # HOST-mode (or missing) edge arrays: XLA fallback
-    # a frontier can't hold more hub rows than it has rows: clamping H
-    # keeps the fix-up buffers frontier-sized without ever undershooting
-    n_hub = min(g.hub_count(width), int(frontier_size))
-    kw = dict(window=(width, n_hub),
-              indices_win=sources['indices'],
-              edge_ids_win=sources.get('edge_ids'), engine=eng)
-    if eng == 'pallas':
-      from ..ops.pallas_kernels import interpret_default
-      kw['interpret'] = interpret_default()
-    return kw
-
   def _one_hop(self, g: Graph, frontier, fanout, key, mask):
     """Dispatch full/uniform/weighted one-hop sampling on graph ``g``."""
+    eids = g.edge_ids if self.with_edge else None
     if fanout < 0:  # full neighborhood inside a |fanout|-wide window
-      # build window kwargs BEFORE touching g.indices/edge_ids: the
-      # padded window copy supersedes the originals (Graph.window_arrays
-      # rebinds the fields), so reading them afterwards keeps the
-      # compiled program referencing ONE resident copy per edge array
-      want_eids = self.with_edge and g.topo.edge_ids is not None
-      wk = self._window_kwargs(
-          g, -fanout, ('indices', 'edge_ids') if want_eids
-          else ('indices',))
-      eids = g.edge_ids if self.with_edge else None
       return sample_full_neighbors(
           g.indptr, g.indices, frontier, -fanout, seed_mask=mask,
-          edge_ids=eids, **wk)
+          edge_ids=eids)
     if self.with_weight and g.edge_weights is not None:
       max_deg = self.max_weighted_degree or g.topo.max_degree
       max_deg = max(max_deg, fanout)
-      wk = self._window_kwargs(g, max_deg, ('edge_weights',))
-      eids = g.edge_ids if self.with_edge else None
       return sample_neighbors_weighted(
           g.indptr, g.indices, g.edge_weights, frontier, fanout, key,
-          max_degree=max_deg, seed_mask=mask, edge_ids=eids, **wk)
-    # build window kwargs BEFORE touching g.indices/edge_ids (same
-    # one-resident-copy rule as the full-neighborhood branch above)
-    wk = self._uniform_hop_kwargs(g, frontier.shape[0])
-    eids = g.edge_ids if self.with_edge else None
+          max_degree=max_deg, seed_mask=mask, edge_ids=eids)
     return sample_neighbors(
         g.indptr, g.indices, frontier, fanout, key, seed_mask=mask,
-        edge_ids=eids, replace=self.replace, **wk)
+        edge_ids=eids, replace=self.replace)
 
   # -- homogeneous sampling ---------------------------------------------
 
@@ -498,7 +197,6 @@ class NeighborSampler(BaseSampler):
     g: Graph = self.graph
     one_hop = lambda ids, fanout, key, mask: self._one_hop(
         g, ids, fanout, key, mask)
-    fused_plan = self._fused_plan(batch_size)
 
     def fn(seeds, n_valid, key, table, scratch):
       # trace-time side effect: one compiles_total{fn=...} tick per
@@ -508,8 +206,7 @@ class NeighborSampler(BaseSampler):
       count_compile('sampler.homo')
       return multihop_sample(one_hop, seeds, n_valid, self.num_neighbors,
                              key, table, scratch,
-                             with_edge=self.with_edge,
-                             fused_plan=fused_plan)
+                             with_edge=self.with_edge)
 
     return jax.jit(fn, donate_argnums=(3, 4))
 
@@ -546,14 +243,6 @@ class NeighborSampler(BaseSampler):
           kwargs.get('key', self._next_key()), table, scratch)
       _synced['out'] = out['num_sampled_edges']
     self._tables[''] = (table, scratch)
-    self._update_table_occupancy(out)
-    metadata = {'seed_labels': out['seed_labels'],
-                'seed_count': out['seed_count']}
-    if 'node_feats' in out:
-      # the fused in-walk gather (pallas_fused + fused_feature):
-      # bit-identical to gather_features(feat, node) — consumers
-      # short-circuit through gather_features(..., fused=...)
-      metadata['node_feats'] = out['node_feats']
     return SamplerOutput(
         node=out['node'], node_count=out['node_count'],
         row=out['row'], col=out['col'], edge_mask=out['edge_mask'],
@@ -562,7 +251,8 @@ class NeighborSampler(BaseSampler):
         num_sampled_edges=out['num_sampled_edges'],
         edge_hop_offsets=self._edge_hop_offsets(batch_size),
         node_hop_offsets=node_hop_offsets(batch_size, self.num_neighbors),
-        metadata=metadata,
+        metadata={'seed_labels': out['seed_labels'],
+                  'seed_count': out['seed_count']},
     )
 
   # -- heterogeneous sampling -------------------------------------------
@@ -603,7 +293,6 @@ class NeighborSampler(BaseSampler):
         e: (lambda ids, fanout, key, mask, _e=e: self._one_hop(
             self.graph[_e], ids, fanout, key, mask))
         for e in self.edge_types}
-    fused_plan = self._hetero_fused_plan(batch_sizes)
 
     def fn(seeds, n_valid, key, tables):
       from ..obs.perf import count_compile
@@ -611,7 +300,7 @@ class NeighborSampler(BaseSampler):
       return multihop_sample_hetero(
           one_hops, trav, self.num_neighbors, self.num_hops, caps,
           budgets, seeds, n_valid, key, tables,
-          with_edge=self.with_edge, fused_plan=fused_plan)
+          with_edge=self.with_edge)
 
     return jax.jit(fn, donate_argnums=(3,))
 
@@ -644,7 +333,6 @@ class NeighborSampler(BaseSampler):
         {t: jnp.asarray(v) for t, v in n_valid.items()},
         key if key is not None else self._next_key(), tables)
     self._tables.update(new_tables)
-    self._update_table_occupancy(out)
 
     # final keys: 'out' reverses the traversal type, 'in' keeps it; row
     # must carry child labels (= our cols), col parent labels (= our rows)

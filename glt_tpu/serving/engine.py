@@ -62,17 +62,11 @@ class InferenceEngine:
       NeighborSampler over ``data.graph`` — how live-update serving
       plugs in a :class:`~glt_tpu.stream.StreamSampler` (whose jitted
       programs survive snapshot swaps; see ``update_snapshot``).
-    row_gather: optional (table [N, D], rows [B]) -> [B, D] override
-      for the serving feature gather (resolve_row_gather seam — tests
-      inject the interpret-mode Pallas kernel). Applied at the gather
-      CALL SITE, so it keeps serving after ``update_snapshot`` swaps
-      in a new stream Feature.
     input_type: REQUIRED for a hetero ``data.graph`` (dict): the seed
       node type requests address. Buckets pad the seed-type batch; the
-      pipeline samples every edge type (one fused multi-edge-type
-      kernel invocation per hop on the ``pallas_fused`` engine) and
-      the forward consumes a ``HeteroBatch`` — RGAT-style serving with
-      the same zero-steady-state-recompile contract as homo.
+      pipeline samples every edge type and the forward consumes a
+      ``HeteroBatch`` — RGAT-style serving with the same
+      zero-steady-state-recompile contract as homo.
   """
 
   def __init__(self, data: Dataset, model, params,
@@ -85,15 +79,13 @@ class InferenceEngine:
                apply_fn: Optional[Callable] = None,
                with_edge: bool = False,
                sampler=None,
-               row_gather=None,
                input_type=None):
     self._hetero = isinstance(data.graph, dict)
     if self._hetero:
       # hetero serving: requests are seed-type node ids; the bucketed
-      # pipeline samples the multi-edge-type neighborhood (one fused
-      # program per bucket — on the pallas_fused engine each hop is one
-      # multi-edge-type kernel invocation) and the forward consumes a
-      # HeteroBatch. Bucket grid stays 1-D: requests seed ONE type.
+      # pipeline samples the multi-edge-type neighborhood (one program
+      # per bucket) and the forward consumes a HeteroBatch. Bucket grid
+      # stays 1-D: requests seed ONE type.
       assert input_type is not None, (
           'hetero serving needs input_type (the seed node type '
           'requests address)')
@@ -111,7 +103,6 @@ class InferenceEngine:
         dict(num_neighbors) if isinstance(num_neighbors, dict)
         else list(num_neighbors),
         edge_dir=data.edge_dir, with_edge=with_edge, seed=seed)
-    self.row_gather = row_gather
     self._apply_fn = apply_fn or (
         lambda params, batch: self.model.apply(params, batch))
     self._fwd = {}            # bucket -> jitted forward
@@ -227,16 +218,12 @@ class InferenceEngine:
       feats = (self.data.node_features
                if isinstance(self.data.node_features, dict) else {})
       x_dict = {
-          t: gather_features(feats[t], n, row_gather=self.row_gather)
+          t: gather_features(feats[t], n)
           for t, n in out.node.items() if feats.get(t) is not None}
       return to_hetero_batch(out, x_dict=x_dict,
                              batch_size=bucket).replace(metadata=None)
     out = self.sampler.sample_from_nodes(seeds, n_valid=n_valid)
-    # a pallas_fused sampler built with fused_feature= hands the rows
-    # back pre-gathered (in-walk); gather_features passes them through
-    x = gather_features(self.data.get_node_feature(), out.node,
-                        row_gather=self.row_gather,
-                        fused=(out.metadata or {}).get('node_feats'))
+    x = gather_features(self.data.get_node_feature(), out.node)
     # metadata carries per-call arrays (seed labels) — stripping it
     # keeps the forward's pytree signature identical across calls
     return to_batch(out, x=x, batch_size=bucket).replace(metadata=None)

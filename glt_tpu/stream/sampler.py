@@ -36,11 +36,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.delta import delta_one_hop
-from ..ops.pipeline import edge_hop_offsets, hop_engine, \
-    make_dedup_tables, multihop_sample
+from ..ops.pipeline import edge_hop_offsets, make_dedup_tables, \
+    multihop_sample
 from ..sampler.base import BaseSampler, NodeSamplerInput, SamplerOutput
 from ..utils import as_numpy
-from ..utils.env import knob
 from ..utils.rng import RandomSeedManager, make_key
 from .snapshot import SnapshotManager
 
@@ -62,11 +61,6 @@ class StreamSampler(BaseSampler):
     tombstone_window: per-node delete-overlay window (defaults to
       ``delta_window``).
     edge_dir: must match the manager's base layout ('out' = CSR).
-    window_hub_cap: static hub capacity ``H`` for the windowed base-hop
-      engines (``GLT_HOP_ENGINE=window|pallas``); defaults to the
-      startup snapshot's true hub count plus 25% headroom. A snapshot
-      whose hub count outgrows the cap warns loudly (hub rows past the
-      cap keep window-truncated picks until the cap is raised).
     seed: RNG seed (defaults to the process RandomSeedManager).
   """
 
@@ -77,7 +71,6 @@ class StreamSampler(BaseSampler):
                replace: bool = False,
                edge_dir: Optional[str] = None,
                full_neighbor_cap: Optional[int] = None,
-               window_hub_cap: Optional[int] = None,
                seed: Optional[int] = None):
     self.manager = manager
     self.is_hetero = False
@@ -121,10 +114,6 @@ class StreamSampler(BaseSampler):
                           for f in self._base_fanouts]
     self.num_hops = len(self._base_fanouts)
 
-    self.window_hub_cap = window_hub_cap
-    self._hub_cap = {}            # width -> resolved static hub cap
-    self._hub_checked_key = None  # last (version, width) hub-checked
-    self._window_warned_version = -1
     self._base_key = make_key(
         seed if seed is not None
         else RandomSeedManager.getInstance().getSeed())
@@ -170,74 +159,9 @@ class StreamSampler(BaseSampler):
       self._tables[''] = make_dedup_tables(num_nodes)
     return self._tables['']
 
-  def _window_plan(self, snap) -> tuple:
-    """Resolve the base-hop read engine for this snapshot: ('element',
-    0, 0) or (engine, W, H_cap). Static per compiled program (part of
-    the fn cache key) so a stable engine choice keeps the zero-
-    steady-state-recompile guarantee; the ONLY flips are env changes or
-    a snapshot whose capacity slack no longer covers W (loud warning,
-    one retrace — same class of event as a capacity growth).
-
-    The snapshot's capacity-padded ``indices`` doubles as the window
-    source: every valid window needs ``start + W <= capacity``, i.e.
-    padding slack >= W (starts never exceed the live edge count)."""
-    eng = getattr(self, '_hop_engine_override', None) or hop_engine()
-    if eng == 'pallas_fused':
-      # delta hops interleave base picks with tombstone masks and
-      # insert-overlay expansion — the VMEM dedup table can't sit
-      # across that merge, so the stream path rides the plain pallas
-      # megakernel for its base reads (counted, once per sampler)
-      if not getattr(self, '_fused_fallback_counted', False):
-        self._fused_fallback_counted = True
-        from ..ops.pipeline import count_engine_fallback
-        requested = (getattr(self, '_hop_engine_override', None)
-                     or knob('GLT_HOP_ENGINE', 'auto'))
-        count_engine_fallback(requested, 'pallas', 'stream_overlay')
-      eng = 'pallas'
-    if eng == 'element' or not any(f > 0 for f in self._base_fanouts):
-      return ('element', 0, 0)
-    from ..sampler.neighbor_sampler import _window_width
-    width = _window_width()
-    slack = int(snap.arrays['indices'].shape[0]) - int(snap.num_edges)
-    if slack < width:
-      if snap.version != self._window_warned_version:
-        self._window_warned_version = snap.version
-        logger.warning(
-            'snapshot v%d capacity slack %d < window width %d: the '
-            'windowed base-hop engine (%s) falls back to element reads '
-            'until a compaction grows capacity. Raise edge_capacity/'
-            'delta_capacity to keep >= W slots free.',
-            snap.version, slack, width, eng)
-      return ('element', 0, 0)
-    # ONE O(num_rows) degree scan per (snapshot version, width): it
-    # both resolves the static cap (first time) and checks the current
-    # snapshot against it. Only the latest version's marker is kept —
-    # versions are monotone, so per-version memo entries would grow
-    # without bound over a long-running stream.
-    if width not in self._hub_cap or \
-        self._hub_checked_key != (snap.version, width):
-      hubs = int((np.diff(snap.topo.indptr) > width).sum())
-      self._hub_checked_key = (snap.version, width)
-      if width not in self._hub_cap:
-        self._hub_cap[width] = int(
-            self.window_hub_cap if self.window_hub_cap is not None
-            else hubs + max(8, hubs // 4))
-      elif hubs > self._hub_cap[width]:
-        logger.warning(
-            'snapshot v%d has %d hub rows (degree > %d) but the static '
-            'hub cap is %d: rows past the cap sample from a truncated '
-            'window. Rebuild the sampler with a larger window_hub_cap.',
-            snap.version, hubs, width, self._hub_cap[width])
-    return (eng, width, self._hub_cap[width])
-
-  def _build_fn(self, batch_size: int, plan: tuple):
+  def _build_fn(self, batch_size: int):
     eff = list(self.num_neighbors)
     base = list(self._base_fanouts)
-    eng, width, hub_cap = plan
-    interp = False
-    if eng == 'pallas':
-      from ..ops.pallas_kernels import interpret_default
-      interp = interpret_default()
 
     def fn(arrays, seeds, n_valid, key, table, scratch):
       self.trace_count += 1  # trace-time only; executions never bump
@@ -248,11 +172,6 @@ class StreamSampler(BaseSampler):
       def one_hop(ids, _eff_fanout, sub, mask):
         f = base[hop['i']]
         hop['i'] += 1
-        wk = {}
-        if eng != 'element' and f > 0:
-          wk = dict(base_window=(width, min(hub_cap, ids.shape[0])),
-                    indices_win=arrays['indices'], engine=eng,
-                    interpret=interp)
         return delta_one_hop(
             arrays['indptr'], arrays['indices'],
             arrays['ins_indptr'], arrays['ins_indices'],
@@ -260,7 +179,7 @@ class StreamSampler(BaseSampler):
             ids, f, sub, mask,
             ins_window=self.delta_window,
             del_window=self.tombstone_window,
-            replace=self.replace, **wk)
+            replace=self.replace)
 
       return multihop_sample(one_hop, seeds, n_valid, eff, key,
                              table, scratch, with_edge=False)
@@ -279,10 +198,9 @@ class StreamSampler(BaseSampler):
     table, scratch = self._get_tables(self.manager.num_nodes)
     snap = self.manager.acquire()
     try:
-      plan = self._window_plan(snap)
-      cache_key = ('homo', batch_size, plan)
+      cache_key = ('homo', batch_size)
       if cache_key not in self._fn_cache:
-        self._fn_cache[cache_key] = self._build_fn(batch_size, plan)
+        self._fn_cache[cache_key] = self._build_fn(batch_size)
       if (self._full_cap is not None
           and snap.max_degree > self._full_cap
           and snap.version != self._trunc_warned_version):

@@ -10,7 +10,7 @@ benches) and turns a too-late selection into an error instead of a
 silently different device; :func:`configure_compile_cache` gives every
 entry point the same persistent cache directory. Both must run before
 the first backend contact, so every entry point (``chip_smoke.py``,
-``bench.py``, ``__graft_entry__``, the benchmarks, the test conftest,
+``__graft_entry__``, the benchmarks, the test conftest,
 ``examples/common.py``) calls them first.
 """
 from __future__ import annotations

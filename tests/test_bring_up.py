@@ -43,16 +43,6 @@ def test_chip_smoke_refuses_to_run_without_a_tpu():
   assert proc.stdout == ''  # no phase ran, no result line
 
 
-def test_hop_engine_auto_is_a_fixed_answer_on_a_tpu(monkeypatch):
-  from glt_tpu.ops.pallas_kernels import kernel_launch_count
-  from glt_tpu.ops.pipeline import hop_engine
-  monkeypatch.delenv('GLT_HOP_ENGINE', raising=False)
-  monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
-  launches = kernel_launch_count()
-  assert hop_engine() == 'element'
-  assert kernel_launch_count() == launches  # no kernel was traced
-
-
 def test_package_imports_without_the_compat_shims():
   import glt_tpu  # noqa: F401
   assert importlib.util.find_spec('glt_tpu.utils.compat') is None

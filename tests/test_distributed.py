@@ -971,19 +971,19 @@ def test_dist_hetero_sampler_sort_engine(tmp_path_factory, mesh,
   assert ('item', 'rev_u2i', 'user') in out['row']
 
 
-@pytest.mark.pallas
-def test_dist_feature_pallas_row_gather_parity(mesh, dist_datasets):
-  # injected interpret-mode Pallas serving gather == XLA take through
-  # the PB-routed all_to_all lookup
-  import functools
-  from glt_tpu.ops.pallas_kernels import gather_rows
-  base = DistFeature.from_dist_datasets(mesh, dist_datasets)
-  fast = DistFeature.from_dist_datasets(
-      mesh, dist_datasets,
-      row_gather=functools.partial(gather_rows, interpret=True))
-  ids = np.random.default_rng(1).integers(0, N_NODES, N_PARTS * 16)
-  np.testing.assert_array_equal(np.asarray(base.lookup(ids)),
-                                np.asarray(fast.lookup(ids)))
+def test_dist_feature_lookup_serves_rows_and_zero_rows(mesh,
+                                                       dist_datasets):
+  # the PB-routed all_to_all lookup is table[ids] (feature row i is
+  # [i] * dim here); masked-out and negative requests come back zero
+  df = DistFeature.from_dist_datasets(mesh, dist_datasets)
+  rng = np.random.default_rng(1)
+  ids = rng.integers(0, N_NODES, N_PARTS * 16)
+  valid = rng.random(N_PARTS * 16) < 0.75
+  ids[::7] = -1
+  out = np.asarray(df.lookup(ids, jnp.asarray(valid)))
+  ok = valid & (ids >= 0)
+  want = np.where(ok[:, None], ids[:, None].astype(out.dtype), 0)
+  np.testing.assert_array_equal(out, np.broadcast_to(want, out.shape))
 
 
 def test_dist_feature_spill_parity(mesh, dist_datasets):

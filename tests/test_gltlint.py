@@ -272,8 +272,8 @@ def test_knob_types_and_malformed_defaults(monkeypatch):
   with pytest.warns(RuntimeWarning):
     assert env.knob('GLT_T_BOOL', True) is True
 
-  monkeypatch.setenv('GLT_T_STR', 'pallas_fused')
-  assert env.knob('GLT_T_STR', 'auto') == 'pallas_fused'
+  monkeypatch.setenv('GLT_T_STR', 'sort_fused')
+  assert env.knob('GLT_T_STR', 'auto') == 'sort_fused'
   monkeypatch.delenv('GLT_T_STR')
   assert env.knob('GLT_T_STR', 'auto') == 'auto'
   monkeypatch.setenv('GLT_T_STR', '')

@@ -230,7 +230,7 @@ def test_history_rows_from_bench_json_skips_failures():
   doc = {'metric': 'x', 'value': 9.0, 'unit': 'edges/s',
          'engine': 'sort', 'backend': 'cpu', 'scale': 's1',
          'engines': {'sort+fused': {'edges_per_sec': 8.0},
-                     'pallas_error': 'boom'},
+                     'lost_engine_error': 'boom'},
          'train_steps_per_sec': {'per_batch': 3.0, 'superstep': 4.0}}
   rows = rows_from_bench_json(doc)
   assert {(r['bench'], r['engine']) for r in rows} == {
@@ -558,23 +558,3 @@ def test_fabric_harvest_partial_on_dead_endpoint(tmp_path, registry):
     cli_live.close()
     cli_dead.close()
     srv.stop()
-
-
-# -- bench worker failure path -------------------------------------------
-
-def test_bench_worker_failure_dumps_obs_artifacts(tmp_path,
-                                                  monkeypatch):
-  """The GLT_OBS_DUMP artifacts must land on the worker's FAILURE path
-  too — the crashed run is the one whose registry/trace state matters."""
-  import importlib.util
-  spec = importlib.util.spec_from_file_location(
-      'bench_mod', os.path.join(REPO, 'bench.py'))
-  bench = importlib.util.module_from_spec(spec)
-  spec.loader.exec_module(bench)
-  monkeypatch.setenv('GLT_OBS_DUMP', str(tmp_path))
-  get_registry().inc('loader_batches_total')  # some state to dump
-  bench._dump_obs_on_failure()
-  reg = json.load(open(tmp_path / 'obs_registry.json'))
-  assert 'counters' in reg
-  tr = json.load(open(tmp_path / 'obs_trace.json'))
-  assert 'traceEvents' in tr

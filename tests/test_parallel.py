@@ -152,23 +152,6 @@ def test_sharded_segment_mean_scattered_matches_global(mesh):
   np.testing.assert_allclose(got, expect, rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.pallas
-def test_sharded_feature_pallas_row_gather_parity(mesh):
-  # the injected interpret-mode Pallas row gather must serve identical
-  # rows to the XLA take through the full all_to_all lookup
-  import functools
-  from glt_tpu.ops.pallas_kernels import gather_rows
-  n, d = 64, 8
-  feats = np.arange(n * d, dtype=np.float32).reshape(n, d)
-  ids = np.random.default_rng(0).integers(0, n, 8 * 16)
-  base = ShardedFeature(feats, mesh)
-  fast = ShardedFeature(feats, mesh,
-                        row_gather=functools.partial(gather_rows,
-                                                     interpret=True))
-  np.testing.assert_array_equal(np.asarray(base.lookup(ids)),
-                                np.asarray(fast.lookup(ids)))
-
-
 def test_sharded_feature_spill_parity(mesh):
   # host-spill store must be value-identical to the fully-resident one
   n, d = 100, 8

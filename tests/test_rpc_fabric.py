@@ -81,8 +81,11 @@ def test_rpc_fabric_three_ranks():
   assert all(msg == 'ok' for _, msg in results), results
 
 
-def test_rpc_fabric_requires_identity_without_context():
-  from glt_tpu.distributed import init_rpc
+def test_rpc_fabric_requires_identity_without_context(monkeypatch):
+  from glt_tpu.distributed import dist_context, init_rpc
+  # an earlier file on this xdist worker may have left its context set
+  # (init_server / init_client without a shutdown)
+  monkeypatch.setattr(dist_context, '_context', None)
   with pytest.raises(ValueError, match='rank/world_size'):
     init_rpc('127.0.0.1', _free_port())
 
@@ -214,6 +217,49 @@ def test_rpc_fabric_role_scoped_collectives():
   q = ctx.Queue()
   procs = [ctx.Process(target=_role_worker, args=(r, world, port, q))
            for r in range(world)]
+  for p in procs:
+    p.start()
+  results = [q.get(timeout=600) for _ in range(world)]
+  for p in procs:
+    p.join(timeout=120)
+  assert all(msg == 'ok' for _, msg in results), results
+
+
+def _slow_reply_worker(rank: int, world: int, port: int, q) -> None:
+  try:
+    import time
+    from glt_tpu.distributed import barrier, init_rpc, rpc, shutdown_rpc
+    init_rpc('127.0.0.1', port, rank=rank, world_size=world)
+    if rank == 0:
+      # the master's serve threads hold every PEER's barrier reply for
+      # half a second (rank 0's own goes out at once): what a loaded
+      # machine does to them now and then
+      send = rpc._send_msg
+
+      def held(conn, msg):
+        own = rpc._fabric['ctx'].master._sock.getsockname()
+        if msg == ('ok', True) and conn.getpeername() != own:
+          time.sleep(0.5)
+        return send(conn, msg)
+
+      rpc._send_msg = held
+    barrier()
+    shutdown_rpc()
+    q.put((rank, 'ok'))
+  except BaseException as e:
+    q.put((rank, f'FAIL: {type(e).__name__}: {e}'))
+
+
+def test_rpc_shutdown_waits_for_the_peers_barrier_replies():
+  """The master must not stop its server while a peer's reply to the
+  final barrier is still in a serve thread's hands: the peer would read
+  'peer closed' (the flake of the two tests above under six workers)."""
+  world = 3
+  port = _free_port()
+  ctx = mp.get_context('spawn')
+  q = ctx.Queue()
+  procs = [ctx.Process(target=_slow_reply_worker,
+                       args=(r, world, port, q)) for r in range(world)]
   for p in procs:
     p.start()
   results = [q.get(timeout=600) for _ in range(world)]

@@ -141,29 +141,6 @@ def test_neighbor_probs_hotness():
   np.testing.assert_allclose(np.asarray(probs), [0.0, 0.5, 0.5])
 
 
-@pytest.mark.pallas
-def test_pallas_gather_rows_parity():
-  """Interpret-mode parity of the Pallas feature gather vs jnp.take."""
-  from glt_tpu.ops.pallas_kernels import gather_rows
-  rng = np.random.default_rng(0)
-  table = jnp.asarray(rng.normal(size=(64, 128)).astype(np.float32))
-  rows = jnp.asarray(rng.integers(0, 64, 16, dtype=np.int32))
-  got = gather_rows(table, rows, interpret=True)
-  np.testing.assert_allclose(np.asarray(got),
-                             np.asarray(table)[np.asarray(rows)])
-
-
-@pytest.mark.pallas
-def test_pallas_gather_rows_clamps():
-  from glt_tpu.ops.pallas_kernels import gather_rows
-  table = jnp.arange(12.0).reshape(3, 4)
-  # pad rows to a multiple-of-8-friendly length; out-of-range clamps
-  rows = jnp.array([0, 2, 99, -5, 1, 1, 0, 2], jnp.int32)
-  got = np.asarray(gather_rows(table, rows, interpret=True))
-  np.testing.assert_allclose(got[2], np.asarray(table)[2])
-  np.testing.assert_allclose(got[3], np.asarray(table)[0])
-
-
 def test_multihop_sample_many_matches_single():
   from glt_tpu.ops.pipeline import multihop_sample, multihop_sample_many
   from glt_tpu.ops.unique import dense_make_tables
@@ -196,138 +173,3 @@ def test_multihop_sample_many_matches_single():
                                table, scratch)
   got = set(np.asarray(out2['node'])[:int(out2['node_count'])].tolist())
   assert got == {7, 8, 9, 10}
-
-
-@pytest.mark.pallas
-def test_pallas_gather_windows_parity():
-  from glt_tpu.ops.pallas_kernels import gather_windows
-  rng = np.random.default_rng(3)
-  arr = jnp.asarray(rng.integers(0, 999, 5000).astype(np.int32))
-  starts = jnp.asarray(rng.integers(0, 5000, 37).astype(np.int32))
-  w = 16
-  got = np.asarray(gather_windows(arr, starts, w, interpret=True))
-  st = np.clip(np.asarray(starts), 0, 5000 - w)
-  want = np.stack([np.asarray(arr)[x:x + w] for x in st])
-  np.testing.assert_array_equal(got, want)
-
-
-@pytest.mark.pallas
-def test_pallas_gather_windows_block_padding():
-  # row count not a multiple of the block: the pad rows must not leak
-  from glt_tpu.ops.pallas_kernels import gather_windows
-  arr = jnp.arange(100, dtype=jnp.int32)
-  starts = jnp.array([0, 50, 84], jnp.int32)   # 3 rows, block 8
-  got = np.asarray(gather_windows(arr, starts, 16, block=8,
-                                  interpret=True))
-  assert got.shape == (3, 16)
-  np.testing.assert_array_equal(got[0], np.arange(16))
-  np.testing.assert_array_equal(got[2], np.arange(84, 100))
-
-
-@pytest.mark.pallas
-@pytest.mark.parametrize('engine', ['table', 'sort'])
-def test_window_dma_path_matches_xla_weighted_and_full(monkeypatch,
-                                                       engine):
-  """The Pallas window-gather fast path (injected in interpret mode on
-  CPU) must reproduce the XLA slice-gather path bit-for-bit: same key
-  -> same Gumbel draws -> same picks, and the weight windows are equal
-  because the padded source satisfies the kernel's containment
-  contract. Both dedup engines are covered — on TPU the sort engine is
-  the one that will carry the window path's sentinel lanes."""
-  import functools
-  from fixtures import ring_dataset
-  from glt_tpu.ops.pallas_kernels import gather_windows
-  from glt_tpu.sampler import NeighborSampler
-
-  monkeypatch.setenv('GLT_DEDUP', engine)
-  ds = ring_dataset(num_nodes=30, weighted=True)
-  seeds = np.arange(0, 30, 3)
-
-  def run(inject):
-    s = NeighborSampler(ds.get_graph(), [2, 2], with_edge=True,
-                        with_weight=True, seed=9)
-    if inject:
-      s._window_gather_fn = functools.partial(gather_windows,
-                                              interpret=True)
-    out = s.sample_from_nodes(seeds, key=jax.random.key(3))
-    return jax.tree.map(np.asarray, dict(
-        node=out.node, count=out.node_count, row=out.row, col=out.col,
-        mask=out.edge_mask, edge=out.edge))
-
-  a, b = run(False), run(True)
-  for k in a:
-    np.testing.assert_array_equal(a[k], b[k], err_msg=k)
-
-
-@pytest.mark.pallas
-@pytest.mark.parametrize('engine', ['table', 'sort'])
-def test_window_dma_path_matches_xla_full_neighborhood(monkeypatch,
-                                                       engine):
-  import functools
-  from fixtures import ring_dataset
-  from glt_tpu.ops.pallas_kernels import gather_windows
-  from glt_tpu.sampler import NeighborSampler
-
-  monkeypatch.setenv('GLT_DEDUP', engine)
-  ds = ring_dataset(num_nodes=24)
-  seeds = np.array([0, 7, 13])
-
-  def run(inject):
-    s = NeighborSampler(ds.get_graph(), [-1, -1], with_edge=True,
-                        seed=2)
-    if inject:
-      s._window_gather_fn = functools.partial(gather_windows,
-                                              interpret=True)
-    out = s.sample_from_nodes(seeds, key=jax.random.key(1))
-    return jax.tree.map(np.asarray, dict(
-        node=out.node, count=out.node_count, row=out.row, col=out.col,
-        mask=out.edge_mask, edge=out.edge))
-
-  a, b = run(False), run(True)
-  for k in a:
-    np.testing.assert_array_equal(a[k], b[k], err_msg=k)
-
-
-@pytest.mark.parametrize('fanouts', [[-1, -1], [3]])
-def test_window_dma_variable_degree_mask_sanitized(monkeypatch, fanouts):
-  """Variable-degree graph: short windows DO read sentinel lanes in the
-  DMA path (unlike the uniform ring). Valid lanes must match the XLA
-  path exactly; masked lanes are contractually unspecified, so the
-  comparison sanitizes them with the mask first."""
-  import functools
-  from glt_tpu.data import Dataset
-  from glt_tpu.ops.pallas_kernels import gather_windows
-  from glt_tpu.sampler import NeighborSampler
-
-  rng = np.random.default_rng(11)
-  n = 30
-  edges = set()
-  for v in range(n):                     # degrees 0..6
-    for w in rng.choice(n, int(rng.integers(0, 7)), replace=False):
-      if int(w) != v:
-        edges.add((v, int(w)))
-  ei = np.array(sorted(edges)).T
-  ds = Dataset(edge_dir='out')
-  ds.init_graph(edge_index=ei, num_nodes=n,
-                edge_weights=(np.arange(ei.shape[1]) % 5 + 1
-                              ).astype(np.float32))
-  seeds = np.arange(0, n, 4)
-  weighted = fanouts == [3]
-
-  def run(inject):
-    s = NeighborSampler(ds.get_graph(), fanouts, with_edge=True,
-                        with_weight=weighted, seed=5)
-    if inject:
-      s._window_gather_fn = functools.partial(gather_windows,
-                                              interpret=True)
-    out = s.sample_from_nodes(seeds, key=jax.random.key(7))
-    m = np.asarray(out.edge_mask)
-    return dict(
-        node=np.asarray(out.node), count=int(out.node_count), mask=m,
-        row=np.where(m, np.asarray(out.row), -1),
-        col=np.where(m, np.asarray(out.col), -1),
-        edge=np.where(m, np.asarray(out.edge), -1))
-
-  a, b = run(False), run(True)
-  for k in a:
-    np.testing.assert_array_equal(a[k], b[k], err_msg=k)

@@ -120,57 +120,6 @@ def test_version_bump_invalidates_cache(model_and_params):
   assert not np.allclose(before, after)
 
 
-@pytest.mark.pallas
-def test_row_gather_override_threads_through_serving(model_and_params):
-  """resolve_row_gather seam, serving path: an injected gather kernel
-  (here the interpret-mode Pallas row gather) serves EVERY feature-row
-  gather the engine performs, and results match the XLA gather path."""
-  import functools
-  from glt_tpu.ops.pallas_kernels import gather_rows
-  calls = {'n': 0}
-
-  def counting_gather(table, rows):
-    calls['n'] += 1    # trace-time count: proves the override is used
-    return gather_rows(table, rows, interpret=True)
-
-  ref = make_engine(model_and_params, cache_capacity=0)
-  eng = make_engine(model_and_params, cache_capacity=0,
-                    row_gather=counting_gather)
-  eng.warmup()
-  assert calls['n'] > 0
-  ids = np.array([3, 7, 11])
-  np.testing.assert_allclose(eng.infer(ids), ref.infer(ids), atol=1e-5)
-
-
-@pytest.mark.pallas
-def test_row_gather_override_reaches_offloaded_store():
-  """The injection seam also covers host-offloaded stores: the hot-row
-  gather inside the fused mixed gather runs the injected kernel."""
-  from fixtures import skip_unless_pinned_host
-  skip_unless_pinned_host()
-  import jax.numpy as jnp
-
-  from glt_tpu.data.feature import Feature, gather_features
-  from glt_tpu.ops.pallas_kernels import gather_rows
-  calls = {'n': 0}
-
-  def counting_gather(table, rows):
-    calls['n'] += 1
-    return gather_rows(table, rows, interpret=True)
-
-  rows = np.arange(20, dtype=np.float32)[:, None] * np.ones((1, 4),
-                                                            np.float32)
-  feat = Feature(rows, split_ratio=0.5, host_offload=True)
-  feat.lazy_init()
-  assert feat.cold_array is not None
-  ids = np.array([0, 3, 12, 19])
-  want = gather_features(feat, jnp.asarray(ids))
-  got = gather_features(feat, jnp.asarray(ids),
-                        row_gather=counting_gather)
-  assert calls['n'] > 0
-  np.testing.assert_allclose(np.asarray(got), np.asarray(want))
-
-
 def test_invalidate_nodes_hook(model_and_params):
   eng = make_engine(model_and_params)
   eng.warmup()

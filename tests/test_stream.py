@@ -389,50 +389,6 @@ def test_invalidation_expands_to_in_neighbors(stream_serving):
   assert 4 in eng.cache.lookup([4], v)
 
 
-@pytest.mark.pallas
-def test_row_gather_override_survives_snapshot_swap():
-  """resolve_row_gather seam, stream path: the engine-level gather
-  override rides the gather CALL SITE, so it keeps serving after
-  update_snapshot swaps in a freshly-built stream Feature (a
-  store-level attribute would be lost with the old store)."""
-  import jax
-
-  from glt_tpu.models import GraphSAGE
-  from glt_tpu.ops.pallas_kernels import gather_rows
-  ds, mgr = make_manager()
-  sampler = StreamSampler(mgr, [-1, -1], delta_window=4, seed=0)
-  calls = {'n': 0}
-
-  def counting_gather(table, rows):
-    calls['n'] += 1
-    return gather_rows(table, rows, interpret=True)
-
-  model = GraphSAGE(hidden_features=8, out_features=OUT_DIM,
-                    num_layers=2)
-  eng = InferenceEngine(ds, model, None, [-1, -1], buckets=(4,),
-                        sampler=sampler, cache_capacity=0,
-                        row_gather=counting_gather)
-  eng.init_params(jax.random.key(0))
-  eng.warmup()
-  assert calls['n'] > 0
-  before = eng.infer([5, 6])
-  # mutate node 5's feature and compact: update_snapshot installs the
-  # NEW Feature; the override must still serve the gather against it
-  buf = FeatureDeltaBuffer(
-      capacity=8, num_nodes=N,
-      feature_dim=ds.get_node_feature().feature_dim)
-  new_row = np.full((1, ds.get_node_feature().feature_dim), 77.0,
-                    np.float32)
-  buf.update_rows([5], new_row)
-  snap, info = mgr.compact(feat_cut=buf.drain())
-  eng.update_snapshot(snap, touched_ids=info['touched'])
-  n_before = calls['n']
-  after = eng.infer([5, 6])
-  assert calls['n'] == n_before + 1  # override still serves the gather
-  assert not np.allclose(before[0], after[0])   # new feature visible
-  assert eng.data.node_features is snap.feature
-
-
 def test_ingest_gauges_surface_in_serving_metrics(stream_serving):
   ds, mgr, sampler, eng, ing = stream_serving
   metrics = ServingMetrics()

@@ -1,7 +1,7 @@
 """GLT003/GLT004 — trace-time staging and jit closure hazards.
 
-GLT003 bug class: Graph.window_arrays (PR 4) rebound live instance
-state inside a function being traced by ``jax.jit`` — the attribute
+GLT003 bug class: a padded-array cache on Graph (PR 4; gone since
+PR 29) rebound live instance state inside a function being traced by ``jax.jit`` — the attribute
 ended up holding a leaked tracer, poisoning every later untraced read.
 Any ``self.X = ...`` (or ``self.X[...] = ...``) executed at trace time
 is that bug unless wrapped in ``jax.ensure_compile_time_eval()``.
@@ -140,7 +140,7 @@ class TraceStagingRule(Rule):
             col=node.col_offset, scope=scope, token=store_attr,
             message=(f'self.{store_attr} is rebound inside a jitted '
                      'callee: at trace time this stores a tracer into '
-                     'live state (Graph.window_arrays leak, PR 4); '
+                     'live state (the padded-array cache leak, PR 4); '
                      'stage under jax.ensure_compile_time_eval() or '
                      'move the mutation out of the traced function'))
       # -- GLT004: closure over instance / module arrays
