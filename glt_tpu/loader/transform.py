@@ -44,6 +44,18 @@ class Batch:
   #: by it as they trim the edges by edge_hop_offsets. None: no promise.
   node_hop_offsets: Optional[tuple] = flax.struct.field(
       pytree_node=False, default=None)
+  #: static; ``(K_0, K_1, ...)``: hop h's block of edge slots
+  #: (``edge_hop_offsets``) is groups of ``K_h`` adjacent slots, ``col``
+  #: is one value over a group (a group's parent is its first slot's
+  #: ``col``: no new array crosses the step), and a label that heads a
+  #: group with a live slot heads no other such group. A promise of the
+  #: producer (ops.pipeline.hop_fanouts: SPMDSageTrainStep, and
+  #: NeighborSampler on a one-type graph); models/conv.py::SAGEConv
+  #: reads it and sums a parent's children with a reshape and a masked
+  #: reduce where it would scatter-add every slot. None: no promise,
+  #: ``col`` is taken as arbitrary.
+  hop_fanouts: Optional[tuple] = flax.struct.field(
+      pytree_node=False, default=None)
 
   @property
   def edge_index(self) -> jax.Array:
@@ -115,6 +127,7 @@ def to_batch(out: SamplerOutput,
       if out.edge_hop_offsets else None,
       node_hop_offsets=tuple(out.node_hop_offsets)
       if out.node_hop_offsets else None,
+      hop_fanouts=tuple(out.hop_fanouts) if out.hop_fanouts else None,
   )
 
 

@@ -12,6 +12,19 @@ for the first ``num_out`` nodes only (``None``: all of ``x``). Children are
 still read from every row of ``x``; an edge whose parent lies beyond
 ``num_out`` is masked. models/sage.py passes the hop prefix a later layer
 reads (``Batch.node_hop_offsets``).
+
+``SAGEConv`` also takes ``groups``, static ``(offset, S, K)`` triples:
+the producer's promise (``Batch.hop_fanouts``, given by
+ops/pipeline.py::hop_fanouts, handed on by models/sage.py) that the edge
+slots from ``offset`` on are ``S`` groups of ``K`` adjacent slots with
+one parent each, and that a parent heads one group with a live slot.
+A parent's children are then summed by :func:`grouped_aggregate`: a
+take, a reshape and a masked reduce over the fanout axis, and one
+placing of the ``S`` group results at their parents, where the segment
+path scatter-adds every slot (on the benchmark's cells 936,960 slots
+into 169,984 rows at conv0; the slots, not the rows, bound it). Without
+``groups`` the segment path computes what it always did, bit for bit;
+``GATConv`` and ``GCNConv`` know nothing of groups.
 """
 from __future__ import annotations
 
@@ -53,8 +66,78 @@ _AGGRS = {
 }
 
 
+def _group_sum(msgs, live):
+  return jnp.where(live[:, :, None], msgs, 0.0).sum(axis=0)
+
+
+def _group_mean(msgs, live):
+  cnt = live.sum(axis=0).astype(msgs.dtype)
+  return _group_sum(msgs, live) / jnp.maximum(cnt[:, None], 1.0)
+
+
+def _group_max(msgs, live):
+  out = jnp.where(live[:, :, None], msgs, -jnp.inf).max(axis=0)
+  return jnp.where(jnp.isfinite(out), out, 0.0)
+
+
+_GROUP_AGGRS = {
+    'mean': _group_mean,
+    'sum': _group_sum,
+    'max': _group_max,
+}
+
+
+def grouped_aggregate(aggr: str, x: jax.Array, row: jax.Array,
+                      col: jax.Array, ok: jax.Array, groups,
+                      num_segments: int) -> jax.Array:
+  """``_AGGRS[aggr]`` over edge slots that are parent-major: ``groups``
+  holds static ``(offset, S, K)`` triples, ``S`` groups of ``K`` adjacent
+  slots from ``offset`` on, ``col`` one value over a group.
+
+  The layout is what the v5e measured best (PERF.md, section 6, PR 30).
+  A hop's child indices are transposed to ``[K, S]``, so that the reduce
+  runs over the leading axis (``K`` additions of ``[S, D]`` slabs; with
+  ``K`` of 5, 10 or 15 on the second-minor axis the messages were
+  relaid, 2 ms at conv0), and taken as one flat vector a hop (XLA then
+  joins the hops' transposes into one scatter-add; one take for all
+  hops paid a copy of each hop's slice). A masked slot reads the row of
+  its own position, under the mask: with every masked slot on row 0 the
+  same take ran 30 % slower. A mean divides by its group's own count,
+  since a parent heads one live group. What is left of the scatter
+  places the group results at their parents, once for all hops: unique
+  rows, because a group with no live slot (every slot masked, a parent
+  of -1 or beyond ``num_segments``) is sent past the end, each to an
+  index of its own, and dropped."""
+  n = x.shape[0]
+  reduce = _GROUP_AGGRS[aggr]
+  vals, parents, kept = [], [], []
+  for off, s, k in groups:
+    end = off + s * k
+    idx = row[off:end].reshape(s, k).T.reshape(-1)
+    live = ok[off:end].reshape(s, k).T
+    spread = jnp.arange(s * k, dtype=idx.dtype) % n
+    msgs = jnp.take(x, jnp.where(live.reshape(-1), idx, spread), axis=0,
+                    mode='clip')
+    vals.append(reduce(msgs.reshape(k, s, -1), live))
+    # not col[off:end:k]: a strided slice of a 1-D array is 1.1 ms here
+    parents.append(col[off:end].reshape(s, k)[:, 0])
+    kept.append(live.any(axis=0))
+  vals, parents, kept = (jnp.concatenate(v) for v in (vals, parents, kept))
+  beyond = num_segments + jnp.arange(parents.shape[0], dtype=parents.dtype)
+  return jnp.zeros((num_segments, x.shape[1]), vals.dtype).at[
+      jnp.where(kept, parents, beyond)].set(
+          vals, mode='drop', unique_indices=True)
+
+
 class SAGEConv(nn.Module):
-  """GraphSAGE convolution: W_root·x + W_nbr·aggr(x[children])."""
+  """GraphSAGE convolution: W_root·x + W_nbr·aggr(x[children]).
+
+  ``groups`` (static, ``None``: no promise): ``(offset, S, K)`` triples
+  that cover the edge slots given, the producer's promise that they are
+  parent-major (module docstring). With it the aggregation is
+  :func:`grouped_aggregate`, without it the segment path; the same
+  mathematics, and only the order of a parent's ``K`` additions
+  differs."""
   out_features: int
   aggr: str = 'mean'
   use_bias: bool = True
@@ -63,15 +146,16 @@ class SAGEConv(nn.Module):
   @nn.compact
   def __call__(self, x: jax.Array, row: jax.Array, col: jax.Array,
                edge_mask: jax.Array, num_out=None,
-               x_dst=None) -> jax.Array:
+               x_dst=None, groups=None) -> jax.Array:
     n = x.shape[0]
     x_dst = x if x_dst is None else x_dst   # parents of another node type
     m = x_dst.shape[0] if num_out is None else num_out
-    safe_row = jnp.clip(row, 0, n - 1)
-    msgs = jnp.take(x, safe_row, axis=0)
-    agg = _AGGRS[self.aggr](
-        msgs, jnp.clip(col, 0, m - 1),
-        edge_mask & (row >= 0) & (col >= 0) & (col < m), m)
+    ok = edge_mask & (row >= 0) & (col >= 0) & (col < m)
+    if groups:
+      agg = grouped_aggregate(self.aggr, x, row, col, ok, groups, m)
+    else:
+      msgs = jnp.take(x, jnp.clip(row, 0, n - 1), axis=0)
+      agg = _AGGRS[self.aggr](msgs, jnp.clip(col, 0, m - 1), ok, m)
     lin_nbr = nn.Dense(self.out_features, use_bias=False,
                        param_dtype=self.param_dtype, name='lin_nbr')
     lin_root = nn.Dense(self.out_features, use_bias=self.use_bias,

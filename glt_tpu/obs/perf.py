@@ -84,6 +84,23 @@ def gauge_layer_rows(fn: str, rows,
     pass
 
 
+def gauge_grouped_aggregation(
+    fn: str, groups, registry: Optional[MetricsRegistry] = None) -> None:
+  """Trace-time hook beside :func:`gauge_layer_rows`: how many groups of
+  adjacent edge slots each layer of program ``fn``'s model aggregates by
+  a reshape and a masked reduce, as
+  ``model_grouped_aggregation{fn=..., layer=i}``; 0 for a layer that
+  scatter-adds every slot (a batch without ``Batch.hop_fanouts``, a
+  convolution that does not read it). Static: set once a trace."""
+  try:
+    reg = registry or get_registry()
+    for i, n in enumerate(groups):
+      reg.set('model_grouped_aggregation', float(n), fn=str(fn),
+              layer=str(i))
+  except Exception:  # accounting must never break a trace
+    pass
+
+
 def gauge_budgets(fn: str, node_budget: dict, edge_budget: dict,
                   registry: Optional[MetricsRegistry] = None) -> None:
   """Build-time hook of a typed step program ``fn``: the static padded

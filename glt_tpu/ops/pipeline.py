@@ -8,7 +8,7 @@ in the NeighborSampler docstring.
 """
 from __future__ import annotations
 
-from typing import Callable, Dict, List, Sequence
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -135,6 +135,27 @@ def node_hop_offsets(batch_size: int, fanouts: Sequence[int]) -> List[int]:
   pins it for each; tests/sampler_oracle.py checks it of every batch)."""
   return [sample_budget(batch_size, fanouts[:h])
           for h in range(len(fanouts) + 1)]
+
+
+def hop_fanouts(fanouts: Sequence[int]) -> Optional[Tuple[int, ...]]:
+  """The promise behind ``Batch.hop_fanouts``: ``(K_0, K_1, ...)``,
+  ``K_h = |fanout_h|``, where the hop loop that will run keeps slot
+  order, ``None`` where it does not. Every loop below writes a hop's
+  parents as ``jnp.repeat(frontier_labels, K_h)``, so hop ``h``'s block
+  of edge slots (:func:`edge_hop_offsets`) is groups of ``K_h`` adjacent
+  slots with one value of ``col`` each, the label of the frontier slot
+  the group was drawn for. A frontier slot that is no new head (a
+  duplicate, a pad) has every slot of its group masked, and a node is a
+  new head in one hop, so a label heads at most one group with a live
+  slot in the whole batch. The table engine and the sort engine's fused
+  assign hand a block back in slot order; the unfused ``sort`` loop's
+  :func:`sorted_hop_dedup` permutes a block's edges, so it gives no
+  promise. Read when a producer is built, like
+  :func:`make_dedup_tables` (tests/sampler_oracle.py checks it of every
+  batch)."""
+  if dedup_engine() == 'sort' and not fused_hops():
+    return None
+  return tuple(abs(k) for k in fanouts)
 
 
 def multihop_sample(one_hop: OneHopFn,

@@ -45,8 +45,8 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..data import Graph
-from ..ops.pipeline import edge_hop_offsets, multihop_sample, \
-    node_hop_offsets, sample_budget
+from ..ops.pipeline import edge_hop_offsets, hop_fanouts, \
+    multihop_sample, node_hop_offsets, sample_budget
 from ..ops.sample import sample_neighbors
 from ..ops.pipeline import make_dedup_tables
 from ..ops.superstep import scan_consume, superstep as build_superstep
@@ -126,11 +126,15 @@ class SPMDSageTrainStep:
     self.with_edge = bool(with_edge)
     #: the static fields of every Batch the step builds: the hop
     #: boundaries of the edge slots and of the node labels (the sampler
-    #: hands labels out hop by hop), by which the model trims both
+    #: hands labels out hop by hop), by which the model trims both, and
+    #: the widths of the groups of adjacent slots that share a parent,
+    #: by which it sums a parent's children without a scatter over the
+    #: slots (None where the hop loop does not keep slot order)
     self._batch_static = dict(
         batch_size=self.bs,
         edge_hop_offsets=tuple(edge_hop_offsets(self.bs, self.fanouts)),
-        node_hop_offsets=tuple(node_hop_offsets(self.bs, self.fanouts)))
+        node_hop_offsets=tuple(node_hop_offsets(self.bs, self.fanouts)),
+        hop_fanouts=hop_fanouts(self.fanouts))
     graph.lazy_init()
     self.labels = jax.device_put(labels, NamedSharding(mesh, P()))
     # one-time replication of the topology over the mesh: these ride
@@ -160,6 +164,10 @@ class SPMDSageTrainStep:
     #: when a program is traced (static: the node trim engages at trace
     #: time); None before, and for a model that does not say
     self.layer_rows = None
+    #: groups of adjacent edge slots each layer aggregates by a reshape
+    #: and a masked reduce (0: the layer scatter-adds every slot);
+    #: static and filled like ``layer_rows``
+    self.layer_groups = None
     self._step_fn = self._build()
     self._superstep_fn = self._build_superstep()
     if self._streaming:
@@ -186,13 +194,20 @@ class SPMDSageTrainStep:
 
   def _note_layer_rows(self, batch: Batch) -> None:
     """Trace-time side effect, as ``step_traces``: what the node trim
-    leaves each layer to compute, on the attribute and as the gauge
-    ``model_layer_rows{fn="train.step", layer=i}``."""
+    leaves each layer to compute and how many groups of edge slots it
+    aggregates by the grouped reduce, on the attributes and as the
+    gauges ``model_layer_rows{fn="train.step", layer=i}`` and
+    ``model_grouped_aggregation{fn="train.step", layer=i}``."""
+    from ..obs.perf import gauge_grouped_aggregation, gauge_layer_rows
     rows_of = getattr(self.model, 'layer_rows', None)
     if rows_of is not None:
-      from ..obs.perf import gauge_layer_rows
       self.layer_rows = rows_of(batch)
       gauge_layer_rows('train.step', self.layer_rows)
+    groups_of = getattr(self.model, 'layer_groups', None)
+    if groups_of is not None:
+      self.layer_groups = tuple(
+          sum(s for _, s, _ in g) for g in groups_of(batch))
+      gauge_grouped_aggregation('train.step', self.layer_groups)
 
   # -- shared per-batch body ----------------------------------------------
 

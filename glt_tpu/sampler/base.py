@@ -17,7 +17,7 @@ from __future__ import annotations
 import dataclasses
 import enum
 import math
-from typing import Dict, List, Optional, Union
+from typing import Dict, List, Optional, Sequence, Union
 
 import jax
 import numpy as np
@@ -132,6 +132,10 @@ class SamplerOutput:
   #: seed hold labels below node_hop_offsets[h]); None where the producer
   #: does not promise hop-compact labels
   node_hop_offsets: Optional[List[int]] = None
+  #: per-hop widths of the groups of adjacent edge slots that share a
+  #: parent (ops.pipeline.hop_fanouts); None where the producer does not
+  #: promise parent-major slots
+  hop_fanouts: Optional[Sequence[int]] = None
   metadata: Optional[Dict] = None
 
   @property

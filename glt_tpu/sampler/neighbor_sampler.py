@@ -26,8 +26,8 @@ import numpy as np
 
 from ..data import Graph
 from ..ops.pipeline import dedup_engine, edge_hop_offsets, \
-    hetero_edge_hop_offsets, make_dedup_tables, multihop_sample, \
-    multihop_sample_hetero, node_hop_offsets
+    hetero_edge_hop_offsets, hop_fanouts, make_dedup_tables, \
+    multihop_sample, multihop_sample_hetero, node_hop_offsets
 from ..ops.sample import (
     neighbor_probs, sample_full_neighbors, sample_neighbors,
     sample_neighbors_weighted,
@@ -251,6 +251,7 @@ class NeighborSampler(BaseSampler):
         num_sampled_edges=out['num_sampled_edges'],
         edge_hop_offsets=self._edge_hop_offsets(batch_size),
         node_hop_offsets=node_hop_offsets(batch_size, self.num_neighbors),
+        hop_fanouts=hop_fanouts(self.num_neighbors),
         metadata={'seed_labels': out['seed_labels'],
                   'seed_count': out['seed_count']},
     )

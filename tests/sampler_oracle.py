@@ -12,7 +12,8 @@ against the edge list:
   positive-weight edges first under weights, the whole row in order for
   a full-neighbourhood hop;
 * multi-hop: ``node[:node_count]`` holds no duplicate, labels are handed
-  out hop by hop (the ``node_hop_offsets`` prefix property), every node
+  out hop by hop (the ``node_hop_offsets`` prefix property), edge slots
+  parent-major where the hop loop promises it (``hop_fanouts``), every node
   new at hop ``h`` is a parent at hop ``h + 1``, masked lanes carry -1,
   the per-hop counts equal the counts recomputed from the edges,
   ``seed_labels`` maps duplicate seeds to one label, ``batch`` is the
@@ -198,6 +199,33 @@ def _check_new_labels(node, lo, n_new, first_seen, order, where):
     assert sorted(fresh) == sorted(first_seen), where
 
 
+def _check_groups(c, m, k, heads, lo, frontier, order, where):
+  """One hop's block as ``Batch.hop_fanouts`` promises it: groups of
+  ``k`` adjacent lanes under one parent label each, no label at the head
+  of two groups with a live lane (``heads`` runs over the whole batch),
+  and the parent the label of the frontier slot the group was drawn
+  for: the table engine's frontier is the new labels in order (``lo``
+  on), the fused sort's (``order='value'``) the lanes of the hop before
+  (``frontier``: their child labels, -1 where masked), where a lane
+  that found no new node leaves its whole group masked."""
+  assert k > 0 and c.shape[0] % k == 0, where
+  c, m = c.reshape(-1, k), m.reshape(-1, k)
+  assert (c == c[:, :1]).all(), (
+      f'{where}: col changes inside a group of {k} lanes')
+  parent, live = c[:, 0], m.any(axis=1)
+  for p in parent[live]:
+    assert int(p) not in heads, (
+        f'{where}: label {int(p)} heads two groups with a live lane')
+    heads.add(int(p))
+  if order == 'slot':
+    assert (parent[live] == lo + np.nonzero(live)[0]).all(), (
+        f'{where}: a group\'s parent is not its frontier slot\'s label')
+  elif order == 'value' and frontier is not None:
+    assert frontier.shape[0] == parent.shape[0], where
+    assert (parent[live] == frontier[live]).all(), (
+        f'{where}: a group\'s parent is not its frontier slot\'s label')
+
+
 def edge_offsets(batch_size, widths):
   offs, cap = [0], batch_size
   for k in widths:
@@ -208,11 +236,13 @@ def edge_offsets(batch_size, widths):
 
 def check_multihop(g: EdgeTable, seeds, n_valid, fanouts, out, *,
                    replace=False, weighted=False, new_label_order=None,
-                   widths=None):
+                   widths=None, hop_fanouts=None):
   """A one-type multi-hop batch. ``out``: numpy arrays under the
   sampler's names (``row`` child labels, ``col`` parent labels).
   ``widths``: lanes a frontier row owns at each hop where that is more
-  than ``|fanout|`` (the stream sampler appends its insert window)."""
+  than ``|fanout|`` (the stream sampler appends its insert window).
+  ``hop_fanouts``: the producer's promise of parent-major edge slots
+  (``Batch.hop_fanouts``) where it gave one; the batch is held to it."""
   seeds = np.asarray(seeds).reshape(-1)
   batch_size = seeds.shape[0]
   node = np.asarray(out['node'])
@@ -238,10 +268,18 @@ def check_multihop(g: EdgeTable, seeds, n_valid, fanouts, out, *,
     assert int(out['seed_count']) == cum
   lo, hi = 0, cum                      # the frontier's label range
   prefix = batch_size
+  if hop_fanouts is not None:
+    assert tuple(hop_fanouts) == tuple(abs(k) for k in widths or fanouts)
+  heads = set()                        # labels that head a live group
+  prev_children = None                 # the last hop's lanes: this frontier
   for h, k in enumerate(fanouts):
     where = f'hop {h}'
     sl = slice(offs[h], offs[h + 1])
     r, c, m = row[sl], col[sl], emask[sl]
+    if hop_fanouts is not None:
+      _check_groups(c, m, hop_fanouts[h], heads, lo, prev_children,
+                    new_label_order, where)
+      prev_children = np.where(m, r, -1)
     e = edge[sl] if edge is not None else None
     assert (r[~m] == -1).all(), f'{where}: a masked lane carries a label'
     assert int(hop_edges[h]) == int(m.sum()), (

@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from glt_tpu.models import GCNConv, SAGEConv
 from glt_tpu.models.conv import segment_mean
@@ -134,3 +135,103 @@ def test_trim_equivalence_more_layers_than_hops():
   out_f = GraphSAGE(trim=False, **kw).apply(params, b)
   np.testing.assert_allclose(np.asarray(out_t), np.asarray(out_f),
                              rtol=1e-5, atol=1e-6)
+
+
+# -- the grouped aggregation (Batch.hop_fanouts) against the segment path --
+
+def _parent_major_slots(fanouts, batch_size=6, seed=0):
+  """Edge slots as the hop loops lay them out: hop h is ``S_h`` groups
+  of ``K_h`` adjacent slots under one parent. Among the groups: wholly
+  masked ones (their parents' labels are used again by a live group of
+  the next hop, as the table engine does), parents of -1, a duplicate
+  seed (two groups of one label, one of them dead), seed slots beyond
+  ``n_valid`` and parents beyond any ``num_out`` under the node count."""
+  rng = np.random.default_rng(seed)
+  groups, row, col, mask = [], [], [], []
+  off, s, lo = 0, batch_size, 0
+  for h, k in enumerate(fanouts):
+    parents = lo + np.arange(s)
+    live = rng.random(s) < 0.7
+    if h == 0:
+      parents[2] = parents[1]          # a duplicate seed: its group is dead
+      live[2] = False
+      live[-1] = False                 # n_valid under the batch size
+      parents[-1] = -1
+    else:
+      parents[rng.random(s) < 0.1] = -1
+      live[0], live[1] = False, True
+    live &= parents >= 0
+    m = (rng.random((s, k)) < 0.6) & live[:, None]
+    m[live, 0] = True                  # a live group has a live slot
+    n_children = lo + s + s * k
+    r = np.where(m, rng.integers(0, n_children, (s, k)), -1)
+    groups.append((off, s, k))
+    row.append(r.reshape(-1))
+    col.append(np.repeat(parents, k))
+    mask.append(m.reshape(-1))
+    off, lo, s = off + s * k, lo + s, s * k
+  n = lo + s
+  as_i32 = lambda a: jnp.asarray(np.concatenate(a).astype(np.int32))
+  return (tuple(groups), n, as_i32(row), as_i32(col),
+          jnp.asarray(np.concatenate(mask)))
+
+
+@pytest.mark.parametrize('trimmed', [False, True],
+                         ids=['all_rows', 'num_out'])
+@pytest.mark.parametrize('fanouts', [(5, 3), (10, 2), (15,), (8, 16)],
+                         ids=['f5_3', 'f10_2', 'f15', 'f8_16'])
+@pytest.mark.parametrize('aggr', ['mean', 'sum', 'max'])
+def test_sage_conv_grouped_matches_segment(aggr, fanouts, trimmed):
+  groups, n, row, col, mask = _parent_major_slots(fanouts)
+  # half of the parents lie beyond num_out
+  num_out = (int(col.max()) + 1) // 2 if trimmed else None
+  dead = [not np.asarray(mask)[o:o + s * k].reshape(s, k)[i].any()
+          for o, s, k in groups for i in range(s)]
+  assert any(dead) and not all(dead) and (np.asarray(col) == -1).any()
+  x = jnp.asarray(np.random.default_rng(1).normal(size=(n, 12))
+                  .astype(np.float32))
+  conv = SAGEConv(7, aggr=aggr)
+  params = conv.init(jax.random.key(0), x, row, col, mask)
+  w = jnp.asarray(np.random.default_rng(2).normal(
+      size=(n if num_out is None else num_out, 7)).astype(np.float32))
+
+  def out_and_grads(groups):
+    def f(p, x):
+      out = conv.apply(p, x, row, col, mask, num_out=num_out,
+                       groups=groups)
+      return (out * w).sum(), out
+    (_, out), grads = jax.value_and_grad(f, argnums=(0, 1),
+                                         has_aux=True)(params, x)
+    return out, grads
+
+  got, g_got = out_and_grads(groups)
+  want, g_want = out_and_grads(None)
+  assert got.shape == want.shape == w.shape
+  np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                             rtol=1e-5, atol=1e-5)
+  for a, b in zip(jax.tree.leaves(g_got), jax.tree.leaves(g_want)):
+    assert np.abs(np.asarray(b)).max() > 0
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                               atol=1e-5)
+
+
+@pytest.mark.parametrize('aggr', ['mean', 'sum', 'max'])
+def test_sage_conv_without_groups_is_the_segment_path(aggr):
+  """No promise, no change: ``groups=None`` (and a call that never
+  heard of groups) computes the segment aggregation bit for bit."""
+  from glt_tpu.models.conv import _AGGRS
+  _, n, row, col, mask = _parent_major_slots((5, 3))
+  x = jnp.asarray(np.random.default_rng(1).normal(size=(n, 12))
+                  .astype(np.float32))
+  conv = SAGEConv(7, aggr=aggr)
+  params = conv.init(jax.random.key(0), x, row, col, mask)
+  p = params['params']
+  ok = mask & (row >= 0) & (col >= 0) & (col < n)
+  agg = _AGGRS[aggr](jnp.take(x, jnp.clip(row, 0, n - 1), axis=0),
+                     jnp.clip(col, 0, n - 1), ok, n)
+  want = (x @ p['lin_root']['kernel'] + p['lin_root']['bias']
+          + agg @ p['lin_nbr']['kernel'])
+  for kw in ({}, {'groups': None}, {'groups': ()}):
+    np.testing.assert_array_equal(
+        np.asarray(conv.apply(params, x, row, col, mask, **kw)),
+        np.asarray(want))

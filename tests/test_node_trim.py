@@ -21,8 +21,9 @@ from glt_tpu.loader import NeighborLoader
 from glt_tpu.loader.transform import Batch
 from glt_tpu.models import GraphSAGE
 from glt_tpu.obs import get_registry
-from glt_tpu.ops.pipeline import (edge_hop_offsets, multihop_sample,
-                                  node_hop_offsets, sample_budget)
+from glt_tpu.ops.pipeline import (edge_hop_offsets, hop_fanouts,
+                                  multihop_sample, node_hop_offsets,
+                                  sample_budget)
 from glt_tpu.ops.sample import sample_neighbors
 from glt_tpu.ops.unique import dense_make_tables
 from glt_tpu.parallel import ShardedFeature, SPMDSageTrainStep, make_mesh
@@ -144,6 +145,80 @@ def test_node_trim_matches_untrimmed(hub_graph, conv, num_layers, fanouts):
                                atol=1e-6, err_msg=str(path))
 
 
+# -- (a') grouped aggregation == segment aggregation, end to end ----------
+
+@pytest.mark.parametrize('engine,fused', [('table', '0'), ('sort', '1')],
+                         ids=['table', 'sort_fused'])
+@pytest.mark.parametrize('num_layers,fanouts', [
+    (3, (5, 3, 2)), (2, (10, 2)), (3, (3, 2))])
+def test_grouped_aggregation_matches_segment(hub_graph, monkeypatch, engine,
+                                             fused, num_layers, fanouts):
+  """A sampled batch with ``hop_fanouts`` against the same batch with
+  the field cleared: seed logits, every row (``return_all``) and every
+  gradient to float32 rounding, on the chip's hop loop and the table's."""
+  monkeypatch.setenv('GLT_DEDUP', engine)
+  monkeypatch.setenv('GLT_FUSED_HOP', fused)
+  plain = _batch(hub_graph, fanouts, n_valid=6)
+  assert plain.hop_fanouts is None and hop_fanouts(fanouts) == fanouts
+  batch = plain.replace(hop_fanouts=hop_fanouts(fanouts))
+  model = GraphSAGE(hidden_features=16, out_features=5,
+                    num_layers=num_layers)
+  params = model.init(jax.random.key(0), batch)
+  y = jnp.arange(BS) % 5
+  eoffs = edge_hop_offsets(BS, fanouts)
+  hops = tuple((eoffs[h], (eoffs[h + 1] - eoffs[h]) // k, k)
+               for h, k in enumerate(fanouts))
+  assert model.layer_groups(batch) == tuple(
+      hops[:max(min(len(fanouts), num_layers - i), 1)]
+      for i in range(num_layers))
+  assert model.layer_groups(plain) == ((),) * num_layers
+  # only SAGEConv reads groups
+  assert GraphSAGE(16, 5, num_layers=num_layers, conv='gat').layer_groups(
+      batch) == ((),) * num_layers
+
+  def loss_and_logits(p, b):
+    logits = model.apply(p, b)
+    loss = optax.softmax_cross_entropy_with_integer_labels(logits, y)
+    return loss.mean(), logits
+
+  (_, lo_g), g_g = jax.value_and_grad(loss_and_logits, has_aux=True)(
+      params, batch)
+  (_, lo_p), g_p = jax.value_and_grad(loss_and_logits, has_aux=True)(
+      params, plain)
+  np.testing.assert_allclose(np.asarray(lo_g), np.asarray(lo_p),
+                             rtol=1e-5, atol=1e-6)
+  for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(g_g),
+                          jax.tree.leaves(g_p)):
+    assert np.abs(np.asarray(b)).max() > 0, path
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                               atol=1e-6, err_msg=str(path))
+  np.testing.assert_allclose(
+      np.asarray(model.apply(params, batch, return_all=True)),
+      np.asarray(model.apply(params, plain, return_all=True)),
+      rtol=1e-5, atol=1e-6)
+
+
+def test_unfused_sort_loop_gives_no_promise(hub_graph, monkeypatch):
+  """``sorted_hop_dedup`` permutes a hop's edges, so the helper gives
+  ``None`` for that loop and its batches aggregate over segments."""
+  monkeypatch.setenv('GLT_DEDUP', 'sort')
+  monkeypatch.setenv('GLT_FUSED_HOP', '0')
+  fanouts = (3, 2, 2)
+  assert hop_fanouts(fanouts) is None
+  out = _sample(hub_graph, fanouts)
+  col = np.asarray(out['col'])[:BS * 3].reshape(BS, 3)
+  assert (col != col[:, :1]).any()    # a group holds several parents
+  batch = _batch(hub_graph, fanouts).replace(
+      hop_fanouts=hop_fanouts(fanouts))
+  assert GraphSAGE(16, 5, num_layers=3).layer_groups(batch) == ((),) * 3
+
+
+def test_hop_fanouts_must_divide_the_hop_blocks(hub_graph):
+  batch = _batch(hub_graph, (3, 2)).replace(hop_fanouts=(3, 5))
+  with pytest.raises(ValueError, match='hop_fanouts'):
+    GraphSAGE(16, 5, num_layers=2).layer_groups(batch)
+
+
 # -- (b) the property the slice rests on ---------------------------------
 
 @pytest.mark.parametrize('engine,fused', [
@@ -177,6 +252,7 @@ def test_neighbor_loader_batches_are_hop_compact(hub_graph, fanouts):
   for b in batches:
     assert b.node_hop_offsets == tuple(node_hop_offsets(BS, fanouts))
     assert b.edge_hop_offsets == tuple(edge_hop_offsets(BS, fanouts))
+    assert b.hop_fanouts == fanouts
     assert b.x.shape[0] == b.node_hop_offsets[-1]
     _assert_hop_compact(b.row, b.col, b.edge_mask, b.node_count, BS,
                         fanouts)
@@ -309,19 +385,43 @@ def test_trainer_records_layer_rows_and_trains_alike(mesh, hub_graph):
   assert step.layer_rows is None     # filled when the program is traced
   got = _three_steps(step, params, opt)
   assert step.layer_rows == (bs + bs * k1 + bs * k1 * k2, bs + bs * k1, bs)
+  # groups each layer aggregates by the grouped reduce: one a frontier
+  # slot of the hops it keeps (off the TPU the table loop runs, and it
+  # gives the promise)
+  assert step.layer_groups == step.layer_rows
   assert step.step_traces == 1
   gauges = get_registry().snapshot()['gauges']
-  for i, n in enumerate(step.layer_rows):
-    key = [k for k in gauges if k.startswith('model_layer_rows{')
-           and f'layer="{i}"' in k and 'fn="train.step"' in k]
-    assert key and gauges[key[0]] == n, (i, gauges)
+  for name, values in (('model_layer_rows', step.layer_rows),
+                       ('model_grouped_aggregation', step.layer_groups)):
+    for i, n in enumerate(values):
+      key = [k for k in gauges if k.startswith(name + '{')
+             and f'layer="{i}"' in k and 'fn="train.step"' in k]
+      assert key and gauges[key[0]] == n, (name, i, gauges)
 
   ref_step, ref_params, ref_opt = _trainer(mesh, hub_graph, trim=False)
   want = _three_steps(ref_step, ref_params, ref_opt)
   budget = sample_budget(bs, (3, 2, 2))
   assert ref_step.layer_rows == (budget,) * 3
+  assert ref_step.layer_groups == (budget - bs * k1 * k2 * 2,) * 3
   assert np.isfinite(got).all() and (got[0] != got[-1]).all()
   np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+def test_trainer_without_the_promise_counts_no_groups(mesh, hub_graph,
+                                                     monkeypatch):
+  """The unfused sort loop gives no promise: the step's batches carry
+  ``hop_fanouts=None`` and the counter reads 0 for every layer."""
+  monkeypatch.setenv('GLT_DEDUP', 'sort')
+  monkeypatch.setenv('GLT_FUSED_HOP', '0')
+  step, _, _ = _trainer(mesh, hub_graph, trim=True)
+  batch = step._dummy_batch()
+  assert batch.hop_fanouts is None
+  step._note_layer_rows(batch)        # what a trace of the step does
+  assert step.layer_groups == (0, 0, 0)
+  gauges = get_registry().snapshot()['gauges']
+  keys = [k for k in gauges if k.startswith('model_grouped_aggregation{')
+          and 'fn="train.step"' in k]
+  assert len(keys) == 3 and all(gauges[k] == 0 for k in keys)
 
 
 def test_superstep_shares_the_trimmed_body(mesh, hub_graph):

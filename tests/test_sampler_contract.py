@@ -18,15 +18,24 @@ import jax.numpy as jnp
 import pytest
 
 from glt_tpu.data import Dataset, Topology
-from glt_tpu.ops.pipeline import (make_dedup_tables, multihop_sample,
-                                  multihop_sample_many)
+from glt_tpu.ops.pipeline import (hop_fanouts, make_dedup_tables,
+                                  multihop_sample, multihop_sample_many)
 from glt_tpu.ops.sample import (sample_full_neighbors, sample_neighbors,
                                 sample_neighbors_weighted)
 
 from fixtures import ring_dataset, ring_edges
-from sampler_oracle import EdgeTable, check_hop, check_multihop
+import sampler_oracle
+from sampler_oracle import EdgeTable, check_hop
 
 K = 4
+
+
+def check_multihop(g, seeds, n_valid, fanouts, out, **kw):
+  """The oracle, with every batch held to the promise of parent-major
+  edge slots that the hop loop which ran gives (``Batch.hop_fanouts``;
+  the engine knobs are still set while a case checks its batch)."""
+  kw.setdefault('hop_fanouts', hop_fanouts(kw.get('widths') or fanouts))
+  sampler_oracle.check_multihop(g, seeds, n_valid, fanouts, out, **kw)
 
 
 def _csr(degrees, seed=7):
@@ -334,6 +343,12 @@ def test_sampler_weighted_and_full_neighbourhood(monkeypatch, engine,
     cap *= abs(k)
     want.append(want[-1] + cap)
   assert list(out.node_hop_offsets) == want
+  # and the promise of parent-major slots, which the unfused sort loop
+  # (the sort engine off the TPU) does not give: it permutes a block
+  if engine == 'sort':
+    assert out.hop_fanouts is None and hop_fanouts(internal) is None
+  else:
+    assert out.hop_fanouts == tuple(abs(k) for k in internal)
 
 
 # -- row gathers ---------------------------------------------------------
@@ -457,9 +472,35 @@ def _break_duplicate_node(out):
   out['node'][int(out['node_count']) - 1] = out['node'][0]
 
 
+def _live_lanes_by_parent(out):
+  m, c = out['edge_mask'].astype(bool), out['col']
+  return {int(p): np.nonzero(m & (c == p))[0] for p in np.unique(c[m])}
+
+
+def _break_group(out):
+  """Two lanes of different parents change places: every parent keeps
+  its picks, ``col`` is no longer constant over a group."""
+  (_, a), (_, b) = list(_live_lanes_by_parent(out).items())[:2]
+  for k in ('row', 'col', 'edge'):
+    out[k][a[0]], out[k][b[0]] = out[k][b[0]], out[k][a[0]]
+
+
+def _break_one_head(out):
+  """A parent's picks move into a wholly masked group of its hop: the
+  label then heads two groups with a live lane."""
+  m = out['edge_mask'].astype(bool)
+  lanes = next(v for v in _live_lanes_by_parent(out).values()
+               if v.shape[0] == 2 and v[0] < 8)   # hop 0, fanout 2
+  dead = next(g for g in range(0, 8, 2) if not m[g:g + 2].any())
+  for k in ('row', 'col', 'edge', 'edge_mask'):
+    out[k][dead], out[k][lanes[1]] = out[k][lanes[1]], out[k][dead]
+  out['col'][dead + 1] = out['col'][dead]
+
+
 @pytest.mark.parametrize('breakage', [
     _break_child, _break_distinct, _break_masked_lane, _break_count,
-    _break_seed_label, _break_duplicate_node],
+    _break_seed_label, _break_duplicate_node, _break_group,
+    _break_one_head],
     ids=lambda f: f.__name__[len('_break_'):])
 def test_oracle_refuses_a_broken_batch(chip_engines, breakage):
   g, seeds, out = _good_batch()
