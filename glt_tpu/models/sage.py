@@ -134,5 +134,10 @@ class GraphSAGE(nn.Module):
   def embed(self, batch: Batch, train: bool = False) -> jax.Array:
     """Embeddings for ALL sampled nodes (link/unsupervised tasks index
     these by edge_label_index / src_index / dst_*_index, which range over
-    every seed endpoint, not just the first batch_size labels)."""
+    every seed endpoint, not just the first batch_size labels). Every
+    row at every layer: no node trim. The endpoints' labels are the
+    first ``seed count`` ones, so a producer that sets ``batch_size`` to
+    the seed count (the fused link step, parallel/train.py: ``4B`` for
+    ``B`` pairs) reads them off ``__call__`` and keeps the trim; the two
+    give one loss (tests/test_link_step.py)."""
     return self.__call__(batch, train=train, return_all=True)

@@ -10,10 +10,18 @@ a stable argsort on validity — no dynamic shapes anywhere.
 """
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+
+#: probe rounds of :func:`edge_in_csr`: a lower-bound binary search
+#: halves ``[lo, hi)`` once a round, so 34 rounds settle any row of up
+#: to 2^34 entries, more than an int32-indexed CSR can hold. The count
+#: is fixed by that worst case and not by the graph: a row of the
+#: benchmark's graph is at most 2,000 wide (11 rounds), and the rounds
+#: past a row's own depth re-read one settled element each.
+PROBE_ROUNDS = 34
 
 
 def edge_in_csr(indptr: jax.Array, indices: jax.Array,
@@ -21,14 +29,19 @@ def edge_in_csr(indptr: jax.Array, indices: jax.Array,
   """Vectorized membership test: does edge (rows[i] -> cols[i]) exist?
 
   Requires columns sorted within each row (Topology guarantees this).
-  Fixed-depth lower-bound binary search (34 steps covers 2^34 edges),
-  the TPU analogue of EdgeInCSR (random_negative_sampler.cu:37-54).
+  Fixed-depth lower-bound binary search, the TPU analogue of EdgeInCSR
+  (random_negative_sampler.cu:37-54): ``PROBE_ROUNDS`` dependent rounds
+  of one element read a pair, whatever the graph (the bound is the
+  widest row an index can address, 2^34 entries, not the widest row
+  there is). A round past a row's own depth changes nothing. On the
+  benchmark's link cell the whole negative stage (5 x 256 proposals)
+  is ``link_negative_device_ms`` (PERF.md, section 5).
   """
   num_edges = indices.shape[0]
   lo = jnp.take(indptr, rows, mode='clip')
   hi = jnp.take(indptr, rows + 1, mode='clip')
   cols = cols.astype(indices.dtype)
-  for _ in range(34):
+  for _ in range(PROBE_ROUNDS):
     probing = lo < hi
     # overflow-safe midpoint: indptr may be int32 with values near 2^31
     mid = lo + ((hi - lo) >> 1)
@@ -47,6 +60,10 @@ class NegativeOutput(NamedTuple):
   cols: jax.Array   # [req]
   mask: jax.Array   # [req] valid negatives (False only if padding=False
                     # and trials exhausted)
+  # scalars, from random_negative_sample alone: proposals of all rounds
+  # that were edges, and slots with no round that was not
+  rejected: Optional[jax.Array] = None
+  padded: Optional[jax.Array] = None
 
 
 def random_negative_sample(
@@ -65,7 +82,9 @@ def random_negative_sample(
   Mirrors CUDARandomNegativeSampler::Sample(req_num, trials_num, padding)
   (py_export_glt.cc:198-201): propose uniform pairs, keep non-edges; with
   ``padding=True`` remaining slots are filled with (possibly-positive)
-  uniform pairs so the output is always full.
+  uniform pairs so the output is always full. ``rejected`` counts the
+  proposals of every round that were edges, ``padded`` the slots none of
+  whose rounds was a non-edge (nought both in non-strict mode).
   """
   t = max(trials_num, 1)
   kr, kc = jax.random.split(key)
@@ -91,4 +110,7 @@ def random_negative_sample(
     mask = jnp.ones((req_num,), bool)
   else:
     rows, cols, mask = sel_rows, sel_cols, any_ok
-  return NegativeOutput(rows=rows, cols=cols, mask=mask)
+  return NegativeOutput(
+      rows=rows, cols=cols, mask=mask,
+      rejected=(~ok).sum(dtype=jnp.int32),
+      padded=(~any_ok).sum(dtype=jnp.int32))

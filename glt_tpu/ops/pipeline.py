@@ -165,11 +165,18 @@ def multihop_sample(one_hop: OneHopFn,
                     key: jax.Array,
                     table: jax.Array,
                     scratch: jax.Array,
-                    with_edge: bool = False) -> Dict[str, jax.Array]:
+                    with_edge: bool = False,
+                    seed_mask: Optional[jax.Array] = None,
+                    ) -> Dict[str, jax.Array]:
   """Runs the full hop loop; returns (out_dict, table, scratch).
 
   ``one_hop(frontier_ids, fanout, key, mask)`` performs one sampling hop.
-  Tables are returned reset, ready for the next batch.
+  Tables are returned reset, ready for the next batch. The valid seeds
+  are the first ``n_valid``, or where ``seed_mask`` ([batch] bool) is
+  given, the slots it marks (edge seeds: the endpoints of a pair past
+  the valid pairs are no suffix). Seeds may repeat: a repeated seed has
+  one label, and every slot that holds it reads that label in
+  ``seed_labels``.
 
   Result contract (both engines, homo and hetero): lanes where
   ``edge_mask`` is False carry -1 in the child-label buffer (``row``
@@ -186,13 +193,14 @@ def multihop_sample(one_hop: OneHopFn,
   count_compile('ops.multihop_sample')
   if dedup_engine() == 'sort':
     out = _multihop_sample_sorted(one_hop, seeds, n_valid, fanouts, key,
-                                  with_edge=with_edge)
+                                  with_edge=with_edge, seed_mask=seed_mask)
     return out, table, scratch
   _check_engine_tables(table)
   batch_size = seeds.shape[0]
   budget = sample_budget(batch_size, fanouts)
   state = dense_init(table, scratch, budget)
-  seed_mask = jnp.arange(batch_size) < n_valid
+  if seed_mask is None:
+    seed_mask = jnp.arange(batch_size) < n_valid
   state, seed_labels = dense_assign(state, seeds, seed_mask)
   frontier_ids = jax.lax.slice(state.nodes, (0,), (batch_size,))
   frontier_labels = jnp.arange(batch_size, dtype=jnp.int32)
@@ -252,7 +260,9 @@ def _multihop_sample_sorted(one_hop: OneHopFn,
                             n_valid: jax.Array,
                             fanouts: Sequence[int],
                             key: jax.Array,
-                            with_edge: bool = False) -> Dict[str, jax.Array]:
+                            with_edge: bool = False,
+                            seed_mask: Optional[jax.Array] = None,
+                            ) -> Dict[str, jax.Array]:
   """The hop loop on the sort-merge inducer (ops/unique.py
   sorted_hop_dedup): no [N]-sized tables, no scatters, no gathers — two
   multi-operand sorts + prefix scans per hop. Labels, node list, batch,
@@ -262,7 +272,8 @@ def _multihop_sample_sorted(one_hop: OneHopFn,
   parity test canonicalizes)."""
   batch_size = seeds.shape[0]
   budget = sample_budget(batch_size, fanouts)
-  seed_mask = jnp.arange(batch_size) < n_valid
+  if seed_mask is None:
+    seed_mask = jnp.arange(batch_size) < n_valid
 
   u_ids = jnp.zeros((0,), jnp.int32)
   u_labs = jnp.zeros((0,), jnp.int32)

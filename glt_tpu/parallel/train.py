@@ -24,6 +24,18 @@ Two execution modes share one batch body:
     (ops/superstep.py::superstep_hetero, which this trainer's homo
     ``(table, scratch)`` superstep is now a special case of).
 
+The per-batch body has two fronts and two losses, chosen when the step
+is built. Given labelled node seeds it trains a classifier. Given a
+``NegativeSampling`` it is the link-prediction step of the reference's
+unsupervised GraphSAGE recipe (``LinkNeighborLoader`` + binary
+negatives + dot-product BCE, examples/graph_sage_unsup.py) in the same
+one donated program: ``[B, 2]`` positive pairs in, strict negatives
+drawn from the step's key (ops/negative.py), the ``4B`` endpoints as
+the seeds of the one hop loop, the endpoints' embeddings read off the
+seed prefix, so the node trim and the grouped aggregation engage as on
+the node path. The supersteps and the streaming consume refuse edge
+seeds.
+
 For host-spilled features WITHOUT the pinned-host cold block
 (``cold_array is None``) the fused body cannot resolve cold rows
 in-program; ``cold_streaming=True`` instead splits each superstep into a
@@ -45,6 +57,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..data import Graph
+from ..ops.negative import random_negative_sample
 from ..ops.pipeline import edge_hop_offsets, hop_fanouts, \
     multihop_sample, node_hop_offsets, sample_budget
 from ..ops.sample import sample_neighbors
@@ -52,21 +65,56 @@ from ..ops.pipeline import make_dedup_tables
 from ..ops.superstep import scan_consume, superstep as build_superstep
 from ..loader.transform import Batch
 from ..obs.device import register_step_program, scope
+from ..sampler.base import NegativeSampling
 from .mesh import replicate
 
+#: rounds of proposals a strict negative gets before it is padded with
+#: the last round's (reference RandomNegativeSampler.sample's default)
+NEG_TRIALS = 5
 
-def _sage_update(model, tx, axis, bs, params, opt_state, batch, n_valid):
-  """Forward/backward + DDP pmean + optimizer update for one batch —
-  the training tail shared by the per-batch, fused-superstep and
-  streaming-consume bodies (identical op sequence = loss parity)."""
-  def loss_fn(p):
-    with jax.named_scope('forward'):
-      logits = model.apply(p, batch)
-      mask = jnp.arange(bs) < n_valid
-      losses = optax.softmax_cross_entropy_with_integer_labels(
-          logits, batch.y)
+
+def _node_loss(bs):
+  """Mean softmax cross-entropy of the ``bs`` seed rows over ``batch.y``,
+  the rows past ``n_valid`` left out."""
+  def loss(logits, batch, n_valid):
+    mask = jnp.arange(bs) < n_valid
+    losses = optax.softmax_cross_entropy_with_integer_labels(
+        logits, batch.y)
+    return (jnp.where(mask, losses, 0).sum()
+            / jnp.maximum(mask.sum(), 1))
+  return loss
+
+
+def _link_loss(num_pos):
+  """Mean binary cross-entropy with logits over ``num_pos`` positive
+  pairs and their ``num_pos`` negatives: a pair's logit is the dot
+  product of its endpoints' embeddings, read off the seed rows by
+  ``edge_label_index`` (the seed slots' labels); a pair past
+  ``n_valid`` and its negative are left out of the mean."""
+  def loss(emb, batch, n_valid):
+    with jax.named_scope('link_loss'):
+      src, dst = jnp.maximum(batch.metadata['edge_label_index'], 0)
+      logit = (jnp.take(emb, src, axis=0)
+               * jnp.take(emb, dst, axis=0)).sum(-1)
+      mask = jnp.tile(jnp.arange(num_pos) < n_valid, 2)
+      losses = optax.sigmoid_binary_cross_entropy(
+          logit, batch.metadata['edge_label'])
       return (jnp.where(mask, losses, 0).sum()
               / jnp.maximum(mask.sum(), 1))
+  return loss
+
+
+def _sage_update(model, tx, axis, loss_of, params, opt_state, batch,
+                 n_valid):
+  """Forward/backward + DDP pmean + optimizer update for one batch —
+  the training tail shared by the per-batch, fused-superstep and
+  streaming-consume bodies (identical op sequence = loss parity).
+  ``loss_of(seed_rows, batch, n_valid)`` is the task's loss over what
+  the model gives for the seed rows (:func:`_node_loss`,
+  :func:`_link_loss`)."""
+  def loss_fn(p):
+    with jax.named_scope('forward'):
+      return loss_of(model.apply(p, batch), batch, n_valid)
 
   with scope('model_step'):
     loss, grads = jax.value_and_grad(loss_fn)(params)
@@ -90,9 +138,10 @@ class SPMDSageTrainStep:
     graph: replicated Graph (HBM-resident topology on every chip; the
       sharded-topology variant lives in glt_tpu.distributed).
     feature: a ShardedFeature row-sharded over the mesh.
-    labels: [N] label array (replicated).
+    labels: [N] label array (replicated); None for a link step.
     fanouts: per-hop fanouts.
-    batch_size_per_device: seed count per device per step.
+    batch_size_per_device: seed count per device per step: seed nodes,
+      or positive pairs for a link step.
     with_edge: also thread sampled edge ids through the pipeline into
       ``Batch.edge`` (edge-feature consumers).
     cold_streaming: opt-in — accept a host-spilled store WITHOUT the
@@ -100,13 +149,44 @@ class SPMDSageTrainStep:
       module docstring). Only the superstep path serves such stores;
       per-batch ``__call__`` raises. Without it, such stores are
       rejected at construction exactly as before.
+    neg_sampling: a ``NegativeSampling`` (or what casts to one) makes
+      this a link-prediction step, as ``LinkNeighborLoader`` takes it:
+      ``__call__`` is handed ``[n_dev * B, 2]`` positive ``(src, dst)``
+      pairs where it is handed node ids, draws ``B`` negatives a device
+      inside the program (binary mode, amount 1; ``strict`` rejects
+      edges of the graph over ``NEG_TRIALS`` rounds and pads with the
+      last round's proposal), seeds the hop loop with the ``4B``
+      endpoints ``[src; neg_src; dst; neg_dst]`` (``sample_from_edges``'s
+      order) and trains the dot-product BCE of the model's seed rows.
+      ``labels`` is not read. Per-batch only: the supersteps and
+      ``cold_streaming`` raise. What only this path counts comes back
+      through :meth:`link_counters`.
+    keep_seeds: a link step also hands back the ``[4B]`` endpoint seeds
+      it drew and expanded (through :meth:`link_counters`), for a check
+      of the negatives themselves against the graph; off, the program
+      has no such output.
   """
 
   def __init__(self, mesh: Mesh, model, tx, graph: Graph, feature,
                labels, fanouts: Sequence[int],
                batch_size_per_device: int, axis: str = 'data',
-               with_edge: bool = False, cold_streaming: bool = False):
+               with_edge: bool = False, cold_streaming: bool = False,
+               neg_sampling=None, keep_seeds: bool = False):
     from .dist_feature import require_device_resident
+    self.neg_sampling = NegativeSampling.cast(neg_sampling)
+    self._link = self.neg_sampling is not None
+    self._keep_seeds = bool(keep_seeds)
+    if self._link:
+      if not self.neg_sampling.is_binary() \
+          or self.neg_sampling.amount != 1:
+        raise NotImplementedError(
+            f'SPMDSageTrainStep draws binary negatives, one a positive, '
+            f'in its program; got {self.neg_sampling}: triplet mode and '
+            f'other amounts run through LinkNeighborLoader')
+      if cold_streaming:
+        raise NotImplementedError(
+            'cold_streaming runs through the supersteps, which take no '
+            'edge seeds')
     self._streaming = bool(cold_streaming)
     if not self._streaming:
       require_device_resident(feature, 'SPMDSageTrainStep')
@@ -122,6 +202,9 @@ class SPMDSageTrainStep:
     self.feature = feature
     self.fanouts = list(fanouts)
     self.bs = batch_size_per_device
+    #: seed slots of the hop loop a device: a link step seeds it with
+    #: both endpoints of every positive and of every negative pair
+    self.seed_slots = 4 * self.bs if self._link else self.bs
     self.axis = axis
     self.with_edge = bool(with_edge)
     #: the static fields of every Batch the step builds: the hop
@@ -131,12 +214,17 @@ class SPMDSageTrainStep:
     #: by which it sums a parent's children without a scatter over the
     #: slots (None where the hop loop does not keep slot order)
     self._batch_static = dict(
-        batch_size=self.bs,
-        edge_hop_offsets=tuple(edge_hop_offsets(self.bs, self.fanouts)),
-        node_hop_offsets=tuple(node_hop_offsets(self.bs, self.fanouts)),
+        batch_size=self.seed_slots,
+        edge_hop_offsets=tuple(
+            edge_hop_offsets(self.seed_slots, self.fanouts)),
+        node_hop_offsets=tuple(
+            node_hop_offsets(self.seed_slots, self.fanouts)),
         hop_fanouts=hop_fanouts(self.fanouts))
     graph.lazy_init()
-    self.labels = jax.device_put(labels, NamedSharding(mesh, P()))
+    # a link step reads no label: one element stands in as the argument
+    self.labels = jax.device_put(
+        jnp.zeros((1,), jnp.int32) if self._link else labels,
+        NamedSharding(mesh, P()))
     # one-time replication of the topology over the mesh: these ride
     # the step as jit ARGUMENTS (as closed-over constants, hundreds of
     # MB of topology would be baked into the compiled program), and
@@ -168,6 +256,9 @@ class SPMDSageTrainStep:
     #: and a masked reduce (0: the layer scatter-adds every slot);
     #: static and filled like ``layer_rows``
     self.layer_groups = None
+    #: what the last link step counted, still on the device, a device a
+    #: row (:meth:`link_counters` reads it); None on a node step
+    self._link_stats = None
     self._step_fn = self._build()
     self._superstep_fn = self._build_superstep()
     if self._streaming:
@@ -181,7 +272,7 @@ class SPMDSageTrainStep:
     return replicate(params, self.mesh)
 
   def _dummy_batch(self) -> Batch:
-    budget = sample_budget(self.bs, self.fanouts)
+    budget = sample_budget(self.seed_slots, self.fanouts)
     ecap = self._batch_static['edge_hop_offsets'][-1]
     return Batch(
         x=jnp.zeros((budget, self.feature.feature_dim)),
@@ -190,7 +281,8 @@ class SPMDSageTrainStep:
         edge_mask=jnp.zeros((ecap,), bool),
         node=jnp.zeros((budget,), jnp.int32),
         node_count=jnp.zeros((), jnp.int32),
-        y=jnp.zeros((self.bs,), jnp.int32), **self._batch_static)
+        y=jnp.zeros((self.seed_slots,), jnp.int32),
+        **self._batch_static)
 
   def _note_layer_rows(self, batch: Batch) -> None:
     """Trace-time side effect, as ``step_traces``: what the node trim
@@ -211,38 +303,78 @@ class SPMDSageTrainStep:
 
   # -- shared per-batch body ----------------------------------------------
 
+  def _link_seeds(self, indptr, indices, pairs, n_valid, key):
+    """The link step's front, inside the ``sampler`` scope: ``B``
+    negatives from ``key`` (as ``sample_from_edges`` draws them:
+    uniform pairs, ``NEG_TRIALS`` rounds, the first round that is no
+    edge of the graph, the last round's proposal where none is), then
+    the seeds ``[src; neg_src; dst; neg_dst]``, their mask (a pair past
+    ``n_valid`` and its negative seed nothing) and the labels of the
+    ``2B`` pairs. Returns ``(seeds [4B], seed_mask [4B], edge_label
+    [2B], counters)``."""
+    bs, num_nodes = self.bs, self.graph.num_nodes
+    with jax.named_scope('negative'):
+      neg = random_negative_sample(
+          indptr, indices, bs, NEG_TRIALS, key, num_nodes, num_nodes,
+          strict=self.neg_sampling.strict, padding=True)
+    seeds = jnp.concatenate(
+        [pairs[:, 0], neg.rows, pairs[:, 1], neg.cols]).astype(jnp.int32)
+    seed_mask = jnp.tile(jnp.arange(bs) < n_valid, 4)
+    edge_label = jnp.concatenate(
+        [jnp.ones((bs,), jnp.float32), jnp.zeros((bs,), jnp.float32)])
+    return seeds, seed_mask, edge_label, dict(
+        negatives_rejected=neg.rejected, negatives_padded=neg.padded)
+
   def _make_batch_body(self, feat_shard, labels, indptr, indices,
                        cold_shard):
     """The body of ONE training step as seen from inside shard_map:
     sample -> gather -> forward/backward -> pmean -> update. Shared
     verbatim by the per-batch step and the superstep scan so the two
-    engines stay bit-identical."""
+    engines stay bit-identical. Its last output is the loss; a link
+    step's is ``(loss, counters)``."""
     feature, model, tx, axis = self.feature, self.model, self.tx, self.axis
     fanouts, bs = self.fanouts, self.bs
-    with_edge = self.with_edge
+    with_edge, link = self.with_edge, self._link
+    loss_of = _link_loss(bs) if link else _node_loss(bs)
     one_hop = lambda ids, fanout, k, mask: sample_neighbors(
         indptr, indices, ids, fanout, k, seed_mask=mask)
 
     def body(params, opt_state, table, scratch, seeds, n_valid, key):
       with scope('sampler'):
         key = jax.random.fold_in(key[0], jax.lax.axis_index(axis))
+        seed_mask = meta = None
+        if link:
+          kneg, key = jax.random.split(key)
+          seeds, seed_mask, edge_label, stats = self._link_seeds(
+              indptr, indices, seeds, n_valid[0], kneg)
         out, table, scratch = multihop_sample(
             one_hop, seeds, n_valid[0], fanouts, key, table, scratch,
-            with_edge=with_edge)
+            with_edge=with_edge, seed_mask=seed_mask)
+        if link:
+          # a seed slot's label is its endpoint's row of the model's
+          # output: the labels of the seeds are the first ones
+          meta = dict(
+              edge_label_index=out['seed_labels'].reshape(2, -1),
+              edge_label=edge_label)
+          stats['seed_unique'] = out['seed_count']
+          if self._keep_seeds:
+            stats['seeds'] = seeds
       with scope('feature_store'):
         node_valid = jnp.arange(out['node'].shape[0]) < out['node_count']
         x = feature.lookup_local(
             feat_shard, jnp.maximum(out['node'], 0), node_valid,
             axis_name=axis, cold_shard=cold_shard)
-        y = jnp.take(labels, jnp.maximum(out['batch'], 0)[:bs])
+        y = None if link else jnp.take(
+            labels, jnp.maximum(out['batch'], 0)[:bs])
       batch = Batch(
           x=x, row=out['row'], col=out['col'], edge_mask=out['edge_mask'],
           node=out['node'], node_count=out['node_count'], y=y,
-          edge=out.get('edge'), **self._batch_static)
+          edge=out.get('edge'), metadata=meta, **self._batch_static)
       self._note_layer_rows(batch)
       params, opt_state, loss = _sage_update(
-          model, tx, axis, bs, params, opt_state, batch, n_valid[0])
-      return params, opt_state, table, scratch, loss
+          model, tx, axis, loss_of, params, opt_state, batch, n_valid[0])
+      return (params, opt_state, table, scratch,
+              (loss, stats) if link else loss)
 
     return body
 
@@ -253,10 +385,10 @@ class SPMDSageTrainStep:
       body = self._make_batch_body(
           feat_shard, labels, indptr, indices,
           cold_shard[0] if cold_shard else None)
-      params, opt_state, table, scratch, loss = body(
+      params, opt_state, table, scratch, aux = body(
           params, opt_state, table[0], scratch[0], seeds, n_valid, key)
       return (params, opt_state, table[None], scratch[None],
-              loss[None])
+              jax.tree.map(lambda a: a[None], aux))
 
     offloaded = self.feature.cold_array is not None
     fn = jax.shard_map(
@@ -292,7 +424,7 @@ class SPMDSageTrainStep:
     params/opt-state/dedup-tables in the carry. Unsupported for
     streaming stores (cold rows are not in-program resolvable there);
     ``superstep()`` routes those through sample+stage+consume."""
-    if self._streaming:
+    if self._streaming or self._link:
       return None
     axis = self.axis
 
@@ -354,6 +486,7 @@ class SPMDSageTrainStep:
     consuming ``keys[t]`` would). Params/opt-state are DONATED — reuse
     the returned ones. Returns (params, opt_state, loss [T, n_dev]).
     """
+    self._refuse_edge_seeds('superstep')
     seeds, n_valid, keys = self._stacked_put(seeds_stack, n_valid_stack,
                                              keys)
     params, opt_state = replicate((params, opt_state), self.mesh)
@@ -470,7 +603,8 @@ class SPMDSageTrainStep:
             **self._batch_static)
         self._note_layer_rows(batch)
         params, opt_state, loss = _sage_update(
-            model, tx, axis, bs, params, opt_state, batch, n_valid[0])
+            model, tx, axis, _node_loss(bs), params, opt_state, batch,
+            n_valid[0])
         return (params, opt_state), loss
 
       run = scan_consume(body)
@@ -520,12 +654,19 @@ class SPMDSageTrainStep:
 
   # -- epoch drivers ------------------------------------------------------
 
+  def _refuse_edge_seeds(self, entry: str) -> None:
+    if self._link:
+      raise NotImplementedError(
+          f'SPMDSageTrainStep.{entry} takes node seeds only: a link '
+          f'step (neg_sampling given) runs per batch, through __call__')
+
   def make_epoch_loader(self, seeds, superstep_len: int = 8,
                         shuffle: bool = True, drop_last: bool = False,
                         drop_last_superstep: bool = False,
                         rng=None):
     """A DeviceEpochLoader pre-committed to this trainer's mesh layout
     (seed stacks [T, n_dev*bs] sharded on the batch axis)."""
+    self._refuse_edge_seeds('make_epoch_loader')
     from ..loader.device_epoch import DeviceEpochLoader
     n_dev = self.mesh.shape[self.axis]
     sh = NamedSharding(self.mesh, P(None, self.axis))
@@ -546,6 +687,7 @@ class SPMDSageTrainStep:
     gather no longer serializes against compute. Returns
     (params, opt_state, losses [T_total, n_dev]).
     """
+    self._refuse_edge_seeds('run_epoch')
     n_dev = self.mesh.shape[self.axis]
 
     def keyed():
@@ -581,8 +723,10 @@ class SPMDSageTrainStep:
   # -- per-batch path -----------------------------------------------------
 
   def __call__(self, params, opt_state, seeds, n_valid_per_device, keys):
-    """seeds: [n_dev * bs] shard-major; n_valid_per_device: [n_dev];
-    keys: [n_dev] PRNG keys. Returns (params, opt_state, loss[n_dev])."""
+    """seeds: [n_dev * bs] shard-major (a link step: [n_dev * bs, 2]
+    positive ``(src, dst)`` pairs, each an edge of the graph);
+    n_valid_per_device: [n_dev]; keys: [n_dev] PRNG keys. Returns
+    (params, opt_state, loss[n_dev])."""
     if self._streaming:
       raise NotImplementedError(
           'cold_streaming stores run through superstep()/run_epoch(); '
@@ -610,10 +754,25 @@ class SPMDSageTrainStep:
              params, opt_state, self.tables, self.scratches, seeds,
              n_valid, keys, self.feature.array, self.labels,
              self._indptr, self._indices, *extra)
+      if self._link:
+        loss, self._link_stats = loss
       _synced['loss'] = loss
     if tracer.enabled:
       get_registry().set('train_step_traces', float(self.step_traces))
     return params, opt_state, loss
+
+  def link_counters(self) -> dict:
+    """What the last link step counted, a device an entry, read back
+    from the device (it waits for that step): ``negatives_rejected``
+    (proposals of the ``NEG_TRIALS x B`` that were edges of the graph),
+    ``negatives_padded`` (pairs of the ``B`` with no round that was no
+    edge: they carry the last round's proposal, an edge),
+    ``seed_unique`` (distinct endpoints among the valid of the ``4B``:
+    the hop-0 node count) and, from a step built with ``keep_seeds``,
+    ``seeds`` (``[4B]`` node ids, ``[src; neg_src; dst; neg_dst]``)."""
+    if self._link_stats is None:
+      raise RuntimeError('no link step has run')
+    return {k: np.asarray(v) for k, v in self._link_stats.items()}
 
   def scope_profile(self, params, opt_state, batches) -> dict:
     """Device time by layer of the per-batch step, from a profiler

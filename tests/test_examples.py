@@ -53,9 +53,12 @@ def test_stream_updates_example():
   assert 'fresh predictions for updated nodes:' in out
 
 
-def test_unsup_example():
-  out = _run('graph_sage_unsup.py', '--epochs', '1', timeout=300)
+@pytest.mark.parametrize('entry', [(), ('--fused',)],
+                         ids=['loader', 'fused'])
+def test_unsup_example(entry):
+  out = _run('graph_sage_unsup.py', '--epochs', '1', *entry, timeout=300)
   assert 'loss=' in out
+  assert ('fused=1' in out) == bool(entry)
 
 
 def test_seal_example():
