@@ -21,7 +21,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs.device import register_step_program, scope
-from ..ops.pipeline import (hetero_edge_hop_offsets, make_dedup_tables,
+from ..ops.pipeline import (hetero_edge_hop_offsets, hetero_hop_fanouts,
+                            make_dedup_tables,
                             multihop_sample_hetero)
 from ..parallel.mesh import replicate
 from ..typing import EdgeType, NodeType, as_str, reverse_edge_type
@@ -625,13 +626,23 @@ class DistHeteroTrainStep:
     #: when a program is traced (the node trim engages at trace time);
     #: None before, and for a model that does not say
     self.layer_rows = None
+    #: groups of adjacent edge slots that each layer reduces over the
+    #: fanout axis for each relation (``HeteroBatch.hop_fanouts_dict``),
+    #: ``[{relation: groups}]``, 0 where a relation aggregates over
+    #: segments; filled beside ``layer_rows``
+    self.layer_groups = None
     #: what the producer promises of a batch's labels, per type and per
-    #: relation: the static hop prefixes that models/rgnn.py trims by
+    #: relation: the static hop prefixes that models/rgnn.py trims by,
+    #: and the parent-major groups of a relation's edge slots
     self._batch_static = dict(
         edge_hop_offsets_dict=edge_offsets,
         node_hop_offsets_dict={
             t: tuple(int(x) for x in np.cumsum([c[t] for c in caps]))
-            for t in budgets})
+            for t in budgets},
+        hop_fanouts_dict={
+            self._final_key(e): v for e, v in hetero_hop_fanouts(
+                caps, trav, self.sampler.num_neighbors,
+                self.sampler.num_hops).items()})
     from ..obs.perf import gauge_budgets
     gauge_budgets('train.hetero_step', self.node_budget, self.edge_budget)
     self._step_fn = self._build()
@@ -676,13 +687,19 @@ class DistHeteroTrainStep:
 
   def _note_layer_rows(self, batch) -> None:
     """Trace-time side effect, as ``step_traces``: what the node trim
-    leaves each layer to compute for each type, on the attribute and as
-    the gauge ``model_layer_rows{fn, layer, type}``."""
+    leaves each layer to compute for each type and how many groups of
+    edge slots it reduces over the fanout axis for each relation, on the
+    attributes and as the gauges ``model_layer_rows{fn, layer, type}``
+    and ``model_grouped_aggregation{fn, layer, relation}``."""
+    from ..obs.perf import gauge_grouped_aggregation, gauge_layer_rows
     rows_of = getattr(self.model, 'layer_rows', None)
     if rows_of is not None:
-      from ..obs.perf import gauge_layer_rows
       self.layer_rows = rows_of(batch)
       gauge_layer_rows('train.hetero_step', self.layer_rows)
+    groups_of = getattr(self.model, 'layer_groups', None)
+    if groups_of is not None:
+      self.layer_groups = groups_of(batch)
+      gauge_grouped_aggregation('train.hetero_step', self.layer_groups)
 
   def init_params(self, key):
     params = self.model.init(key, self.dummy_batch())

@@ -98,6 +98,15 @@ class HeteroBatch:
   #: ``None`` models/rgnn.py computes every row.
   node_hop_offsets_dict: Optional[Dict] = flax.struct.field(
       pytree_node=False, default=None)
+  #: static per-etype ``Dict[etype, ((offset, S, K), ...)]``, keyed like
+  #: ``edge_hop_offsets_dict``: from ``offset`` on, a relation's edge
+  #: slots are ``S`` groups of ``K`` adjacent slots with one value of
+  #: ``col`` each, and a label heads at most one group with a live slot
+  #: (ops/pipeline.py::hetero_hop_fanouts). A promise of the producer
+  #: that the slots are parent-major; where it is ``None`` models/rgnn.py
+  #: aggregates over segments.
+  hop_fanouts_dict: Optional[Dict] = flax.struct.field(
+      pytree_node=False, default=None)
 
   def edge_index_dict(self) -> Dict[EdgeType, jax.Array]:
     return {k: jnp.stack([self.row_dict[k], self.col_dict[k]])

@@ -13,18 +13,22 @@ still read from every row of ``x``; an edge whose parent lies beyond
 ``num_out`` is masked. models/sage.py passes the hop prefix a later layer
 reads (``Batch.node_hop_offsets``).
 
-``SAGEConv`` also takes ``groups``, static ``(offset, S, K)`` triples:
-the producer's promise (``Batch.hop_fanouts``, given by
-ops/pipeline.py::hop_fanouts, handed on by models/sage.py) that the edge
-slots from ``offset`` on are ``S`` groups of ``K`` adjacent slots with
-one parent each, and that a parent heads one group with a live slot.
-A parent's children are then summed by :func:`grouped_aggregate`: a
-take, a reshape and a masked reduce over the fanout axis, and one
-placing of the ``S`` group results at their parents, where the segment
-path scatter-adds every slot (on the benchmark's cells 936,960 slots
-into 169,984 rows at conv0; the slots, not the rows, bound it). Without
-``groups`` the segment path computes what it always did, bit for bit;
-``GATConv`` and ``GCNConv`` know nothing of groups.
+``SAGEConv`` and ``GATConv`` also take ``groups``, static ``(offset, S,
+K)`` triples: the producer's promise (``Batch.hop_fanouts``, given by
+ops/pipeline.py::hop_fanouts and handed on by models/sage.py; per
+relation ``HeteroBatch.hop_fanouts_dict``, given by
+ops/pipeline.py::hetero_hop_fanouts and handed on by models/rgnn.py)
+that the edge slots from ``offset`` on are ``S`` groups of ``K`` adjacent
+slots with one parent each, and that a parent heads one group with a
+live slot. A parent's children are then summed by
+:func:`grouped_aggregate`: a take, a reshape and a masked reduce over
+the fanout axis, and one placing of the ``S`` group results at their
+parents, where the segment path scatter-adds every slot (on the
+benchmark's cells 936,960 slots into 169,984 rows at conv0; the slots,
+not the rows, bound it); ``GATConv`` computes its softmax and its
+weighted sum over the same axis, in the same layout. Without ``groups``
+the segment paths compute what they always did, bit for bit; ``GCNConv``
+knows nothing of groups.
 """
 from __future__ import annotations
 
@@ -111,22 +115,104 @@ def grouped_aggregate(aggr: str, x: jax.Array, row: jax.Array,
   n = x.shape[0]
   reduce = _GROUP_AGGRS[aggr]
   vals, parents, kept = [], [], []
-  for off, s, k in groups:
-    end = off + s * k
-    idx = row[off:end].reshape(s, k).T.reshape(-1)
-    live = ok[off:end].reshape(s, k).T
-    spread = jnp.arange(s * k, dtype=idx.dtype) % n
-    msgs = jnp.take(x, jnp.where(live.reshape(-1), idx, spread), axis=0,
-                    mode='clip')
+  for group in groups:
+    _, s, k = group
+    idx, live = _group_children(row, ok, group, n)
+    msgs = jnp.take(x, idx, axis=0, mode='clip')
     vals.append(reduce(msgs.reshape(k, s, -1), live))
-    # not col[off:end:k]: a strided slice of a 1-D array is 1.1 ms here
-    parents.append(col[off:end].reshape(s, k)[:, 0])
+    parents.append(_group_parents(col, group))
     kept.append(live.any(axis=0))
   vals, parents, kept = (jnp.concatenate(v) for v in (vals, parents, kept))
-  beyond = num_segments + jnp.arange(parents.shape[0], dtype=parents.dtype)
-  return jnp.zeros((num_segments, x.shape[1]), vals.dtype).at[
+  return _place_groups(vals, parents, kept, num_segments)
+
+
+def _group_children(row, ok, group, n):
+  """One hop block's child indices, flat in ``[K, S]`` order, and its
+  ``[K, S]`` mask; a masked slot reads the row of its own position."""
+  off, s, k = group
+  end = off + s * k
+  idx = row[off:end].reshape(s, k).T.reshape(-1)
+  live = ok[off:end].reshape(s, k).T
+  spread = jnp.arange(s * k, dtype=idx.dtype) % n
+  return jnp.where(live.reshape(-1), idx, spread), live
+
+
+def _group_parents(col, group):
+  off, s, k = group
+  # not col[off:end:k]: a strided slice of a 1-D array is 1.1 ms here
+  return col[off:off + s * k].reshape(s, k)[:, 0]
+
+
+def _beyond(parents, num_segments):
+  return num_segments + jnp.arange(parents.shape[0], dtype=parents.dtype)
+
+
+def _group_rows(parents, kept, num_segments):
+  """The row of each group's result: its parent, or for a group with no
+  live slot an index of its own past the end, so that the rows are
+  unique and such a group is dropped."""
+  return jnp.where(kept, parents, _beyond(parents, num_segments))
+
+
+def _place_groups(vals, parents, kept, num_segments):
+  # :func:`_group_rows`, written out around the zeros: the order of
+  # these ops is the order of the SAGE cells' StableHLO since PR 30
+  beyond = _beyond(parents, num_segments)
+  return jnp.zeros((num_segments,) + vals.shape[1:], vals.dtype).at[
       jnp.where(kept, parents, beyond)].set(
           vals, mode='drop', unique_indices=True)
+
+
+def grouped_attention(proj, logit_dst, att_src, row, col, ok, groups,
+                      negative_slope):
+  """``GATConv`` over edge slots that are parent-major (``groups`` as
+  :func:`grouped_aggregate`'s): the softmax over a parent's children
+  and their weighted sum as masked reduces over the fanout axis, a hop
+  block at a time in that function's layout. ``proj``: the children's
+  projected rows, ``[n, heads, out]``; ``logit_dst``: the parents'
+  logits, ``[m, heads]``; returns ``[m, heads, out]``.
+
+  A block's projected rows are taken once, and the children's logits
+  come from the taken rows: the same products row by row, and no
+  ``[E, heads]`` take whose transpose scatter-adds every slot a second
+  time (11 ms a relation of 250,560 slots on the v5e: PERF.md, section
+  6, PR 32). A group's result is placed at its parent once; the
+  parents' logits are read, and their gradient placed, by the same
+  unique rows."""
+  (n, h, f), m = proj.shape, logit_dst.shape[0]
+  blocks, parents, kept = [], [], []
+  with jax.named_scope('aggregate'):
+    # rows of the 2-D projection: taken from ``[n, heads, out]`` the
+    # whole projection was relaid first (3 ms a relation and pass) and
+    # the take's transposed scatter-add ran at half the speed
+    flat = proj.reshape(n, h * f)
+    for group in groups:
+      _, s, k = group
+      idx, live = _group_children(row, ok, group, n)
+      src = jnp.take(flat, idx, axis=0, mode='clip')
+      blocks.append((src.reshape(k, s, h, f), live[:, :, None]))
+      parents.append(_group_parents(col, group))
+      kept.append(live.any(axis=0))
+    parents, kept = jnp.concatenate(parents), jnp.concatenate(kept)
+  with jax.named_scope('attention'):
+    parent_logit = logit_dst.at[_group_rows(parents, kept, m)].get(
+        mode='fill', fill_value=0.0, unique_indices=True)  # [sum S, h]
+    alphas, at = [], 0
+    for src, live in blocks:
+      s = src.shape[1]
+      logit = nn.leaky_relu(
+          (src * att_src).sum(-1) + parent_logit[at:at + s],
+          negative_slope=negative_slope)                   # [K, S, h]
+      at += s
+      # numerically-stable masked softmax over the fanout axis
+      top = jnp.where(live, logit, -jnp.inf).max(axis=0)
+      top = jnp.where(jnp.isfinite(top), top, 0.0)
+      z = jnp.where(live, jnp.exp(logit - top), 0.0)
+      alphas.append(z / jnp.maximum(z.sum(axis=0), 1e-16))
+  with jax.named_scope('aggregate'):
+    vals = [(src * alpha[:, :, :, None]).sum(axis=0)
+            for (src, _), alpha in zip(blocks, alphas)]
+    return _place_groups(jnp.concatenate(vals), parents, kept, m)
 
 
 class SAGEConv(nn.Module):
@@ -172,6 +258,12 @@ class GATConv(nn.Module):
   parents are rows of ``x``). Only children are projected to ``heads x
   out``: a parent's logit is ``x_dst @ (W . att_dst)``, an ``[m, heads]``
   product, which equals ``((x_dst @ W) * att_dst).sum(-1)``.
+
+  ``groups`` (static, ``None``: no promise): as ``SAGEConv``'s. With it
+  the per-parent maximum, the softmax's denominator and the weighted sum
+  are reduces over the fanout axis (:func:`grouped_attention`), without it
+  ``segment_max`` and ``segment_sum`` over every slot; the same
+  mathematics, and only the order of a parent's ``K`` additions differs.
   """
   out_features: int
   heads: int = 1
@@ -180,7 +272,8 @@ class GATConv(nn.Module):
   param_dtype: jnp.dtype = jnp.float32
 
   @nn.compact
-  def __call__(self, x, row, col, edge_mask, num_out=None, x_dst=None):
+  def __call__(self, x, row, col, edge_mask, num_out=None, x_dst=None,
+               groups=None):
     n = x.shape[0]
     x_dst = x if x_dst is None else x_dst
     m = x_dst.shape[0] if num_out is None else num_out
@@ -198,6 +291,11 @@ class GATConv(nn.Module):
       kernel = dense.variables['params']['kernel'].reshape(-1, h, f)
       w_dst = (kernel * att_dst).sum(-1)                    # [in, h]
       logit_dst = x_dst[:m].astype(proj.dtype) @ w_dst        # [m, h]
+    if groups:
+      out = grouped_attention(proj, logit_dst, att_src, row, col, ok,
+                              groups, self.negative_slope)
+      return out.reshape(m, h * f) if self.concat else out.mean(axis=1)
+    with jax.named_scope('attention'):
       logit_src = (proj * att_src).sum(-1)                  # [n, h]
       seg = jnp.where(ok, col, m)
       logit = nn.leaky_relu(

@@ -48,16 +48,21 @@ class HeteroConvLayer(nn.Module):
       if self.concat:
         assert width % self.heads == 0, (width, self.heads)
         width //= self.heads
-    if self.remat:   # num_out is static: argument 5, the module being 0
-      cls = nn.remat(cls, static_argnums=(5,))
+    if self.remat:   # num_out and groups are static: arguments 5 and
+      cls = nn.remat(cls, static_argnums=(5, 7))   # 7, the module being 0
     return cls(width, name=f'conv_{as_str(etype)}', **kw)
 
   @nn.compact
   def __call__(self, x_dict: Dict[NodeType, jax.Array],
                row_dict, col_dict, mask_dict,
-               num_out: Optional[Dict[NodeType, int]] = None):
+               num_out: Optional[Dict[NodeType, int]] = None,
+               groups: Optional[Dict[EdgeType, tuple]] = None):
     """``num_out[t]``: output rows to compute for type ``t`` (static;
-    ``None`` or a type left out: every row of ``x_dict[t]``)."""
+    ``None`` or a type left out: every row of ``x_dict[t]``).
+    ``groups[e]``: static ``(offset, S, K)`` triples over relation
+    ``e``'s edge slots as given here, the producer's promise that they
+    are parent-major (models/conv.py); ``None`` or a relation left out:
+    no promise, the segment path."""
     rows_of = lambda t: (num_out or {}).get(t, x_dict[t].shape[0])
     out: Dict[NodeType, jax.Array] = {}
     for etype in self.edge_types:
@@ -71,7 +76,8 @@ class HeteroConvLayer(nn.Module):
       h = self._make(etype)(
           x_dict[src_t], row_dict[etype], col_dict[etype],
           mask_dict[etype], rows_of(dst_t),
-          None if src_t == dst_t else x_dict[dst_t])
+          None if src_t == dst_t else x_dict[dst_t],
+          (groups or {}).get(etype))
       out[dst_t] = out.get(dst_t, 0) + h
     # types with no incoming relation keep a transformed self-embedding
     for t, x in x_dict.items():
@@ -79,6 +85,18 @@ class HeteroConvLayer(nn.Module):
         out[t] = nn.Dense(self.out_features,
                           name=f'self_{t}')(x[:rows_of(t)])
     return out
+
+
+def _groups_under(groups, end, etype):
+  """The ``(offset, S, K)`` triples whose block lies within the first
+  ``end`` edge slots (``None``: all of them)."""
+  if end is None:
+    return tuple(groups)
+  kept = tuple(g for g in groups if g[0] + g[1] * g[2] <= end)
+  if any(g[0] < end for g in groups[len(kept):]):
+    raise ValueError(f'hop_fanouts_dict[{etype}] {groups} has a block '
+                     f'across the edge trim at slot {end}')
+  return kept
 
 
 class RGNN(nn.Module):
@@ -92,6 +110,10 @@ class RGNN(nn.Module):
   When it also carries ``node_hop_offsets_dict`` (the producer's promise
   that labels are hop-compact per type), layer i computes output rows only
   for the nodes a later layer reads, as models/sage.py does for one type.
+  When it carries ``hop_fanouts_dict`` (the promise that a relation's edge
+  slots are parent-major), a relation's convolution reduces a parent's
+  children over the fanout axis (models/conv.py); without it, over
+  segments: the same mathematics in another order of additions.
 
   ``head``: every layer is ``hidden_features`` wide (attention heads
   concatenated, ``hidden_features // heads`` each) and a linear layer maps
@@ -111,44 +133,60 @@ class RGNN(nn.Module):
   remat: bool = False
 
   def layer_plan(self, batch: HeteroBatch, return_all: bool = False):
-    """Per layer ``(edge_ends, rows)``: ``edge_ends[e]`` leading edge
-    slots are read (``None``: all), ``rows[t]`` output rows are computed
-    (``None``: every row of the input). Static, from the batch's hop
-    offsets alone."""
+    """Per layer ``(edge_ends, rows, groups)``: ``edge_ends[e]`` leading
+    edge slots are read (``None``: all), ``rows[t]`` output rows are
+    computed (``None``: every row of the input), ``groups[e]`` are the
+    ``(offset, S, K)`` triples of ``hop_fanouts_dict`` that lie under
+    ``edge_ends[e]`` (``None``: no promise). Static, from the batch's
+    hop offsets alone."""
     offs = batch.edge_hop_offsets_dict if self.trim else None
     noffs = (batch.node_hop_offsets_dict
              if offs and not return_all else None)
+    fans = batch.hop_fanouts_dict
     num_hops = (max(len(v) for v in offs.values()) - 1) if offs else 0
     plan = []
     for i in range(self.num_layers):
       if not offs:
-        plan.append((None, None))
+        plan.append((None, None, fans))
         continue
       # layer i still feeds num_layers-1-i later propagations, so hop
       # h is useful iff h <= num_layers - i (clamped to sampled hops)
       keep = max(min(num_hops, self.num_layers - i), 1)
-      ends = {e: max(v[min(keep, len(v) - 1)], 1)   # non-empty for XLA
-              for e, v in offs.items()}
+      hop_ends = {e: v[min(keep, len(v) - 1)] for e, v in offs.items()}
+      # what is read is non-empty, for XLA
+      ends = {e: max(v, 1) for e, v in hop_ends.items()}
       rows = None
       if noffs:
         out_hops = min(num_hops, self.num_layers - 1 - i)
         rows = {t: max(v[min(out_hops, len(v) - 1)], 1)
                 for t, v in noffs.items()}
-      plan.append((ends, rows))
+      plan.append((ends, rows, fans and {
+          e: _groups_under(g, hop_ends.get(e), e)
+          for e, g in fans.items()}))
     return plan
 
   def layer_rows(self, batch: HeteroBatch, return_all: bool = False):
     """``[{type: output rows}]`` a layer, as the step's counter reads."""
     return [rows if rows is not None else
             {t: x.shape[0] for t, x in batch.x_dict.items()}
-            for _, rows in self.layer_plan(batch, return_all)]
+            for _, rows, _ in self.layer_plan(batch, return_all)]
+
+  def layer_groups(self, batch: HeteroBatch):
+    """``[{relation: groups}]`` a layer: the groups of adjacent edge
+    slots a relation's convolution reduces over the fanout axis, 0 where
+    it aggregates over segments. The step's counter reads it; the
+    promise holds whatever rows are asked for."""
+    return [{e: sum(s for _, s, _ in (groups or {}).get(e, ()))
+             for e in self.edge_types if e in batch.row_dict}
+            for _, _, groups in self.layer_plan(batch)]
 
   @nn.compact
   def __call__(self, batch: HeteroBatch, train: bool = False,
                return_all: bool = False):
     conv_kind = 'gat' if self.conv == 'rgat' else 'sage'
     x_dict = dict(batch.x_dict)
-    for i, (ends, rows) in enumerate(self.layer_plan(batch, return_all)):
+    for i, (ends, rows, groups) in enumerate(
+        self.layer_plan(batch, return_all)):
       last = i == self.num_layers - 1
       dim = (self.out_features if last and not self.head
              else self.hidden_features)
@@ -159,7 +197,7 @@ class RGNN(nn.Module):
           conv=conv_kind, heads=self.heads, concat=self.head,
           remat=self.remat, name=f'layer{i}')(
               x_dict, cut(batch.row_dict), cut(batch.col_dict),
-              cut(batch.edge_mask_dict), rows)
+              cut(batch.edge_mask_dict), rows, groups)
       if not last or self.head:
         x_dict = {t: nn.relu(v) for t, v in x_dict.items()}
       if not last and self.dropout > 0:

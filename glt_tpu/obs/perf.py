@@ -91,12 +91,19 @@ def gauge_grouped_aggregation(
   a reshape and a masked reduce, as
   ``model_grouped_aggregation{fn=..., layer=i}``; 0 for a layer that
   scatter-adds every slot (a batch without ``Batch.hop_fanouts``, a
-  convolution that does not read it). Static: set once a trace."""
+  convolution that does not read it). A typed model gives a dict a
+  layer, groups per relation, and the series carry a ``relation`` label
+  too. Static: set once a trace."""
   try:
     reg = registry or get_registry()
     for i, n in enumerate(groups):
-      reg.set('model_grouped_aggregation', float(n), fn=str(fn),
-              layer=str(i))
+      if isinstance(n, dict):
+        for e, m in n.items():
+          reg.set('model_grouped_aggregation', float(m), fn=str(fn),
+                  layer=str(i), relation=as_str(e))
+      else:
+        reg.set('model_grouped_aggregation', float(n), fn=str(fn),
+                layer=str(i))
   except Exception:  # accounting must never break a trace
     pass
 

@@ -374,6 +374,27 @@ def hetero_edge_hop_offsets(caps, trav, num_neighbors, num_hops):
   return offs
 
 
+def hetero_hop_fanouts(caps, trav, num_neighbors, num_hops):
+  """The promise behind ``HeteroBatch.hop_fanouts_dict``, the typed
+  counterpart of :func:`hop_fanouts`: per relation the static
+  ``(offset, S, K)`` triples of its edge buffer, one a hop the loops
+  below run for it (their own test skips the others): from ``offset``
+  (:func:`hetero_edge_hop_offsets`) on, ``S = caps[h][row_t]`` groups of
+  ``K = |num_neighbors[e][h]|`` adjacent slots. Both typed loops append
+  ``jnp.repeat(f_labels, K)`` as a hop's parents and rebuild its edge
+  buffers in slot order, whatever the dedup engine (the unfused ``sort``
+  loop un-permutes its labels first), so the parent label is one value
+  over a group, and a label heads at most one group with a live slot
+  inside a relation: a node is a new head in one hop, and a frontier
+  slot that is no new head has its whole group masked
+  (tests/sampler_oracle.py checks it of every typed batch)."""
+  offs = hetero_edge_hop_offsets(caps, trav, num_neighbors, num_hops)
+  return {e: tuple((offs[e][h], caps[h][row_t], abs(num_neighbors[e][h]))
+                   for h in range(num_hops)
+                   if caps[h][row_t] and num_neighbors[e][h])
+          for e, (row_t, _) in trav.items()}
+
+
 def multihop_sample_hetero(one_hops, trav, num_neighbors, num_hops,
                            caps, budgets, seeds, n_valid, key, tables,
                            with_edge: bool = False):

@@ -340,6 +340,12 @@ def check_multihop_typed(graphs, trav, fanouts, seeds, n_valid, out, *,
     ``seed_labels`` / ``num_sampled_nodes`` by type, ``row`` (child
     labels) / ``col`` (parent labels) / ``edge_mask`` / ``edge`` /
     ``num_sampled_edges`` by relation.
+
+  Every batch is also held to ``HeteroBatch.hop_fanouts_dict``'s promise
+  (ops/pipeline.py::hetero_hop_fanouts), which all three typed loops
+  keep: inside a relation's buffer a hop's block is groups of ``|k|``
+  adjacent lanes under one parent label, and no label heads two groups
+  with a live lane.
   """
   num_hops = len(next(iter(fanouts.values())))
   types = sorted({t for rc in trav.values() for t in rc})
@@ -370,6 +376,7 @@ def check_multihop_typed(graphs, trav, fanouts, seeds, n_valid, out, *,
 
   cursor = {e: 0 for e in trav}        # lanes of a relation used so far
   hop_index = {e: 0 for e in trav}     # active hops of a relation so far
+  heads = {e: set() for e in trav}     # labels at the head of a live group
   for h in range(num_hops):
     first_seen = {t: [] for t in types}
     for e, (row_t, col_t) in trav.items():
@@ -386,6 +393,8 @@ def check_multihop_typed(graphs, trav, fanouts, seeds, n_valid, out, *,
       ed = (np.asarray(out['edge'][e])[sl]
             if out.get('edge') is not None else None)
       assert r.shape[0] == width, f'{where}: the block is short'
+      # a typed frontier is no run of new labels, so only the groups
+      _check_groups(c, m, abs(k), heads[e], None, None, None, where)
       assert (r[~m] == -1).all(), (
           f'{where}: a masked lane carries a label')
       got = int(np.asarray(out['num_sampled_edges'][e])[hop_index[e]])

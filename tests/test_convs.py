@@ -235,3 +235,95 @@ def test_sage_conv_without_groups_is_the_segment_path(aggr):
     np.testing.assert_array_equal(
         np.asarray(conv.apply(params, x, row, col, mask, **kw)),
         np.asarray(want))
+
+
+# -- GATConv: the softmax and the weighted sum over the fanout axis --------
+
+def _gat_case(fanouts, heads, concat, typed, trimmed):
+  """A parent-major batch (wholly masked groups, parents of -1, two hop
+  blocks of different ``K``), the convolution, its parameters and the
+  parents' rows: another type's where ``typed``; ``trimmed`` puts half
+  of the parents beyond ``num_out``."""
+  from glt_tpu.models import GATConv
+  groups, n, row, col, mask = _parent_major_slots(fanouts)
+  num_out = (int(col.max()) + 1) // 2 if trimmed else None
+  rng = np.random.default_rng(1)
+  x = jnp.asarray(rng.normal(size=(n, 12)).astype(np.float32))
+  x_dst = (jnp.asarray(rng.normal(size=(int(col.max()) + 4, 12))
+                       .astype(np.float32)) if typed else None)
+  conv = GATConv(7, heads=heads, concat=concat)
+  params = conv.init(jax.random.key(0), x, row, col, mask, x_dst=x_dst)
+  return conv, params, x, x_dst, row, col, mask, num_out, groups
+
+
+@pytest.mark.parametrize('trimmed', [False, True],
+                         ids=['all_rows', 'num_out'])
+@pytest.mark.parametrize('typed', [False, True], ids=['x', 'x_dst'])
+@pytest.mark.parametrize('concat', [True, False], ids=['concat', 'mean'])
+@pytest.mark.parametrize('heads', [1, 4])
+@pytest.mark.parametrize('fanouts', [(5, 3), (15,)], ids=['f5_3', 'f15'])
+def test_gat_conv_grouped_matches_segment(fanouts, heads, concat, typed,
+                                          trimmed):
+  (conv, params, x, x_dst, row, col, mask, num_out,
+   groups) = _gat_case(fanouts, heads, concat, typed, trimmed)
+  m = (x_dst if typed else x).shape[0] if num_out is None else num_out
+  w = jnp.asarray(np.random.default_rng(2).normal(
+      size=(m, 7 * heads if concat else 7)).astype(np.float32))
+
+  def out_and_grads(groups):
+    def f(p, x, x_dst):
+      out = conv.apply(p, x, row, col, mask, num_out=num_out, x_dst=x_dst,
+                       groups=groups)
+      return (out * w).sum(), out
+    (_, out), grads = jax.jit(jax.value_and_grad(
+        f, argnums=(0, 1, 2) if typed else (0, 1), has_aux=True))(
+            params, x, x_dst)
+    leaves = {jax.tree_util.keystr(k): v for k, v in
+              jax.tree_util.tree_leaves_with_path(grads)}
+    return out, leaves
+
+  got, g_got = out_and_grads(groups)
+  want, g_want = out_and_grads(None)
+  assert got.shape == want.shape == w.shape
+  np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                             rtol=1e-5, atol=1e-5)
+  # att_dst first: its gradient cancels within a parent (PERF.md, 2)
+  names = sorted(g_want, key=lambda k: 'att_dst' not in k)
+  assert 'att_dst' in names[0] and len(names) == (5 if typed else 4)
+  for k in names:
+    assert np.abs(np.asarray(g_want[k])).max() > 0, k
+    np.testing.assert_allclose(np.asarray(g_got[k]), np.asarray(g_want[k]),
+                               rtol=1e-5, atol=1e-5, err_msg=k)
+
+
+@pytest.mark.parametrize('typed', [False, True], ids=['x', 'x_dst'])
+def test_gat_conv_without_groups_is_the_segment_path(typed):
+  """No promise, no change: ``groups=None`` (and a call that never heard
+  of groups) computes the segment softmax bit for bit, as written out
+  here from the convolution's own parameters."""
+  conv, params, x, x_dst, row, col, mask, _, _ = _gat_case(
+      (5, 3), 4, True, typed, False)
+  p = params['params']
+  dst = x if x_dst is None else x_dst
+  n, m, h, f = x.shape[0], dst.shape[0], 4, 7
+  ok = mask & (row >= 0) & (row < n) & (col >= 0) & (col < m)
+  proj = (x @ p['proj']['kernel']).reshape(n, h, f)
+  w_dst = (p['proj']['kernel'].reshape(-1, h, f) * p['att_dst']).sum(-1)
+  seg = jnp.where(ok, col, m)
+  logit = jax.nn.leaky_relu(
+      jnp.take((proj * p['att_src']).sum(-1), jnp.clip(row, 0, n - 1),
+               axis=0)
+      + jnp.take(dst @ w_dst, jnp.clip(col, 0, m - 1), axis=0), 0.2)
+  top = jax.ops.segment_max(jnp.where(ok[:, None], logit, -jnp.inf), seg,
+                            m + 1)
+  top = jnp.where(jnp.isfinite(top), top, 0.0)
+  z = jnp.where(ok[:, None], jnp.exp(logit - top[jnp.clip(seg, 0, m)]), 0.0)
+  alpha = z / jnp.maximum(
+      jax.ops.segment_sum(z, seg, m + 1)[jnp.clip(seg, 0, m)], 1e-16)
+  want = jax.ops.segment_sum(
+      jnp.take(proj, jnp.clip(row, 0, n - 1), axis=0) * alpha[:, :, None],
+      seg, m + 1)[:m].reshape(m, h * f)
+  for kw in ({}, {'groups': None}, {'groups': ()}):
+    np.testing.assert_array_equal(
+        np.asarray(conv.apply(params, x, row, col, mask, x_dst=x_dst,
+                              **kw)), np.asarray(want))

@@ -115,6 +115,8 @@ def test_step_matches_the_reference(layers, dedup_engine):
   step, tx = build_step(edges, feats, labels, layers, layers, head=True)
   params0 = step.init_params(jax.random.key(3))
   losses, first, params = train(step, tx, params0)
+  # the promise is on: every layer reduced groups over the fanout axis
+  assert all(sum(g.values()) > 0 for g in step.layer_groups)
   ref, ref_params, ref_first = reference.follow(
       params0, (sampled_batch(step, feats, labels, t) for t in range(3)),
       layers, HEADS, 1e-3)
@@ -303,3 +305,168 @@ def test_remat_changes_nothing():
   np.testing.assert_allclose(runs[0][0], runs[1][0], rtol=1e-6)
   for a, b in zip(jax.tree.leaves(runs[0][1]), jax.tree.leaves(runs[1][1])):
     np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-8)
+
+
+# -- the promise of parent-major edge slots (HeteroBatch.hop_fanouts_dict) --
+
+def padded_batch(step, feats, labels, t=0):
+  """Step ``t``'s batch as the step's own program assembles it: the
+  padded buffers of the step's sampler on the step's key, the rows of
+  every slot, and the step's static promises."""
+  from glt_tpu.loader.transform import HeteroBatch
+  seeds, key = feed(t)
+  out = step.sampler.sample_from_nodes('a', seeds, key=key)
+  first = lambda d: {k: jnp.asarray(np.asarray(v)[0]) for k, v in d.items()}
+  node = first(out['node'])
+  return HeteroBatch(
+      x_dict={k: jnp.asarray(feats[k])[jnp.maximum(v, 0)]
+              for k, v in node.items()},
+      row_dict=first(out['row']), col_dict=first(out['col']),
+      edge_mask_dict=first(out['edge_mask']), node_dict=node,
+      node_count_dict=first(out['node_count']),
+      y_dict={'a': jnp.asarray(labels['a'][seeds])}, input_type='a',
+      batch_size=BATCH, **step._batch_static)
+
+
+def _loss_and_grads(model, params, batch):
+  def loss(p, x_dict):
+    logits = model.apply(p, batch.replace(x_dict=x_dict))
+    return -jax.nn.log_softmax(logits)[
+        jnp.arange(BATCH), batch.y_dict['a']].mean(), logits
+  (_, logits), grads = jax.jit(jax.value_and_grad(
+      loss, argnums=(0, 1), has_aux=True))(params, batch.x_dict)
+  return logits, grads
+
+
+@pytest.mark.parametrize('conv,remat,engine', [
+    ('rgat', False, 'table'), ('rgat', True, 'sort+fused'),
+    ('rsage', False, 'sort+fused'), ('rsage', True, 'table')])
+def test_rgnn_with_the_promise_and_with_it_withheld(conv, remat, engine,
+                                                    monkeypatch):
+  """One batch, the promise on and withheld: the same logits and the
+  same gradients for the parameters and the features, and the counter
+  says which path ran. The promise holds of the batch (every typed hop
+  loop keeps it)."""
+  monkeypatch.setenv('GLT_DEDUP', engine.split('+')[0])
+  monkeypatch.setenv('GLT_FUSED_HOP', str(int('fused' in engine)))
+  edges, feats, labels = typed_graph()
+  step, _ = build_step(edges, feats, labels, 2, 2, head=True)
+  batch = padded_batch(step, feats, labels)
+  for e, groups in batch.hop_fanouts_dict.items():
+    col, mask = (np.asarray(d[e]) for d in (batch.col_dict,
+                                            batch.edge_mask_dict))
+    heads = []
+    for off, s, k in groups:
+      c = col[off:off + s * k].reshape(s, k)
+      assert (c == c[:, :1]).all(), e
+      heads += c[mask[off:off + s * k].reshape(s, k).any(axis=1), 0].tolist()
+    assert len(heads) == len(set(heads)), e
+    assert sum(s * k for _, s, k in groups) == col.shape[0] or not groups
+  model = RGNN(edge_types=[reverse_edge_type(e) for e in RELATIONS],
+               hidden_features=HIDDEN, out_features=CLASSES, num_layers=2,
+               conv=conv, heads=HEADS, head=True, remat=remat)
+  params = jax.jit(model.init)(jax.random.key(1), batch)
+  withheld = batch.replace(hop_fanouts_dict=None)
+  assert all(sum(g.values()) > 0 for g in model.layer_groups(batch))
+  assert all(sum(g.values()) == 0 for g in model.layer_groups(withheld))
+  got, g_got = _loss_and_grads(model, params, batch)
+  want, g_want = _loss_and_grads(model, params, withheld)
+  np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+  flat = lambda tree: {jax.tree_util.keystr(k): np.asarray(v) for k, v in
+                       jax.tree_util.tree_leaves_with_path(tree)}
+  g_got, g_want = flat(g_got), flat(g_want)
+  assert set(g_got) == set(g_want)
+  for k in sorted(g_want, key=lambda k: 'att_dst' not in k):
+    np.testing.assert_allclose(g_got[k], g_want[k], rtol=1e-4, atol=1e-6,
+                               err_msg=k)
+
+
+def test_rgnn_without_the_promise_is_the_layers_without_groups():
+  """No promise, no change: on a batch without ``hop_fanouts_dict`` RGNN
+  is its layers applied with no ``groups`` at all, bit for bit, and a
+  loader's batch carries no promise."""
+  from glt_tpu.loader.transform import HeteroBatch
+  assert HeteroBatch.__dataclass_fields__['hop_fanouts_dict'].default is None
+  edges, feats, labels = typed_graph()
+  step, _ = build_step(edges, feats, labels, 2, 2, head=True)
+  batch = padded_batch(step, feats, labels).replace(hop_fanouts_dict=None)
+  model = step.model
+  params = model.init(jax.random.key(1), batch)
+  x = dict(batch.x_dict)
+  for i, (ends, rows, groups) in enumerate(model.layer_plan(batch)):
+    assert groups is None
+    cut = lambda d: {e: v[:ends[e]] for e, v in d.items()}
+    layer = HeteroConvLayer(list(model.edge_types), HIDDEN, conv='gat',
+                            heads=HEADS, concat=True)
+    x = layer.apply({'params': params['params'][f'layer{i}']}, x,
+                    cut(batch.row_dict), cut(batch.col_dict),
+                    cut(batch.edge_mask_dict), rows)
+    x = {t: nn.relu(v) for t, v in x.items()}
+  head = params['params']['head']
+  want = x['a'][:BATCH] @ head['kernel'] + head['bias']
+  np.testing.assert_array_equal(np.asarray(model.apply(params, batch)),
+                                np.asarray(want))
+
+
+def test_a_block_across_the_trim_is_refused():
+  edges, feats, labels = typed_graph()
+  step, _ = build_step(edges, feats, labels, 2, 2, head=True)
+  batch = step.dummy_batch()
+  e = reverse_edge_type(('a', 'aa', 'a'))
+  (o0, s0, k0), (o1, s1, k1) = batch.hop_fanouts_dict[e]
+  bad = dict(batch.hop_fanouts_dict)
+  bad[e] = ((o0, s0 + 1, k0), (o1 + k0, s1 - 1, k1))
+  with pytest.raises(ValueError, match='across the edge trim'):
+    step.model.layer_plan(batch.replace(hop_fanouts_dict=bad))
+
+
+def test_layer_groups_counter_and_gauge():
+  """``DistHeteroTrainStep.layer_groups``: per layer and relation the
+  groups reduced over the fanout axis, the frontier slots of the hops
+  the layer keeps; 0 for every relation when the promise is withheld;
+  the gauge carries the relation."""
+  from glt_tpu.obs import get_registry
+  edges, feats, labels = typed_graph()
+  step, tx = build_step(edges, feats, labels, 3, 3, head=True)
+  assert step.layer_groups is None   # nothing traced yet
+  params = step.init_params(jax.random.key(3))
+  train(step, tx, params, steps=1)
+  fk = reverse_edge_type
+  # a hop's groups are its frontier's slots, by hand
+  # (test_budgets_are_the_sum_of_slots): a = 4, 12, 24; b = 0, 12, 24;
+  # c = 0, 0, 24. Layer i keeps 3 - i hops.
+  assert step.layer_groups[0] == {
+      fk(('a', 'aa', 'a')): 4 + 12 + 24, fk(('a', 'ab', 'b')): 4 + 12 + 24,
+      fk(('b', 'bc', 'c')): 12 + 24, fk(('c', 'ca', 'a')): 24}
+  assert step.layer_groups[1] == {
+      fk(('a', 'aa', 'a')): 4 + 12, fk(('a', 'ab', 'b')): 4 + 12,
+      fk(('b', 'bc', 'c')): 12, fk(('c', 'ca', 'a')): 0}
+  assert step.layer_groups[2] == {
+      fk(('a', 'aa', 'a')): 4, fk(('a', 'ab', 'b')): 4,
+      fk(('b', 'bc', 'c')): 0, fk(('c', 'ca', 'a')): 0}
+  # non-zero wherever a layer reads a real block of the relation
+  offs = step._batch_static['edge_hop_offsets_dict']
+  for i, groups in enumerate(step.layer_groups):
+    for e, n in groups.items():
+      assert (n > 0) == (offs[e][3 - i] > 0), (i, e)
+  reg = get_registry()
+  for i, groups in enumerate(step.layer_groups):
+    for e, n in groups.items():
+      assert reg.get('model_grouped_aggregation', -1.0,
+                     fn='train.hetero_step', layer=str(i),
+                     relation=as_str(e)) == n
+  # the SAGE step's series carry no relation label, as before
+  from glt_tpu.obs.perf import gauge_grouped_aggregation
+  gauge_grouped_aggregation('test.sage', (7, 0))
+  assert reg.get('model_grouped_aggregation', -1.0, fn='test.sage',
+                 layer='0') == 7
+  # withheld: the segment path everywhere, and the counter says so
+  step2, tx2 = build_step(edges, feats, labels, 3, 3, head=True)
+  step2._batch_static['hop_fanouts_dict'] = None
+  step2._step_fn = step2._build()
+  l2, g2, _ = train(step2, tx2, params, steps=1)
+  assert all(n == 0 for g in step2.layer_groups for n in g.values())
+  l1, g1, _ = train(step, tx, params, steps=1)
+  np.testing.assert_allclose(l1, l2, rtol=1e-6)
+  for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
+    np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-7)

@@ -152,3 +152,33 @@ def test_typed_multihop_other_dedups(monkeypatch, engine, fused, order):
                        fanouts, seeds, {t: n_valid for t in seeds},
                        _traversal_output(out, True),
                        new_label_order=order)
+
+
+@pytest.mark.parametrize('engine,fused,order', [
+    ('table', '0', 'slot'), ('sort', '0', 'slot'), ('sort', '1', 'value')],
+    ids=['table', 'sort', 'sort_fused'])
+def test_a_permuted_hop_block_breaks_the_promise(monkeypatch, engine, fused,
+                                                 order):
+  """``hop_fanouts_dict``'s promise is held of each typed loop's batch,
+  and a batch that is right in all but the order of one hop block's
+  lanes (the same edges, rolled by one lane) is refused for it."""
+  monkeypatch.setenv('GLT_DEDUP', engine)
+  monkeypatch.setenv('GLT_FUSED_HOP', fused)
+  make, fanouts, seeds, n_valid = CASES['two_seed_types']
+  ds, graphs = make()
+  seeds = {t: np.asarray(s, np.int64) for t, s in seeds.items()}
+  out = NeighborSampler(ds.graph, fanouts, seed=4, with_edge=True
+                        ).sample_from_nodes(seeds, n_valid=n_valid)
+  got = _traversal_output(out, True)
+  check = lambda: check_multihop_typed(
+      graphs, {e: (e[0], e[2]) for e in fanouts}, fanouts, seeds,
+      {t: n_valid for t in seeds}, got, new_label_order=order)
+  check()
+  lo, hi = out.metadata['edge_hop_offsets'][reverse_edge_type(I2I)][1:3]
+  assert hi - lo > 2 and got['edge_mask'][I2I][lo:hi].any()
+  for name in ('row', 'col', 'edge_mask', 'edge'):
+    block = got[name][I2I].copy()
+    block[lo:hi] = np.roll(block[lo:hi], 1)
+    got[name][I2I] = block
+  with pytest.raises(AssertionError, match='col changes inside a group'):
+    check()
