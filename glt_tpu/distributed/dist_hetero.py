@@ -552,8 +552,11 @@ def _hetero_update(model, tx, axis, bs, params, opt_state, batch, y,
 class DistHeteroTrainStep:
   """One-program hetero distributed training (the IGBH deployment shape,
   examples/igbh/dist_train_rgnn.py): hetero collective sampling +
-  per-type feature all_to_all + RGNN forward/backward + gradient pmean,
-  all inside a single shard_map step.
+  per-type feature all_to_all + the typed model's forward/backward +
+  gradient pmean, all inside a single shard_map step. ``model`` is any
+  flax module over a ``HeteroBatch`` that returns the seeds' logits:
+  ``models/rgnn.py::RGNN`` and ``models/hgt.py::HGT`` both read the
+  batch's static promises through models/plan.py.
   """
 
   def __init__(self, graph: DistHeteroGraph,
@@ -631,8 +634,13 @@ class DistHeteroTrainStep:
     #: ``[{relation: groups}]``, 0 where a relation aggregates over
     #: segments; filled beside ``layer_rows``
     self.layer_groups = None
+    #: relations that share each parent type's softmax in each layer, for
+    #: a model whose softmax crosses relations (models/hgt.py says
+    #: ``layer_joint_relations``; ``[{type: relations}]``); filled beside
+    #: ``layer_rows``, None for a model that does not say
+    self.layer_joint_relations = None
     #: what the producer promises of a batch's labels, per type and per
-    #: relation: the static hop prefixes that models/rgnn.py trims by,
+    #: relation: the static hop prefixes that models/plan.py trims by,
     #: and the parent-major groups of a relation's edge slots
     self._batch_static = dict(
         edge_hop_offsets_dict=edge_offsets,
@@ -690,8 +698,11 @@ class DistHeteroTrainStep:
     leaves each layer to compute for each type and how many groups of
     edge slots it reduces over the fanout axis for each relation, on the
     attributes and as the gauges ``model_layer_rows{fn, layer, type}``
-    and ``model_grouped_aggregation{fn, layer, relation}``."""
-    from ..obs.perf import gauge_grouped_aggregation, gauge_layer_rows
+    and ``model_grouped_aggregation{fn, layer, relation}``; for a model
+    whose softmax crosses relations also
+    ``model_joint_softmax_relations{fn, layer, type}``."""
+    from ..obs.perf import (gauge_grouped_aggregation, gauge_joint_softmax,
+                            gauge_layer_rows)
     rows_of = getattr(self.model, 'layer_rows', None)
     if rows_of is not None:
       self.layer_rows = rows_of(batch)
@@ -700,6 +711,10 @@ class DistHeteroTrainStep:
     if groups_of is not None:
       self.layer_groups = groups_of(batch)
       gauge_grouped_aggregation('train.hetero_step', self.layer_groups)
+    joint_of = getattr(self.model, 'layer_joint_relations', None)
+    if joint_of is not None:
+      self.layer_joint_relations = joint_of(batch)
+      gauge_joint_softmax('train.hetero_step', self.layer_joint_relations)
 
   def init_params(self, key):
     params = self.model.init(key, self.dummy_batch())

@@ -22,6 +22,7 @@ import jax
 
 from ..loader.transform import HeteroBatch
 from ..typing import EdgeType, NodeType, as_str
+from . import plan
 from .conv import GATConv, SAGEConv
 
 
@@ -87,33 +88,19 @@ class HeteroConvLayer(nn.Module):
     return out
 
 
-def _groups_under(groups, end, etype):
-  """The ``(offset, S, K)`` triples whose block lies within the first
-  ``end`` edge slots (``None``: all of them)."""
-  if end is None:
-    return tuple(groups)
-  kept = tuple(g for g in groups if g[0] + g[1] * g[2] <= end)
-  if any(g[0] < end for g in groups[len(kept):]):
-    raise ValueError(f'hop_fanouts_dict[{etype}] {groups} has a block '
-                     f'across the edge trim at slot {end}')
-  return kept
-
-
 class RGNN(nn.Module):
   """Relational GNN stack (reference examples/igbh/rgnn.py): 'rsage' or
   'rgat' layers over a HeteroBatch, classifier head on the seed type.
 
-  When the batch carries ``edge_hop_offsets_dict`` (hetero NeighborLoader
-  batches do), layers trim hierarchically: layer i only reads the edge
-  slots of hops [0, num_hops - i) per edge type — the reference's
-  trim_to_layer (examples/hetero/hierarchical_sage.py), as static slices.
-  When it also carries ``node_hop_offsets_dict`` (the producer's promise
-  that labels are hop-compact per type), layer i computes output rows only
-  for the nodes a later layer reads, as models/sage.py does for one type.
-  When it carries ``hop_fanouts_dict`` (the promise that a relation's edge
-  slots are parent-major), a relation's convolution reduces a parent's
-  children over the fanout axis (models/conv.py); without it, over
-  segments: the same mathematics in another order of additions.
+  The layers follow the typed models' one plan (models/plan.py): with
+  ``edge_hop_offsets_dict`` (hetero NeighborLoader batches carry it)
+  layer i only reads the edge slots of hops [0, num_hops - i) per edge
+  type; with ``node_hop_offsets_dict`` it computes output rows only for
+  the nodes a later layer reads; with ``hop_fanouts_dict`` (the promise
+  that a relation's edge slots are parent-major) a relation's convolution
+  reduces a parent's children over the fanout axis (models/conv.py), and
+  without it over segments: the same mathematics in another order of
+  additions.
 
   ``head``: every layer is ``hidden_features`` wide (attention heads
   concatenated, ``hidden_features // heads`` each) and a linear layer maps
@@ -133,52 +120,21 @@ class RGNN(nn.Module):
   remat: bool = False
 
   def layer_plan(self, batch: HeteroBatch, return_all: bool = False):
-    """Per layer ``(edge_ends, rows, groups)``: ``edge_ends[e]`` leading
-    edge slots are read (``None``: all), ``rows[t]`` output rows are
-    computed (``None``: every row of the input), ``groups[e]`` are the
-    ``(offset, S, K)`` triples of ``hop_fanouts_dict`` that lie under
-    ``edge_ends[e]`` (``None``: no promise). Static, from the batch's
-    hop offsets alone."""
-    offs = batch.edge_hop_offsets_dict if self.trim else None
-    noffs = (batch.node_hop_offsets_dict
-             if offs and not return_all else None)
-    fans = batch.hop_fanouts_dict
-    num_hops = (max(len(v) for v in offs.values()) - 1) if offs else 0
-    plan = []
-    for i in range(self.num_layers):
-      if not offs:
-        plan.append((None, None, fans))
-        continue
-      # layer i still feeds num_layers-1-i later propagations, so hop
-      # h is useful iff h <= num_layers - i (clamped to sampled hops)
-      keep = max(min(num_hops, self.num_layers - i), 1)
-      hop_ends = {e: v[min(keep, len(v) - 1)] for e, v in offs.items()}
-      # what is read is non-empty, for XLA
-      ends = {e: max(v, 1) for e, v in hop_ends.items()}
-      rows = None
-      if noffs:
-        out_hops = min(num_hops, self.num_layers - 1 - i)
-        rows = {t: max(v[min(out_hops, len(v) - 1)], 1)
-                for t, v in noffs.items()}
-      plan.append((ends, rows, fans and {
-          e: _groups_under(g, hop_ends.get(e), e)
-          for e, g in fans.items()}))
-    return plan
+    """Per layer ``(edge_ends, rows, groups)``: models/plan.py, the one
+    plan every typed model reads."""
+    return plan.layer_plan(batch, self.num_layers, self.trim, return_all)
 
   def layer_rows(self, batch: HeteroBatch, return_all: bool = False):
     """``[{type: output rows}]`` a layer, as the step's counter reads."""
-    return [rows if rows is not None else
-            {t: x.shape[0] for t, x in batch.x_dict.items()}
-            for _, rows, _ in self.layer_plan(batch, return_all)]
+    return plan.layer_rows(self.layer_plan(batch, return_all), batch)
 
   def layer_groups(self, batch: HeteroBatch):
     """``[{relation: groups}]`` a layer: the groups of adjacent edge
     slots a relation's convolution reduces over the fanout axis, 0 where
     it aggregates over segments. The step's counter reads it; the
     promise holds whatever rows are asked for."""
-    return [{e: sum(s for _, s, _ in (groups or {}).get(e, ()))
-             for e in self.edge_types if e in batch.row_dict}
-            for _, _, groups in self.layer_plan(batch)]
+    return plan.layer_groups(self.layer_plan(batch), self.edge_types,
+                             batch)
 
   @nn.compact
   def __call__(self, batch: HeteroBatch, train: bool = False,
@@ -190,8 +146,7 @@ class RGNN(nn.Module):
       last = i == self.num_layers - 1
       dim = (self.out_features if last and not self.head
              else self.hidden_features)
-      cut = lambda d: d if ends is None else {
-          e: v[:ends[e]] if e in ends else v for e, v in d.items()}
+      cut = lambda d: plan.cut_edges(d, ends)
       x_dict = HeteroConvLayer(
           edge_types=list(self.edge_types), out_features=dim,
           conv=conv_kind, heads=self.heads, concat=self.head,

@@ -108,6 +108,23 @@ def gauge_grouped_aggregation(
     pass
 
 
+def gauge_joint_softmax(
+    fn: str, relations, registry: Optional[MetricsRegistry] = None) -> None:
+  """Trace-time hook beside :func:`gauge_grouped_aggregation`, for a
+  typed model whose softmax crosses relations (models/hgt.py): how many
+  relations share a parent type's softmax in each layer of program
+  ``fn``'s model, as ``model_joint_softmax_relations{fn, layer, type}``;
+  0 on a type that no relation reaches. Static: set once a trace."""
+  try:
+    reg = registry or get_registry()
+    for i, n in enumerate(relations):
+      for t, m in n.items():
+        reg.set('model_joint_softmax_relations', float(m), fn=str(fn),
+                layer=str(i), type=str(t))
+  except Exception:  # accounting must never break a trace
+    pass
+
+
 def gauge_budgets(fn: str, node_budget: dict, edge_budget: dict,
                   registry: Optional[MetricsRegistry] = None) -> None:
   """Build-time hook of a typed step program ``fn``: the static padded

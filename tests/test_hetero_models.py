@@ -164,3 +164,49 @@ def test_hetero_trim_equivalence_more_layers_than_hops():
   out_t = np.asarray(RGNN(trim=True, **kw).apply(params, batch))
   out_f = np.asarray(RGNN(trim=False, **kw).apply(params, batch))
   np.testing.assert_allclose(out_t, out_f, rtol=1e-5, atol=1e-5)
+
+
+def _hgt_ring_batch(fanout, layers, batch_size=4):
+  from fixtures import hetero_ring_dataset
+  from glt_tpu.typing import reverse_edge_type
+  ds = hetero_ring_dataset(num_users=12, num_items=24)
+  loader = NeighborLoader(ds, fanout, ('user', np.arange(12)),
+                          batch_size=batch_size, shuffle=False, seed=0)
+  batch = next(iter(loader))
+  kw = dict(node_types=['user', 'item'],
+            edge_types=[reverse_edge_type(U2I), I2I], hidden_features=8,
+            out_features=3, num_layers=layers, heads=2)
+  return batch, kw
+
+
+@pytest.mark.parametrize('fanout,layers', [([3, 2], 2), ([2, 2], 3)])
+def test_hgt_trim_equivalence(fanout, layers):
+  """HGT under the typed models' one plan: the edge trim of a loader's
+  batch does not change the seeds' outputs (as RGNN's does not)."""
+  batch, kw = _hgt_ring_batch(fanout, layers)
+  assert batch.edge_hop_offsets_dict
+  params = HGT(trim=True, **kw).init(jax.random.key(0), batch)
+  out_t = np.asarray(HGT(trim=True, **kw).apply(params, batch))
+  out_f = np.asarray(HGT(trim=False, **kw).apply(params, batch))
+  np.testing.assert_allclose(out_t, out_f, rtol=1e-5, atol=1e-5)
+
+
+def test_hgt_on_a_loaders_batch_runs_the_segment_form():
+  """A loader's batch carries no promise of parent-major edge slots:
+  every layer's groups read 0, each type is the parent of one relation,
+  and the parameters are the tree the reference names."""
+  batch, kw = _hgt_ring_batch([3, 2], 2)
+  assert batch.hop_fanouts_dict is None
+  model = HGT(**kw)
+  assert all(n == 0 for g in model.layer_groups(batch) for n in g.values())
+  joint = model.layer_joint_relations(batch)
+  assert joint[0] == {'user': 1, 'item': 1}
+  params = model.init(jax.random.key(0), batch)['params']
+  assert set(params) == {'in_user', 'in_item', 'layer0', 'layer1', 'head'}
+  assert {'k_user', 'q_item', 'v_item', 'a_user', 'skip_item',
+          'watt_item__i2i__item', 'wmsg_item__rev_u2i__user',
+          'prior_item__i2i__item'} <= set(params['layer0'])
+  assert float(params['layer0']['skip_user']) == 1.0
+  assert np.all(np.asarray(params['layer0']['prior_item__i2i__item']) == 1)
+  out = model.apply({'params': params}, batch)
+  assert out.shape == (4, 3) and np.isfinite(np.asarray(out)).all()
