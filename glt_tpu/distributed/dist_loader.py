@@ -45,20 +45,20 @@ class DistNeighborLoader:
       exactly like the reference's per-rank seed splits).
     batch_size: per-device batch size.
 
-  bucket_cap sizing (pass to the DistFeature builder): measured on the
-  8-device mesh (benchmarks/bench_bucket_drain.py, committed grid in
-  benchmarks/results/bench_bucket_drain_cpu.json), capped request
-  buckets beat the uncapped [P, B] exchange at EVERY tested skew —
-  smaller messages outweigh extra drain rounds:
-
-    * near-uniform ids: ``bucket_cap = 2 * ceil(B / P)`` — 1 round,
-      ~6x faster than uncapped at 1/4 the bytes per round;
-    * zipf-skewed / adversarial ids: ``4 * ceil(B / P)`` — 2 rounds,
-      still ~1.5x faster than uncapped.
-
-  Default stays uncapped (0) until the TPU wall-times confirm the
-  virtual-mesh ordering; drain ROUND counts are exact either way (the
-  host replay is deterministic).
+  bucket_cap sizing (pass to the DistFeature builder): DistFeature's
+  default stays uncapped (0: [P, B] buckets), and any cap is exact (the
+  overflow drains in-program). The round counts of a cap of
+  ``slack * ceil(B / P)`` on an 8-device virtual mesh are in
+  benchmarks/results/bench_bucket_drain_cpu.json
+  (benchmarks/bench_bucket_drain.py): near-uniform ids 1 round at
+  slack 2, zipf-skewed and hot-spot ids 2 rounds at slack 4; that
+  grid's times are a CPU's and no timing of a chip. On the chip the
+  one-type store (``parallel.ShardedFeature``) now defaults to
+  ``ceil(B / P)`` on more than one shard: v5e 2x2, B = 937,984, one
+  round a step, the step 201.6 -> 135.9 ms against [P, B] buckets
+  (PERF.md §6, PR 34). DistFeature has the twin line and waits for the
+  merge of the two stores (ROADMAP D5): no cell runs its exchange over
+  more than one partition.
   """
 
   def __init__(self, dist_graph: DistGraph,

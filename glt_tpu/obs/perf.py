@@ -155,6 +155,20 @@ def gauge_in_place(fn: str, in_place: bool,
     pass
 
 
+def gauge_bucket_cap(fn: str, cap: int,
+                     registry: Optional[MetricsRegistry] = None) -> None:
+  """Trace-time hook of a feature store's exchange: publish
+  ``feature_store_bucket_cap{fn}``, the slots of one per-owner request
+  bucket in the program ``fn`` traced. Static like
+  :func:`gauge_in_place`, beside which it is set: the cap follows from
+  the request count, the shard count and ``bucket_cap``."""
+  try:
+    (registry or get_registry()).set('feature_store_bucket_cap',
+                                     float(cap), fn=str(fn))
+  except Exception:  # accounting must never break a trace
+    pass
+
+
 def compile_counts(registry: Optional[MetricsRegistry] = None) -> dict:
   """{fn: count} view over ``compiles_total`` — the assertable surface
   (tests pin a label's count flat across steady-state traffic)."""

@@ -6,7 +6,8 @@ book -> rpc to owners -> stitch): requests are packed into fixed-capacity
 per-owner buckets, exchanged with one all_to_all over ICI, served
 locally, and sent back with a second all_to_all; the un-bucketing scatter
 is the positional stitch (stitch_sample_results.cu analog). All shapes
-static; worst-case capacity = the full request vector per peer.
+static: a bucket holds the full request vector at worst, or a cap, with
+the requests ranked past it served by further rounds (capped_drain).
 """
 from __future__ import annotations
 
@@ -86,9 +87,10 @@ def drain_rounds(meta: BucketMeta, n_shards: int, cap: int,
 
 
 def capped_drain(round_out, meta: 'BucketMeta', n_shards: int, cap: int,
-                 axis_name: str, zeros):
+                 axis_name: str, zeros, rounds=None):
   """Accumulate ``round_out(base)`` over however many capped-exchange
-  rounds serve every request (see :func:`drain_rounds`).
+  rounds serve every request (see :func:`drain_rounds`, whose count a
+  caller that reports it hands in as ``rounds``).
 
   ``round_out`` returns a pytree of per-request accumulators for the
   requests ranked [base, base+cap) per bucket; rounds past the true
@@ -103,7 +105,8 @@ def capped_drain(round_out, meta: 'BucketMeta', n_shards: int, cap: int,
   def merge(a, o):
     return a | o if a.dtype == jnp.bool_ else a + o
 
-  rounds = drain_rounds(meta, n_shards, cap, axis_name)
+  if rounds is None:
+    rounds = drain_rounds(meta, n_shards, cap, axis_name)
 
   def body(state):
     k, acc = state

@@ -259,6 +259,9 @@ class SPMDSageTrainStep:
     #: what the last link step counted, still on the device, a device a
     #: row (:meth:`link_counters` reads it); None on a node step
     self._link_stats = None
+    #: what the store's exchange counted in the last per-batch step, the
+    #: same way (:meth:`store_counters`); None where it serves in place
+    self._store_stats = None
     self._step_fn = self._build()
     self._superstep_fn = self._build_superstep()
     if self._streaming:
@@ -326,20 +329,23 @@ class SPMDSageTrainStep:
         negatives_rejected=neg.rejected, negatives_padded=neg.padded)
 
   def _make_batch_body(self, feat_shard, labels, indptr, indices,
-                       cold_shard):
+                       cold_shard, counters=False):
     """The body of ONE training step as seen from inside shard_map:
     sample -> gather -> forward/backward -> pmean -> update. Shared
     verbatim by the per-batch step and the superstep scan so the two
     engines stay bit-identical. Its last output is the loss; a link
-    step's is ``(loss, counters)``."""
+    step's is ``(loss, counters)``, and with ``counters`` (the per-batch
+    step) a store that exchanges puts its own behind them."""
     feature, model, tx, axis = self.feature, self.model, self.tx, self.axis
     fanouts, bs = self.fanouts, self.bs
     with_edge, link = self.with_edge, self._link
+    store_counts = counters and not feature.in_place
     loss_of = _link_loss(bs) if link else _node_loss(bs)
     one_hop = lambda ids, fanout, k, mask: sample_neighbors(
         indptr, indices, ids, fanout, k, seed_mask=mask)
 
     def body(params, opt_state, table, scratch, seeds, n_valid, key):
+      counted = ()
       with scope('sampler'):
         key = jax.random.fold_in(key[0], jax.lax.axis_index(axis))
         seed_mask = meta = None
@@ -347,6 +353,7 @@ class SPMDSageTrainStep:
           kneg, key = jax.random.split(key)
           seeds, seed_mask, edge_label, stats = self._link_seeds(
               indptr, indices, seeds, n_valid[0], kneg)
+          counted += (stats,)
         out, table, scratch = multihop_sample(
             one_hop, seeds, n_valid[0], fanouts, key, table, scratch,
             with_edge=with_edge, seed_mask=seed_mask)
@@ -363,7 +370,10 @@ class SPMDSageTrainStep:
         node_valid = jnp.arange(out['node'].shape[0]) < out['node_count']
         x = feature.lookup_local(
             feat_shard, jnp.maximum(out['node'], 0), node_valid,
-            axis_name=axis, cold_shard=cold_shard)
+            axis_name=axis, cold_shard=cold_shard, counters=store_counts)
+        if store_counts:
+          x, store_stats = x
+          counted += (store_stats,)
         y = None if link else jnp.take(
             labels, jnp.maximum(out['batch'], 0)[:bs])
       batch = Batch(
@@ -374,7 +384,7 @@ class SPMDSageTrainStep:
       params, opt_state, loss = _sage_update(
           model, tx, axis, loss_of, params, opt_state, batch, n_valid[0])
       return (params, opt_state, table, scratch,
-              (loss, stats) if link else loss)
+              (loss,) + counted if counted else loss)
 
     return body
 
@@ -384,7 +394,7 @@ class SPMDSageTrainStep:
                     *cold_shard):
       body = self._make_batch_body(
           feat_shard, labels, indptr, indices,
-          cold_shard[0] if cold_shard else None)
+          cold_shard[0] if cold_shard else None, counters=True)
       params, opt_state, table, scratch, aux = body(
           params, opt_state, table[0], scratch[0], seeds, n_valid, key)
       return (params, opt_state, table[None], scratch[None],
@@ -754,8 +764,12 @@ class SPMDSageTrainStep:
              params, opt_state, self.tables, self.scratches, seeds,
              n_valid, keys, self.feature.array, self.labels,
              self._indptr, self._indices, *extra)
-      if self._link:
-        loss, self._link_stats = loss
+      if isinstance(loss, tuple):
+        loss, *counted = loss
+        if self._link:
+          self._link_stats = counted.pop(0)
+        if counted:
+          self._store_stats, = counted
       _synced['loss'] = loss
     if tracer.enabled:
       get_registry().set('train_step_traces', float(self.step_traces))
@@ -773,6 +787,24 @@ class SPMDSageTrainStep:
     if self._link_stats is None:
       raise RuntimeError('no link step has run')
     return {k: np.asarray(v) for k, v in self._link_stats.items()}
+
+  def store_counters(self) -> dict:
+    """What the feature store's exchange counted in the last per-batch
+    step, a device an entry, read back from the device (it waits for
+    that step): ``store_rounds`` (exchange rounds the drain ran, the
+    same on every device: ``ceil`` of the mesh's fullest per-owner
+    bucket over the bucket's cap), ``store_bucket_max`` (requests in
+    the device's fullest bucket) and ``store_requests`` (its valid
+    requests: the batch's ``node_count``). Only where the store
+    exchanges: on one shard it serves in place, the step has no such
+    output, and this raises."""
+    if self.feature.in_place:
+      raise RuntimeError(
+          'the feature store is on one shard and serves in place: '
+          'nothing is bucketed or exchanged, so nothing is counted')
+    if self._store_stats is None:
+      raise RuntimeError('no per-batch step has run')
+    return {k: np.asarray(v) for k, v in self._store_stats.items()}
 
   def scope_profile(self, params, opt_state, batches) -> dict:
     """Device time by layer of the per-batch step, from a profiler

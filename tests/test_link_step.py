@@ -380,9 +380,15 @@ def test_a_node_seeded_step_carries_nothing_of_the_link_front(chips):
       jax.device_put(s.n_valid, rows), keys, t.feature.array, t.labels,
       t._indptr, t._indices).out_info
   # (params, opt_state, tables, scratches, loss): a plain loss, where a
-  # link step hands back (loss, counters)
-  assert jax.tree.structure(out[-1]).num_leaves == 1
-  assert tuple(out[-1].shape) == (chips,)
+  # link step hands back (loss, counters); over more than one shard the
+  # exchanging store's three counters ride behind it, none of the link's
+  loss = out[-1]
+  if chips > 1:
+    loss, store = loss
+    assert sorted(store) == ['store_bucket_max', 'store_requests',
+                             'store_rounds']
+  assert jax.tree.structure(loss).num_leaves == 1
+  assert tuple(loss.shape) == (chips,)
   assert np.asarray(fused.step(s, 0)).shape == (chips,)
   with pytest.raises(RuntimeError, match='no link step'):
     t.link_counters()

@@ -306,10 +306,14 @@ def test_sharded_feature_bucket_cap_with_spill(mesh):
 
 # -- one shard: requests served in place ----------------------------------
 
-def _in_place_gauge():
+def _store_gauge(name):
   from glt_tpu.obs import get_registry
-  return get_registry().get('feature_store_in_place', default=-1.0,
+  return get_registry().get(name, default=-1.0,
                             fn='ShardedFeature.lookup_local')
+
+
+def _in_place_gauge():
+  return _store_gauge('feature_store_in_place')
 
 
 def _both_forms(sf, ids, valid):
@@ -400,3 +404,163 @@ def test_lookup_local_exchanges_only_over_more_than_one_shard(chips):
   assert text.count('all_to_all') == (0 if in_place else 2), text
   assert ('stablehlo.sort' in text) != in_place   # the owner argsort
   assert _in_place_gauge() == float(in_place)
+
+
+# -- more shards: buckets of b / P slots and the drain behind them ---------
+
+def _lookup_counted(sf, ids, valid):
+  """``lookup_local(counters=True)`` through a jitted shard_map: rows
+  [P * b, D] and the counters, a device an entry."""
+  from jax.sharding import PartitionSpec as P
+  cold = () if sf.cold_array is None else (sf.cold_array,)
+
+  def form(shard, i, v, c=None):
+    rows, counted = sf.lookup_local(shard, i, v, cold_shard=c,
+                                    counters=True)
+    return rows, {k: a[None] for k, a in counted.items()}
+
+  fn = jax.jit(jax.shard_map(
+      form, mesh=sf.mesh, in_specs=(P(sf.axis),) * (3 + len(cold)),
+      out_specs=P(sf.axis), check_vma=False))
+  rows, counted = fn(sf.array, jnp.asarray(ids), jnp.asarray(valid), *cold)
+  return np.asarray(rows), {k: np.asarray(a) for k, a in counted.items()}
+
+
+def _requests(case, rng, p, b, n):
+  """(ids [p * b], valid [p * b]) of a drain case, rows_per_shard n / p."""
+  rps = n // p
+  ids = rng.integers(0, n, size=p * b)
+  valid = rng.random(p * b) < 0.36   # a third of the slots are live
+  if case == 'generator_skew':       # half on shard 0: floor(N u^2)
+    ids = np.floor(n * rng.random(p * b) ** 2).astype(np.int64)
+  elif case == 'one_owner':          # every request of every device
+    ids = rng.integers(rps, 2 * rps, size=p * b)
+    valid[:] = True
+  elif case == 'none_valid':
+    valid[:] = False
+  return ids.astype(np.int32), valid
+
+
+_DRAIN_CASES = {
+    'spread_evenly': dict(),
+    'generator_skew': dict(),
+    'one_owner': dict(),
+    'none_valid': dict(),
+    'b_not_a_multiple': dict(b=600),
+    'hot_only_spill': dict(store=dict(split_ratio=0.3, host_offload=False),
+                           hot_only=True),
+    'cold_shard': dict(store=dict(split_ratio=0.3), pinned=True),
+}
+
+
+@pytest.mark.parametrize('case', list(_DRAIN_CASES))
+def test_default_store_over_four_shards_drains_exactly(case):
+  cfg = _DRAIN_CASES[case]
+  if cfg.get('pinned'):
+    from fixtures import skip_unless_pinned_host
+    skip_unless_pinned_host()
+  p, n, d, b = 4, 400, 8, cfg.get('b', 512)
+  rng = np.random.default_rng(41)
+  feats = rng.normal(size=(n, d)).astype(np.float32)
+  sf = ShardedFeature(feats, make_mesh(p), **cfg.get('store', {}))
+  assert sf.bucket_cap == 0 and not sf.in_place
+  cap = sf.exchange_cap(b)
+  assert cap == -(-(-(-b // p)) // 128) * 128 < b   # 128, or 256 of 600
+  ids, valid = _requests(case, rng, p, b, n)
+  rows, counted = _lookup_counted(sf, ids, valid)
+  served = valid
+  if cfg.get('hot_only'):
+    # cold lanes come back zero, as from the uncapped exchange
+    served = valid & (ids % sf.rows_per_shard < sf.hot_count)
+    assert served.sum() < valid.sum()
+  want = np.where(served[:, None], feats[ids], 0)
+  assert rows.dtype == np.float32
+  np.testing.assert_array_equal(rows.view(np.uint32), want.view(np.uint32))
+  # the counters against a numpy count of the same ids
+  per_owner = np.stack([
+      np.bincount(ids[lo:lo + b][valid[lo:lo + b]] // sf.rows_per_shard,
+                  minlength=p) for lo in range(0, p * b, b)])
+  rounds = -(-per_owner.max() // cap)
+  np.testing.assert_array_equal(counted['store_rounds'], [rounds] * p)
+  np.testing.assert_array_equal(counted['store_bucket_max'],
+                                per_owner.max(axis=1))
+  np.testing.assert_array_equal(counted['store_requests'],
+                                per_owner.sum(axis=1))
+  if case == 'one_owner':
+    assert rounds == p
+  elif case == 'none_valid':
+    assert rounds == 0 and not rows.any()
+  elif case == 'generator_skew':
+    share = per_owner.sum(axis=0) / per_owner.sum()
+    assert 0.45 < share[0] < 0.55 and rounds == 1
+  assert _store_gauge('feature_store_bucket_cap') == float(cap)
+  assert _in_place_gauge() == 0.0
+  if sf.cold_array is None and not cfg.get('hot_only'):
+    # the host-side API goes through the same program
+    np.testing.assert_array_equal(
+        np.asarray(sf.lookup(ids, jnp.asarray(valid))), want)
+
+
+def _tiny_step(chips):
+  from glt_tpu.data import Dataset
+  n = 64
+  rng = np.random.default_rng(43)
+  src = np.repeat(np.arange(n), 3)
+  dst = (src + rng.integers(1, n, src.shape[0])) % n
+  ds = Dataset(edge_dir='out')
+  ds.init_graph(edge_index=np.stack([src, dst]), num_nodes=n)
+  mesh = make_mesh(chips)
+  feats = rng.normal(size=(n, 8)).astype(np.float32)
+  tx = optax.adam(1e-2)
+  step = SPMDSageTrainStep(
+      mesh, GraphSAGE(hidden_features=8, out_features=4, num_layers=2), tx,
+      ds.get_graph(), ShardedFeature(feats, mesh),
+      rng.integers(0, 4, n).astype(np.int32), fanouts=[3, 2],
+      batch_size_per_device=64)
+  params = step.init_params(jax.random.key(0))
+  seeds = rng.integers(0, n, size=chips * 64)
+  keys = jax.random.split(jax.random.key(1), chips)
+  return step, params, tx.init(params), seeds, np.full(chips, 64), keys
+
+
+def test_step_hands_back_the_store_counters_over_four_shards():
+  step, params, opt, seeds, n_valid, keys = _tiny_step(4)
+  with pytest.raises(RuntimeError, match='no per-batch step has run'):
+    step.store_counters()
+  out = step(params, opt, seeds, n_valid, keys)
+  assert len(out) == 3 and np.isfinite(np.asarray(out[2])).all()
+  counted = step.store_counters()
+  assert sorted(counted) == ['store_bucket_max', 'store_requests',
+                             'store_rounds']
+  assert all(v.shape == (4,) for v in counted.values())
+  b = 64 * (1 + 3 + 6)   # sample_budget(64, [3, 2]) slots a device
+  cap = step.feature.exchange_cap(b)
+  assert cap == 256 < b
+  assert _store_gauge('feature_store_bucket_cap') == 256.0
+  # every device found all 64 nodes or fewer, at least its distinct seeds
+  assert (counted['store_requests'] <= 64).all()
+  assert (counted['store_requests'] >= 30).all()
+  assert (counted['store_bucket_max'] <= counted['store_requests']).all()
+  assert (counted['store_rounds'] == 1).all()
+  # the supersteps keep their outputs and drop the counters
+  k = jax.random.split(jax.random.key(2), (2, 4))
+  out = step.superstep(out[0], out[1], np.stack([seeds, seeds]),
+                       np.stack([n_valid, n_valid]), k)
+  assert len(out) == 3 and np.asarray(out[2]).shape == (2, 4)
+
+
+def test_step_on_one_shard_serves_in_place_and_counts_nothing():
+  step, params, opt, seeds, n_valid, keys = _tiny_step(1)
+  out = step(params, opt, seeds, n_valid, keys)
+  assert len(out) == 3 and step.feature.in_place
+  assert _in_place_gauge() == 1.0
+  with pytest.raises(RuntimeError, match='serves in place'):
+    step.store_counters()
+  from jax.sharding import PartitionSpec as P
+  sf = step.feature
+  with pytest.raises(ValueError, match='serves in place'):
+    jax.shard_map(
+        lambda s, i, v: sf.lookup_local(s, i, v, counters=True)[0],
+        mesh=sf.mesh, in_specs=(P(sf.axis),) * 3, out_specs=P(sf.axis),
+        check_vma=False)(sf.array, jnp.zeros((8,), jnp.int32),
+                         jnp.ones((8,), bool))

@@ -16,9 +16,13 @@ PARENT_STABLEHLO = {
     # sha256 of the tiny cells' step programs as StableHLO (no locations),
     # under GLT_DEDUP=sort GLT_FUSED_HOP=1, read on the parent of the PR
     # that brought HGT (bd94cd5): a PR that means to change one of these
-    # programs reads the hash anew and says so
+    # programs reads the hash anew and says so. c4's was read anew by the
+    # PR that gave the store's per-owner buckets ceil(b / P) slots and put
+    # the drain loop and its three counters into the four-chip step (it
+    # was a03c3aee...bad7d59); the other four are the parent's still,
+    # which is that PR's proof that their cells run the parent's program
     'c1': '796cd17c7761caa22f05c36e824c0e4463ada262b71cbf0f6ad08ab11f391096',
-    'c4': 'a03c3aeebbd7365ce45967f243f8a16e44e2846e7236e9dd2ad643cefbad7d59',
+    'c4': 'f4a4b6defb06c356082034e36271ab47a6f059b96890156774adb49e409b5347',
     'link': '4a6baa938beadfe9a51ca6f744bb73ba48aca6774a3d968395484e1a456f93fe',
     'typed': '196fde82f9b6340e00476b67d6d05c1ab120e0e8b4a78d2abdab2521e6904d19',
     'typed_withheld':
