@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..obs.device import register_step_program, scope
+from ..obs.device import StepCounters, register_step_program, scope
 from ..ops.pipeline import (hetero_edge_hop_offsets, hetero_hop_fanouts,
                             make_dedup_tables,
                             multihop_sample_hetero)
@@ -549,14 +549,17 @@ def _hetero_update(model, tx, axis, bs, params, opt_state, batch, y,
   return params, opt_state, loss
 
 
-class DistHeteroTrainStep:
+class DistHeteroTrainStep(StepCounters):
   """One-program hetero distributed training (the IGBH deployment shape,
   examples/igbh/dist_train_rgnn.py): hetero collective sampling +
   per-type feature all_to_all + the typed model's forward/backward +
   gradient pmean, all inside a single shard_map step. ``model`` is any
   flax module over a ``HeteroBatch`` that returns the seeds' logits:
   ``models/rgnn.py::RGNN`` and ``models/hgt.py::HGT`` both read the
-  batch's static promises through models/plan.py.
+  batch's static promises through models/plan.py. Every per-batch step
+  also says how full its padded budgets were: ``counters()`` reads what
+  the newest steps counted by type and relation, ``counter_slots()`` the
+  slots the counts are read against.
   """
 
   def __init__(self, graph: DistHeteroGraph,
@@ -625,6 +628,11 @@ class DistHeteroTrainStep:
             self.sampler.num_hops).items()}
     self.node_budget = dict(budgets)
     self.edge_budget = {e: v[-1] for e, v in edge_offsets.items()}
+    #: the rows of the step's ``nodes_by_hop`` and ``edges_by_hop``
+    #: counters, in order: node types, and relations as ``edge_budget``
+    #: keys them
+    self.counter_node_types = tuple(budgets)
+    self.counter_edge_types = tuple(edge_offsets)
     #: output rows each layer of the model computes for each type, filled
     #: when a program is traced (the node trim engages at trace time);
     #: None before, and for a model that does not say
@@ -653,6 +661,7 @@ class DistHeteroTrainStep:
                 self.sampler.num_hops).items()})
     from ..obs.perf import gauge_budgets
     gauge_budgets('train.hetero_step', self.node_budget, self.edge_budget)
+    self._init_counters()
     self._step_fn = self._build()
     self._superstep_fn = None  # built lazily on first superstep call
     self._eval_fn = None  # built lazily on first eval_step call
@@ -722,9 +731,12 @@ class DistHeteroTrainStep:
 
   def _assembly(self):
     """Shared device-batch assembly for the train and eval programs:
-    returns (device_batch, specs, payloads, table_specs) where
-    ``device_batch(...)`` runs sampling + feature/efeat collate inside
-    shard_map and yields (batch, y, out_tables)."""
+    returns (device_batch, specs, payloads) where ``device_batch(...)``
+    runs sampling + feature/efeat collate inside shard_map and yields
+    (batch, y, out_tables, counters): ``counters`` is what the sampler
+    counted, ``nodes_by_hop`` ``[T, H + 1]`` and ``edges_by_hop``
+    ``[R, H]`` in the order of ``counter_node_types`` and
+    ``counter_edge_types``."""
     from ..loader.transform import HeteroBatch
     g, axis, bs = self.g, self.axis, self.bs
     seed_type = self.seed_type
@@ -754,9 +766,15 @@ class DistHeteroTrainStep:
       my_key = jax.random.fold_in(key[0], jax.lax.axis_index(axis))
       flat_tables = {t: (tables[t][0][0], tables[t][1][0])
                      for t in tables}
+      fk = self._final_key
       with scope('sampler'):
         out, out_tables = device_core(shards_in, seeds, n_valid[0],
                                       my_key, flat_tables)
+        counters = dict(
+            nodes_by_hop=jnp.stack([out['num_sampled_nodes'][t]
+                                    for t in self.counter_node_types]),
+            edges_by_hop=self._edges_by_hop(
+                {fk(e): v for e, v in out['num_sampled_edges'].items()}))
       x_dict = {}
       for t in types:
         fs = feat_shards[t]
@@ -770,7 +788,6 @@ class DistHeteroTrainStep:
       with scope('feature_store'):
         y = jnp.take(labels[seed_type],
                      jnp.maximum(out['batch'], 0)[:bs])
-      fk = self._final_key
       edge_attr_dict = None
       if efeats:
         edge_attr_dict = {}
@@ -796,7 +813,7 @@ class DistHeteroTrainStep:
       self._note_layer_rows(batch)
       out_tables = {t: (tb[None], sc[None])
                     for t, (tb, sc) in out_tables.items()}
-      return batch, y, out_tables
+      return batch, y, out_tables, counters
 
     sp = P(self.axis)
     def etype_spec(e):
@@ -846,12 +863,13 @@ class DistHeteroTrainStep:
 
     def device_step(params, opt_state, shards, feat_shards, efeat_shards,
                     labels, seeds, n_valid, key, tables):
-      batch, y, out_tables = device_batch(
+      batch, y, out_tables, counters = device_batch(
           shards, feat_shards, efeat_shards, labels, seeds, n_valid,
           key, tables)
       params, opt_state, loss = _hetero_update(
           model, tx, axis, bs, params, opt_state, batch, y, n_valid[0])
-      out = (params, opt_state, out_tables, loss[None])
+      out = (params, opt_state, out_tables,
+             (loss[None], jax.tree.map(lambda a: a[None], counters)))
       if self.keep_sample:
         out += (jax.tree_util.tree_map(lambda a: a[None], dict(
             node=batch.node_dict, node_count=batch.node_count_dict,
@@ -908,7 +926,8 @@ class DistHeteroTrainStep:
                          efeat_shards, labels, seeds_stack,
                          n_valid_stack, keys, tables):
       def body(params, opt_state, tables, seeds, n_valid, key):
-        batch, y, out_tables = device_batch(
+        # a scanned batch keeps its loss and drops what it counted
+        batch, y, out_tables, _ = device_batch(
             shards, feat_shards, efeat_shards, labels, seeds, n_valid,
             key, tables)
         params, opt_state, loss = _hetero_update(
@@ -1001,11 +1020,40 @@ class DistHeteroTrainStep:
       with tracer.span('train.step/dispatch'):
         out = self._step_fn(params, opt_state, self.sampler.tables,
                             seeds, nv, keys)
-        params, opt_state, self.sampler.tables, loss = out[:4]
+        (params, opt_state, self.sampler.tables,
+         (loss, counted)) = out[:4]
         if self.keep_sample:
           self.last_sample = out[4]
+      self._keep_counters(counted)
       _synced['loss'] = loss
     return params, opt_state, loss
+
+  def _edges_by_hop(self, by_relation):
+    """``[R, H]`` from the sampler's ``num_sampled_edges``, which holds
+    for each relation the hops it is read in: rows in the order of
+    ``counter_edge_types``, 0 for a hop a relation is not read in (no
+    width in ``edge_hop_offsets_dict``)."""
+    offsets = self._batch_static['edge_hop_offsets_dict']
+    rows = []
+    for e in self.counter_edge_types:
+      read = iter(by_relation[e])
+      rows.append(jnp.stack([next(read) if width else jnp.int32(0)
+                             for width in np.diff(offsets[e])]))
+    return jnp.stack(rows)
+
+  def counter_slots(self) -> dict:
+    """The contract of :meth:`StepCounters.counter_slots`: by type the
+    node slots of each hop (``node_hop_offsets_dict``), by relation the
+    edge slots (``edge_hop_offsets_dict``), rows in the order of
+    ``counter_node_types`` and ``counter_edge_types``."""
+    static = self._batch_static
+    return dict(
+        nodes_by_hop=np.stack([
+            np.diff(static['node_hop_offsets_dict'][t], prepend=0)
+            for t in self.counter_node_types]).astype(np.int64),
+        edges_by_hop=np.stack([
+            np.diff(static['edge_hop_offsets_dict'][e])
+            for e in self.counter_edge_types]).astype(np.int64))
 
   def scope_profile(self, params, opt_state, batches) -> dict:
     """Device time by layer of the per-batch step, from a profiler
@@ -1027,7 +1075,7 @@ class DistHeteroTrainStep:
 
     def device_eval(params, shards, feat_shards, efeat_shards, labels,
                     seeds, n_valid, key, tables):
-      batch, y, out_tables = device_batch(
+      batch, y, out_tables, _ = device_batch(
           shards, feat_shards, efeat_shards, labels, seeds, n_valid,
           key, tables)
       logits = model.apply(params, batch)

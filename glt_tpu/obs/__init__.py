@@ -37,9 +37,8 @@ Three further pieces ride the same registry/tracer surfaces:
 
   * :mod:`perf` — XLA cost accounting (``compiles_total{fn}``,
     ``xla_flops``/``xla_bytes_accessed``/``xla_peak_bytes`` via the
-    :func:`instrument_compiled` seam) and measured device rooflines
-    (:func:`device_ceilings`, :func:`roofline_report`) so every
-    throughput headline restates as % of a *measured* ceiling.
+    :func:`instrument_compiled` seam) and the static gauges the step
+    programs set at trace time.
   * :mod:`recorder` — the always-on :class:`FlightRecorder` (bounded
     operational-event ring; resilience trips dump a postmortem JSON
     into ``GLT_OBS_POSTMORTEM_DIR``) and :class:`SloBurnEvaluator`
@@ -53,7 +52,6 @@ Knobs (see docs/observability.md for the full table):
   GLT_OBS_BUFFER=n        span ring-buffer capacity (default 65536)
   GLT_OBS_XLA_COST=1      opt-in AOT cost publication at test-pinned
                           compile points (serving warmup)
-  GLT_ROOFLINE_CACHE      measured-ceiling JSON cache path
   GLT_OBS_POSTMORTEM_DIR  flight-recorder postmortem dump directory
   GLT_OBS_POSTMORTEM_MIN_S  floor between trip-initiated dumps
   GLT_OBS_SLO             SLO policies: name:metric:threshold[:obj];...
@@ -66,10 +64,7 @@ from .trace import (
     Span, SpanContext, Tracer, collect_endpoint_obs, get_tracer,
     merge_chrome_traces, save_chrome_trace,
 )
-from .perf import (
-    compile_counts, count_compile, device_ceilings, instrument_compiled,
-    measure_hbm_bandwidth, measure_matmul_flops, roofline_report,
-)
+from .perf import compile_counts, count_compile, instrument_compiled
 from .recorder import (
     FlightRecorder, SloBurnEvaluator, SloPolicy, get_recorder,
     parse_slo_env, set_recorder,
@@ -80,9 +75,7 @@ __all__ = [
     'MetricsRegistry', 'get_registry', 'set_registry',
     'Span', 'SpanContext', 'Tracer', 'get_tracer',
     'collect_endpoint_obs', 'merge_chrome_traces', 'save_chrome_trace',
-    'compile_counts', 'count_compile', 'device_ceilings',
-    'instrument_compiled', 'measure_hbm_bandwidth',
-    'measure_matmul_flops', 'roofline_report',
+    'compile_counts', 'count_compile', 'instrument_compiled',
     'FlightRecorder', 'SloBurnEvaluator', 'SloPolicy', 'get_recorder',
     'parse_slo_env', 'set_recorder',
 ]

@@ -10,6 +10,7 @@ its instruction. ``layer_of`` reads one ``op_name``, ``reduce_scopes``
 sums a trace's events by layer and stage, and ``scope_profile`` takes the
 trace: one short profiler session around a step program's own calls.
 """
+import collections
 import contextlib
 import re
 import weakref
@@ -40,6 +41,68 @@ def live_step_programs():
   """The step programs alive in this process: what a console lists, and
   how a reader reaches the trainer that a benchmark's window drove."""
   return list(_LIVE)
+
+
+# -- what a step counted --------------------------------------------------
+
+COUNTER_STEPS = 128   # per-batch steps whose counters a step program holds
+
+
+class StepCounters:
+  """What the fused per-batch steps count, held and read one way
+  (``SPMDSageTrainStep``, ``DistHeteroTrainStep``). Every per-batch
+  program returns ``(loss, counters)``, ``counters`` one flat dict of
+  small integer arrays with a leading device axis; ``__call__`` keeps
+  the newest ``COUNTER_STEPS`` dicts as they come, on the device, with
+  the ordinal of the call (one append a step: no fetch, no wait).
+  The supersteps count nothing."""
+
+  def _init_counters(self):
+    self._counted = collections.deque(maxlen=COUNTER_STEPS)
+    self._calls = 0   # per-batch calls so far: the next step's ordinal
+
+  def _keep_counters(self, counters):
+    self._counted.append((self._calls, counters))
+    self._calls += 1
+
+  def _newest_counters(self, names) -> dict:
+    import numpy as np
+    newest = self._counted[-1][1]
+    return {k: np.asarray(newest[k]) for k in names if k in newest}
+
+  def counters(self) -> dict:
+    """``{'step': int64 [n], name: ndarray [n, devices, ...]}``: what the
+    newest ``n <= COUNTER_STEPS`` per-batch steps counted, oldest first;
+    ``step`` is a step's ordinal among this trainer's per-batch calls,
+    from 0. A read fetches every held array and so waits for the newest
+    step (read at an epoch's end or every so many steps, not every
+    step); it traces and compiles nothing. Before the first step it
+    raises. Every step counts ``nodes_by_hop`` (node rows new at each
+    hop, the seeds' first: ``[H + 1]``, by type ``[T, H + 1]`` in the
+    order of ``counter_node_types``; the sum is the batch's
+    ``node_count``) and ``edges_by_hop`` (valid edge slots of each hop:
+    ``[H]``, by relation ``[R, H]`` in the order of
+    ``counter_edge_types``, 0 for a hop a relation is not read in); a
+    link step also what ``link_counters`` names, a step whose store
+    exchanges also what ``store_counters`` names."""
+    import jax
+    import numpy as np
+    if not self._counted:
+      raise RuntimeError('no per-batch step has run')
+    steps, held = zip(*self._counted)
+    held = jax.device_get(list(held))
+    out = {'step': np.asarray(steps, np.int64)}
+    for name in held[-1]:
+      out[name] = np.stack([h[name] for h in held])
+    return out
+
+  def counter_slots(self) -> dict:
+    """``{name: int64 slots}``: the static budget each counter is read
+    against, in the counter's shape less the step and device axes, so
+    that ``counters()[name].sum() / (n * devices * slots.sum())`` is the
+    share of a budget that held work. An entry with no budget (a link
+    step's ``seeds``) has none."""
+    raise NotImplementedError
 
 
 # -- one op_name ----------------------------------------------------------

@@ -1,32 +1,19 @@
-"""Performance accounting: XLA cost/memory analysis and measured
-device rooflines.
+"""Performance accounting: XLA cost and memory analysis.
 
-Two halves, both publishing into the shared :class:`MetricsRegistry`:
-
-**XLA cost accounting** — every jitted compile point already carries a
-trace-time side effect (the per-module ``*_traces`` counters the
-zero-steady-state-recompile tests assert); :func:`count_compile`
-generalizes those into ONE process-wide ``compiles_total{fn=...}``
-counter, and :func:`instrument_compiled` is the seam over
-``jax.stages.Lowered.cost_analysis()`` /
+Everything here publishes into the shared :class:`MetricsRegistry`.
+Every jitted compile point already carries a trace-time side effect (the
+per-module ``*_traces`` counters the zero-steady-state-recompile tests
+assert); :func:`count_compile` generalizes those into ONE process-wide
+``compiles_total{fn=...}`` counter, and :func:`instrument_compiled` is
+the seam over ``jax.stages.Lowered.cost_analysis()`` /
 ``jax.stages.Compiled.cost_analysis()`` / ``memory_analysis()`` that
 publishes per-program FLOPs, HBM bytes accessed, and peak memory as
 ``xla_flops{fn}`` / ``xla_bytes_accessed{fn}`` /
 ``xla_peak_bytes{fn}`` gauges. Lowering is cheap (a re-trace, no
 compile) but IS a re-trace: callers whose trace counters are pinned by
-tests (serving warmup) gate it behind ``GLT_OBS_XLA_COST``.
-
-**Measured rooflines** — perf claims quoted against an *assumed*
-ceiling are not self-grounding (PAPERS.md "GNNSampler", "Hardware
-Acceleration of Sampling Algorithms in Sample and Aggregate GNNs"):
-:func:`device_ceilings` runs a tiny microbench pair — HBM stream
-bandwidth (saxpy over an HBM-resident array) and peak matmul FLOP/s —
-once per device kind, caches the result as JSON
-(``GLT_ROOFLINE_CACHE``), and publishes
-``roofline_hbm_bytes_per_sec`` / ``roofline_flops_per_sec`` gauges.
-:func:`roofline_report` then restates any items/s headline as % of the
-*measured* ceilings plus bytes-per-item and FLOPs-per-item. Nothing
-in BENCHMARK.json reads this half (ROADMAP D3).
+tests (serving warmup) gate it behind ``GLT_OBS_XLA_COST``. The chip's
+peaks, for a share of a roofline, are the benchmark's
+(``chipbench/peaks.py``).
 
 Everything here is host-side and allocation-free in steady state;
 nothing touches traced code paths except the deliberate trace-time
@@ -35,10 +22,7 @@ effect as the existing ``*_traces`` attribute bumps).
 """
 from __future__ import annotations
 
-import json
 import logging
-import os
-import time
 from typing import Optional
 
 from ..typing import as_str
@@ -277,169 +261,3 @@ def instrument_compiled(fn_name: str, stage=None, *args,
   except Exception as e:  # noqa: BLE001 — accounting is best-effort
     logger.debug('cost analysis for %s unavailable: %s', fn_name, e)
     return {}
-
-
-# -- measured rooflines ---------------------------------------------------
-
-def default_cache_path() -> str:
-  return knob(
-      'GLT_ROOFLINE_CACHE',
-      os.path.join(os.path.expanduser('~'), '.cache', 'glt_tpu',
-                   'roofline.json'))
-
-
-def measure_hbm_bandwidth(device=None, mib: int = 256,
-                          iters: int = 5) -> float:
-  """Measured HBM stream bandwidth in bytes/s: time ``y = a * x + y``
-  (saxpy: 2 reads + 1 write per element) over an HBM-resident array,
-  best of ``iters`` — best because every perturbation is additive
-  noise; the max is the ceiling, exactly what a roofline needs."""
-  import jax
-  import jax.numpy as jnp
-  dev = device or jax.devices()[0]
-  n = max(mib, 1) * (1 << 20) // 4
-  x = jax.device_put(jnp.ones((n,), jnp.float32), dev)
-  y = jax.device_put(jnp.zeros((n,), jnp.float32), dev)
-
-  @jax.jit
-  def saxpy(x, y):
-    return 2.0 * x + y
-
-  jax.block_until_ready(saxpy(x, y))  # compile outside the timing
-  best = float('inf')
-  for _ in range(max(iters, 1)):
-    t0 = time.perf_counter()
-    jax.block_until_ready(saxpy(x, y))
-    best = min(best, time.perf_counter() - t0)
-  return 3.0 * 4.0 * n / best  # 2 loads + 1 store, fp32
-
-
-def measure_matmul_flops(device=None, dim: int = 2048,
-                         iters: int = 5) -> float:
-  """Measured peak matmul throughput in FLOP/s: time an
-  fp32 [dim, dim] x [dim, dim] matmul (2*dim^3 FLOPs), best of
-  ``iters``."""
-  import jax
-  import jax.numpy as jnp
-  dev = device or jax.devices()[0]
-  a = jax.device_put(jnp.ones((dim, dim), jnp.float32), dev)
-  b = jax.device_put(jnp.ones((dim, dim), jnp.float32), dev)
-
-  @jax.jit
-  def mm(a, b):
-    return a @ b
-
-  jax.block_until_ready(mm(a, b))
-  best = float('inf')
-  for _ in range(max(iters, 1)):
-    t0 = time.perf_counter()
-    jax.block_until_ready(mm(a, b))
-    best = min(best, time.perf_counter() - t0)
-  return 2.0 * dim ** 3 / best
-
-
-#: in-process ceilings cache: one measurement per (device kind) per
-#: process even when the disk cache is unwritable
-_CEILINGS: dict = {}
-
-
-def device_ceilings(device=None, refresh: bool = False,
-                    cache_path: Optional[str] = None,
-                    mib: int = 256, dim: int = 2048,
-                    registry: Optional[MetricsRegistry] = None) -> dict:
-  """The measured roofline pair for one device, cached per device kind.
-
-  Returns ``{'device_kind', 'platform', 'hbm_bytes_per_sec',
-  'flops_per_sec', 'measured_at'}``. Resolution order: in-process cache
-  -> JSON disk cache (``GLT_ROOFLINE_CACHE``, keyed by device kind so a
-  v5p entry never answers for a v6e) -> fresh microbench pair (a few
-  hundred ms). Every resolution republishes the
-  ``roofline_hbm_bytes_per_sec`` / ``roofline_flops_per_sec`` gauges so
-  the ceilings ride every registry snapshot next to the throughput
-  counters they ground."""
-  import jax
-  dev = device or jax.devices()[0]
-  kind = f'{dev.platform}:{dev.device_kind}'
-  path = cache_path or default_cache_path()
-  entry = None
-  if not refresh:
-    entry = _CEILINGS.get(kind)
-    if entry is None and os.path.exists(path):
-      try:
-        with open(path) as f:
-          entry = json.load(f).get(kind)
-      except (OSError, ValueError):
-        entry = None
-  if entry is None:
-    entry = {
-        'device_kind': dev.device_kind,
-        'platform': dev.platform,
-        'hbm_bytes_per_sec': measure_hbm_bandwidth(dev, mib=mib),
-        'flops_per_sec': measure_matmul_flops(dev, dim=dim),
-        'measured_at': time.time(),
-    }
-    try:
-      os.makedirs(os.path.dirname(path), exist_ok=True)
-      doc = {}
-      if os.path.exists(path):
-        try:
-          with open(path) as f:
-            doc = json.load(f)
-        except (OSError, ValueError):
-          doc = {}
-      doc[kind] = entry
-      with open(path, 'w') as f:
-        json.dump(doc, f, indent=2)
-    except OSError as e:  # unwritable cache: measure-per-process only
-      logger.debug('roofline cache %s unwritable: %s', path, e)
-  _CEILINGS[kind] = entry
-  reg = registry or get_registry()
-  reg.set('roofline_hbm_bytes_per_sec', entry['hbm_bytes_per_sec'],
-          device=kind)
-  reg.set('roofline_flops_per_sec', entry['flops_per_sec'], device=kind)
-  return entry
-
-
-def roofline_report(items_per_sec: float,
-                    bytes_per_item: Optional[float] = None,
-                    flops_per_item: Optional[float] = None,
-                    ceilings: Optional[dict] = None,
-                    item: str = 'edge') -> dict:
-  """Restate a throughput headline against the measured ceilings.
-
-  Given a rate (e.g. sampled edges/s), the program's HBM bytes moved
-  per item and FLOPs per item (from :func:`instrument_compiled`'s
-  ``bytes_accessed`` / ``flops`` divided by items per dispatch),
-  returns the roofline cell::
-
-      {'hbm_bytes_per_<item>':        bytes the program moves per item,
-       'flops_per_<item>':            model FLOPs per item,
-       'pct_of_measured_hbm_ceiling': 100 * rate*bytes / measured BW,
-       'pct_of_measured_flop_ceiling': 100 * rate*flops / measured peak,
-       'bound':                       'hbm' | 'flops' (larger share),
-       'device_kind':                 the ceiling's device}
-
-  ``ceilings=None`` resolves :func:`device_ceilings` (cached). The two
-  percentages are exactly "how much of what the chip measurably has is
-  this pipeline using" — the self-grounding restatement ROADMAP item 1
-  asks for."""
-  if ceilings is None:
-    ceilings = device_ceilings()
-  out: dict = {'device_kind': ceilings.get('device_kind', '?')}
-  pct_hbm = pct_flop = None
-  if bytes_per_item is not None:
-    out[f'hbm_bytes_per_{item}'] = round(float(bytes_per_item), 2)
-    bw = ceilings.get('hbm_bytes_per_sec') or 0.0
-    if bw > 0:
-      pct_hbm = 100.0 * items_per_sec * bytes_per_item / bw
-      out['pct_of_measured_hbm_ceiling'] = round(pct_hbm, 3)
-  if flops_per_item is not None:
-    out[f'flops_per_{item}'] = round(float(flops_per_item), 2)
-    peak = ceilings.get('flops_per_sec') or 0.0
-    if peak > 0:
-      pct_flop = 100.0 * items_per_sec * flops_per_item / peak
-      out['pct_of_measured_flop_ceiling'] = round(pct_flop, 3)
-  if pct_hbm is not None or pct_flop is not None:
-    out['bound'] = ('hbm' if (pct_hbm or 0.0) >= (pct_flop or 0.0)
-                    else 'flops')
-  return out

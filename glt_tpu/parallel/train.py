@@ -64,13 +64,20 @@ from ..ops.sample import sample_neighbors
 from ..ops.pipeline import make_dedup_tables
 from ..ops.superstep import scan_consume, superstep as build_superstep
 from ..loader.transform import Batch
-from ..obs.device import register_step_program, scope
+from ..obs.device import StepCounters, register_step_program, scope
 from ..sampler.base import NegativeSampling
 from .mesh import replicate
 
 #: rounds of proposals a strict negative gets before it is padded with
 #: the last round's (reference RandomNegativeSampler.sample's default)
 NEG_TRIALS = 5
+
+#: of a step's counters, what only a link step's front counts and what
+#: only a store that exchanges counts (``link_counters``,
+#: ``store_counters``)
+LINK_COUNTERS = ('negatives_rejected', 'negatives_padded', 'seed_unique',
+                 'seeds')
+STORE_COUNTERS = ('store_rounds', 'store_bucket_max', 'store_requests')
 
 
 def _node_loss(bs):
@@ -128,7 +135,7 @@ def _sage_update(model, tx, axis, loss_of, params, opt_state, batch,
   return params, opt_state, loss
 
 
-class SPMDSageTrainStep:
+class SPMDSageTrainStep(StepCounters):
   """Builds and runs the sharded sample+train step.
 
   Args:
@@ -159,12 +166,16 @@ class SPMDSageTrainStep:
       endpoints ``[src; neg_src; dst; neg_dst]`` (``sample_from_edges``'s
       order) and trains the dot-product BCE of the model's seed rows.
       ``labels`` is not read. Per-batch only: the supersteps and
-      ``cold_streaming`` raise. What only this path counts comes back
-      through :meth:`link_counters`.
+      ``cold_streaming`` raise. What only this path counts rides the
+      step's counters (:meth:`counters`, :meth:`link_counters`).
     keep_seeds: a link step also hands back the ``[4B]`` endpoint seeds
-      it drew and expanded (through :meth:`link_counters`), for a check
-      of the negatives themselves against the graph; off, the program
-      has no such output.
+      it drew and expanded (among its counters), for a check of the
+      negatives themselves against the graph; off, the program has no
+      such output.
+
+  Every per-batch step also says how full its padded budgets were:
+  :meth:`counters` reads what the newest steps counted,
+  :meth:`counter_slots` the slots the counts are read against.
   """
 
   def __init__(self, mesh: Mesh, model, tx, graph: Graph, feature,
@@ -256,12 +267,7 @@ class SPMDSageTrainStep:
     #: and a masked reduce (0: the layer scatter-adds every slot);
     #: static and filled like ``layer_rows``
     self.layer_groups = None
-    #: what the last link step counted, still on the device, a device a
-    #: row (:meth:`link_counters` reads it); None on a node step
-    self._link_stats = None
-    #: what the store's exchange counted in the last per-batch step, the
-    #: same way (:meth:`store_counters`); None where it serves in place
-    self._store_stats = None
+    self._init_counters()
     self._step_fn = self._build()
     self._superstep_fn = self._build_superstep()
     if self._streaming:
@@ -329,43 +335,46 @@ class SPMDSageTrainStep:
         negatives_rejected=neg.rejected, negatives_padded=neg.padded)
 
   def _make_batch_body(self, feat_shard, labels, indptr, indices,
-                       cold_shard, counters=False):
+                       cold_shard):
     """The body of ONE training step as seen from inside shard_map:
     sample -> gather -> forward/backward -> pmean -> update. Shared
     verbatim by the per-batch step and the superstep scan so the two
-    engines stay bit-identical. Its last output is the loss; a link
-    step's is ``(loss, counters)``, and with ``counters`` (the per-batch
-    step) a store that exchanges puts its own behind them."""
+    engines stay bit-identical. Its last output is ``(loss, counters)``,
+    ``counters`` one flat dict of what the step counted
+    (:meth:`counters` names every entry): the sampler's new nodes and
+    valid edges by hop, a link step's negatives and distinct seeds, and
+    what a store that exchanges counted."""
     feature, model, tx, axis = self.feature, self.model, self.tx, self.axis
     fanouts, bs = self.fanouts, self.bs
     with_edge, link = self.with_edge, self._link
-    store_counts = counters and not feature.in_place
+    store_counts = not feature.in_place
     loss_of = _link_loss(bs) if link else _node_loss(bs)
     one_hop = lambda ids, fanout, k, mask: sample_neighbors(
         indptr, indices, ids, fanout, k, seed_mask=mask)
 
     def body(params, opt_state, table, scratch, seeds, n_valid, key):
-      counted = ()
+      counted = {}
       with scope('sampler'):
         key = jax.random.fold_in(key[0], jax.lax.axis_index(axis))
         seed_mask = meta = None
         if link:
           kneg, key = jax.random.split(key)
-          seeds, seed_mask, edge_label, stats = self._link_seeds(
+          seeds, seed_mask, edge_label, counted = self._link_seeds(
               indptr, indices, seeds, n_valid[0], kneg)
-          counted += (stats,)
         out, table, scratch = multihop_sample(
             one_hop, seeds, n_valid[0], fanouts, key, table, scratch,
             with_edge=with_edge, seed_mask=seed_mask)
+        counted.update(nodes_by_hop=out['num_sampled_nodes'],
+                       edges_by_hop=out['num_sampled_edges'])
         if link:
           # a seed slot's label is its endpoint's row of the model's
           # output: the labels of the seeds are the first ones
           meta = dict(
               edge_label_index=out['seed_labels'].reshape(2, -1),
               edge_label=edge_label)
-          stats['seed_unique'] = out['seed_count']
+          counted['seed_unique'] = out['seed_count']
           if self._keep_seeds:
-            stats['seeds'] = seeds
+            counted['seeds'] = seeds
       with scope('feature_store'):
         node_valid = jnp.arange(out['node'].shape[0]) < out['node_count']
         x = feature.lookup_local(
@@ -373,7 +382,7 @@ class SPMDSageTrainStep:
             axis_name=axis, cold_shard=cold_shard, counters=store_counts)
         if store_counts:
           x, store_stats = x
-          counted += (store_stats,)
+          counted.update(store_stats)
         y = None if link else jnp.take(
             labels, jnp.maximum(out['batch'], 0)[:bs])
       batch = Batch(
@@ -383,8 +392,7 @@ class SPMDSageTrainStep:
       self._note_layer_rows(batch)
       params, opt_state, loss = _sage_update(
           model, tx, axis, loss_of, params, opt_state, batch, n_valid[0])
-      return (params, opt_state, table, scratch,
-              (loss,) + counted if counted else loss)
+      return params, opt_state, table, scratch, (loss, counted)
 
     return body
 
@@ -394,7 +402,7 @@ class SPMDSageTrainStep:
                     *cold_shard):
       body = self._make_batch_body(
           feat_shard, labels, indptr, indices,
-          cold_shard[0] if cold_shard else None, counters=True)
+          cold_shard[0] if cold_shard else None)
       params, opt_state, table, scratch, aux = body(
           params, opt_state, table[0], scratch[0], seeds, n_valid, key)
       return (params, opt_state, table[None], scratch[None],
@@ -446,7 +454,13 @@ class SPMDSageTrainStep:
       body = self._make_batch_body(
           feat_shard, labels, indptr, indices,
           cold_shard[0] if cold_shard else None)
-      run = build_superstep(body)
+
+      def loss_alone(*args):
+        # a scanned batch keeps its loss and drops what it counted
+        *state, (loss, _) = body(*args)
+        return (*state, loss)
+
+      run = build_superstep(loss_alone)
       params, opt_state, table, scratch, losses = run(
           params, opt_state, tables[0], scratches[0], seeds_stack,
           n_valid_stack, keys)
@@ -760,51 +774,69 @@ class SPMDSageTrainStep:
                if self.feature.cold_array is not None else ())
       with tracer.span('train.step/dispatch'):
         (params, opt_state, self.tables, self.scratches,
-         loss) = self._step_fn(
+         (loss, counted)) = self._step_fn(
              params, opt_state, self.tables, self.scratches, seeds,
              n_valid, keys, self.feature.array, self.labels,
              self._indptr, self._indices, *extra)
-      if isinstance(loss, tuple):
-        loss, *counted = loss
-        if self._link:
-          self._link_stats = counted.pop(0)
-        if counted:
-          self._store_stats, = counted
+      self._keep_counters(counted)
       _synced['loss'] = loss
     if tracer.enabled:
       get_registry().set('train_step_traces', float(self.step_traces))
     return params, opt_state, loss
 
+  def counter_slots(self) -> dict:
+    """The contract of :meth:`StepCounters.counter_slots`: hop by hop
+    the node slots (``node_hop_offsets``) and edge slots
+    (``edge_hop_offsets``) of a device's batch; a link step's
+    ``NEG_TRIALS x B`` proposals, ``B`` negatives and ``4B`` seed slots;
+    over more than one shard the drain's most rounds, a per-owner
+    bucket's ``exchange_cap(b)`` slots and the ``b`` request slots."""
+    static = self._batch_static
+    slots = dict(
+        nodes_by_hop=np.diff(static['node_hop_offsets'], prepend=0),
+        edges_by_hop=np.diff(static['edge_hop_offsets']))
+    if self._link:
+      slots.update(negatives_rejected=NEG_TRIALS * self.bs,
+                   negatives_padded=self.bs, seed_unique=self.seed_slots)
+    if not self.feature.in_place:
+      b = static['node_hop_offsets'][-1]
+      cap = self.feature.exchange_cap(b)
+      slots.update(store_rounds=-(-b // cap), store_bucket_max=cap,
+                   store_requests=b)
+    return {k: np.asarray(v, np.int64) for k, v in slots.items()}
+
   def link_counters(self) -> dict:
-    """What the last link step counted, a device an entry, read back
-    from the device (it waits for that step): ``negatives_rejected``
-    (proposals of the ``NEG_TRIALS x B`` that were edges of the graph),
+    """What the newest link step counted, a device an entry, read back
+    from the device (it waits for that step): a view of
+    :meth:`counters`' newest entry. ``negatives_rejected`` (proposals of
+    the ``NEG_TRIALS x B`` that were edges of the graph),
     ``negatives_padded`` (pairs of the ``B`` with no round that was no
     edge: they carry the last round's proposal, an edge),
     ``seed_unique`` (distinct endpoints among the valid of the ``4B``:
     the hop-0 node count) and, from a step built with ``keep_seeds``,
     ``seeds`` (``[4B]`` node ids, ``[src; neg_src; dst; neg_dst]``)."""
-    if self._link_stats is None:
+    if not self._link or not self._counted:
       raise RuntimeError('no link step has run')
-    return {k: np.asarray(v) for k, v in self._link_stats.items()}
+    return self._newest_counters(LINK_COUNTERS)
 
   def store_counters(self) -> dict:
-    """What the feature store's exchange counted in the last per-batch
+    """What the feature store's exchange counted in the newest per-batch
     step, a device an entry, read back from the device (it waits for
-    that step): ``store_rounds`` (exchange rounds the drain ran, the
-    same on every device: ``ceil`` of the mesh's fullest per-owner
-    bucket over the bucket's cap), ``store_bucket_max`` (requests in
-    the device's fullest bucket) and ``store_requests`` (its valid
-    requests: the batch's ``node_count``). Only where the store
-    exchanges: on one shard it serves in place, the step has no such
-    output, and this raises."""
+    that step): a view of :meth:`counters`' newest entry.
+    ``store_rounds`` (exchange rounds the drain ran, the same on every
+    device: ``ceil`` of the mesh's fullest per-owner bucket over the
+    bucket's cap), ``store_bucket_max`` (requests in the device's
+    fullest bucket) and ``store_requests`` (its valid requests: the
+    batch's ``node_count``). Only where the store exchanges: on one
+    shard it serves in place, the step has no such output, and this
+    raises."""
     if self.feature.in_place:
       raise RuntimeError(
           'the feature store is on one shard and serves in place: '
           'nothing is bucketed or exchanged, so nothing is counted')
-    if self._store_stats is None:
+    if not self._counted:
       raise RuntimeError('no per-batch step has run')
-    return {k: np.asarray(v) for k, v in self._store_stats.items()}
+    return self._newest_counters(STORE_COUNTERS)
 
   def scope_profile(self, params, opt_state, batches) -> dict:
     """Device time by layer of the per-batch step, from a profiler

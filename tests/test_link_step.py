@@ -379,14 +379,14 @@ def test_a_node_seeded_step_carries_nothing_of_the_link_front(chips):
       jax.device_put(np.asarray(seeds, np.int32), rows),
       jax.device_put(s.n_valid, rows), keys, t.feature.array, t.labels,
       t._indptr, t._indices).out_info
-  # (params, opt_state, tables, scratches, loss): a plain loss, where a
-  # link step hands back (loss, counters); over more than one shard the
-  # exchanging store's three counters ride behind it, none of the link's
-  loss = out[-1]
-  if chips > 1:
-    loss, store = loss
-    assert sorted(store) == ['store_bucket_max', 'store_requests',
-                             'store_rounds']
+  # (params, opt_state, tables, scratches, (loss, counters)): every step
+  # counts its nodes and edges by hop; over more than one shard the
+  # exchanging store's three counters ride beside them; nothing of the
+  # link front
+  loss, counted = out[-1]
+  store = ['store_bucket_max', 'store_requests', 'store_rounds']
+  assert sorted(counted) == ['edges_by_hop', 'nodes_by_hop'] + (
+      store if chips > 1 else [])
   assert jax.tree.structure(loss).num_leaves == 1
   assert tuple(loss.shape) == (chips,)
   assert np.asarray(fused.step(s, 0)).shape == (chips,)

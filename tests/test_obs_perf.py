@@ -1,5 +1,5 @@
 """Performance observability: XLA cost accounting (compiles_total +
-instrument_compiled gauges), measured rooflines, the bench trajectory +
+instrument_compiled gauges), the bench trajectory +
 regression gate, the postmortem flight recorder, SLO burn, and the
 exposition/harvest satellites.
 
@@ -21,9 +21,8 @@ import pytest
 
 from glt_tpu.obs import (
     FlightRecorder, MetricsRegistry, SloBurnEvaluator, Tracer,
-    compile_counts, count_compile, device_ceilings, get_registry,
-    get_tracer, instrument_compiled, parse_slo_env, roofline_report,
-    set_recorder, set_registry,
+    compile_counts, count_compile, get_registry, get_tracer,
+    instrument_compiled, parse_slo_env, set_recorder, set_registry,
 )
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -33,7 +32,7 @@ sys.path.insert(0, os.path.join(REPO, 'benchmarks'))
 @pytest.fixture
 def registry():
   """Fresh process-global registry, restored afterwards — compile
-  counters and roofline gauges land on the global surface."""
+  counters and the cost gauges land on the global surface."""
   prev = set_registry(MetricsRegistry())
   yield get_registry()
   set_registry(prev)
@@ -156,49 +155,6 @@ def test_serving_warmup_publishes_costs_opt_in(registry):
   eng.infer(np.arange(3) % 24)
   assert eng.compile_stats()['forward_traces'] == \
       warm['forward_traces']
-
-
-# -- measured rooflines --------------------------------------------------
-
-def test_device_ceilings_measured_then_cached(tmp_path, registry,
-                                              monkeypatch):
-  from glt_tpu.obs import perf
-  cache = str(tmp_path / 'roofline.json')
-  perf._CEILINGS.clear()
-  c1 = device_ceilings(cache_path=cache, mib=2, dim=64)
-  assert c1['hbm_bytes_per_sec'] > 0 and c1['flops_per_sec'] > 0
-  assert os.path.exists(cache)
-  # second resolution must come from the cache, never re-measure
-  perf._CEILINGS.clear()
-
-  def boom(*a, **k):
-    raise AssertionError('re-measured despite a valid cache')
-
-  monkeypatch.setattr(perf, 'measure_hbm_bandwidth', boom)
-  monkeypatch.setattr(perf, 'measure_matmul_flops', boom)
-  c2 = device_ceilings(cache_path=cache)
-  assert c2['hbm_bytes_per_sec'] == c1['hbm_bytes_per_sec']
-  # ...and every resolution republishes the ceiling gauges
-  gauges = registry.snapshot()['gauges']
-  assert any(k.startswith('roofline_hbm_bytes_per_sec') for k in gauges)
-  assert any(k.startswith('roofline_flops_per_sec') for k in gauges)
-
-
-def test_roofline_report_math_and_cell_keys():
-  ceilings = {'device_kind': 'fake', 'hbm_bytes_per_sec': 1e9,
-              'flops_per_sec': 1e12}
-  cell = roofline_report(1e6, bytes_per_item=100.0, flops_per_item=50.0,
-                         ceilings=ceilings, item='edge')
-  # the acceptance cell contract: these keys ride every raced engine
-  assert {'pct_of_measured_hbm_ceiling', 'hbm_bytes_per_edge',
-          'flops_per_edge'} <= set(cell)
-  # 1e6 edges/s * 100 B/edge = 1e8 B/s of a 1e9 B/s ceiling = 10%
-  assert abs(cell['pct_of_measured_hbm_ceiling'] - 10.0) < 1e-6
-  # 1e6 * 50 = 5e7 FLOP/s of 1e12 = 0.005%
-  assert abs(cell['pct_of_measured_flop_ceiling'] - 0.005) < 1e-6
-  assert cell['bound'] == 'hbm'
-  assert roofline_report(1e6, ceilings=ceilings) == \
-      {'device_kind': 'fake'}  # nothing measurable -> no percentages
 
 
 # -- bench history + regression gate -------------------------------------

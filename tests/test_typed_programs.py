@@ -1,7 +1,7 @@
-"""The typed models share one plan since HGT came (models/plan.py): the
-R-GAT step, with the promise of parent-major edge slots and with it
-withheld, and the three SAGE steps still lower to the programs they
-were."""
+"""The five tiny step programs are pinned: the R-GAT step, with the
+promise of parent-major edge slots and with it withheld, and the three
+SAGE steps lower to the programs they were when a PR last meant to change
+them, so a PR that changes a cell's program knows it."""
 import hashlib
 import os
 import sys
@@ -14,19 +14,23 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 PARENT_STABLEHLO = {
     # sha256 of the tiny cells' step programs as StableHLO (no locations),
-    # under GLT_DEDUP=sort GLT_FUSED_HOP=1, read on the parent of the PR
-    # that brought HGT (bd94cd5): a PR that means to change one of these
-    # programs reads the hash anew and says so. c4's was read anew by the
-    # PR that gave the store's per-owner buckets ceil(b / P) slots and put
-    # the drain loop and its three counters into the four-chip step (it
-    # was a03c3aee...bad7d59); the other four are the parent's still,
-    # which is that PR's proof that their cells run the parent's program
-    'c1': '796cd17c7761caa22f05c36e824c0e4463ada262b71cbf0f6ad08ab11f391096',
-    'c4': 'f4a4b6defb06c356082034e36271ab47a6f059b96890156774adb49e409b5347',
-    'link': '4a6baa938beadfe9a51ca6f744bb73ba48aca6774a3d968395484e1a456f93fe',
-    'typed': '196fde82f9b6340e00476b67d6d05c1ab120e0e8b4a78d2abdab2521e6904d19',
+    # under GLT_DEDUP=sort GLT_FUSED_HOP=1: a PR that means to change one
+    # of these programs reads the hash anew and says so. All five were
+    # read anew by the PR that gave every per-batch step the counters
+    # output: ``(loss, counters)`` with the sampler's ``nodes_by_hop`` and
+    # ``edges_by_hop`` (one reduce of a mask a hop and relation, which
+    # lowering dropped while nothing read them), the link front's and the
+    # exchanging store's counters in the same dict. Before it they were
+    # c1 796cd17c...1f391096, c4 f4a4b6de...9e409b5347 (the PR of the
+    # store's per-owner buckets of ceil(b / P) slots), link
+    # 4a6baa93...456f93fe, typed 196fde82...e6904d19 and withheld
+    # 3c10d6a0...4b14d519 (the parent of the PR that brought HGT)
+    'c1': '8a3479caf76d897eae8f2094cf3e30d5875375d9668a89469d4f7b91e1a8f991',
+    'c4': '53ee57eda8ac5f8d8a43fc020def21f66a0493d8aa0d5dabe1c5db5ed1e6937c',
+    'link': '5753ec092c8344ea1b59ac01f67fb13cc94aede3eb438a29f4be08d933d72e12',
+    'typed': '27b86ebeaf7c3aa018e0d40ff218e6a3dd289b8bc6f680e0549207d1ba05094a',
     'typed_withheld':
-        '3c10d6a0010d98233f39aa085120f535d4d1e83dcb44d92ce01fb41d4b14d519',
+        '94b4a39e07413228d5d10f7186d214ab11c9313811318bbd92519d44f1685307',
 }
 
 
@@ -70,8 +74,7 @@ def _typed_text(withheld):
 @pytest.mark.parametrize('name', sorted(PARENT_STABLEHLO))
 def test_the_other_cells_tiny_steps_lower_to_the_parents(name, monkeypatch):
   """R-GAT's typed step, with the promise and with it withheld, and the
-  three SAGE steps lower to the StableHLO they had before the typed
-  models shared one plan."""
+  three SAGE steps lower to the StableHLO pinned above."""
   monkeypatch.setenv('GLT_DEDUP', 'sort')
   monkeypatch.setenv('GLT_FUSED_HOP', '1')
   sys.path.insert(0, REPO)
