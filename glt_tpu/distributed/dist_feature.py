@@ -19,6 +19,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..parallel.collectives import all_to_all, bucket_by_owner, unbucket
+from ..parallel.dist_feature import serve_live_chunks
 from ..utils import as_numpy
 from .dist_graph import _pb_dense
 
@@ -207,8 +208,15 @@ class DistFeature:
 
   # -- in-shard lookup (call inside shard_map) ---------------------------
 
+  @property
+  def in_place(self) -> bool:
+    """One partition holds every row on the device: ``lookup_local``
+    serves in place, the chunks of request slots that hold a request."""
+    return self.num_partitions == 1 and not self._spill
+
   def lookup_local(self, feat_shard, map_shard, pb, ids, valid,
-                   axis_name: Optional[str] = None, cold_shard=None):
+                   axis_name: Optional[str] = None, cold_shard=None,
+                   counters: bool = False):
     """feat_shard: [Rh, D] hot block; map_shard: [N]; pb: [N] — THIS
     device's routing book; ids/valid: [B]. Returns [B, D] (zeros where
     invalid). With host spill active and no ``cold_shard``, returns
@@ -223,22 +231,37 @@ class DistFeature:
     ships bucket ranks [k*cap, (k+1)*cap); the round count is the
     mesh-wide pmax of bucket occupancy over the cap) — no host replay
     of the routing, no retained books, and fused train steps can use
-    capped stores (see parallel.collectives.drain_rounds)."""
+    capped stores (see parallel.collectives.drain_rounds).
+
+    ``counters``: in place (one partition, nothing spilled) also return
+    ``dict(store_chunks=...)``, the chunks of request slots that held a
+    valid request and were gathered, of ``serve_chunks(B)``
+    (``parallel.dist_feature.serve_live_chunks``); the exchange counts
+    nothing and asking raises."""
     from ..parallel.collectives import bucket_payload, capped_drain
     ax = axis_name or self.axis
     n = self.num_partitions
     b = ids.shape[0]
-    if n == 1 and not self._spill:
+    if self.in_place:
       # one partition holds every row: the rows are read in request
       # order, with nothing to bucket, exchange or stitch; the result is
       # the bucketed path's bit for bit
-      with jax.named_scope('serve'):
+      def serve(ids, valid):
         rows = jnp.take(map_shard, jnp.clip(ids, 0, self.num_ids - 1),
                         mode='clip')
         ok = valid & (ids >= 0) & (rows >= 0)
         safe_rows = jnp.clip(rows, 0, self.hot_max - 1)
         rows_out = jnp.take(feat_shard, safe_rows, axis=0)
         return jnp.where(ok[:, None], rows_out, 0)
+
+      with jax.named_scope('serve'):
+        rows, chunks = serve_live_chunks(serve, ids, valid,
+                                         self.feature_dim, feat_shard.dtype)
+      return (rows, dict(store_chunks=chunks)) if counters else rows
+    if counters:
+      raise ValueError(
+          'a store over more than one partition, or one that spills, '
+          'exchanges: it gathers no chunks, so it has no counters')
     # stages as parallel/dist_feature.py names them, below the caller's
     # ``feature_store`` scope
     with jax.named_scope('bucket'):

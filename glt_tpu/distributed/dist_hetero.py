@@ -633,6 +633,8 @@ class DistHeteroTrainStep(StepCounters):
     #: keys them
     self.counter_node_types = tuple(budgets)
     self.counter_edge_types = tuple(edge_offsets)
+    # every node store serves in place: the step counts ``store_chunks``
+    self._stores_in_place = all(st.in_place for st in features.values())
     #: output rows each layer of the model computes for each type, filled
     #: when a program is traced (the node trim engages at trace time);
     #: None before, and for a model that does not say
@@ -736,7 +738,9 @@ class DistHeteroTrainStep(StepCounters):
     (batch, y, out_tables, counters): ``counters`` is what the sampler
     counted, ``nodes_by_hop`` ``[T, H + 1]`` and ``edges_by_hop``
     ``[R, H]`` in the order of ``counter_node_types`` and
-    ``counter_edge_types``."""
+    ``counter_edge_types``, and where every node store serves in place
+    ``store_chunks`` ``[T]``, the chunks of a type's request slots that
+    its store gathered."""
     from ..loader.transform import HeteroBatch
     g, axis, bs = self.g, self.axis, self.bs
     seed_type = self.seed_type
@@ -775,16 +779,23 @@ class DistHeteroTrainStep(StepCounters):
                                     for t in self.counter_node_types]),
             edges_by_hop=self._edges_by_hop(
                 {fk(e): v for e, v in out['num_sampled_edges'].items()}))
-      x_dict = {}
+      x_dict, chunks = {}, {}
       for t in types:
         fs = feat_shards[t]
         with scope('feature_store', 'gather', t):
           valid = (jnp.arange(out['node'][t].shape[0])
                    < out['node_count'][t])
-          x_dict[t] = feats[t].lookup_local(
+          rows = feats[t].lookup_local(
               fs['array'][0], fs['id2index'][0], fs['feat_pb'][0],
               jnp.maximum(out['node'][t], 0), valid, axis_name=axis,
-              cold_shard=fs['cold'][0] if 'cold' in fs else None)
+              cold_shard=fs['cold'][0] if 'cold' in fs else None,
+              counters=self._stores_in_place)
+          if self._stores_in_place:
+            rows, chunks[t] = rows[0], rows[1]['store_chunks']
+          x_dict[t] = rows
+      if self._stores_in_place:
+        counters['store_chunks'] = jnp.stack(
+            [chunks[t] for t in self.counter_node_types])
       with scope('feature_store'):
         y = jnp.take(labels[seed_type],
                      jnp.maximum(out['batch'], 0)[:bs])
@@ -1045,15 +1056,23 @@ class DistHeteroTrainStep(StepCounters):
     """The contract of :meth:`StepCounters.counter_slots`: by type the
     node slots of each hop (``node_hop_offsets_dict``), by relation the
     edge slots (``edge_hop_offsets_dict``), rows in the order of
-    ``counter_node_types`` and ``counter_edge_types``."""
+    ``counter_node_types`` and ``counter_edge_types``; where the node
+    stores serve in place, by type the chunks its request slots are
+    served in."""
+    from ..parallel.dist_feature import serve_chunks
     static = self._batch_static
-    return dict(
+    slots = dict(
         nodes_by_hop=np.stack([
             np.diff(static['node_hop_offsets_dict'][t], prepend=0)
             for t in self.counter_node_types]).astype(np.int64),
         edges_by_hop=np.stack([
             np.diff(static['edge_hop_offsets_dict'][e])
             for e in self.counter_edge_types]).astype(np.int64))
+    if self._stores_in_place:
+      slots['store_chunks'] = np.asarray(
+          [serve_chunks(self.node_budget[t])
+           for t in self.counter_node_types], np.int64)
+    return slots
 
   def scope_profile(self, params, opt_state, batches) -> dict:
     """Device time by layer of the per-batch step, from a profiler

@@ -84,7 +84,9 @@ class StepCounters:
     ``[H]``, by relation ``[R, H]`` in the order of
     ``counter_edge_types``, 0 for a hop a relation is not read in); a
     link step also what ``link_counters`` names, a step whose store
-    exchanges also what ``store_counters`` names."""
+    exchanges also what ``store_counters`` names, one whose store serves
+    in place ``store_chunks`` (the chunks of request slots it gathered:
+    a scalar, by type ``[T]``)."""
     import jax
     import numpy as np
     if not self._counted:

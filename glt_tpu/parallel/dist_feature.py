@@ -16,7 +16,8 @@ request vector a peer (exchange_cap), and as many rounds of the exchange
 as the fullest bucket needs, in the program. Collectives ride ICI.
 On a mesh of one shard there is one owner and the exchange would be the
 identity: lookup_local then serves the requests in place (the local
-gather alone), with the same rows bit for bit.
+gather alone), with the same rows bit for bit, and gathers only the
+chunks of request slots that hold a valid request (serve_live_chunks).
 """
 from __future__ import annotations
 
@@ -65,6 +66,56 @@ def overflow_lanes(owner_key: np.ndarray, n_shards: int, b: int,
     blk[order] = (osort < n_shards) & (pos >= cap)
     over[lo:lo + b] = blk
   return over
+
+
+#: request slots in one chunk of the in-place ``serve``: the least sum
+#: among 4,096 / 8,192 / 16,384 / 32,768 of a probe of the store alone on
+#: a v5e over the cells' request vectors (PERF.md section 6, PR 36: 18.48
+#: / 18.36 / 18.91 / 22.95 ms where the plain gathers read 67.05). A small
+#: chunk wins where a node type fills one chunk (the pads that share it
+#: are read), a large one where the live prefix is long (fewer trips).
+SERVE_CHUNK = 8192
+
+
+def serve_chunks(b: int) -> int:
+  """Chunks that ``serve_live_chunks`` cuts ``b`` request slots into."""
+  return max(1, -(-b // SERVE_CHUNK))
+
+
+def serve_live_chunks(serve, ids, valid, feature_dim: int, dtype):
+  """``(rows [B, D], chunks gathered)`` of an in-place store:
+  ``serve(ids, valid)`` (the store's gather of any number of slots, zero
+  where not valid) run on the chunks of ``SERVE_CHUNK`` consecutive
+  request slots that hold a valid request, and on no other. A fused
+  step's requests are a live prefix behind which two slots in three
+  (GraphSAGE) to 24 in 25 (the typed steps) are pads, and a pad costs a
+  row read like any request. The flags come from ``valid`` alone, so
+  the rows are ``serve(ids, valid)``'s bit for bit for any mask: a
+  scattered one flags more chunks. The rows fill a zero ``[B, D]``
+  buffer in place, in one ``lax.while_loop`` over the flagged chunks;
+  the last chunk of a ``B`` that ``SERVE_CHUNK`` does not divide starts
+  at ``B - SERVE_CHUNK`` and writes its neighbour's rows again. Up to
+  ``SERVE_CHUNK`` slots are one chunk and one plain gather."""
+  b = ids.shape[0]
+  n, c = serve_chunks(b), SERVE_CHUNK
+  if n == 1:
+    return serve(ids, valid), valid.any().astype(jnp.int32)
+  flags = jnp.pad(valid, (0, n * c - b)).reshape(n, c).any(axis=1)
+  count = flags.sum().astype(jnp.int32)
+  live = jnp.nonzero(flags, size=n, fill_value=0)[0].astype(jnp.int32)
+  starts = jnp.minimum(live * c, b - c)
+
+  def gather(carry):
+    k, rows = carry
+    lo = starts[k]
+    got = serve(jax.lax.dynamic_slice(ids, (lo,), (c,)),
+                jax.lax.dynamic_slice(valid, (lo,), (c,)))
+    return k + 1, jax.lax.dynamic_update_slice(rows, got, (lo, 0))
+
+  _, rows = jax.lax.while_loop(
+      lambda carry: carry[0] < count, gather,
+      (jnp.int32(0), jnp.zeros((b, feature_dim), dtype)))
+  return rows, count
 
 
 def require_device_resident(store, ctx: str) -> None:
@@ -223,13 +274,14 @@ class ShardedFeature:
         compute_on('device_host') gather instead of lookup()'s host
         phase. Fused train steps pass ``self.cold_array``'s shard here.
 
-      counters: over more than one shard, also return what the exchange
-        counted (a fused step hands it out as
+      counters: also return what the store counted. Over more than one
+        shard, what the exchange did (a fused step hands it out as
         ``SPMDSageTrainStep.store_counters()``): ``store_rounds`` (the
         drain's round count, the same on every device),
         ``store_bucket_max`` (requests in this device's fullest
         per-owner bucket) and ``store_requests`` (its valid requests).
-        In place there is nothing to count and asking raises.
+        In place ``store_chunks``: the chunks of request slots that
+        held a valid request and were gathered, of ``serve_chunks(B)``.
 
     Returns [B, D]; invalid slots are zero. With ``counters``:
     ``(rows, counters)``.
@@ -237,7 +289,9 @@ class ShardedFeature:
     On a mesh of one shard the owner of every row is this device, so the
     requests are served IN PLACE, in request order: no bucketing by
     owner, no exchange, no stitch, and ``bucket_cap`` has no rounds to
-    drain (it is ignored; ``lookup()`` still pins it). The rows are the
+    drain (it is ignored; ``lookup()`` still pins it). Where nothing
+    spills, only the chunks of request slots that hold a valid request
+    are gathered (``serve_live_chunks``). The rows are the
     exchange's bit for bit, in every form (resident, hot-only spill with
     cold lanes zero, ``cold_shard``); a capped exchange differs in one
     bit only: its drain adds rounds up, which turns a stored -0.0 into
@@ -260,13 +314,23 @@ class ShardedFeature:
     if not self.in_place:
       return self._lookup_exchange(local_shard, ids, valid, ax, cold_shard,
                                    counters)
-    if counters:
-      raise ValueError(
-          'a store on one shard serves in place: it buckets nothing, so '
-          'it has no counters')
     with scope('feature_store', 'serve'):
-      return self._serve(local_shard, jnp.where(valid, ids, -1), ax,
-                         cold_shard)
+      if self._spill:
+        rows = self._serve(local_shard, jnp.where(valid, ids, -1), ax,
+                           cold_shard)
+        chunks = valid.any().astype(jnp.int32)
+      else:
+        rows, chunks = serve_live_chunks(
+            lambda i, v: self._serve(local_shard, jnp.where(v, i, -1), ax,
+                                     None),
+            ids, valid, self.feature_dim, local_shard.dtype)
+    return (rows, dict(store_chunks=chunks)) if counters else rows
+
+  def serve_chunks(self, b: int) -> int:
+    """Chunks the in-place ``serve`` cuts ``b`` request slots into: what
+    a step's ``store_chunks`` counter is read against. A store that
+    spills gathers them as one."""
+    return 1 if self._spill else serve_chunks(b)
 
   def _serve(self, local_shard, req_in, ax, cold_shard):
     """Rows of the local block for the requests ``req_in`` (any shape;

@@ -343,11 +343,11 @@ class SPMDSageTrainStep(StepCounters):
     ``counters`` one flat dict of what the step counted
     (:meth:`counters` names every entry): the sampler's new nodes and
     valid edges by hop, a link step's negatives and distinct seeds, and
-    what a store that exchanges counted."""
+    what the store counted (its exchange, or in place the chunks of
+    request slots it gathered)."""
     feature, model, tx, axis = self.feature, self.model, self.tx, self.axis
     fanouts, bs = self.fanouts, self.bs
     with_edge, link = self.with_edge, self._link
-    store_counts = not feature.in_place
     loss_of = _link_loss(bs) if link else _node_loss(bs)
     one_hop = lambda ids, fanout, k, mask: sample_neighbors(
         indptr, indices, ids, fanout, k, seed_mask=mask)
@@ -377,12 +377,10 @@ class SPMDSageTrainStep(StepCounters):
             counted['seeds'] = seeds
       with scope('feature_store'):
         node_valid = jnp.arange(out['node'].shape[0]) < out['node_count']
-        x = feature.lookup_local(
+        x, store_stats = feature.lookup_local(
             feat_shard, jnp.maximum(out['node'], 0), node_valid,
-            axis_name=axis, cold_shard=cold_shard, counters=store_counts)
-        if store_counts:
-          x, store_stats = x
-          counted.update(store_stats)
+            axis_name=axis, cold_shard=cold_shard, counters=True)
+        counted.update(store_stats)
         y = None if link else jnp.take(
             labels, jnp.maximum(out['batch'], 0)[:bs])
       batch = Batch(
@@ -790,7 +788,8 @@ class SPMDSageTrainStep(StepCounters):
     (``edge_hop_offsets``) of a device's batch; a link step's
     ``NEG_TRIALS x B`` proposals, ``B`` negatives and ``4B`` seed slots;
     over more than one shard the drain's most rounds, a per-owner
-    bucket's ``exchange_cap(b)`` slots and the ``b`` request slots."""
+    bucket's ``exchange_cap(b)`` slots and the ``b`` request slots; on
+    one shard the chunks the ``b`` request slots are served in."""
     static = self._batch_static
     slots = dict(
         nodes_by_hop=np.diff(static['node_hop_offsets'], prepend=0),
@@ -798,8 +797,10 @@ class SPMDSageTrainStep(StepCounters):
     if self._link:
       slots.update(negatives_rejected=NEG_TRIALS * self.bs,
                    negatives_padded=self.bs, seed_unique=self.seed_slots)
-    if not self.feature.in_place:
-      b = static['node_hop_offsets'][-1]
+    b = static['node_hop_offsets'][-1]
+    if self.feature.in_place:
+      slots.update(store_chunks=self.feature.serve_chunks(b))
+    else:
       cap = self.feature.exchange_cap(b)
       slots.update(store_rounds=-(-b // cap), store_bucket_max=cap,
                    store_requests=b)

@@ -16,6 +16,8 @@ from glt_tpu.parallel import make_mesh
 from glt_tpu.partition import RandomPartitioner
 
 from fixtures import ring_edges
+from test_parallel import (CHUNK, CHUNK_CASES, bits, chunk_case,
+                           chunks_with_a_valid_slot)
 
 N_NODES = 40
 N_PARTS = 8
@@ -1261,3 +1263,68 @@ def test_dist_hetero_train_step_with_host_offloaded_spill(
     return out
 
   np.testing.assert_allclose(losses(0.3), losses(None), rtol=1e-6)
+
+
+# -- one partition: only the chunks of slots that hold a request are
+# gathered ------------------------------------------------------------------
+
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+@pytest.mark.parametrize('case', CHUNK_CASES)
+def test_one_partition_gathers_live_chunks_alone_and_the_plain_gathers_rows(
+    case, dtype, monkeypatch):
+  from jax.sharding import PartitionSpec as P
+  from glt_tpu.parallel import dist_feature
+  n, d = 100, 8
+  rng = np.random.default_rng(59)
+  feats = rng.normal(size=(n - 10, d)).astype(np.float32)
+  feats[::9] = -0.0
+  # ten ids have no row in the partition (id2index -1): zero rows
+  id2index = np.full(n, -1, np.int32)
+  id2index[rng.permutation(n)[:n - 10]] = np.arange(n - 10)
+  st = DistFeature(make_mesh(1), [(feats, id2index)], np.zeros(n, np.int32),
+                   n, dtype=jnp.dtype(dtype))
+  assert st.in_place
+  ids, valid = chunk_case(case, rng, n)
+  b = ids.shape[0]
+
+  def run(chunk):
+    def form(f, m, pb, i, v):
+      rows, counted = st.lookup_local(f[0], m[0], pb[0], i, v,
+                                      counters=True)
+      return rows, counted['store_chunks'][None]
+    monkeypatch.setattr(dist_feature, 'SERVE_CHUNK', chunk)
+    return jax.jit(jax.shard_map(
+        form, mesh=st.mesh, in_specs=(P(st.axis),) * 5,
+        out_specs=P(st.axis), check_vma=False))(
+            st.array, st.id2index, st.feat_pb, jnp.asarray(ids),
+            jnp.asarray(valid))
+
+  plain, one = run(b)          # one chunk: the plain gather
+  rows, chunks = run(CHUNK)
+  assert rows.dtype == plain.dtype == jnp.dtype(dtype)
+  np.testing.assert_array_equal(bits(rows), bits(plain))
+  local = id2index[np.clip(ids, 0, n - 1)]
+  asked = valid & (ids >= 0) & (local >= 0)
+  want = np.where(asked[:, None],
+                  np.asarray(st.array)[0][np.clip(local, 0, n - 11)],
+                  np.zeros((), jnp.dtype(dtype)))
+  np.testing.assert_array_equal(bits(rows), bits(want))
+  assert int(chunks[0]) == chunks_with_a_valid_slot(valid)
+  assert int(one[0]) == int(valid.any())
+  # the host-side API goes through the same form
+  np.testing.assert_array_equal(
+      bits(st.lookup(ids, jnp.asarray(valid))), bits(want))
+
+
+def test_a_store_that_exchanges_has_no_chunk_counter(mesh, dist_datasets):
+  from jax.sharding import PartitionSpec as P
+  st = DistFeature.from_dist_datasets(mesh, dist_datasets)
+  assert not st.in_place
+  with pytest.raises(ValueError, match='it gathers no chunks'):
+    jax.shard_map(
+        lambda f, m, pb, i, v: st.lookup_local(
+            f[0], m[0], pb[0], i, v, counters=True)[0],
+        mesh=st.mesh, in_specs=(P(st.axis),) * 5, out_specs=P(st.axis),
+        check_vma=False)(st.array, st.id2index, st.feat_pb,
+                         jnp.zeros((N_PARTS * 8,), jnp.int32),
+                         jnp.ones((N_PARTS * 8,), bool))

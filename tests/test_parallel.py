@@ -389,21 +389,127 @@ def test_one_shard_lookup_in_place_equals_the_exchange_bit_for_bit(case):
       np.where(asked[:, None], rows, 0))
 
 
+# -- one shard: only the chunks of slots that hold a request are gathered --
+
+CHUNK = 16   # SERVE_CHUNK of the chunk tests (the code's is 8,192)
+
+
+def chunk_case(case, rng, n, c=CHUNK):
+  """``(ids [B] int32, valid [B])`` of one case of the chunked serve."""
+  b = {'b_not_a_multiple': 5 * c + 3, 'b_not_a_multiple_prefix': 5 * c + 3,
+       'b_less_than_c': c - 3}.get(case, 6 * c)
+  ids = rng.integers(0, n, size=b).astype(np.int32)
+  valid = np.zeros(b, bool)
+  if case == 'one_valid':
+    valid[2 * c + 5] = True
+  elif case.startswith('prefix_'):
+    live = {'c_minus_1': c - 1, 'c': c, 'c_plus_1': c + 1,
+            'b': b}[case[len('prefix_'):]]
+    valid[:live] = True
+  elif case == 'b_not_a_multiple_prefix':
+    valid[:2 * c + 1] = True    # the overlapping last chunk stays empty
+  elif case in ('b_not_a_multiple', 'b_less_than_c'):
+    valid[:] = rng.random(b) < 0.5
+    valid[-1] = True            # the last chunk is live
+  elif case == 'scattered_with_an_empty_chunk':
+    valid[:] = rng.random(b) < 0.3
+    valid[2 * c:3 * c] = False
+  elif case == 'ids_out_of_range_in_a_live_chunk':
+    valid[:c + 4] = True
+    ids[1:c + 4:5] = -3
+    ids[2:c + 4:5] = n + 5
+    ids[3:c + 4:5] = np.iinfo(np.int32).max
+  else:
+    assert case == 'none_valid'
+  return ids, valid
+
+
+CHUNK_CASES = [
+    'none_valid', 'one_valid', 'prefix_c_minus_1', 'prefix_c',
+    'prefix_c_plus_1', 'prefix_b', 'b_not_a_multiple',
+    'b_not_a_multiple_prefix', 'b_less_than_c',
+    'scattered_with_an_empty_chunk', 'ids_out_of_range_in_a_live_chunk']
+
+
+def chunks_with_a_valid_slot(valid, c=CHUNK):
+  return sum(bool(valid[lo:lo + c].any())
+             for lo in range(0, valid.shape[0], c))
+
+
+def bits(rows):
+  rows = np.asarray(rows)
+  return rows.view({2: np.uint16, 4: np.uint32}[rows.dtype.itemsize])
+
+
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+@pytest.mark.parametrize('case', CHUNK_CASES)
+def test_one_shard_gathers_live_chunks_alone_and_the_plain_gathers_rows(
+    case, dtype, monkeypatch):
+  from jax.sharding import PartitionSpec as P
+  from glt_tpu.parallel import dist_feature
+  monkeypatch.setattr(dist_feature, 'SERVE_CHUNK', CHUNK)
+  n, d = 100, 8
+  rng = np.random.default_rng(53)
+  feats = rng.normal(size=(n, d)).astype(np.float32)
+  feats[::9] = -0.0
+  sf = ShardedFeature(feats, make_mesh(1), dtype=jnp.dtype(dtype))
+  ids, valid = chunk_case(case, rng, n)
+  b = ids.shape[0]
+
+  def run(form):
+    return jax.jit(jax.shard_map(
+        form, mesh=sf.mesh, in_specs=(P(sf.axis),) * 3,
+        out_specs=P(sf.axis), check_vma=False))(
+            sf.array, jnp.asarray(ids), jnp.asarray(valid))
+
+  def chunked(shard, i, v):
+    rows, counted = sf.lookup_local(shard, i, v, counters=True)
+    return rows, counted['store_chunks'][None]
+
+  rows, chunks = run(chunked)
+  plain = run(lambda shard, i, v: sf._serve(
+      shard, jnp.where(v, i, -1), sf.axis, None))
+  assert rows.dtype == plain.dtype == jnp.dtype(dtype)
+  assert rows.shape == (b, d)
+  np.testing.assert_array_equal(bits(rows), bits(plain))
+  asked = valid & (ids >= 0) & (ids < n)
+  want = np.where(asked[:, None], np.asarray(sf.array)[np.clip(ids, 0, n - 1)],
+                  np.zeros((), jnp.dtype(dtype)))
+  np.testing.assert_array_equal(bits(rows), bits(want))
+  assert int(chunks[0]) == chunks_with_a_valid_slot(valid)
+  assert sf.serve_chunks(b) == max(1, -(-b // CHUNK))
+  # the host-side API goes through the same form
+  np.testing.assert_array_equal(
+      bits(sf.lookup(ids, jnp.asarray(valid))), bits(want))
+
+
 @pytest.mark.parametrize('chips', [1, 2, 8])
-def test_lookup_local_exchanges_only_over_more_than_one_shard(chips):
+def test_lookup_local_exchanges_only_over_more_than_one_shard(
+    chips, monkeypatch):
   from jax.sharding import PartitionSpec as P
   n, d, b = 96, 4, 16
   feats = np.arange(n * d, dtype=np.float32).reshape(n, d)
   sf = ShardedFeature(feats, make_mesh(chips))
-  fn = jax.jit(jax.shard_map(
-      sf.lookup_local, mesh=sf.mesh, in_specs=(P(sf.axis),) * 3,
-      out_specs=P(sf.axis), check_vma=False))
   ids = jnp.asarray(np.arange(chips * b, dtype=np.int32) % n)
-  text = fn.lower(sf.array, ids, jnp.ones(ids.shape, bool)).as_text()
+
+  def lowered():
+    return jax.jit(jax.shard_map(
+        sf.lookup_local, mesh=sf.mesh, in_specs=(P(sf.axis),) * 3,
+        out_specs=P(sf.axis), check_vma=False)).lower(
+            sf.array, ids, jnp.ones(ids.shape, bool)).as_text()
+
+  text = lowered()
   in_place = chips == 1
   assert text.count('all_to_all') == (0 if in_place else 2), text
   assert ('stablehlo.sort' in text) != in_place   # the owner argsort
   assert _in_place_gauge() == float(in_place)
+  # the chunk loop is the in-place form's alone, past SERVE_CHUNK slots:
+  # an exchange serves its buckets by the plain gather (uncapped, so
+  # with no drain loop either: no loop at all)
+  assert 'stablehlo.while' not in text
+  from glt_tpu.parallel import dist_feature
+  monkeypatch.setattr(dist_feature, 'SERVE_CHUNK', b // 2)
+  assert ('stablehlo.while' in lowered()) == in_place
 
 
 # -- more shards: buckets of b / P slots and the drain behind them ---------
@@ -549,18 +655,44 @@ def test_step_hands_back_the_store_counters_over_four_shards():
   assert len(out) == 3 and np.asarray(out[2]).shape == (2, 4)
 
 
-def test_step_on_one_shard_serves_in_place_and_counts_nothing():
-  step, params, opt, seeds, n_valid, keys = _tiny_step(1)
-  out = step(params, opt, seeds, n_valid, keys)
-  assert len(out) == 3 and step.feature.in_place
-  assert _in_place_gauge() == 1.0
+def test_step_on_one_shard_serves_in_place_and_counts_its_chunks(
+    monkeypatch):
+  from glt_tpu.parallel import dist_feature
+  code_chunk = dist_feature.SERVE_CHUNK
+  assert code_chunk > 640
+
+  def two_steps(chunk):
+    monkeypatch.setattr(dist_feature, 'SERVE_CHUNK', chunk)
+    step, params, opt, seeds, n_valid, keys = _tiny_step(1)
+    losses = []
+    for _ in range(2):
+      params, opt, loss = step(params, opt, seeds, n_valid, keys)
+      losses.append(np.asarray(loss))
+    return step, losses, jax.tree.map(np.asarray, params)
+
+  b = 64 * (1 + 3 + 6)   # sample_budget(64, [3, 2]) slots
+  step, losses, params = two_steps(128)
+  assert step.feature.in_place and _in_place_gauge() == 1.0
+  counted, slots = step.counters(), step.counter_slots()
+  assert sorted(set(counted) - {'step'}) == [
+      'edges_by_hop', 'nodes_by_hop', 'store_chunks']   # no store_rounds
+  assert counted['store_chunks'].shape == (2, 1)
+  assert counted['store_chunks'].dtype == np.int32
+  assert int(slots['store_chunks']) == 5 == -(-b // 128)
+  # the requests are a live prefix of node_count slots: its chunks
+  node_count = counted['nodes_by_hop'].sum(-1)
+  np.testing.assert_array_equal(counted['store_chunks'],
+                                -(-node_count // 128))
+  assert (counted['store_chunks'] < 5).all()   # 64 nodes: a chunk is pads
+  # what the exchange counts is not there to read
   with pytest.raises(RuntimeError, match='serves in place'):
     step.store_counters()
-  from jax.sharding import PartitionSpec as P
-  sf = step.feature
-  with pytest.raises(ValueError, match='serves in place'):
-    jax.shard_map(
-        lambda s, i, v: sf.lookup_local(s, i, v, counters=True)[0],
-        mesh=sf.mesh, in_specs=(P(sf.axis),) * 3, out_specs=P(sf.axis),
-        check_vma=False)(sf.array, jnp.zeros((8,), jnp.int32),
-                         jnp.ones((8,), bool))
+  # under SERVE_CHUNK slots the requests are one chunk, the parent's
+  # plain gather: the training is the same bit for bit
+  plain, plain_losses, plain_params = two_steps(code_chunk)
+  assert int(plain.counter_slots()['store_chunks']) == 1
+  assert (plain.counters()['store_chunks'] == 1).all()
+  for got, want in zip(losses, plain_losses):
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+  jax.tree.map(lambda got, want: np.testing.assert_array_equal(
+      got.view(np.uint32), want.view(np.uint32)), params, plain_params)

@@ -470,3 +470,41 @@ def test_layer_groups_counter_and_gauge():
   np.testing.assert_allclose(l1, l2, rtol=1e-6)
   for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
     np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-7)
+
+
+def test_step_on_one_partition_counts_its_chunks_and_trains_alike(
+    monkeypatch):
+  """The typed twin of ``test_parallel``'s one-shard step test: every
+  type's store gathers the chunks of its request slots that hold a node,
+  and the training is the plain gather's bit for bit."""
+  from glt_tpu.parallel import dist_feature
+  code_chunk = dist_feature.SERVE_CHUNK
+  edges, feats, labels = typed_graph()
+
+  def two_steps(chunk):
+    monkeypatch.setattr(dist_feature, 'SERVE_CHUNK', chunk)
+    step, tx = build_step(edges, feats, labels, 2, 2)
+    losses, _, params = train(step, tx, step.init_params(jax.random.key(0)),
+                              steps=2)
+    return step, losses, params
+
+  step, losses, params = two_steps(16)
+  counted, slots = step.counters(), step.counter_slots()
+  types = step.counter_node_types
+  assert sorted(set(counted) - {'step'}) == [
+      'edges_by_hop', 'nodes_by_hop', 'store_chunks']
+  assert counted['store_chunks'].shape == (2, 1, len(types))
+  assert slots['store_chunks'].tolist() == [
+      max(1, -(-step.node_budget[t] // 16)) for t in types]
+  assert max(slots['store_chunks']) > 1   # the loop is in the program
+  # a type's requests are a live prefix of node_count slots: its chunks
+  node_count = counted['nodes_by_hop'].sum(-1)
+  np.testing.assert_array_equal(counted['store_chunks'],
+                                -(-node_count // 16))
+  assert (counted['store_chunks'] <= slots['store_chunks']).all()
+  assert counted['store_chunks'].sum() < 2 * slots['store_chunks'].sum()
+  plain, plain_losses, plain_params = two_steps(code_chunk)
+  assert plain.counter_slots()['store_chunks'].tolist() == [1] * len(types)
+  assert losses == plain_losses
+  jax.tree.map(lambda got, want: np.testing.assert_array_equal(
+      got.view(np.uint32), want.view(np.uint32)), params, plain_params)

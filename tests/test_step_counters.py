@@ -60,7 +60,7 @@ def test_sage_steps_count_what_the_numpy_sampler_finds(chips):
   assert counted['step'].tolist() == list(range(warm))
   assert counted['step'].dtype == np.int64
   assert sorted(set(counted) - {'step'}) == HOPS + (
-      sorted(STORE_COUNTERS) if chips > 1 else [])
+      sorted(STORE_COUNTERS) if chips > 1 else ['store_chunks'])
   hops = len(s.fanout)
   assert counted['nodes_by_hop'].shape == (warm, chips, hops + 1)
   assert counted['edges_by_hop'].shape == (warm, chips, hops)
@@ -95,7 +95,10 @@ def test_sage_slots_are_the_hop_budgets(chips):
     assert slots[name].shape == counted[name].shape[2:]
     assert (counted[name] <= slots[name]).all()
   if chips == 1:
-    assert sorted(slots) == HOPS
+    # one shard serves in place, its request slots in one chunk here
+    assert sorted(slots) == HOPS + ['store_chunks']
+    assert int(slots['store_chunks']) == 1
+    assert (counted['store_chunks'] == 1).all()
     return
   budget = int(slots['nodes_by_hop'].sum())
   cap = t.feature.exchange_cap(budget)
@@ -166,7 +169,12 @@ def test_typed_steps_count_what_their_kept_sample_holds(name):
       by_hop = [int(mask[a:b].sum()) for a, b in zip(offsets[e][:-1],
                                                     offsets[e][1:])]
       assert edges[0, i].tolist() == by_hop
-    assert sorted(set(counted) - {'step'}) == HOPS
+    assert sorted(set(counted) - {'step'}) == HOPS + ['store_chunks']
+    # a type's request slots are one chunk at this size, gathered where
+    # the type holds a node
+    assert slots['store_chunks'].tolist() == [1] * types
+    assert counted['store_chunks'][-1, 0].tolist() == [
+        int(kept['node_count'][k][0] > 0) for k in t.counter_node_types]
 
 
 def test_a_typed_step_counts_alike_with_and_without_keep_sample():
@@ -183,7 +191,7 @@ def test_a_typed_step_counts_alike_with_and_without_keep_sample():
     step(params, tx.init(params), seeds, nv, key)
   assert plain.last_sample is None
   got, want = plain.counters(), kept.counters()
-  assert got.keys() == want.keys() == {'step', *HOPS}
+  assert got.keys() == want.keys() == {'step', *HOPS, 'store_chunks'}
   for k in got:
     np.testing.assert_array_equal(got[k], want[k])
   for i, t in enumerate(kept.counter_node_types):

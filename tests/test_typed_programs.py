@@ -24,13 +24,20 @@ PARENT_STABLEHLO = {
     # c1 796cd17c...1f391096, c4 f4a4b6de...9e409b5347 (the PR of the
     # store's per-owner buckets of ceil(b / P) slots), link
     # 4a6baa93...456f93fe, typed 196fde82...e6904d19 and withheld
-    # 3c10d6a0...4b14d519 (the parent of the PR that brought HGT)
-    'c1': '8a3479caf76d897eae8f2094cf3e30d5875375d9668a89469d4f7b91e1a8f991',
+    # 3c10d6a0...4b14d519 (the parent of the PR that brought HGT).
+    # The four one-chip programs were read anew by the PR of the store's
+    # chunked serve (one more counter out, ``store_chunks``: at this size
+    # a type's request slots are one chunk and the gather is the plain
+    # one); before it they were c1 8a3479ca...e1a8f991, link
+    # 5753ec09...d933d72e12, typed 27b86ebe...ba05094a and withheld
+    # 94b4a39e...f1685307. c4's exchange is that PR's parent's, text for
+    # text: its hash stands.
+    'c1': '77dfcba41ae1e3a7347f32d8b7a4fcf53de596c781cc4de5e3219ac6b1c27162',
     'c4': '53ee57eda8ac5f8d8a43fc020def21f66a0493d8aa0d5dabe1c5db5ed1e6937c',
-    'link': '5753ec092c8344ea1b59ac01f67fb13cc94aede3eb438a29f4be08d933d72e12',
-    'typed': '27b86ebeaf7c3aa018e0d40ff218e6a3dd289b8bc6f680e0549207d1ba05094a',
+    'link': '3b9a9823673d7ebbcd4ef7eeff9f4a48d76bf4c9583b0b3128036737195b22a2',
+    'typed': '0b627d6e7dee6ccfcc773ff3ed181d614902cdc464b6942d46db9181c4fa8de0',
     'typed_withheld':
-        '94b4a39e07413228d5d10f7186d214ab11c9313811318bbd92519d44f1685307',
+        '6eb0e928de897abf7c93857516190729f9e8fb5a3fba7cb8106af5a9ec114b53',
 }
 
 
