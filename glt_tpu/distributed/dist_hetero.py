@@ -21,6 +21,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs.device import StepCounters, register_step_program, scope
+from ..ops.negative import random_negative_sample
 from ..ops.pipeline import (hetero_edge_hop_offsets, hetero_hop_fanouts,
                             make_dedup_tables,
                             multihop_sample_hetero)
@@ -30,6 +31,10 @@ from ..utils import as_numpy
 from ..utils.rng import RandomSeedManager
 from .dist_graph import DistGraph
 from .dist_neighbor_sampler import make_dist_one_hop
+
+#: rounds of proposals a strict negative is drawn over inside an
+#: edge-seeded step (parallel/train.py's, the reference sampler's default)
+NEG_TRIALS = 5
 
 
 class DistHeteroGraph:
@@ -291,8 +296,18 @@ def dist_hetero_graph_from_partitions_multihost(
   return out
 
 
+def _seed_caps(batch_size: int, seed_type) -> Dict[NodeType, int]:
+  """``{type: seed slots}``: ``batch_size`` seeds of one type, or of each
+  of a tuple of types (a type named twice holds both blocks)."""
+  caps: Dict[NodeType, int] = {}
+  for t in ((seed_type,) if isinstance(seed_type, str) else seed_type):
+    caps[t] = caps.get(t, 0) + batch_size
+  return caps
+
+
 class DistHeteroNeighborSampler:
-  """SPMD hetero sampling: per-device seed batches of one seed type."""
+  """SPMD hetero sampling: per-device seed batches of one seed type, or
+  inside the train step of the two ends of a seed relation."""
 
   def __init__(self, graph: DistHeteroGraph, num_neighbors,
                with_edge: bool = False, with_weight: bool = False,
@@ -358,10 +373,11 @@ class DistHeteroNeighborSampler:
       out[etype] = (row_t, col_t)
     return out
 
-  def _caps(self, batch_size: int, seed_type: NodeType):
+  def _caps(self, batch_size: int, seed_type):
     trav = self._trav()
     types = list(self.g.node_counts)
-    caps = [{t: (batch_size if t == seed_type else 0) for t in types}]
+    seeded = _seed_caps(batch_size, seed_type)
+    caps = [{t: seeded.get(t, 0) for t in types}]
     for h in range(self.num_hops):
       nxt = {t: 0 for t in types}
       for etype, (row_t, col_t) in trav.items():
@@ -370,10 +386,14 @@ class DistHeteroNeighborSampler:
     budgets = {t: max(1, sum(c[t] for c in caps)) for t in types}
     return caps, budgets
 
-  def _make_device_core(self, batch_size: int, seed_type: NodeType):
+  def _make_device_core(self, batch_size: int, seed_type):
     """Returns device_core(shards, seeds, n_valid_scalar, key, flat_tables)
     -> (result dict, out_tables) with NO leading shard dims — reusable by
-    the train step."""
+    the train step. ``seed_type`` a tuple of node types (the ends of a
+    seed relation, ``batch_size`` slots each): ``seeds`` and the last
+    argument ``seed_mask`` are dicts by type, and so are the result's
+    ``batch`` and ``seed_labels``."""
+    many = not isinstance(seed_type, str)
     g = self.g
     trav = self._trav()
     caps, budgets = self._caps(batch_size, seed_type)
@@ -387,7 +407,7 @@ class DistHeteroNeighborSampler:
               if any(caps[h][trav[e][0]] * abs(self.num_neighbors[e][h])
                      > 0 for h in range(self.num_hops))]
 
-    def device_core(shards, seeds, n_valid, key, tables):
+    def device_core(shards, seeds, n_valid, key, tables, seed_mask=None):
       one_hops = {}
       for e in etypes:
         sh = shards[e]
@@ -405,6 +425,11 @@ class DistHeteroNeighborSampler:
                                             1)))
 
       trav_active = {e: trav[e] for e in etypes}
+      if many:
+        return multihop_sample_hetero(
+            one_hops, trav_active, self.num_neighbors, self.num_hops,
+            caps, budgets, seeds, {t: n_valid for t in seeds}, key,
+            tables, with_edge=self.with_edge, seed_mask=seed_mask)
       result, out_tables = multihop_sample_hetero(
           one_hops, trav_active, self.num_neighbors, self.num_hops,
           caps, budgets, {seed_type: seeds},
@@ -523,19 +548,71 @@ class DistHeteroNeighborSampler:
     return out
 
 
+def _update_by_group(tx, grads, opt_state, params, tables):
+  """``tx.update`` and ``apply_updates`` in two calls, under the scopes
+  ``tables`` and ``rest``: the leaves under a top-level collection named
+  in ``tables`` (a model's ``table_params``: its embedding tables), then
+  all others. Each call sees the other group's leaves as ``None``, in
+  the gradient, the parameters and every part of the state that is
+  shaped like the parameters, and the halves are joined again. For an
+  optimizer that treats each leaf alone (Adam: the recipe's) the two
+  calls compute, leaf for leaf, what one call does, every row of a table
+  included; one that couples leaves (a clip by the global norm) would
+  see each group alone."""
+  import optax
+  none = lambda x: x is None
+  shape = jax.tree.structure(params)
+  like = lambda t: jax.tree.structure(t, is_leaf=none) == shape
+  in_tables = lambda path: any(getattr(k, 'key', None) in tables
+                               for k in path)
+
+  def part(tree, keep):
+    return jax.tree_util.tree_map_with_path(
+        lambda path, a: a if in_tables(path) == keep else None, tree)
+
+  def join(a, b):
+    return jax.tree.map(lambda x, y: y if x is None else x, a, b,
+                        is_leaf=none)
+
+  halves = []
+  for name, keep in (('tables', True), ('rest', False)):
+    with jax.named_scope(name):
+      state = jax.tree.map(lambda t: part(t, keep) if like(t) else t,
+                           opt_state, is_leaf=like)
+      p = part(params, keep)
+      updates, state = tx.update(part(grads, keep), state, p)
+      halves.append((optax.apply_updates(p, updates), state))
+  (p_tab, s_tab), (p_rest, s_rest) = halves
+  return join(p_tab, p_rest), jax.tree.map(
+      lambda a, b: join(a, b) if like(a) else a, s_tab, s_rest,
+      is_leaf=like)
+
+
 def _hetero_update(model, tx, axis, bs, params, opt_state, batch, y,
                    n_valid):
   """Forward/backward + gradient pmean + optimizer update for one typed
   batch: the training tail shared by the per-batch step and the
   superstep scan (identical op sequence = loss parity), under the layer
-  scopes of parallel/train.py::_sage_update."""
+  scopes of parallel/train.py::_sage_update. ``y`` the seeds' labels, or
+  ``None`` for a batch of edge seeds: the model gives the logits of the
+  ``2 * bs`` pairs of ``edge_label_index`` and the loss is the binary
+  cross-entropy against ``edge_label`` (parallel/train.py::_link_loss's
+  form; a pair past ``n_valid`` and its negative are left out). A model
+  that names ``table_params`` has them updated under ``update/tables``
+  and the rest under ``update/rest``."""
   import optax
 
   def loss_fn(p):
     with jax.named_scope('forward'):
       logits = model.apply(p, batch)
       mask = jnp.arange(bs) < n_valid
-      l = optax.softmax_cross_entropy_with_integer_labels(logits, y)
+      if y is None:
+        with jax.named_scope('link_loss'):
+          mask = jnp.tile(mask, 2)
+          l = optax.sigmoid_binary_cross_entropy(
+              logits, batch.metadata['edge_label'])
+      else:
+        l = optax.softmax_cross_entropy_with_integer_labels(logits, y)
       return jnp.where(mask, l, 0).sum() / jnp.maximum(mask.sum(), 1)
 
   with scope('model_step'):
@@ -543,9 +620,14 @@ def _hetero_update(model, tx, axis, bs, params, opt_state, batch, y,
   with scope('collectives', 'grad_sync'):
     grads = jax.lax.pmean(grads, axis)
     loss = jax.lax.pmean(loss, axis)
+  tables = getattr(model, 'table_params', ())
   with scope('model_step', 'update'):
-    updates, opt_state = tx.update(grads, opt_state, params)
-    params = optax.apply_updates(params, updates)
+    if tables:
+      params, opt_state = _update_by_group(tx, grads, opt_state, params,
+                                           tables)
+    else:
+      updates, opt_state = tx.update(grads, opt_state, params)
+      params = optax.apply_updates(params, updates)
   return params, opt_state, loss
 
 
@@ -560,6 +642,21 @@ class DistHeteroTrainStep(StepCounters):
   also says how full its padded budgets were: ``counters()`` reads what
   the newest steps counted by type and relation, ``counter_slots()`` the
   slots the counts are read against.
+
+  Given a ``neg_sampling`` the step is seeded by edges (the form
+  ``SPMDSageTrainStep(neg_sampling=...)`` has): ``seed_type`` names a
+  stored relation, ``labels`` is ``None``, a call's seeds are ``[B, 2]``
+  positive ``(src, dst)`` edges of it a device, and the program draws
+  ``B`` binary negatives on the relation's CSR (strict: ``NEG_TRIALS``
+  rounds, padded), seeds one typed expansion with the ``2B`` sources and
+  the ``2B`` destinations (repeats are deduplicated by the front) and
+  hands the model ``metadata['edge_label_index']`` (``[2, 2B]`` labels
+  of the pairs' ends within their types' rows) and ``edge_label``;
+  ``model`` returns the pairs' logits. A type without an entry in
+  ``features`` has no table: its ``x`` is its ids. That program donates
+  ``params`` and ``opt_state``: use the ones a call returns. It runs per
+  batch, on one partition whose seed relation keeps every row; binary
+  negatives of amount 1 only.
   """
 
   def __init__(self, graph: DistHeteroGraph,
@@ -570,7 +667,8 @@ class DistHeteroTrainStep(StepCounters):
                edge_features: Optional[Dict[EdgeType, object]] = None,
                with_weight: bool = False,
                max_weighted_degree: Optional[int] = None,
-               keep_sample: bool = False):
+               keep_sample: bool = False, neg_sampling=None,
+               keep_seeds: bool = False):
     """``edge_features`` maps *traversal* edge types to edge-id-space
     DistFeatures; when given, sampling emits eids and the batch carries
     ``edge_attr_dict`` (reference efeat collate,
@@ -580,8 +678,11 @@ class DistHeteroTrainStep(StepCounters):
     has the per-batch step return the structure it sampled and trained
     on, kept as ``last_sample`` until the next call: what a check of the
     sample, or a reference's own step on it, reads, with no second
-    program."""
+    program. ``keep_seeds``: an edge-seeded step also hands back its
+    ``[4B]`` endpoint seeds ``[src; neg_src; dst; neg_dst]`` among its
+    counters."""
     from ..parallel.dist_feature import require_device_resident
+    from ..sampler import NegativeSampling
     for t, st in features.items():
       require_device_resident(st, f'DistHeteroTrainStep features[{t!r}]')
     for e, st in (edge_features or {}).items():
@@ -596,13 +697,23 @@ class DistHeteroTrainStep(StepCounters):
     self.bs = int(batch_size_per_device)
     self.mesh = graph.mesh
     self.axis = graph.axis
+    self.neg_sampling = NegativeSampling.cast(neg_sampling)
+    self._link = self.neg_sampling is not None
+    self._keep_seeds = bool(keep_seeds)
+    #: what seeds the typed expansion, and the seed slots of each: the
+    #: seed type's ``bs``, or ``2 * bs`` of each end of the seed relation
+    self._seeded, self._seed_slots = seed_type, self.bs
+    if self._link:
+      self._check_edge_seeds(labels)
+      self._seeded = (seed_type[0], seed_type[2])
+      self._seed_slots = 2 * self.bs
     self.sampler = DistHeteroNeighborSampler(
         graph, num_neighbors, with_edge=bool(self.edge_features),
         with_weight=with_weight, max_weighted_degree=max_weighted_degree,
         seed=seed)
     self.labels = {t: jax.device_put(as_numpy(v),
                                      NamedSharding(self.mesh, P()))
-                   for t, v in labels.items()}
+                   for t, v in (labels or {}).items()}
     #: times each program was TRACED (trace-time side effects;
     #: executions never bump these) — the zero-steady-state-recompile
     #: assertions on the hetero train path read them
@@ -615,7 +726,7 @@ class DistHeteroTrainStep(StepCounters):
     #: message-flow relation; a leading axis of devices), on the device
     self.last_sample = None
     _, caps, budgets, active = self.sampler._make_device_core(
-        self.bs, seed_type)
+        self._seed_slots, self._seeded)
     trav = {e: tc for e, tc in self.sampler._trav().items()
             if e in active}
     #: static counters of the step, in slots: rows of each type's padded
@@ -634,7 +745,8 @@ class DistHeteroTrainStep(StepCounters):
     self.counter_node_types = tuple(budgets)
     self.counter_edge_types = tuple(edge_offsets)
     # every node store serves in place: the step counts ``store_chunks``
-    self._stores_in_place = all(st.in_place for st in features.values())
+    self._stores_in_place = bool(features) and all(
+        st.in_place for st in features.values())
     #: output rows each layer of the model computes for each type, filled
     #: when a program is traced (the node trim engages at trace time);
     #: None before, and for a model that does not say
@@ -672,11 +784,75 @@ class DistHeteroTrainStep(StepCounters):
   def _final_key(self, e):
     return reverse_edge_type(e) if self.g.edge_dir == 'out' else e
 
+  def _check_edge_seeds(self, labels):
+    """What an edge-seeded step can run, refused by name where it
+    cannot."""
+    neg, rel = self.neg_sampling, self.seed_type
+    if not neg.is_binary() or neg.amount != 1:
+      raise NotImplementedError(
+          'an edge-seeded DistHeteroTrainStep draws one binary negative '
+          f'a positive in its program; got {neg}: triplet mode and '
+          'other amounts run through LinkNeighborLoader')
+    if rel not in self.g.graphs:
+      raise ValueError(
+          f'seed_type {rel!r} is no stored relation of the graph; an '
+          f'edge-seeded step takes one of {sorted(self.g.graphs)}')
+    if labels:
+      raise ValueError('an edge-seeded step has no labels table: its '
+                       "labels are the pairs' own (edge_label)")
+    store = self.g.graphs[rel]
+    if self.g.num_partitions != 1 or store.max_rows != store.num_nodes:
+      raise NotImplementedError(
+          "strict negatives are drawn against the seed relation's CSR "
+          'in place: one partition that keeps every row of it '
+          '(DistHeteroGraph.from_csr); got '
+          f'{self.g.num_partitions} partitions, {store.max_rows} of '
+          f'{store.num_nodes} rows')
+
+  def _link_seeds(self, shard, pairs, n_valid, key):
+    """The edge-seeded step's front, inside the ``sampler`` scope
+    (parallel/train.py::_link_seeds's typed twin): ``B`` negatives from
+    ``key`` on the seed relation's CSR (uniform pairs of its two id
+    spaces, ``NEG_TRIALS`` rounds, the first round that is no edge, the
+    last round's proposal where none is), then by type the seeds
+    ``[src; neg_src]`` and ``[dst; neg_dst]`` (one block of ``4B`` where
+    both ends are one type) with their masks: a pair past ``n_valid``
+    and its negative seed nothing. Returns ``(seeds, seed_mask,
+    edge_label [2B], counters)``."""
+    bs, counts = self.bs, self.g.node_counts
+    src_t, _, dst_t = self.seed_type
+    row_t, col_t = self.sampler._trav()[self.seed_type]
+    with jax.named_scope('negative'):
+      neg = random_negative_sample(
+          shard['indptr'], shard['indices'], bs, NEG_TRIALS, key,
+          counts[row_t], counts[col_t], strict=self.neg_sampling.strict,
+          padding=True)
+    neg_src, neg_dst = ((neg.rows, neg.cols) if self.g.edge_dir == 'out'
+                        else (neg.cols, neg.rows))
+    src = jnp.concatenate([pairs[:, 0], neg_src]).astype(jnp.int32)
+    dst = jnp.concatenate([pairs[:, 1], neg_dst]).astype(jnp.int32)
+    live = jnp.tile(jnp.arange(bs) < n_valid, 2)
+    if src_t == dst_t:
+      seeds = {src_t: jnp.concatenate([src, dst])}
+      mask = {src_t: jnp.tile(live, 2)}
+    else:
+      seeds, mask = {src_t: src, dst_t: dst}, {src_t: live, dst_t: live}
+    edge_label = jnp.concatenate(
+        [jnp.ones((bs,), jnp.float32), jnp.zeros((bs,), jnp.float32)])
+    counted = dict(negatives_rejected=neg.rejected,
+                   negatives_padded=neg.padded)
+    if self._keep_seeds:
+      counted['seeds'] = jnp.concatenate([src, dst])
+    return seeds, mask, edge_label, counted
+
   def dummy_batch(self):
     from ..loader.transform import HeteroBatch
     budgets = self.node_budget
-    x_dict = {t: jnp.zeros((budgets[t], self.features[t].feature_dim))
-              for t in self.features}
+    # a type without a table is read by its ids
+    x_dict = {t: (jnp.zeros((budgets[t], self.features[t].feature_dim))
+                  if t in self.features
+                  else jnp.zeros((budgets[t],), jnp.int32))
+              for t in budgets}
     row_d, col_d, mask_d, eattr_d, eid_d = {}, {}, {}, {}, {}
     for e in self.sampler.edge_types:
       k = self._final_key(e)
@@ -697,10 +873,14 @@ class DistHeteroTrainStep(StepCounters):
         edge_attr_dict=eattr_d or None,
         edge_dict=eid_d or None,
         node_dict={t: jnp.zeros((budgets[t],), jnp.int32)
-                   for t in self.features},
-        node_count_dict={t: jnp.zeros((), jnp.int32)
-                         for t in self.features},
-        y_dict={self.seed_type: jnp.zeros((self.bs,), jnp.int32)},
+                   for t in budgets},
+        node_count_dict={t: jnp.zeros((), jnp.int32) for t in budgets},
+        y_dict=(None if self._link else
+                {self.seed_type: jnp.zeros((self.bs,), jnp.int32)}),
+        metadata=(dict(
+            edge_label_index=jnp.zeros((2, 2 * self.bs), jnp.int32),
+            edge_label=jnp.zeros((2 * self.bs,), jnp.float32))
+                  if self._link else None),
         input_type=self.seed_type, batch_size=self.bs,
         **self._batch_static)
 
@@ -726,6 +906,10 @@ class DistHeteroTrainStep(StepCounters):
     if joint_of is not None:
       self.layer_joint_relations = joint_of(batch)
       gauge_joint_softmax('train.hetero_step', self.layer_joint_relations)
+    tables = getattr(self.model, 'embedding_tables', None)
+    if tables is not None:
+      from ..obs.perf import gauge_embedding_rows
+      gauge_embedding_rows('train.hetero_step', tables)
 
   def init_params(self, key):
     params = self.model.init(key, self.dummy_batch())
@@ -743,11 +927,12 @@ class DistHeteroTrainStep(StepCounters):
     its store gathered."""
     from ..loader.transform import HeteroBatch
     g, axis, bs = self.g, self.axis, self.bs
-    seed_type = self.seed_type
+    seed_type, link = self.seed_type, self._link
     device_core, caps, budgets, etypes = self.sampler._make_device_core(
-        bs, seed_type)
+        self._seed_slots, self._seeded)
     types = list(g.node_counts)
     feats = self.features
+    stored = [t for t in self.counter_node_types if t in feats]
     unknown = set(self.edge_features) - set(self.sampler.edge_types)
     assert not unknown, (
         f'edge_features keys {sorted(map(str, unknown))} are not '
@@ -772,15 +957,33 @@ class DistHeteroTrainStep(StepCounters):
                      for t in tables}
       fk = self._final_key
       with scope('sampler'):
+        seed_mask, counted, meta = None, {}, None
+        if link:
+          kneg, my_key = jax.random.split(my_key)
+          seeds, seed_mask, edge_label, counted = self._link_seeds(
+              shards_in[seed_type], seeds, n_valid[0], kneg)
         out, out_tables = device_core(shards_in, seeds, n_valid[0],
-                                      my_key, flat_tables)
+                                      my_key, flat_tables, seed_mask)
         counters = dict(
             nodes_by_hop=jnp.stack([out['num_sampled_nodes'][t]
                                     for t in self.counter_node_types]),
             edges_by_hop=self._edges_by_hop(
                 {fk(e): v for e, v in out['num_sampled_edges'].items()}))
+        if link:
+          # a seed slot's label is its endpoint's row of its type: the
+          # labels of a type's seeds are its first ones
+          ends = [out['seed_labels'][t] for t in seeds]
+          meta = dict(
+              edge_label_index=(jnp.stack(ends) if len(ends) == 2
+                                else ends[0].reshape(2, -1)),
+              edge_label=edge_label)
+          counters.update(counted, seed_unique=jnp.stack(
+              [out['num_sampled_nodes'][t][0] for t in self._seeded]))
       x_dict, chunks = {}, {}
       for t in types:
+        if t not in feats:   # no table: the type is read by its ids
+          x_dict[t] = out['node'][t]
+          continue
         fs = feat_shards[t]
         with scope('feature_store', 'gather', t):
           valid = (jnp.arange(out['node'][t].shape[0])
@@ -794,11 +997,12 @@ class DistHeteroTrainStep(StepCounters):
             rows, chunks[t] = rows[0], rows[1]['store_chunks']
           x_dict[t] = rows
       if self._stores_in_place:
-        counters['store_chunks'] = jnp.stack(
-            [chunks[t] for t in self.counter_node_types])
-      with scope('feature_store'):
-        y = jnp.take(labels[seed_type],
-                     jnp.maximum(out['batch'], 0)[:bs])
+        counters['store_chunks'] = jnp.stack([chunks[t] for t in stored])
+      y = None
+      if not link:
+        with scope('feature_store'):
+          y = jnp.take(labels[seed_type],
+                       jnp.maximum(out['batch'], 0)[:bs])
       edge_attr_dict = None
       if efeats:
         edge_attr_dict = {}
@@ -819,9 +1023,14 @@ class DistHeteroTrainStep(StepCounters):
           edge_dict=({fk(e): out['edge'][e] for e in etypes}
                      if 'edge' in out else None),
           node_dict=out['node'], node_count_dict=out['node_count'],
-          y_dict={seed_type: y}, input_type=seed_type, batch_size=bs,
-          **self._batch_static)
+          y_dict=None if link else {seed_type: y}, metadata=meta,
+          input_type=seed_type, batch_size=bs, **self._batch_static)
       self._note_layer_rows(batch)
+      slots_of = getattr(self.model, 'embedding_slots', None)
+      if slots_of is not None:
+        with scope('sampler'):
+          counters['embedding_rows'] = self._embedding_rows(
+              slots_of(batch), out)
       out_tables = {t: (tb[None], sc[None])
                     for t, (tb, sc) in out_tables.items()}
       return batch, y, out_tables, counters
@@ -840,7 +1049,7 @@ class DistHeteroTrainStep(StepCounters):
       return d
     specs = dict(
         shards={e: etype_spec(e) for e in etypes},
-        feats={t: store_spec(feats[t]) for t in types},
+        feats={t: store_spec(st) for t, st in feats.items()},
         efeats={e: store_spec(efeats[e]) for e in efeats},
         tables={t: (sp, sp) for t in types},
         labels={t: P() for t in self.labels},
@@ -863,7 +1072,7 @@ class DistHeteroTrainStep(StepCounters):
         return d
       return (
           {e: etype_payload(e) for e in etypes},
-          {t: store_payload(feats[t]) for t in types},
+          {t: store_payload(st) for t, st in feats.items()},
           {e: store_payload(efeats[e]) for e in efeats})
 
     return device_batch, specs, payloads
@@ -898,8 +1107,14 @@ class DistHeteroTrainStep(StepCounters):
         out_specs=out_specs + ((sp,) if self.keep_sample else ()),
         check_vma=False)
 
+    # an edge-seeded step owns its state (tables of parameters with their
+    # moments: a second copy live across the update is gigabytes): the
+    # caller steps on with what a call returns. The node-seeded drivers
+    # hold on to what they passed in, so that program donates its dedup
+    # tables alone
     import functools
-    @functools.partial(jax.jit, donate_argnums=(9,))
+    @functools.partial(jax.jit,
+                       donate_argnums=(0, 1, 9) if self._link else (9,))
     def step(params, opt_state, shards, feat_shards, efeat_shards,
              labels, seeds, n_valid, keys, tables):
       self.step_traces += 1  # trace-time side effect only
@@ -989,6 +1204,10 @@ class DistHeteroTrainStep(StepCounters):
     1/T — with zero recompiles across calls of the same T
     (``superstep_traces`` stays flat; a ragged epoch tail traces once
     more by design, like the homo superstep)."""
+    if self._link:
+      raise NotImplementedError(
+          'an edge-seeded step (neg_sampling given) runs per batch, '
+          'through __call__')
     if self._superstep_fn is None:
       self._superstep_fn = self._build_superstep()
     sh = NamedSharding(self.mesh, P(None, self.axis))
@@ -1022,8 +1241,10 @@ class DistHeteroTrainStep(StepCounters):
     # is named after the child that covers it
     with tracer.span('train.step', sync=lambda: _synced.get('loss')):
       with tracer.span('train.step/put'):
-        seeds = jax.device_put(
-            jnp.asarray(np.asarray(seeds).reshape(-1), jnp.int32), shard)
+        seeds = np.asarray(seeds)
+        seeds = jax.device_put(jnp.asarray(
+            seeds.reshape(-1, 2) if self._link else seeds.reshape(-1),
+            jnp.int32), shard)
         nv = jax.device_put(jnp.asarray(n_valid_per_device, jnp.int32),
                             shard)
         keys = jax.random.split(key, n_dev)
@@ -1052,13 +1273,40 @@ class DistHeteroTrainStep(StepCounters):
                              for width in np.diff(offsets[e])]))
     return jnp.stack(rows)
 
+  def _embedding_rows(self, slots, out):
+    """``[T]``: the distinct rows of each type's table that a step reads,
+    from what the model says it reads of a type's node slots
+    (``embedding_slots``: a hop prefix under the node trim, or every
+    slot): the live nodes among them."""
+    return jnp.stack([
+        jnp.minimum(out['node_count'][t], slots.get(t, 0))
+        for t in self.counter_node_types]).astype(jnp.int32)
+
+  def link_counters(self) -> dict:
+    """What the newest edge-seeded step counted, a device an entry, read
+    back from the device (it waits for that step): a view of
+    :meth:`counters`' newest entry, as
+    ``SPMDSageTrainStep.link_counters``. ``negatives_rejected``,
+    ``negatives_padded``, ``seed_unique`` (``[2]``: distinct valid
+    sources and destinations, hop 0 of their types' ``nodes_by_hop``),
+    ``embedding_rows`` (``[T]``, for a model with tables) and, built
+    with ``keep_seeds``, ``seeds`` (``[4B]``: ``[src; neg_src; dst;
+    neg_dst]``)."""
+    if not self._link or not self._counted:
+      raise RuntimeError('no edge-seeded step has run')
+    return self._newest_counters(
+        ('negatives_rejected', 'negatives_padded', 'seed_unique',
+         'embedding_rows', 'seeds'))
+
   def counter_slots(self) -> dict:
     """The contract of :meth:`StepCounters.counter_slots`: by type the
     node slots of each hop (``node_hop_offsets_dict``), by relation the
     edge slots (``edge_hop_offsets_dict``), rows in the order of
     ``counter_node_types`` and ``counter_edge_types``; where the node
     stores serve in place, by type the chunks its request slots are
-    served in."""
+    served in; of an edge-seeded step the ``NEG_TRIALS x B`` proposals,
+    the ``B`` negatives and the ``2B`` seed slots of each end, and for a
+    model with tables each table's rows."""
     from ..parallel.dist_feature import serve_chunks
     static = self._batch_static
     slots = dict(
@@ -1071,7 +1319,17 @@ class DistHeteroTrainStep(StepCounters):
     if self._stores_in_place:
       slots['store_chunks'] = np.asarray(
           [serve_chunks(self.node_budget[t])
-           for t in self.counter_node_types], np.int64)
+           for t in self.counter_node_types if t in self.features],
+          np.int64)
+    if self._link:
+      slots.update(
+          negatives_rejected=np.int64(NEG_TRIALS * self.bs),
+          negatives_padded=np.int64(self.bs),
+          seed_unique=np.full(2, 2 * self.bs, np.int64))
+    tables = getattr(self.model, 'embedding_tables', None)
+    if tables is not None:
+      slots['embedding_rows'] = np.asarray(
+          [tables.get(t, 0) for t in self.counter_node_types], np.int64)
     return slots
 
   def scope_profile(self, params, opt_state, batches) -> dict:

@@ -6,3 +6,6 @@ from .rgnn import RGNN, HeteroConvLayer
 from .hgt import HGT, HGTConv
 
 __all__ += ['RGNN', 'HeteroConvLayer', 'HGT', 'HGTConv']
+from .bipartite_sage import BipartiteSAGE
+
+__all__ += ['BipartiteSAGE']

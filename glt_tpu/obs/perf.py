@@ -109,6 +109,21 @@ def gauge_joint_softmax(
     pass
 
 
+def gauge_embedding_rows(
+    fn: str, tables: dict,
+    registry: Optional[MetricsRegistry] = None) -> None:
+  """Trace-time hook beside :func:`gauge_layer_rows`, for a model that
+  owns embedding tables (models/bipartite_sage.py): the rows of each
+  node type's table among program ``fn``'s parameters, as
+  ``model_embedding_rows{fn, type}``. Static: set once a trace."""
+  try:
+    reg = registry or get_registry()
+    for t, n in tables.items():
+      reg.set('model_embedding_rows', float(n), fn=str(fn), type=str(t))
+  except Exception:  # accounting must never break a trace
+    pass
+
+
 def gauge_budgets(fn: str, node_budget: dict, edge_budget: dict,
                   registry: Optional[MetricsRegistry] = None) -> None:
   """Build-time hook of a typed step program ``fn``: the static padded

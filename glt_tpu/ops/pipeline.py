@@ -397,7 +397,7 @@ def hetero_hop_fanouts(caps, trav, num_neighbors, num_hops):
 
 def multihop_sample_hetero(one_hops, trav, num_neighbors, num_hops,
                            caps, budgets, seeds, n_valid, key, tables,
-                           with_edge: bool = False):
+                           with_edge: bool = False, seed_mask=None):
   """Hetero hop loop shared by the single-device engine and the SPMD
   distributed engine (only the per-edge-type ``one_hops`` differ:
   in-HBM sampling vs the all_to_all collective version).
@@ -409,6 +409,9 @@ def multihop_sample_hetero(one_hops, trav, num_neighbors, num_hops,
     caps/budgets: static per-hop frontier capacities / node budgets per
       node type (callers compute them identically from trav).
     seeds/n_valid: Dict[NodeType, array] — multi-type seeding.
+    seed_mask: Dict[NodeType, bool array] in place of ``n_valid``'s
+      prefixes, for seed slots that are live in no prefix order (the
+      endpoints of edge seeds: a masked pair masks a slot of each half).
     tables: Dict[NodeType, (table, scratch)].
 
   Returns (result dict, out_tables) with per-type node lists, per-etype
@@ -421,7 +424,7 @@ def multihop_sample_hetero(one_hops, trav, num_neighbors, num_hops,
   if dedup_engine() == 'sort':
     result = _multihop_sample_hetero_sorted(
         one_hops, trav, num_neighbors, num_hops, caps, budgets, seeds,
-        n_valid, key, with_edge=with_edge)
+        n_valid, key, with_edge=with_edge, seed_mask=seed_mask)
     return result, tables
   for t in tables:
     _check_engine_tables(tables[t][0])
@@ -430,7 +433,8 @@ def multihop_sample_hetero(one_hops, trav, num_neighbors, num_hops,
             for t in types}
   seed_labels = {}
   for t, s in seeds.items():
-    mask = jnp.arange(s.shape[0]) < n_valid[t]
+    mask = (seed_mask[t] if seed_mask
+            else jnp.arange(s.shape[0]) < n_valid[t])
     states[t], seed_labels[t] = dense_assign(states[t], s, mask)
 
   frontier = {}
@@ -513,7 +517,8 @@ def multihop_sample_hetero(one_hops, trav, num_neighbors, num_hops,
 
 def _multihop_sample_hetero_sorted(one_hops, trav, num_neighbors,
                                    num_hops, caps, budgets, seeds,
-                                   n_valid, key, with_edge: bool = False):
+                                   n_valid, key, with_edge: bool = False,
+                                   seed_mask=None):
   """The hetero hop loop on the sort-merge inducer: per node type an
   append-form seen-set threaded through :func:`sorted_hop_dedup`, with
   one extra sort per (type, hop) un-permuting labels back to slot order
@@ -530,7 +535,8 @@ def _multihop_sample_hetero_sorted(one_hops, trav, num_neighbors,
     c0 = max(1, caps[0][t])
     if t in seeds:
       s = seeds[t]
-      mask = jnp.arange(s.shape[0]) < n_valid[t]
+      mask = (seed_mask[t] if seed_mask
+              else jnp.arange(s.shape[0]) < n_valid[t])
       d = sorted_hop_dedup(*seen[t], s, mask)
       sl = jax.lax.sort([d['pos3'], d['labels3']], num_keys=1)[1]
       seed_labels[t] = jnp.where(mask, sl, -1)
