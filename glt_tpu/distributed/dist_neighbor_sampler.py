@@ -58,8 +58,8 @@ def make_dist_one_hop(graph_shards: Dict[str, jax.Array], num_nodes: int,
     if n_parts == 1:
       # one partition owns every row: each request is served in its own
       # slot, with nothing to bucket, exchange or stitch (the bucketing
-      # is a stable sort and three scatters a hop, and most of what the
-      # typed step's program takes to compile)
+      # is a running count an owner and a scatter, the stitch three
+      # gathers a hop)
       flat = jnp.where(mask, ids.astype(jnp.int32), -1)
     else:
       owner = jnp.take(node_pb, jnp.clip(ids, 0, num_nodes - 1),

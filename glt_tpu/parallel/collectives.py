@@ -4,10 +4,19 @@ This is the TPU-native replacement for the reference's cross-partition
 RPC fan-out (dist_neighbor_sampler.py:616-687: split ids by partition
 book -> rpc to owners -> stitch): requests are packed into fixed-capacity
 per-owner buckets, exchanged with one all_to_all over ICI, served
-locally, and sent back with a second all_to_all; the un-bucketing scatter
+locally, and sent back with a second all_to_all; the un-bucketing gather
 is the positional stitch (stitch_sample_results.cu analog). All shapes
 static: a bucket holds the full request vector at worst, or a cap, with
 the requests ranked past it served by further rounds (capped_drain).
+
+The map between request order and bucket order is no permutation: a
+request's bucket is its owner and its slot there is its rank, the number
+of earlier requests with the same owner (``BucketMeta``, from one running
+count an owner over the owners in request order: ``rank_by_owner``). That
+is where a stable argsort by owner would put it, so the buckets are such
+a sort's, with no sort: the pack scatters a payload straight to
+``(owner, rank)`` and the stitch is one gather from there, already in
+request order.
 """
 from __future__ import annotations
 
@@ -16,11 +25,40 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..ops.scan import cumsum_i32
+
 
 class BucketMeta(NamedTuple):
-  order: jax.Array         # argsort of owner (stable)
-  owner_sorted: jax.Array  # [B]
-  pos_in_bucket: jax.Array  # [B]
+  owner: jax.Array   # [B] in request order; n_shards = a dropped request
+  rank: jax.Array    # [B] earlier requests with the same owner
+  counts: jax.Array  # [n_shards] requests an owner
+
+
+def rank_by_owner(owner: jax.Array, n_shards: int) -> BucketMeta:
+  """Where each request goes: bucket ``owner[i]``, slot ``rank[i]`` = the
+  number of earlier requests with the same owner, from ``n_shards``
+  running counts of ``owner == p`` in request order (exact: 0/1
+  indicators). ``owner`` is in [0, n_shards) for a request and
+  == n_shards for a dropped one, which gets no rank."""
+  owner = owner.astype(jnp.int32)
+  rank = jnp.zeros(owner.shape, jnp.int32)
+  counts = []
+  for p in range(n_shards):
+    hit = owner == p
+    run = cumsum_i32(hit)
+    rank = jnp.where(hit, run - 1, rank)
+    counts.append(run[-1])
+  return BucketMeta(owner, rank, jnp.stack(counts))
+
+
+def _round_slots(meta: BucketMeta, n_shards: int, cap: int, round_offset):
+  """(ok [B], slot [B]) of the drain round that holds the requests ranked
+  [round_offset, round_offset + cap) per bucket: whether a request is in
+  it, and its place ``owner * cap + rank - round_offset`` among the
+  round's ``n_shards * cap`` bucket slots."""
+  pos = meta.rank - round_offset
+  ok = (meta.owner < n_shards) & (pos >= 0) & (pos < cap)
+  return ok, meta.owner * cap + pos
 
 
 def bucket_by_owner(ids: jax.Array, owner: jax.Array, n_shards: int,
@@ -35,22 +73,11 @@ def bucket_by_owner(ids: jax.Array, owner: jax.Array, n_shards: int,
   bucket: a device then ships n_shards*C elements instead of
   n_shards*B. Requests ranked past the cap are NOT packed — they come
   back as ``invalid_value`` from :func:`unbucket`, and the caller
-  re-issues them (the bucketing is deterministic, so the host can
-  replay it and drain overflow through the same compiled program; see
-  ShardedFeature.lookup).
+  re-issues them with a ``round_offset`` (capped_drain).
   """
-  b = ids.shape[0]
-  cap = capacity if capacity and capacity < b else b
-  order = jnp.argsort(owner, stable=True)
-  owner_sorted = jnp.take(owner, order)
-  counts = jnp.bincount(jnp.minimum(owner_sorted, n_shards),
-                        length=n_shards + 1)[:n_shards]
-  offsets = jnp.cumsum(counts) - counts
-  pos = jnp.arange(b) - jnp.take(
-      offsets, jnp.minimum(owner_sorted, n_shards - 1))
-  meta = BucketMeta(order, owner_sorted, pos)
+  meta = rank_by_owner(owner, n_shards)
   return bucket_payload(ids, meta, n_shards, fill_value,
-                        capacity=cap), meta
+                        capacity=capacity), meta
 
 
 def unbucket(resp: jax.Array, meta: BucketMeta, n_shards: int,
@@ -60,16 +87,18 @@ def unbucket(resp: jax.Array, meta: BucketMeta, n_shards: int,
   slots get ``invalid_value``. ``round_offset`` (may be a traced
   scalar) selects the drain round: only requests whose in-bucket rank
   lies in [round_offset, round_offset + C) are decoded — the inverse of
-  the same offset passed to :func:`bucket_payload`."""
+  the same offset passed to :func:`bucket_payload`. One gather: a
+  request reads the slot it was packed to, and a masked slot reads a row
+  of its own (on a TPU a gather whose masked slots share a row waits on
+  it: 15.3 ms against 10.6 at 937,984 slots of which 63 % are pads,
+  benchmarks/bench_bucket_drain.py --forms)."""
   cap = resp.shape[1]
-  pos = meta.pos_in_bucket - round_offset
-  ok = (meta.owner_sorted < n_shards) & (pos >= 0) & (pos < cap)
-  gathered = resp[jnp.minimum(meta.owner_sorted, n_shards - 1),
-                  jnp.clip(pos, 0, cap - 1)]
-  shape = (ok.shape[0],) + (1,) * (gathered.ndim - 1)
-  gathered = jnp.where(ok.reshape(shape), gathered, invalid_value)
-  out = jnp.zeros_like(gathered)
-  return out.at[meta.order].set(gathered)
+  ok, slot = _round_slots(meta, n_shards, cap, round_offset)
+  lane = jnp.arange(slot.shape[0], dtype=slot.dtype) % (n_shards * cap)
+  gathered = jnp.take(resp.reshape((n_shards * cap,) + resp.shape[2:]),
+                      jnp.where(ok, slot, lane), axis=0)
+  ok = ok.reshape(ok.shape + (1,) * (gathered.ndim - 1))
+  return jnp.where(ok, gathered, invalid_value)
 
 
 def drain_rounds(meta: BucketMeta, n_shards: int, cap: int,
@@ -80,9 +109,7 @@ def drain_rounds(meta: BucketMeta, n_shards: int, cap: int,
   lax.while_loop conditioned on it keeps the collectives inside the
   loop aligned — the drain runs entirely in-program (no host replay of
   the bucketing, no cross-process agreement round)."""
-  counts = jnp.bincount(jnp.minimum(meta.owner_sorted, n_shards),
-                        length=n_shards + 1)[:n_shards]
-  local = (counts.max() + cap - 1) // cap
+  local = (meta.counts.max() + cap - 1) // cap
   return jax.lax.pmax(local.astype(jnp.int32), axis_name)
 
 
@@ -128,23 +155,21 @@ def all_to_all(x: jax.Array, axis_name: str) -> jax.Array:
 def bucket_payload(values: jax.Array, meta: BucketMeta, n_shards: int,
                    fill_value=0, capacity: int = 0,
                    round_offset=0) -> jax.Array:
-  """Pack a companion payload with the SAME ordering as an existing
+  """Pack a companion payload [B, ...] into the slots of an existing
   bucket_by_owner call (e.g. the col of a (row, col) pair routed by the
-  row's owner). ``round_offset`` (may be a traced scalar, e.g. the
-  drain-loop counter times the capacity) packs the requests ranked
-  [round_offset, round_offset + cap) within each bucket — drain round k
-  of a capped exchange packs offset k*cap."""
+  row's owner): one scatter to ``(owner, rank)``. ``round_offset`` (may
+  be a traced scalar, e.g. the drain-loop counter times the capacity)
+  packs the requests ranked [round_offset, round_offset + cap) within
+  each bucket — drain round k of a capped exchange packs offset k*cap.
+  Everything else is sent past the last slot and dropped."""
   b = values.shape[0]
   cap = capacity if capacity and capacity < b else b
-  vals_sorted = jnp.take(values, meta.order)
-  pos = meta.pos_in_bucket - round_offset
-  ok = (meta.owner_sorted < n_shards) & (pos >= 0) & (pos < cap)
-  buckets = jnp.full((n_shards + 1, cap), fill_value, values.dtype)
-  buckets = buckets.at[
-      jnp.where(ok, meta.owner_sorted, n_shards),
-      jnp.where(ok, jnp.clip(pos, 0, cap - 1), 0)].set(
-          jnp.where(ok, vals_sorted, fill_value))
-  return buckets[:n_shards]
+  ok, slot = _round_slots(meta, n_shards, cap, round_offset)
+  buckets = jnp.full((n_shards * cap,) + values.shape[1:], fill_value,
+                     values.dtype)
+  buckets = buckets.at[jnp.where(ok, slot, n_shards * cap)].set(
+      values, mode='drop')
+  return buckets.reshape((n_shards, cap) + values.shape[1:])
 
 
 def sharded_segment_mean(msgs: jax.Array, targets: jax.Array,

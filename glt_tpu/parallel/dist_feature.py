@@ -12,11 +12,11 @@ id // rows_per_shard), and lookup inside shard_map is
     -> positional un-bucket (the stitch, stitch_sample_results.cu analog)
 
 with fixed-capacity buckets so shapes stay static: an even share of the
-request vector a peer (exchange_cap), and as many rounds of the exchange
-as the fullest bucket needs, in the program. Collectives ride ICI.
-On a mesh of one shard there is one owner and the exchange would be the
-identity: lookup_local then serves the requests in place (the local
-gather alone), with the same rows bit for bit, and gathers only the
+request vector a peer (exchange_cap), as many rounds as the fullest one
+needs; a request's slot is its rank among its owner's requests (no sort:
+collectives.rank_by_owner). On one shard there is one owner and the
+exchange would be the identity: lookup_local then serves in place (the
+local gather alone), the same rows bit for bit, and gathers only the
 chunks of request slots that hold a valid request (serve_live_chunks).
 """
 from __future__ import annotations
@@ -375,22 +375,15 @@ class ShardedFeature:
     (the exchange is then the identity), which is how tests hold the
     in-place form to it."""
     from ..obs.perf import gauge_bucket_cap
-    from .collectives import (BucketMeta, all_to_all, bucket_payload,
-                              capped_drain, drain_rounds, unbucket)
+    from .collectives import (all_to_all, bucket_payload, capped_drain,
+                              drain_rounds, rank_by_owner, unbucket)
     n_shards = self.mesh.shape[self.axis]
     b = ids.shape[0]
     store = lambda stage: scope('feature_store', stage)
     with store('bucket'):
       owner = jnp.clip(ids // self.rows_per_shard, 0, n_shards - 1)
-      owner = jnp.where(valid, owner, n_shards)  # pads sort last
-      order = jnp.argsort(owner, stable=True)    # group requests by owner
-      owner_sorted = jnp.take(owner, order)
-      counts = jnp.bincount(jnp.minimum(owner_sorted, n_shards),
-                            length=n_shards + 1)[:n_shards]
-      offsets = jnp.cumsum(counts) - counts
-      pos_in_bucket = jnp.arange(b) - jnp.take(
-          offsets, jnp.minimum(owner_sorted, n_shards - 1))
-      meta = BucketMeta(order, owner_sorted, pos_in_bucket)
+      owner = jnp.where(valid, owner, n_shards)  # pads are dropped
+      meta = rank_by_owner(owner, n_shards)      # bucket, slot a request
     # fixed-capacity request buckets [n_shards, cap]
     cap = self.exchange_cap(b)
     gauge_bucket_cap('ShardedFeature.lookup_local', cap)
@@ -425,8 +418,8 @@ class ShardedFeature:
     if not counters:
       return rows
     return rows, dict(store_rounds=rounds,
-                      store_bucket_max=counts.max().astype(jnp.int32),
-                      store_requests=counts.sum().astype(jnp.int32))
+                      store_bucket_max=meta.counts.max().astype(jnp.int32),
+                      store_requests=meta.counts.sum().astype(jnp.int32))
 
   def _cold_values_host(self, nodes: np.ndarray, valid: np.ndarray):
     """The host cold-row gather core shared by the lookup() host phase

@@ -501,7 +501,9 @@ def test_lookup_local_exchanges_only_over_more_than_one_shard(
   text = lowered()
   in_place = chips == 1
   assert text.count('all_to_all') == (0 if in_place else 2), text
-  assert ('stablehlo.sort' in text) != in_place   # the owner argsort
+  # a request's bucket slot is its owner and its rank in request order:
+  # neither form sorts
+  assert 'stablehlo.sort' not in text
   assert _in_place_gauge() == float(in_place)
   # the chunk loop is the in-place form's alone, past SERVE_CHUNK slots:
   # an exchange serves its buckets by the plain gather (uncapped, so
@@ -605,6 +607,161 @@ def test_default_store_over_four_shards_drains_exactly(case):
     # the host-side API goes through the same program
     np.testing.assert_array_equal(
         np.asarray(sf.lookup(ids, jnp.asarray(valid))), want)
+
+
+# -- the map between request order and bucket order -------------------------
+
+def _owners(case, rng, p, b):
+  """owner [b] of a bucketing case: in [0, p) for a request, p for a pad."""
+  owner = rng.integers(0, p, size=b)
+  if case == 'live_prefix':        # the four-chip cell's shape in small:
+    # a 37 % prefix is live and shard 0 owns half of it
+    owner = np.minimum((p * rng.random(b) ** 2).astype(np.int64), p - 1)
+    owner[int(0.37 * b):] = p
+  elif case == 'scattered_mask':
+    owner[rng.random(b) < 0.6] = p
+  elif case == 'all_pads':
+    owner[:] = p
+  elif case == 'one_owner':        # every request on the last owner
+    owner[:] = p - 1
+  return owner.astype(np.int32)
+
+
+def _sorted_reference(owner, p, cap, base):
+  """What a stable argsort by owner makes of drain round ``base // cap``:
+  (order, bucket row, bucket column, packed) in sorted order, and the
+  requests an owner."""
+  order = np.argsort(owner, kind='stable')
+  osort = owner[order]
+  counts = np.bincount(np.minimum(osort, p), minlength=p + 1)[:p]
+  offsets = np.cumsum(counts) - counts
+  pos = np.arange(owner.shape[0]) - offsets[np.minimum(osort, p - 1)] - base
+  ok = (osort < p) & (pos >= 0) & (pos < cap)
+  return order, osort, pos, ok, counts
+
+
+_BUCKET_CASES = {
+    # case: (b, cap; 0 = b)
+    'live_prefix': (4096, 1024),
+    'scattered_mask': (1024, 0),
+    'all_pads': (640, 256),
+    'one_owner': (1024, 256),       # four rounds: P on four owners
+    'b_not_a_multiple': (601, 160),  # neither P nor 128 divides b
+}
+
+
+@pytest.mark.parametrize('payload', ['ids', 'pairs', 'bool'])
+@pytest.mark.parametrize('p', [1, 2, 4])
+@pytest.mark.parametrize('case', list(_BUCKET_CASES))
+def test_buckets_are_a_stable_sorts_bit_for_bit(case, p, payload):
+  from glt_tpu.parallel.collectives import (bucket_by_owner, bucket_payload,
+                                            unbucket)
+  b, cap = _BUCKET_CASES[case]
+  rng = np.random.default_rng(47)
+  owner = _owners(case, rng, p, b)
+  eff_cap = cap or b
+  values, fill, invalid, resp_shape = {
+      'ids': (rng.integers(0, 10_000, b).astype(np.int32), -1, 0,
+              (p, eff_cap, 3)),
+      'pairs': (rng.integers(0, 10_000, (b, 3)).astype(np.int32), -7, -1,
+                (p, eff_cap, 3)),
+      'bool': (rng.random(b) < 0.5, False, False, (p, eff_cap)),
+  }[payload]
+  resp = rng.integers(0, 2, resp_shape) if payload == 'bool' else \
+      rng.normal(size=resp_shape)
+  resp = resp.astype({'ids': np.float32, 'pairs': np.int32,
+                      'bool': bool}[payload])
+
+  first, meta = bucket_by_owner(jnp.asarray(values), jnp.asarray(owner), p,
+                                fill_value=fill, capacity=cap)
+  _, _, _, _, counts = _sorted_reference(owner, p, eff_cap, 0)
+  np.testing.assert_array_equal(np.asarray(meta.counts), counts)
+  np.testing.assert_array_equal(np.asarray(meta.owner), owner)
+  # the rank is the number of earlier requests with the same owner
+  live = owner < p
+  earlier = np.array([(owner[:i] == owner[i]).sum() for i in range(b)])
+  np.testing.assert_array_equal(np.asarray(meta.rank)[live], earlier[live])
+
+  @jax.jit
+  def round_trip(meta, base):   # the offset is traced, as in the drain
+    return (bucket_payload(jnp.asarray(values), meta, p, fill_value=fill,
+                           capacity=cap, round_offset=base),
+            unbucket(jnp.asarray(resp), meta, p, invalid_value=invalid,
+                     round_offset=base))
+
+  rounds = max(1, -(-int(counts.max(initial=0)) // eff_cap))
+  if case == 'one_owner':
+    assert rounds == b // cap == 4   # as many as four owners' worst
+  seen = np.zeros(b, bool)
+  for k in range(rounds + 1):   # one round past the last packs nothing
+    base = k * eff_cap
+    order, osort, pos, ok, _ = _sorted_reference(owner, p, eff_cap, base)
+    want = np.full((p, eff_cap) + values.shape[1:], fill, values.dtype)
+    want[osort[ok], pos[ok]] = values[order][ok]
+    stitched = np.full((b,) + resp.shape[2:], invalid, resp.dtype)
+    stitched[order[ok]] = resp[osort[ok], pos[ok]]
+    got, rows = round_trip(meta, jnp.int32(base))
+    assert got.dtype == values.dtype and rows.dtype == resp.dtype
+    np.testing.assert_array_equal(np.asarray(got), want)
+    if resp.dtype == np.float32:
+      np.testing.assert_array_equal(np.asarray(rows).view(np.uint32),
+                                    stitched.view(np.uint32))
+    else:
+      np.testing.assert_array_equal(np.asarray(rows), stitched)
+    if k == 0:
+      np.testing.assert_array_equal(np.asarray(first), want)
+    assert not (seen[order[ok]]).any()
+    seen[order[ok]] = True
+    assert ok.any() == (k < rounds and live.any())
+  np.testing.assert_array_equal(seen, live)   # every request, in one round
+
+
+_EXCHANGE_CASES = {
+    # case: (mask, bucket_cap; 0 = exchange_cap's even share, rounds)
+    'live_prefix': ('prefix', 0, 1),
+    'scattered_mask': ('scattered', 0, 1),
+    'all_pads': ('none', 0, 0),
+    'small_cap_several_rounds': ('scattered', 40, None),   # three or more
+    'one_owner_small_cap': ('one_owner', 100, 6),
+    'one_round_holds_all': ('prefix', 10_000, 1),
+}
+
+
+@pytest.mark.parametrize('case', list(_EXCHANGE_CASES))
+def test_four_shard_lookup_rows_and_counters_for_any_mask_and_cap(case):
+  mask, bucket_cap, want_rounds = _EXCHANGE_CASES[case]
+  p, n, d, b = 4, 1000, 8, 520   # 128 does not divide b
+  rng = np.random.default_rng(53)
+  feats = rng.normal(size=(n, d)).astype(np.float32)
+  feats[::7, 0] = -0.0   # a stored -0.0 comes back +0.0 from a drain's add
+  sf = ShardedFeature(feats, make_mesh(p), bucket_cap=bucket_cap)
+  cap = sf.exchange_cap(b)
+  assert cap == (min(bucket_cap, b) if bucket_cap else 256)
+  ids = np.floor(n * rng.random(p * b) ** 2).astype(np.int32)
+  valid = {
+      'prefix': np.tile(np.arange(b) < int(0.37 * b), p),
+      'scattered': rng.random(p * b) < 0.4,
+      'none': np.zeros(p * b, bool),
+      'one_owner': np.ones(p * b, bool),
+  }[mask]
+  if mask == 'one_owner':
+    ids = rng.integers(sf.rows_per_shard, 2 * sf.rows_per_shard,
+                       size=p * b).astype(np.int32)
+  rows, counted = _lookup_counted(sf, ids, valid)
+  want = np.where(valid[:, None], feats[ids], np.float32(0))
+  if cap < b:
+    want = want + np.float32(0)   # the drain adds rounds up: -0.0 -> +0.0
+  np.testing.assert_array_equal(rows.view(np.uint32), want.view(np.uint32))
+  per_owner = np.stack([
+      np.bincount(ids[lo:lo + b][valid[lo:lo + b]] // sf.rows_per_shard,
+                  minlength=p) for lo in range(0, p * b, b)])
+  rounds = -(-per_owner.max() // cap)
+  np.testing.assert_array_equal(counted['store_rounds'], [rounds] * p)
+  np.testing.assert_array_equal(counted['store_bucket_max'],
+                                per_owner.max(axis=1))
+  np.testing.assert_array_equal(counted['store_requests'],
+                                per_owner.sum(axis=1))
+  assert rounds == want_rounds if want_rounds is not None else rounds >= 3
 
 
 def _tiny_step(chips):

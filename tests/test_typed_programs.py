@@ -32,8 +32,14 @@ PARENT_STABLEHLO = {
     # 5753ec09...d933d72e12, typed 27b86ebe...ba05094a and withheld
     # 94b4a39e...f1685307. c4's exchange is that PR's parent's, text for
     # text: its hash stands.
+    # c4 alone was read anew by PR 39, which took the stable argsort by
+    # owner out of the four-shard exchange (a request's bucket slot is its
+    # owner and its rank in request order: one blocked running count an
+    # owner, the pack's scatter straight to that slot, one gather back);
+    # before it c4 was 53ee57ed...d1e6937c. The four one-chip programs
+    # never bucket: their hashes stand.
     'c1': '77dfcba41ae1e3a7347f32d8b7a4fcf53de596c781cc4de5e3219ac6b1c27162',
-    'c4': '53ee57eda8ac5f8d8a43fc020def21f66a0493d8aa0d5dabe1c5db5ed1e6937c',
+    'c4': 'd0fff17311d26803807dbe50a6b51d0010029ce93925e1e28efa950c424ed731',
     'link': '3b9a9823673d7ebbcd4ef7eeff9f4a48d76bf4c9583b0b3128036737195b22a2',
     'typed': '0b627d6e7dee6ccfcc773ff3ed181d614902cdc464b6942d46db9181c4fa8de0',
     'typed_withheld':
@@ -97,3 +103,57 @@ def test_the_other_cells_tiny_steps_lower_to_the_parents(name, monkeypatch):
             'link': lambda: _sage_text(link_fused, test_link_cell.tiny_cell(),
                                        1)}[name]()
   assert hashlib.sha256(text.encode()).hexdigest() == PARENT_STABLEHLO[name]
+
+
+def _scoped_primitives(jaxpr, prefix=''):
+  """(name stack, primitive) of every equation, through every inner
+  jaxpr (shard_map, while, pjit)."""
+  out = []
+  for eqn in jaxpr.eqns:
+    stack = '/'.join(x for x in (prefix, str(eqn.source_info.name_stack))
+                     if x)
+    out.append((stack, eqn.primitive.name))
+    for value in eqn.params.values():
+      for sub in value if isinstance(value, (tuple, list)) else (value,):
+        inner = getattr(sub, 'jaxpr', sub)
+        if hasattr(inner, 'eqns'):
+          out += _scoped_primitives(inner, stack)
+  return out
+
+
+@pytest.mark.parametrize('bucket_cap', [0, 10_000], ids=['drain', 'one_round'])
+def test_the_four_shard_exchange_holds_no_sort_and_stitches_by_one_gather(
+    bucket_cap):
+  """The map between request order and bucket order is no permutation
+  (PR 39): the exchange's program has no sort, its one scatter is the
+  pack's, and the stitch is one gather with nothing scattered behind
+  it. So an argsort, a ``bincount`` by scatter-add or an
+  ``.at[order].set`` cannot come back unnoticed."""
+  import jax.numpy as jnp
+  from jax.sharding import PartitionSpec as P
+  from glt_tpu.parallel import ShardedFeature, make_mesh
+  n, d, b = 96, 4, 600
+  sf = ShardedFeature(np.arange(n * d, dtype=np.float32).reshape(n, d),
+                      make_mesh(4), bucket_cap=bucket_cap)
+  def counted(shard, i, v):
+    rows, counters = sf.lookup_local(shard, i, v, counters=True)
+    return rows, {k: c[None] for k, c in counters.items()}
+
+  exchange = jax.shard_map(
+      counted, mesh=sf.mesh, in_specs=(P(sf.axis),) * 3, out_specs=P(sf.axis),
+      check_vma=False)
+  args = (sf.array, jnp.asarray(np.arange(4 * b, dtype=np.int32) % n),
+          jnp.ones(4 * b, bool))
+  text = jax.jit(exchange).lower(*args).as_text()
+  assert 'stablehlo.sort' not in text
+  assert text.count('"stablehlo.scatter"') == 1
+  assert ('stablehlo.while' in text) == (bucket_cap == 0)
+  ops = _scoped_primitives(jax.make_jaxpr(exchange)(*args).jaxpr)
+  store = [(stack, name) for stack, name in ops if 'feature_store' in stack]
+  assert not [op for op in store if 'sort' in op[1]]
+  assert [stack for stack, name in store if name.startswith('scatter')] == [
+      'feature_store/bucket']
+  assert [name for stack, name in store
+          if stack == 'feature_store/unbucket' and
+          name in ('gather', 'dynamic_slice', 'scatter', 'scatter-add')] == [
+              'gather']
