@@ -13,6 +13,21 @@ TPU design: enclosing subgraphs are padded static [N_cap]-node graphs,
 DRNL is a jitted edge-parallel BFS (``glt_tpu.ops.drnl``), and the DGCNN
 forward is vmapped over the batch so XLA fuses the whole batch into
 dense MXU matmuls.
+
+This script is the loader path: ``NeighborSampler.subgraph`` a link at a
+time (two full-neighbourhood hops, edge slots with edge ids, the one-set
+``ops/subgraph.py::induced_subgraph``, exact while ``max_degree`` bounds
+every member's row), the labels fetched to the host between extraction
+and model. SEAL also runs through the fused step: ``SPMDSageTrainStep``
+given a ``NegativeSampling`` and an ``ops.subgraph.EncloseSpec`` draws the
+negatives, takes one hop, extracts every link's enclosing subgraph as a
+dense block (exact on a graph with hubs inside counted budgets), labels
+it and trains DGCNN over the whole batch of graphs in one device program
+a step; the benchmark's cell ``seal-papers100m-c1.fused`` runs SEAL_OGB's
+ogbl-citation2 recipe that way (``PERF.md`` sections 4 and 5,
+``tests/test_seal_step.py``). The batched extraction returns blocks, not
+edge slots with edge ids, and one hop of fringe, so this script's
+two-hop, edge-slot extraction stays on the one-set op.
 """
 import argparse
 import os

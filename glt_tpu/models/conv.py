@@ -355,3 +355,32 @@ class GCNConv(nn.Module):
       agg = agg + self.param('bias', nn.initializers.zeros,
                              (self.out_features,), self.param_dtype)
     return agg
+
+
+class DenseGCNConv(nn.Module):
+  """:class:`GCNConv` over a batch of small graphs given as dense blocks:
+  ``x [L, S, F]``, ``adj [L, S, S]`` float32 0/1, symmetric, no
+  self-loops. PyG's ``GCNConv``: self-loops added, both ends normalised
+  by the degree with its loop, ``out = D^-1/2 (A + I) D^-1/2 (x W) + b``.
+  The parameters are ``GCNConv``'s (``lin/kernel``, ``bias``), so one
+  tree serves both. The neighbours' sum is a product with the block on
+  the matrix unit at ``highest`` precision: it stands where PyG
+  scatter-adds float32 rows, so it rounds nothing (the linear map rounds
+  as every linear map of the step does)."""
+  out_features: int
+  use_bias: bool = True
+  param_dtype: jnp.dtype = jnp.float32
+
+  @nn.compact
+  def __call__(self, x, adj):
+    h = nn.Dense(self.out_features, use_bias=False,
+                 param_dtype=self.param_dtype, name='lin')(x)
+    deg = adj.sum(-1) + 1.0
+    inv = jax.lax.rsqrt(deg)[..., None]
+    out = jnp.einsum('lij,ljc->lic', adj, h * inv,
+                     precision=jax.lax.Precision.HIGHEST) * inv
+    out = out + h / deg[..., None]
+    if self.use_bias:
+      out = out + self.param('bias', nn.initializers.zeros,
+                             (self.out_features,), self.param_dtype)
+    return out

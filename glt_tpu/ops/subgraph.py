@@ -7,6 +7,19 @@ node's neighbor window (capped at ``max_degree``) is gathered, membership
 of the endpoint in the set is a fixed-depth binary search over the *sorted*
 unique node list, and the relabeled COO comes out padded [U, max_degree]
 with a mask — compaction happens only if the caller asks for it.
+
+Two extractions live here, and each says what it does past its budget:
+
+* :func:`induced_subgraph`, one node set, edge slots with edge ids: a
+  member's row is read through a ``[U, max_degree]`` window, so it is
+  exact only where ``max_degree`` bounds every member's degree; a wider
+  row is truncated to its first ``max_degree`` entries, silently
+  (``NeighborSampler.subgraph`` passes the graph's widest row, so the
+  loader path is exact; on a graph with hubs that window is the memory).
+* :func:`enclosing_subgraphs`, ``L`` small node sets at once (a batch of
+  SEAL links) as dense ``[L, S, S]`` blocks, exact on a graph with hubs
+  inside static budgets: what a budget cannot hold is counted
+  (``edges_dropped``), never dropped silently.
 """
 from __future__ import annotations
 
@@ -15,7 +28,8 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from .unique import ordered_unique
+from .negative import edge_in_csr
+from .unique import ordered_unique, unique_rows
 
 
 class SubGraph(NamedTuple):
@@ -54,7 +68,10 @@ def induced_subgraph(
 
   Labels follow first-occurrence order of ``srcs`` (matching the
   reference's inducer-based relabeling). ``max_degree`` must bound the
-  degree of every node in the set for exact extraction.
+  degree of every node in the set for exact extraction: a wider member's
+  row is cut to its first ``max_degree`` entries and the edges past them
+  are missing from the result without a count (the module's text;
+  :func:`enclosing_subgraphs` is the extraction that counts).
   """
   uniq, count, _ = ordered_unique(srcs, src_mask, node_capacity)
   node_valid = jnp.arange(node_capacity) < count
@@ -91,3 +108,178 @@ def induced_subgraph(
     eids = jnp.full((node_capacity * max_degree,), -1, jnp.int32)
   return SubGraph(nodes=uniq, node_count=count, rows=rows, cols=cols,
                   eids=eids, edge_mask=edge_mask)
+
+
+# -- a batch of enclosing subgraphs, dense ---------------------------------
+
+#: entries of ``indices`` one read of the batched extraction takes: a row
+#: of ``indices`` seen as ``[E / TILE, TILE]`` (one row gather a tile; an
+#: element gather costs as much for one entry)
+TILE = 128
+
+
+class EncloseSpec(NamedTuple):
+  """The static budgets of :func:`enclosing_subgraphs` (one hop).
+
+  ``fanout``: neighbours taken of an endpoint: its whole row where that
+    is no wider, else a uniform sample without replacement; a link has
+    ``node_slots = 2 + 2 * fanout`` node slots.
+  ``tile_budget``: tiles of ``TILE`` entries of ``indices`` read a link.
+    A member at most ``hub_width`` wide is *read*: its whole row, in
+    ``ceil`` of its span over ``TILE`` tiles, members in slot order while
+    the link's budget lasts.
+  ``hub_width``: a member wider than this is never read (a row of the
+    benchmark's graph is up to 40 k wide).
+  ``hub_pairs``: pairs of members of one link that were both not read
+    (wider than ``hub_width``, or past the tile budget), probed one by
+    one with ``edge_in_csr``, over the whole batch. An edge with a read
+    end is found from that end (the graph is symmetric); an edge between
+    two unread members only by its probe, so the pairs past this budget
+    are what the extraction can lose: ``edges_dropped`` counts them.
+  ``max_z``: DRNL labels are clipped to ``max_z - 1`` (the embedding's
+    rows).
+  """
+  fanout: int = 127
+  tile_budget: int = 512
+  hub_width: int = 2048
+  hub_pairs: int = 8192
+  max_z: int = 1000
+
+  @property
+  def node_slots(self) -> int:
+    return 2 + 2 * self.fanout
+
+
+def pad_to_tiles(indices: jax.Array) -> jax.Array:
+  """``indices`` padded with -1 to whole tiles (a copy; a trainer pads
+  its graph once, when it is built)."""
+  short = -indices.shape[0] % TILE
+  return jnp.pad(indices, (0, short), constant_values=-1) if short \
+      else indices
+
+
+def enclosing_subgraphs(indptr: jax.Array, indices: jax.Array,
+                        ends: jax.Array, nbrs: jax.Array,
+                        nbr_mask: jax.Array, link_mask: jax.Array,
+                        spec: EncloseSpec) -> dict:
+  """The one-hop enclosing subgraphs of ``L`` links, each deduped and
+  induced on its own, as dense blocks.
+
+  The CSR must be symmetric (every edge in both directions), ascending
+  within rows, ``indices`` a whole number of tiles long
+  (:func:`pad_to_tiles`).
+
+  Args:
+    ends: ``[2, L]`` the links' sources and destinations.
+    nbrs, nbr_mask: ``[2, L, K]`` the neighbours taken of each endpoint
+      (``sample_neighbors``' output for the sources and destinations).
+    link_mask: ``[L]``; a masked link has no node.
+
+  Returns a dict: ``nodes [L, S]`` (global ids, -1 padded: source in slot
+  0, destination in slot 1, then the fringe in the order it was taken, no
+  fringe node twice nor equal to an endpoint), ``node_mask [L, S]``,
+  ``adj [L, S, S]`` bool (symmetric, no loops; every edge of the graph
+  between two nodes of a link, less the link itself in both directions),
+  and the scalar int32 counts ``subgraph_nodes``, ``subgraph_edges``
+  (directed), ``tiles_read``, ``hub_members`` (live members not read),
+  ``hub_pairs_probed`` and ``edges_dropped`` (pairs of unread members
+  past ``hub_pairs``: each may be an edge that ``adj`` lacks; 0 means
+  ``adj`` is exact).
+  """
+  num_links, s = ends.shape[1], spec.node_slots
+  tl, cap = spec.tile_budget, spec.hub_pairs
+  assert indices.shape[0] % TILE == 0, 'pad_to_tiles(indices) first'
+  with jax.named_scope('dedup'):
+    ids = jnp.concatenate([ends[0][:, None], ends[1][:, None],
+                           nbrs[0], nbrs[1]], axis=1).astype(jnp.int32)
+    on = link_mask[:, None]
+    valid = jnp.concatenate([on, on, nbr_mask[0] & on, nbr_mask[1] & on],
+                            axis=1)
+    nodes, count = unique_rows(ids, valid, fixed=2)
+    slot = jnp.arange(s, dtype=jnp.int32)
+    live = slot[None, :] < count[:, None]
+  with jax.named_scope('induce'):
+    at = jnp.maximum(nodes, 0)
+    start = jnp.take(indptr, at, mode='clip').astype(jnp.int32)
+    deg = jnp.where(
+        live, jnp.take(indptr, at + 1, mode='clip').astype(jnp.int32)
+        - start, 0)
+    # the tiles a member's row spans, and which members the budget reads
+    span = jnp.where((deg > 0) & (deg <= spec.hub_width),
+                     (start % TILE + deg + TILE - 1) // TILE, 0)
+    hi = jnp.cumsum(span, axis=1)
+    read = (span > 0) & (hi <= tl)
+    hi = jnp.where(read, hi, 0)
+    lo = jnp.where(read, hi - span, 0)
+    t = jnp.arange(tl, dtype=jnp.int32)[None, :, None]
+    owner = (lo[:, None, :] <= t) & (t < hi[:, None, :])     # [L, TL, S]
+    of_owner = lambda a: jnp.sum(
+        jnp.where(owner, a[:, None, :], 0), axis=-1)        # [L, TL]
+    tile = of_owner(start // TILE - lo) + t[:, :, 0]
+    row_lo, row_hi = of_owner(start), of_owner(start + deg)
+    tiles = jnp.take(
+        indices.reshape(-1, TILE),
+        jnp.clip(tile, 0, indices.shape[0] // TILE - 1).reshape(-1),
+        axis=0).reshape(num_links, tl, TILE)
+    pos = tile[..., None] * TILE + jnp.arange(TILE, dtype=jnp.int32)
+    vals = jnp.where((pos >= row_lo[..., None]) & (pos < row_hi[..., None]),
+                     tiles, -1)
+    member = jnp.where(live, nodes, -2)
+    match = (vals[:, :, None, :] == member[:, None, :, None]).any(-1)
+    # a member's tiles summed into its row of the block, on the matrix
+    # unit: 0/1 products, sums of at most TL, exact in float32
+    seen = jnp.einsum('lti,ltj->lij', owner.astype(jnp.bfloat16),
+                      match.astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32)
+    unread = live & (deg > 0) & ~read
+    with jax.named_scope('hub_pairs'):
+      ranks = jnp.cumsum(unread, axis=1, dtype=jnp.int32)    # [L, S]
+      h = ranks[:, -1]
+      pairs = h * (h - 1) // 2
+      upto = jnp.cumsum(pairs)
+      total = upto[-1]
+      q = jnp.arange(cap, dtype=jnp.int32)
+      link = jnp.minimum((upto[None, :] <= q[:, None]).sum(-1),
+                         num_links - 1).astype(jnp.int32)
+      r = q - jnp.take(upto - pairs, link)
+      hq = jnp.take(h, link)
+      # pair r of a link's h unread members, ranks a < b: row a of the
+      # triangle starts at a * (2h - a - 1) / 2
+      first = lambda a: a * (2 * hq[:, None] - a - 1) // 2
+      a = ((slot[None, 1:] <= hq[:, None] - 1)
+           & (first(slot[None, 1:]) <= r[:, None])).sum(-1).astype(jnp.int32)
+      b = a + 1 + r - first(a[:, None])[:, 0]
+      rank_rows = jnp.take(ranks, link, axis=0)              # [P, S]
+      slot_of = lambda k: (rank_rows <= k[:, None]).sum(-1).astype(jnp.int32)
+      sa, sb = slot_of(a), slot_of(b)
+      flat = nodes.reshape(-1)
+      ua = jnp.take(flat, link * s + jnp.minimum(sa, s - 1))
+      ub = jnp.take(flat, link * s + jnp.minimum(sb, s - 1))
+      # a slot past the list's end probes a row of its own, the slots'
+      # rows spread evenly over the graph: left on one node, two slots in
+      # three read ONE address a round, which takes the chip twice the
+      # time of reads apart and a time that moves with the address (a
+      # step in one of three speeds, 42 to 46 ms, on the benchmark's cell)
+      num_nodes = indptr.shape[0] - 1
+      spare = q * max(num_nodes // cap, 1) % num_nodes
+      listed = q < total
+      found = listed & edge_in_csr(
+          indptr, indices, jnp.where(listed, jnp.maximum(ua, 0), spare),
+          jnp.where(listed, jnp.maximum(ub, 0), spare))
+      where = jnp.where(found, (link * s + sa) * s + sb, seen.size)
+      seen = seen.reshape(-1).at[where].add(1.0, mode='drop').reshape(
+          seen.shape)
+      probed = jnp.minimum(total, cap)
+    adj = (seen + jnp.swapaxes(seen, 1, 2)) > 0
+    adj &= live[:, :, None] & live[:, None, :]
+    adj &= slot[:, None] != slot[None, :]
+    target = (slot[:, None] < 2) & (slot[None, :] < 2)
+    adj &= ~target[None]
+  return dict(
+      nodes=nodes, node_mask=live, adj=adj,
+      subgraph_nodes=count.sum(dtype=jnp.int32),
+      subgraph_edges=adj.sum(dtype=jnp.int32),
+      tiles_read=hi.max(axis=1).sum(dtype=jnp.int32),
+      hub_members=unread.sum(dtype=jnp.int32),
+      hub_pairs_probed=probed.astype(jnp.int32),
+      edges_dropped=(total - probed).astype(jnp.int32))

@@ -66,6 +66,32 @@ def ordered_unique(
   return uniq, count, inverse
 
 
+def unique_rows(ids: jax.Array, valid: jax.Array, fixed: int = 0
+                ) -> Tuple[jax.Array, jax.Array]:
+  """``ordered_unique`` for a batch of small sets at once: row ``l`` of
+  ``ids`` ``[L, M]`` is one set, deduped on its own (not over the batch).
+
+  A valid slot is kept unless an earlier valid slot of its row holds its
+  id; the kept ids move to the front of the row in their order, the rest
+  of the row is -1. The first ``fixed`` slots are kept as they stand where
+  valid, equal or not (a link's two endpoints keep slots 0 and 1). Dense:
+  an ``[M, M]`` comparison a row and one sort along the row, no gather;
+  meant for ``M`` of a few hundred.
+
+  Returns ``(uniq [L, M], count [L] int32)``.
+  """
+  m = ids.shape[1]
+  pos = jnp.arange(m, dtype=jnp.int32)
+  earlier = pos[None, :] < pos[:, None]                # [i, j]: j < i
+  dup = ((ids[:, :, None] == ids[:, None, :]) & valid[:, None, :]
+         & earlier[None]).any(-1)
+  keep = valid & ~(dup & (pos >= fixed)[None, :])
+  key = jnp.where(keep, pos[None, :], m + pos[None, :])
+  uniq = jax.lax.sort((key, ids), dimension=1, num_keys=1)[1]
+  count = keep.sum(axis=1, dtype=jnp.int32)
+  return jnp.where(pos[None, :] < count[:, None], uniq, -1), count
+
+
 class InducerState(NamedTuple):
   """Functional equivalent of the stateful CUDA/CPU Inducer
   (include/inducer_base.h:28-48): the growing list of unique nodes whose

@@ -36,6 +36,16 @@ seed prefix, so the node trim and the grouped aggregation engage as on
 the node path. The supersteps and the streaming consume refuse edge
 seeds.
 
+Given an ``EncloseSpec`` as well, the link step is SEAL's
+(examples/seal_link_pred.py): the batch is not a fan-out tree but ``2B``
+small graphs, one a link. In the same one program: the link step's
+positives and strict negatives, one hop from the ``4B`` endpoints, then
+for every link on its own the dedup into ``S`` node slots, the exact
+induced edges among them as a dense ``[S, S]`` block with the link itself
+taken out (``ops/subgraph.py::enclosing_subgraphs``), DRNL
+(``ops/drnl.py::drnl_dense``), the store's gather of the live slots, a
+model that reads out a graph (``models/dgcnn.py``) and a loss a link.
+
 For host-spilled features WITHOUT the pinned-host cold block
 (``cold_array is None``) the fused body cannot resolve cold rows
 in-program; ``cold_streaming=True`` instead splits each superstep into a
@@ -57,10 +67,12 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..data import Graph
+from ..ops.drnl import drnl_dense
 from ..ops.negative import random_negative_sample
 from ..ops.pipeline import edge_hop_offsets, hop_fanouts, \
     multihop_sample, node_hop_offsets, sample_budget
 from ..ops.sample import sample_neighbors
+from ..ops.subgraph import EncloseSpec, enclosing_subgraphs, pad_to_tiles
 from ..ops.pipeline import make_dedup_tables
 from ..ops.superstep import scan_consume, superstep as build_superstep
 from ..loader.transform import Batch
@@ -111,20 +123,67 @@ def _link_loss(num_pos):
   return loss
 
 
+def _graph_loss(num_pos):
+  """Mean binary cross-entropy with logits over the ``2 * num_pos`` links
+  of an enclosing-subgraph step, a logit a graph; a pair past ``n_valid``
+  and its negative are left out of the mean."""
+  def loss(out, batch, n_valid):
+    logit, order = out
+    with jax.named_scope('link_loss'):
+      mask = jnp.tile(jnp.arange(num_pos) < n_valid, 2)
+      losses = optax.sigmoid_binary_cross_entropy(logit,
+                                                  batch['edge_label'])
+      return (jnp.where(mask, losses, 0).sum()
+              / jnp.maximum(mask.sum(), 1)), order
+  return loss
+
+
+class _OnSubgraphs:
+  """A graph model (``models.DGCNN``) taken as ``_sage_update`` takes a
+  model: ``apply(params, batch)`` over an enclosing-subgraph step's
+  batch, a dict of ``x [L, S, F]``, ``z [L, S]``, ``adj [L, S, S]`` and
+  ``node_mask [L, S]``; one logit a graph, and the readout's order (the
+  ``k`` node slots a graph was pooled from, in the order it kept them)."""
+
+  def __init__(self, model):
+    self.model = model
+
+  def _call(self, fn, first, batch, **kw):
+    return fn(first, batch['x'], z=batch['z'], adj=batch['adj'],
+              node_mask=batch['node_mask'], **kw)
+
+  def init(self, key, batch):
+    return self._call(self.model.init, key, batch)
+
+  def apply(self, params, batch):
+    return self._call(self.model.apply, params, batch, with_order=True)
+
+
+def _count_distinct(ids, mask):
+  """Distinct ids among the masked, by one sort."""
+  big = jnp.iinfo(ids.dtype).max
+  xs = jnp.sort(jnp.where(mask, ids, big))
+  head = jnp.concatenate([jnp.ones((1,), bool), xs[1:] != xs[:-1]])
+  return (head & (xs != big)).sum(dtype=jnp.int32)
+
+
 def _sage_update(model, tx, axis, loss_of, params, opt_state, batch,
-                 n_valid):
+                 n_valid, has_aux=False):
   """Forward/backward + DDP pmean + optimizer update for one batch —
   the training tail shared by the per-batch, fused-superstep and
   streaming-consume bodies (identical op sequence = loss parity).
   ``loss_of(seed_rows, batch, n_valid)`` is the task's loss over what
   the model gives for the seed rows (:func:`_node_loss`,
-  :func:`_link_loss`)."""
+  :func:`_link_loss`). With ``has_aux`` it gives ``(loss, aux)`` and
+  ``aux`` is returned last, no gradient through it."""
   def loss_fn(p):
     with jax.named_scope('forward'):
       return loss_of(model.apply(p, batch), batch, n_valid)
 
   with scope('model_step'):
-    loss, grads = jax.value_and_grad(loss_fn)(params)
+    loss, grads = jax.value_and_grad(loss_fn, has_aux=has_aux)(params)
+    if has_aux:
+      loss, aux = loss
   with scope('collectives', 'grad_sync'):
     # DDP allreduce (mean over devices), riding ICI
     grads = jax.lax.pmean(grads, axis)
@@ -132,7 +191,8 @@ def _sage_update(model, tx, axis, loss_of, params, opt_state, batch,
   with scope('model_step', 'update'):
     updates, opt_state = tx.update(grads, opt_state, params)
     params = optax.apply_updates(params, updates)
-  return params, opt_state, loss
+  return (params, opt_state, loss, aux) if has_aux else (
+      params, opt_state, loss)
 
 
 class SPMDSageTrainStep(StepCounters):
@@ -172,6 +232,30 @@ class SPMDSageTrainStep(StepCounters):
       it drew and expanded (among its counters), for a check of the
       negatives themselves against the graph; off, the program has no
       such output.
+    enclose: an ``ops.subgraph.EncloseSpec`` makes the link step SEAL's:
+      ``fanouts`` is the one hop ``[enclose.fanout]``, ``model`` reads
+      out graphs (``models.DGCNN`` with ``max_z``), the graph is read
+      undirected (a symmetric CSR) and ``__call__`` takes and returns
+      what the link step does. Of the ``2B`` links a device the first
+      ``B`` are the positives. Link ``l`` holds ``S = 2 + 2 * fanout``
+      node slots: its source, its destination, then the distinct
+      neighbours the hop took of either. Its edges are every edge of the
+      graph among its nodes less the link itself: never fewer, unless
+      ``edges_dropped`` says so (``EncloseSpec``'s budgets). The step
+      counts, beside a link step's counters: ``nodes_by_hop`` ``[2]``
+      (endpoint slots, fringe nodes), ``subgraph_nodes``,
+      ``subgraph_edges`` (directed), ``edges_dropped``, ``links_capped``
+      (links with an endpoint wider than the fanout), ``tiles_read``,
+      ``hub_members``, ``hub_pairs_probed``, ``drnl_rounds``,
+      ``drnl_unreachable`` and the store's.
+    keep_sample: an enclosing-subgraph step also hands back what it
+      extracted, among its counters: ``nodes [2B, S]`` (-1 padded), ``z
+      [2B, S]`` and ``adj_bits [2B, S, ceil(S / 8)]`` (``numpy.packbits``
+      of the blocks' rows), for a check against the CSR; and
+      ``pool_order [2B, k]``, the node slots the model's readout kept of
+      each graph, in its order: a sort is a choice, not arithmetic, so a
+      comparison of the arithmetic takes the choice as made and checks it
+      on its own.
 
   Every per-batch step also says how full its padded budgets were:
   :meth:`counters` reads what the newest steps counted,
@@ -182,11 +266,22 @@ class SPMDSageTrainStep(StepCounters):
                labels, fanouts: Sequence[int],
                batch_size_per_device: int, axis: str = 'data',
                with_edge: bool = False, cold_streaming: bool = False,
-               neg_sampling=None, keep_seeds: bool = False):
+               neg_sampling=None, keep_seeds: bool = False,
+               enclose: EncloseSpec = None, keep_sample: bool = False):
     from .dist_feature import require_device_resident
     self.neg_sampling = NegativeSampling.cast(neg_sampling)
     self._link = self.neg_sampling is not None
     self._keep_seeds = bool(keep_seeds)
+    self._enclose, self._keep_sample = enclose, bool(keep_sample)
+    if enclose is not None:
+      if not self._link:
+        raise ValueError('an enclosing-subgraph step is a link step: '
+                         'give it a neg_sampling')
+      if list(fanouts) != [enclose.fanout]:
+        raise ValueError(f'an enclosing-subgraph step takes one hop at '
+                         f'its spec\'s fanout {enclose.fanout}; got '
+                         f'fanouts={list(fanouts)}')
+      model = _OnSubgraphs(model)
     if self._link:
       if not self.neg_sampling.is_binary() \
           or self.neg_sampling.amount != 1:
@@ -242,8 +337,9 @@ class SPMDSageTrainStep(StepCounters):
     # pre-committing the replicated sharding here keeps the per-step
     # call from re-broadcasting them each execution
     self._indptr = jax.device_put(graph.indptr, NamedSharding(mesh, P()))
-    self._indices = jax.device_put(graph.indices,
-                                   NamedSharding(mesh, P()))
+    self._indices = jax.device_put(
+        graph.indices if enclose is None else pad_to_tiles(graph.indices),
+        NamedSharding(mesh, P()))
     n_dev = mesh.shape[axis]
     # per-device inducer tables, stacked on the mesh axis
     table, scratch = make_dedup_tables(graph.num_nodes)
@@ -280,7 +376,13 @@ class SPMDSageTrainStep(StepCounters):
     params = self.model.init(key, batch)
     return replicate(params, self.mesh)
 
-  def _dummy_batch(self) -> Batch:
+  def _dummy_batch(self):
+    if self._enclose is not None:
+      links, s = 2 * self.bs, self._enclose.node_slots
+      return dict(x=jnp.zeros((links, s, self.feature.feature_dim)),
+                  z=jnp.zeros((links, s), jnp.int32),
+                  adj=jnp.zeros((links, s, s), bool),
+                  node_mask=jnp.zeros((links, s), bool))
     budget = sample_budget(self.seed_slots, self.fanouts)
     ecap = self._batch_static['edge_hop_offsets'][-1]
     return Batch(
@@ -345,6 +447,9 @@ class SPMDSageTrainStep(StepCounters):
     valid edges by hop, a link step's negatives and distinct seeds, and
     what the store counted (its exchange, or in place the chunks of
     request slots it gathered)."""
+    if self._enclose is not None:
+      return self._make_enclose_body(feat_shard, indptr, indices,
+                                     cold_shard)
     feature, model, tx, axis = self.feature, self.model, self.tx, self.axis
     fanouts, bs = self.fanouts, self.bs
     with_edge, link = self.with_edge, self._link
@@ -390,6 +495,77 @@ class SPMDSageTrainStep(StepCounters):
       self._note_layer_rows(batch)
       params, opt_state, loss = _sage_update(
           model, tx, axis, loss_of, params, opt_state, batch, n_valid[0])
+      return params, opt_state, table, scratch, (loss, counted)
+
+    return body
+
+  def _make_enclose_body(self, feat_shard, indptr, indices, cold_shard):
+    """``_make_batch_body`` for an enclosing-subgraph step: the link
+    step's front as it is, then a batch of ``2B`` graphs where the
+    fan-out tree stood (the module's text). The dedup tables pass
+    through untouched: every link is deduped on its own."""
+    feature, model, tx, axis = self.feature, self.model, self.tx, self.axis
+    spec, bs = self._enclose, self.bs
+    links, s, k = 2 * bs, spec.node_slots, spec.fanout
+    loss_of = _graph_loss(bs)
+
+    def body(params, opt_state, table, scratch, pairs, n_valid, key):
+      with scope('sampler'):
+        key = jax.random.fold_in(key[0], jax.lax.axis_index(axis))
+        kneg, key = jax.random.split(key)
+        seeds, seed_mask, edge_label, counted = self._link_seeds(
+            indptr, indices, pairs, n_valid[0], kneg)
+        counted['seed_unique'] = _count_distinct(seeds, seed_mask)
+        if self._keep_seeds:
+          counted['seeds'] = seeds
+        with jax.named_scope('enclose'):
+          with jax.named_scope('sample_hop0'):
+            _, sub = jax.random.split(key)
+            hop = sample_neighbors(indptr, indices, seeds, k, sub,
+                                   seed_mask=seed_mask)
+            wide = (jnp.take(indptr, seeds + 1) - jnp.take(indptr, seeds)
+                    > k).reshape(2, links).any(0)
+          link_mask = seed_mask[:links]
+          out = enclosing_subgraphs(
+              indptr, indices, seeds.reshape(2, links),
+              hop.nbrs.reshape(2, links, k), hop.mask.reshape(2, links, k),
+              link_mask, spec)
+          with jax.named_scope('drnl'):
+            z, rounds, unreachable = drnl_dense(
+                out['adj'], out['node_mask'], spec.max_z)
+        ends = 2 * link_mask.sum(dtype=jnp.int32)
+        counted.update(
+            nodes_by_hop=jnp.stack([ends, out['subgraph_nodes'] - ends]),
+            links_capped=(wide & link_mask).sum(dtype=jnp.int32),
+            drnl_rounds=rounds, drnl_unreachable=unreachable,
+            **{name: out[name] for name in (
+                'subgraph_nodes', 'subgraph_edges', 'edges_dropped',
+                'tiles_read', 'hub_members', 'hub_pairs_probed')})
+        if self._keep_sample:
+          counted.update(nodes=out['nodes'], z=z,
+                         adj_bits=jnp.packbits(out['adj'], axis=-1))
+      with scope('feature_store'):
+        x, store_stats = feature.lookup_local(
+            feat_shard, jnp.maximum(out['nodes'], 0).reshape(-1),
+            out['node_mask'].reshape(-1), axis_name=axis,
+            cold_shard=cold_shard, counters=True)
+        counted.update(store_stats)
+        # the rows' only reader is a matmul at the default precision,
+        # which on a TPU rounds them to bfloat16. Left to the compiler,
+        # that rounding moves in front of the gather: the WHOLE table
+        # converted every step (9.3 ms and 2 GB on the benchmark's 8 M
+        # rows, read on the chip; a barrier alone does not stop it). An
+        # integer view behind a barrier does: the gather's rows have a
+        # reader that needs all 32 bits, and no value changes
+        x = jax.lax.bitcast_convert_type(jax.lax.optimization_barrier(
+            jax.lax.bitcast_convert_type(x, jnp.int32)), x.dtype)
+      batch = dict(x=x.reshape(links, s, -1), z=z, adj=out['adj'],
+                   node_mask=out['node_mask'], edge_label=edge_label)
+      params, opt_state, loss, order = _sage_update(
+          model, tx, axis, loss_of, params, opt_state, batch, n_valid[0],
+          has_aux=True)
+      if self._keep_sample:
+        counted['pool_order'] = order
       return params, opt_state, table, scratch, (loss, counted)
 
     return body
@@ -791,13 +967,25 @@ class SPMDSageTrainStep(StepCounters):
     bucket's ``exchange_cap(b)`` slots and the ``b`` request slots; on
     one shard the chunks the ``b`` request slots are served in."""
     static = self._batch_static
-    slots = dict(
-        nodes_by_hop=np.diff(static['node_hop_offsets'], prepend=0),
-        edges_by_hop=np.diff(static['edge_hop_offsets']))
+    if self._enclose is not None:
+      # a link's slots: 2 endpoints and the fringe; its block's entries
+      # off the diagonal less the link itself; its tiles; a batch's pairs
+      spec, links = self._enclose, 2 * self.bs
+      s = spec.node_slots
+      b = links * s
+      slots = dict(
+          nodes_by_hop=[2 * links, b - 2 * links], subgraph_nodes=b,
+          subgraph_edges=links * (s * (s - 1) - 2), links_capped=links,
+          tiles_read=links * spec.tile_budget, hub_members=b,
+          hub_pairs_probed=spec.hub_pairs, drnl_unreachable=b)
+    else:
+      slots = dict(
+          nodes_by_hop=np.diff(static['node_hop_offsets'], prepend=0),
+          edges_by_hop=np.diff(static['edge_hop_offsets']))
+      b = static['node_hop_offsets'][-1]
     if self._link:
       slots.update(negatives_rejected=NEG_TRIALS * self.bs,
                    negatives_padded=self.bs, seed_unique=self.seed_slots)
-    b = static['node_hop_offsets'][-1]
     if self.feature.in_place:
       slots.update(store_chunks=self.feature.serve_chunks(b))
     else:
