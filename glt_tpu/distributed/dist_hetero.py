@@ -484,6 +484,7 @@ class DistHeteroNeighborSampler:
         'batch': sp, 'seed_labels': sp,
         'num_sampled_nodes': {t: sp for t in types},
         'num_sampled_edges': {e: sp for e in etypes},
+        'hop_rows_read': {e: sp for e in etypes},
     }
     if self.with_edge:
       out_elem['edge'] = {e: sp for e in etypes}
@@ -540,8 +541,8 @@ class DistHeteroNeighborSampler:
         {final_key(e): v for e, v in out['row'].items()})
     out['edge_mask'] = {final_key(e): v
                         for e, v in out['edge_mask'].items()}
-    out['num_sampled_edges'] = {
-        final_key(e): v for e, v in out['num_sampled_edges'].items()}
+    for counted in ('num_sampled_edges', 'hop_rows_read'):
+      out[counted] = {final_key(e): v for e, v in out[counted].items()}
     if self.with_edge:
       out['edge'] = {final_key(e): v for e, v in out['edge'].items()}
     out['input_type'] = seed_type
@@ -739,6 +740,13 @@ class DistHeteroTrainStep(StepCounters):
             self.sampler.num_hops).items()}
     self.node_budget = dict(budgets)
     self.edge_budget = {e: v[-1] for e, v in edge_offsets.items()}
+    #: slots of the frontier each relation expands in each hop (0 for a
+    #: hop it is not read in): what ``hop_rows_read`` is read against
+    self._frontier_slots = {
+        self._final_key(e): tuple(
+            caps[h][row_t] if self.sampler.num_neighbors[e][h] else 0
+            for h in range(self.sampler.num_hops))
+        for e, (row_t, _) in trav.items()}
     #: the rows of the step's ``nodes_by_hop`` and ``edges_by_hop``
     #: counters, in order: node types, and relations as ``edge_budget``
     #: keys them
@@ -920,9 +928,9 @@ class DistHeteroTrainStep(StepCounters):
     returns (device_batch, specs, payloads) where ``device_batch(...)``
     runs sampling + feature/efeat collate inside shard_map and yields
     (batch, y, out_tables, counters): ``counters`` is what the sampler
-    counted, ``nodes_by_hop`` ``[T, H + 1]`` and ``edges_by_hop``
-    ``[R, H]`` in the order of ``counter_node_types`` and
-    ``counter_edge_types``, and where every node store serves in place
+    counted, ``nodes_by_hop`` ``[T, H + 1]``, ``edges_by_hop`` and
+    ``hop_rows_read`` ``[R, H]`` in the order of ``counter_node_types``
+    and ``counter_edge_types``, and where every node store serves in place
     ``store_chunks`` ``[T]``, the chunks of a type's request slots that
     its store gathered."""
     from ..loader.transform import HeteroBatch
@@ -964,11 +972,13 @@ class DistHeteroTrainStep(StepCounters):
               shards_in[seed_type], seeds, n_valid[0], kneg)
         out, out_tables = device_core(shards_in, seeds, n_valid[0],
                                       my_key, flat_tables, seed_mask)
+        by_relation = lambda counted: self._by_relation_and_hop(
+            {fk(e): v for e, v in out[counted].items()})
         counters = dict(
             nodes_by_hop=jnp.stack([out['num_sampled_nodes'][t]
                                     for t in self.counter_node_types]),
-            edges_by_hop=self._edges_by_hop(
-                {fk(e): v for e, v in out['num_sampled_edges'].items()}))
+            edges_by_hop=by_relation('num_sampled_edges'),
+            hop_rows_read=by_relation('hop_rows_read'))
         if link:
           # a seed slot's label is its endpoint's row of its type: the
           # labels of a type's seeds are its first ones
@@ -1260,11 +1270,11 @@ class DistHeteroTrainStep(StepCounters):
       _synced['loss'] = loss
     return params, opt_state, loss
 
-  def _edges_by_hop(self, by_relation):
-    """``[R, H]`` from the sampler's ``num_sampled_edges``, which holds
-    for each relation the hops it is read in: rows in the order of
-    ``counter_edge_types``, 0 for a hop a relation is not read in (no
-    width in ``edge_hop_offsets_dict``)."""
+  def _by_relation_and_hop(self, by_relation):
+    """``[R, H]`` from the sampler's ``num_sampled_edges`` or
+    ``hop_rows_read``, which hold for each relation the hops it is read
+    in: rows in the order of ``counter_edge_types``, 0 for a hop a
+    relation is not read in (no width in ``edge_hop_offsets_dict``)."""
     offsets = self._batch_static['edge_hop_offsets_dict']
     rows = []
     for e in self.counter_edge_types:
@@ -1301,8 +1311,9 @@ class DistHeteroTrainStep(StepCounters):
   def counter_slots(self) -> dict:
     """The contract of :meth:`StepCounters.counter_slots`: by type the
     node slots of each hop (``node_hop_offsets_dict``), by relation the
-    edge slots (``edge_hop_offsets_dict``), rows in the order of
-    ``counter_node_types`` and ``counter_edge_types``; where the node
+    edge slots (``edge_hop_offsets_dict``) and the slots of the frontier
+    it expands, rows in the order of ``counter_node_types`` and
+    ``counter_edge_types``; where the node
     stores serve in place, by type the chunks its request slots are
     served in; of an edge-seeded step the ``NEG_TRIALS x B`` proposals,
     the ``B`` negatives and the ``2B`` seed slots of each end, and for a
@@ -1315,7 +1326,10 @@ class DistHeteroTrainStep(StepCounters):
             for t in self.counter_node_types]).astype(np.int64),
         edges_by_hop=np.stack([
             np.diff(static['edge_hop_offsets_dict'][e])
-            for e in self.counter_edge_types]).astype(np.int64))
+            for e in self.counter_edge_types]).astype(np.int64),
+        hop_rows_read=np.asarray(
+            [self._frontier_slots[e] for e in self.counter_edge_types],
+            np.int64))
     if self._stores_in_place:
       slots['store_chunks'] = np.asarray(
           [serve_chunks(self.node_budget[t])

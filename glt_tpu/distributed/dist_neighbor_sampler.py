@@ -93,7 +93,7 @@ def make_dist_one_hop(graph_shards: Dict[str, jax.Array], num_nodes: int,
     from ..ops.sample import NeighborOutput
     if n_parts == 1:
       return NeighborOutput(nbrs=out.nbrs, mask=out.mask & mask[:, None],
-                            eids=out.eids)
+                            eids=out.eids, rows_read=out.rows_read)
     resp_nbrs = all_to_all(out.nbrs.reshape(n_parts, f, width), axis)
     resp_mask = all_to_all(out.mask.reshape(n_parts, f, width), axis)
     resp_eids = all_to_all(out.eids.reshape(n_parts, f, width), axis)
@@ -101,7 +101,7 @@ def make_dist_one_hop(graph_shards: Dict[str, jax.Array], num_nodes: int,
     nmask = unbucket(resp_mask, meta, n_parts, invalid_value=False)
     out_eids = unbucket(resp_eids, meta, n_parts, invalid_value=-1)
     return NeighborOutput(nbrs=nbrs, mask=nmask & mask[:, None],
-                          eids=out_eids)
+                          eids=out_eids, rows_read=out.rows_read)
 
   return one_hop
 
@@ -206,7 +206,7 @@ class DistNeighborSampler:
   def _out_keys(self):
     keys = ['node', 'node_count', 'row', 'col', 'edge_mask', 'batch',
             'seed_labels', 'seed_count', 'num_sampled_nodes',
-            'num_sampled_edges']
+            'num_sampled_edges', 'hop_rows_read']
     if self.with_edge:
       keys.append('edge')
     return keys

@@ -82,7 +82,11 @@ class StepCounters:
     order of ``counter_node_types``; the sum is the batch's
     ``node_count``) and ``edges_by_hop`` (valid edge slots of each hop:
     ``[H]``, by relation ``[R, H]`` in the order of
-    ``counter_edge_types``, 0 for a hop a relation is not read in); a
+    ``counter_edge_types``, 0 for a hop a relation is not read in) and
+    ``hop_rows_read`` (frontier rows a hop read ``indptr`` and
+    ``indices`` for, ``ops/sample.py::sample_neighbors``: the live
+    rows in whole chunks, or every slot where it took the plain read;
+    in ``edges_by_hop``'s shape; an enclosing-subgraph step has none); a
     link step also what ``link_counters`` names, a step whose store
     exchanges also what ``store_counters`` names, one whose store serves
     in place ``store_chunks`` (the chunks of request slots it gathered:

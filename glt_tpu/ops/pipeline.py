@@ -158,6 +158,14 @@ def hop_fanouts(fanouts: Sequence[int]) -> Optional[Tuple[int, ...]]:
   return tuple(abs(k) for k in fanouts)
 
 
+def hop_rows_read(out: NeighborOutput, frontier_ids: jax.Array):
+  """Frontier rows a hop read ``indptr`` and ``indices`` for: what the
+  hop counted (``NeighborOutput.rows_read``), or every slot of the
+  frontier for a hop that does not say."""
+  rows = frontier_ids.shape[0] if out.rows_read is None else out.rows_read
+  return jnp.asarray(rows, jnp.int32)
+
+
 def multihop_sample(one_hop: OneHopFn,
                     seeds: jax.Array,
                     n_valid: jax.Array,
@@ -209,7 +217,7 @@ def multihop_sample(one_hop: OneHopFn,
 
   rows_parent, cols_child, emasks, eid_list = [], [], [], []
   hop_node_counts = [seed_count]
-  hop_edge_counts = []
+  hop_edge_counts, hop_rows = [], []
   cap = batch_size
   for hop_idx, fanout in enumerate(fanouts):
     width = abs(fanout)  # negative = full-neighborhood hop, window |k|
@@ -220,6 +228,7 @@ def multihop_sample(one_hop: OneHopFn,
     # them (``sampler/sample_hop0``, ``sampler/dedup0``, ...)
     with jax.named_scope(f'sample_hop{hop_idx}'):
       out = one_hop(frontier_ids, fanout, sub, frontier_mask)
+    hop_rows.append(hop_rows_read(out, frontier_ids))
     prev_count = state.count
     with jax.named_scope(f'dedup{hop_idx}'):
       state, labels_flat = dense_assign(
@@ -249,6 +258,7 @@ def multihop_sample(one_hop: OneHopFn,
       seed_count=seed_count,
       num_sampled_nodes=jnp.stack(hop_node_counts),
       num_sampled_edges=jnp.stack(hop_edge_counts),
+      hop_rows_read=jnp.stack(hop_rows),
   )
   if with_edge:
     out_dict['edge'] = jnp.concatenate(eid_list)
@@ -291,7 +301,7 @@ def _multihop_sample_sorted(one_hop: OneHopFn,
   fused = fused_hops()
   rows_parent, cols_child, emasks, eid_list = [], [], [], []
   hop_node_counts = [seed_count]
-  hop_edge_counts = []
+  hop_edge_counts, hop_rows = [], []
   for hop_idx, fanout in enumerate(fanouts):
     width = abs(fanout)
     key, sub = jax.random.split(key)
@@ -299,6 +309,7 @@ def _multihop_sample_sorted(one_hop: OneHopFn,
     # counterpart of the host obs spans; see multihop_sample above)
     with jax.named_scope(f'sample_hop{hop_idx}'):
       out = one_hop(frontier_ids, fanout, sub, frontier_mask)
+    hop_rows.append(hop_rows_read(out, frontier_ids))
     rows_flat = jnp.repeat(frontier_labels, width)
     ids_flat = out.nbrs.reshape(-1)
     mask_flat = out.mask.reshape(-1)
@@ -345,6 +356,7 @@ def _multihop_sample_sorted(one_hop: OneHopFn,
       seed_count=seed_count,
       num_sampled_nodes=jnp.stack(hop_node_counts),
       num_sampled_edges=jnp.stack(hop_edge_counts),
+      hop_rows_read=jnp.stack(hop_rows),
   )
   if with_edge:
     out_dict['edge'] = jnp.concatenate(eid_list)
@@ -446,7 +458,7 @@ def multihop_sample_hetero(one_hops, trav, num_neighbors, num_hops,
 
   rows_d, cols_d, mask_d, eid_d = {}, {}, {}, {}
   hop_nodes = {t: [states[t].count] for t in types}
-  hop_edges = {}
+  hop_edges, hop_rows = {}, {}
   for h in range(num_hops):
     per_type_nbrs = {t: [] for t in types}
     per_meta = []
@@ -459,6 +471,7 @@ def multihop_sample_hetero(one_hops, trav, num_neighbors, num_hops,
       with jax.named_scope(f'sample_hop{h}'), jax.named_scope(as_str(e)):
         key, sub = jax.random.split(key)
         out = one_hops[e](f_ids, k, sub, f_mask)
+      hop_rows.setdefault(e, []).append(hop_rows_read(out, f_ids))
       per_type_nbrs[col_t].append(
           (out.nbrs.reshape(-1), out.mask.reshape(-1)))
       per_meta.append((e, col_t, jnp.repeat(f_labels, width),
@@ -509,6 +522,7 @@ def multihop_sample_hetero(one_hops, trav, num_neighbors, num_hops,
       seed_labels=seed_labels,
       num_sampled_nodes={t: jnp.stack(v) for t, v in hop_nodes.items()},
       num_sampled_edges={e: jnp.stack(v) for e, v in hop_edges.items()},
+      hop_rows_read={e: jnp.stack(v) for e, v in hop_rows.items()},
   )
   if with_edge:
     result['edge'] = {e: jnp.concatenate(v) for e, v in eid_d.items()}
@@ -549,7 +563,7 @@ def _multihop_sample_hetero_sorted(one_hops, trav, num_neighbors,
 
   rows_d, cols_d, mask_d, eid_d = {}, {}, {}, {}
   hop_nodes = {t: [seen[t][2]] for t in types}
-  hop_edges = {}
+  hop_edges, hop_rows = {}, {}
   for h in range(num_hops):
     per_type = {t: [] for t in types}
     per_meta = []
@@ -562,6 +576,7 @@ def _multihop_sample_hetero_sorted(one_hops, trav, num_neighbors,
       with jax.named_scope(f'sample_hop{h}'), jax.named_scope(as_str(e)):
         key, sub = jax.random.split(key)
         out = one_hops[e](f_ids, k, sub, f_mask)
+      hop_rows.setdefault(e, []).append(hop_rows_read(out, f_ids))
       mflat = out.mask.reshape(-1)
       per_type[col_t].append((out.nbrs.reshape(-1), mflat))
       per_meta.append((e, col_t, jnp.repeat(f_labels, width), mflat,
@@ -626,6 +641,7 @@ def _multihop_sample_hetero_sorted(one_hops, trav, num_neighbors,
       seed_labels=seed_labels,
       num_sampled_nodes={t: jnp.stack(v) for t, v in hop_nodes.items()},
       num_sampled_edges={e: jnp.stack(v) for e, v in hop_edges.items()},
+      hop_rows_read={e: jnp.stack(v) for e, v in hop_rows.items()},
   )
   if with_edge:
     result['edge'] = {e: jnp.concatenate(v) for e, v in eid_d.items()}

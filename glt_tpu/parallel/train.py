@@ -443,10 +443,10 @@ class SPMDSageTrainStep(StepCounters):
     verbatim by the per-batch step and the superstep scan so the two
     engines stay bit-identical. Its last output is ``(loss, counters)``,
     ``counters`` one flat dict of what the step counted
-    (:meth:`counters` names every entry): the sampler's new nodes and
-    valid edges by hop, a link step's negatives and distinct seeds, and
-    what the store counted (its exchange, or in place the chunks of
-    request slots it gathered)."""
+    (:meth:`counters` names every entry): the sampler's new nodes,
+    valid edges and frontier rows read by hop, a link step's negatives
+    and distinct seeds, and what the store counted (its exchange, or in
+    place the chunks of request slots it gathered)."""
     if self._enclose is not None:
       return self._make_enclose_body(feat_shard, indptr, indices,
                                      cold_shard)
@@ -470,7 +470,8 @@ class SPMDSageTrainStep(StepCounters):
             one_hop, seeds, n_valid[0], fanouts, key, table, scratch,
             with_edge=with_edge, seed_mask=seed_mask)
         counted.update(nodes_by_hop=out['num_sampled_nodes'],
-                       edges_by_hop=out['num_sampled_edges'])
+                       edges_by_hop=out['num_sampled_edges'],
+                       hop_rows_read=out['hop_rows_read'])
         if link:
           # a seed slot's label is its endpoint's row of the model's
           # output: the labels of the seeds are the first ones
@@ -960,8 +961,9 @@ class SPMDSageTrainStep(StepCounters):
 
   def counter_slots(self) -> dict:
     """The contract of :meth:`StepCounters.counter_slots`: hop by hop
-    the node slots (``node_hop_offsets``) and edge slots
-    (``edge_hop_offsets``) of a device's batch; a link step's
+    the node slots (``node_hop_offsets``), the edge slots
+    (``edge_hop_offsets``) and the frontier's slots of a device's
+    batch; a link step's
     ``NEG_TRIALS x B`` proposals, ``B`` negatives and ``4B`` seed slots;
     over more than one shard the drain's most rounds, a per-owner
     bucket's ``exchange_cap(b)`` slots and the ``b`` request slots; on
@@ -979,9 +981,11 @@ class SPMDSageTrainStep(StepCounters):
           tiles_read=links * spec.tile_budget, hub_members=b,
           hub_pairs_probed=spec.hub_pairs, drnl_unreachable=b)
     else:
+      edges = np.diff(static['edge_hop_offsets'])
+      # a hop's frontier holds a slot for every ``K`` of its edge slots
       slots = dict(
           nodes_by_hop=np.diff(static['node_hop_offsets'], prepend=0),
-          edges_by_hop=np.diff(static['edge_hop_offsets']))
+          edges_by_hop=edges, hop_rows_read=edges // np.abs(self.fanouts))
       b = static['node_hop_offsets'][-1]
     if self._link:
       slots.update(negatives_rejected=NEG_TRIALS * self.bs,

@@ -386,7 +386,8 @@ def test_a_node_seeded_step_carries_nothing_of_the_link_front(chips):
   loss, counted = out[-1]
   store = ['store_bucket_max', 'store_requests', 'store_rounds']
   # ... and on one the chunks it gathered
-  assert sorted(counted) == ['edges_by_hop', 'nodes_by_hop'] + (
+  assert sorted(counted) == ['edges_by_hop', 'hop_rows_read',
+                             'nodes_by_hop'] + (
       store if chips > 1 else ['store_chunks'])
   assert jax.tree.structure(loss).num_leaves == 1
   assert tuple(loss.shape) == (chips,)

@@ -832,7 +832,8 @@ def test_step_on_one_shard_serves_in_place_and_counts_its_chunks(
   assert step.feature.in_place and _in_place_gauge() == 1.0
   counted, slots = step.counters(), step.counter_slots()
   assert sorted(set(counted) - {'step'}) == [
-      'edges_by_hop', 'nodes_by_hop', 'store_chunks']   # no store_rounds
+      'edges_by_hop', 'hop_rows_read', 'nodes_by_hop',
+      'store_chunks']   # no store_rounds
   assert counted['store_chunks'].shape == (2, 1)
   assert counted['store_chunks'].dtype == np.int32
   assert int(slots['store_chunks']) == 5 == -(-b // 128)

@@ -492,7 +492,7 @@ def test_step_on_one_partition_counts_its_chunks_and_trains_alike(
   counted, slots = step.counters(), step.counter_slots()
   types = step.counter_node_types
   assert sorted(set(counted) - {'step'}) == [
-      'edges_by_hop', 'nodes_by_hop', 'store_chunks']
+      'edges_by_hop', 'hop_rows_read', 'nodes_by_hop', 'store_chunks']
   assert counted['store_chunks'].shape == (2, 1, len(types))
   assert slots['store_chunks'].tolist() == [
       max(1, -(-step.node_budget[t] // 16)) for t in types]

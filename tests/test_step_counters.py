@@ -1,5 +1,8 @@
 """What the fused per-batch steps count: node and edge occupancy by hop
-against oracles that share nothing with the step, the store's and the
+against oracles that share nothing with the step, the frontier rows each
+hop read (every slot at these sizes: a frontier is one chunk of
+``ops/sample.py::HOP_CHUNK``; tests/test_sample_live_rows.py patches the
+chunk small), the store's and the
 link front's counters in the same flat dict, the 128 newest steps held
 on the device, and a read that traces and compiles nothing. On the CPU,
 on the dedup combination the chip runs."""
@@ -19,7 +22,7 @@ from glt_tpu.parallel.train import LINK_COUNTERS, STORE_COUNTERS
 
 from test_parallel import _tiny_step   # 64 nodes, 64 seeds a device
 
-HOPS = ['edges_by_hop', 'nodes_by_hop']
+HOPS = ['edges_by_hop', 'hop_rows_read', 'nodes_by_hop']
 
 
 @pytest.fixture(autouse=True)
@@ -64,6 +67,11 @@ def test_sage_steps_count_what_the_numpy_sampler_finds(chips):
   hops = len(s.fanout)
   assert counted['nodes_by_hop'].shape == (warm, chips, hops + 1)
   assert counted['edges_by_hop'].shape == (warm, chips, hops)
+  # a frontier of one chunk or fewer is read whole
+  np.testing.assert_array_equal(
+      counted['hop_rows_read'],
+      np.broadcast_to(t.counter_slots()['hop_rows_read'],
+                      (warm, chips, hops)))
   assert all(v.dtype == np.int32 for k, v in counted.items() if k != 'step')
   for step in range(warm):
     seeds, keys = driver.feed(s, step)
@@ -90,6 +98,7 @@ def test_sage_slots_are_the_hop_budgets(chips):
                                             b * k0 * k1 * k2]
   assert slots['edges_by_hop'].tolist() == [b * k0, b * k0 * k1,
                                             b * k0 * k1 * k2]
+  assert slots['hop_rows_read'].tolist() == [b, b * k0, b * k0 * k1]
   counted = t.counters()
   for name in HOPS:
     assert slots[name].shape == counted[name].shape[2:]
@@ -121,6 +130,12 @@ def test_the_papers100m_step_would_state_the_issues_slots():
       1024, 15360, 153600, 768000]
   assert np.diff(edge_hop_offsets(1024, fanout)).tolist() == [
       15360, 153600, 768000]
+  # the frontiers: 169,984 slots, of which hops 1 and 2 are read by
+  # chunks of their live rows
+  from glt_tpu.ops import sample
+  frontiers = 1024 * np.cumprod([1] + fanout[:-1])
+  assert frontiers.tolist() == [1024, 15360, 153600]
+  assert frontiers[0] <= sample.HOP_CHUNK < frontiers[1]
 
 
 def _typed_cell(name):
@@ -146,6 +161,13 @@ def test_typed_steps_count_what_their_kept_sample_holds(name):
   assert slots['edges_by_hop'].shape == (rels, hops)
   assert slots['edges_by_hop'].sum(1).tolist() == [
       t.edge_budget[e] for e in t.counter_edge_types]
+  # a relation's frontier in a hop is its edge slots over the fanout
+  widths = {t._final_key(e): np.abs(k)
+            for e, k in t.sampler.num_neighbors.items()}
+  np.testing.assert_array_equal(
+      slots['hop_rows_read'] * np.stack([widths[e]
+                                         for e in t.counter_edge_types]),
+      slots['edges_by_hop'])
   # a type no frontier reaches holds one slot and no hop
   assert [max(int(n), 1) for n in slots['nodes_by_hop'].sum(1)] == [
       t.node_budget[k] for k in t.counter_node_types]
@@ -169,6 +191,8 @@ def test_typed_steps_count_what_their_kept_sample_holds(name):
       by_hop = [int(mask[a:b].sum()) for a, b in zip(offsets[e][:-1],
                                                     offsets[e][1:])]
       assert edges[0, i].tolist() == by_hop
+    np.testing.assert_array_equal(counted['hop_rows_read'][-1, 0],
+                                  slots['hop_rows_read'])
     assert sorted(set(counted) - {'step'}) == HOPS + ['store_chunks']
     # a type's request slots are one chunk at this size, gathered where
     # the type holds a node

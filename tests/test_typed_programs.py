@@ -1,7 +1,8 @@
-"""The five tiny step programs are pinned: the R-GAT step, with the
-promise of parent-major edge slots and with it withheld, and the three
-SAGE steps lower to the programs they were when a PR last meant to change
-them, so a PR that changes a cell's program knows it."""
+"""The six tiny step programs are pinned: the R-GAT step, with the
+promise of parent-major edge slots and with it withheld, the three SAGE
+steps and the enclosing-subgraph step lower to the programs they were
+when a PR last meant to change them, so a PR that changes a cell's
+program knows it."""
 import hashlib
 import os
 import sys
@@ -38,12 +39,28 @@ PARENT_STABLEHLO = {
     # owner, the pack's scatter straight to that slot, one gather back);
     # before it c4 was 53ee57ed...d1e6937c. The four one-chip programs
     # never bucket: their hashes stand.
-    'c1': '77dfcba41ae1e3a7347f32d8b7a4fcf53de596c781cc4de5e3219ac6b1c27162',
-    'c4': 'd0fff17311d26803807dbe50a6b51d0010029ce93925e1e28efa950c424ed731',
-    'link': '3b9a9823673d7ebbcd4ef7eeff9f4a48d76bf4c9583b0b3128036737195b22a2',
-    'typed': '0b627d6e7dee6ccfcc773ff3ed181d614902cdc464b6942d46db9181c4fa8de0',
+    # All five were read anew by PR 41, which gave every per-batch step
+    # whose sampler walks hops one more counter out, ``hop_rows_read``
+    # (the frontier rows each hop read ``indptr`` and ``indices`` for).
+    # At these sizes every frontier is one chunk of
+    # ``ops/sample.py::HOP_CHUNK`` or fewer, so each hop traces to the
+    # plain read it was, the counter is a stack of constants (the
+    # frontiers' slots) and the text differs by that output and the
+    # numbering behind it alone (the parent's texts diffed against these:
+    # nothing else). Before it they were c1 77dfcba4...b1c27162, c4
+    # d0fff173...424ed731, link 3b9a9823...195b22a2, typed
+    # 0b627d6e...c4fa8de0 and withheld 6eb0e928...ec114b53.
+    'c1': 'd8421d94ef828854a54e1566f9ca29868f330c72455d2fe60af2dd58679acddb',
+    'c4': '52c9d8d64d5c450d8d0d4da4944639bb9e52d1fba656699abeff2b54101d017f',
+    'link': '6c3771c000d78e927929dd7ee03d901a34597d309676c6a21adb8b82acf941d0',
+    'typed': '4042ca194a3ca703db26eb53d0ee47bb0597ca927683312e0cdd592016418285',
     'typed_withheld':
-        '6eb0e928de897abf7c93857516190729f9e8fb5a3fba7cb8106af5a9ec114b53',
+        '057c64d7fa971a3ad132256880bfb53101ba45d8bb9d1b3b2060994025c3b24d',
+    # the enclosing-subgraph step, pinned by PR 41 at its parent's text
+    # (read on both trees): its body walks no hop loop and is left as it
+    # is, and its one hop's 4B endpoints are one chunk, so
+    # ``sample_neighbors`` traces to the plain read it was
+    'seal': '8dee044f57434574f2adc83bd2d6c587889c3ed69de7e9633534485804858d05',
 }
 
 
@@ -86,8 +103,9 @@ def _typed_text(withheld):
 
 @pytest.mark.parametrize('name', sorted(PARENT_STABLEHLO))
 def test_the_other_cells_tiny_steps_lower_to_the_parents(name, monkeypatch):
-  """R-GAT's typed step, with the promise and with it withheld, and the
-  three SAGE steps lower to the StableHLO pinned above."""
+  """R-GAT's typed step, with the promise and with it withheld, the three
+  SAGE steps and the enclosing-subgraph step lower to the StableHLO
+  pinned above."""
   monkeypatch.setenv('GLT_DEDUP', 'sort')
   monkeypatch.setenv('GLT_FUSED_HOP', '1')
   sys.path.insert(0, REPO)
@@ -95,12 +113,15 @@ def test_the_other_cells_tiny_steps_lower_to_the_parents(name, monkeypatch):
   if name.startswith('typed'):
     text = _typed_text(name == 'typed_withheld')
   else:
-    from chipbench.drivers import fused, link_fused
+    from chipbench.drivers import fused, link_fused, seal_fused
     import test_chipbench
     import test_link_cell
+    import test_seal_cell
     text = {'c1': lambda: _sage_text(fused, test_chipbench.tiny_cell(1), 1),
             'c4': lambda: _sage_text(fused, test_chipbench.tiny_cell(4), 4),
             'link': lambda: _sage_text(link_fused, test_link_cell.tiny_cell(),
+                                       1),
+            'seal': lambda: _sage_text(seal_fused, test_seal_cell.tiny_cell(),
                                        1)}[name]()
   assert hashlib.sha256(text.encode()).hexdigest() == PARENT_STABLEHLO[name]
 
