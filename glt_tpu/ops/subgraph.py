@@ -19,7 +19,9 @@ Two extractions live here, and each says what it does past its budget:
 * :func:`enclosing_subgraphs`, ``L`` small node sets at once (a batch of
   SEAL links) as dense ``[L, S, S]`` blocks, exact on a graph with hubs
   inside static budgets: what a budget cannot hold is counted
-  (``edges_dropped``), never dropped silently.
+  (``edges_dropped``), never dropped silently. A budget is a bound, not
+  the work: the members' rows are read tile by tile in one loop over
+  the tiles the batch holds (:func:`live_tiles_seen`).
 """
 from __future__ import annotations
 
@@ -124,10 +126,13 @@ class EncloseSpec(NamedTuple):
   ``fanout``: neighbours taken of an endpoint: its whole row where that
     is no wider, else a uniform sample without replacement; a link has
     ``node_slots = 2 + 2 * fanout`` node slots.
-  ``tile_budget``: tiles of ``TILE`` entries of ``indices`` read a link.
-    A member at most ``hub_width`` wide is *read*: its whole row, in
-    ``ceil`` of its span over ``TILE`` tiles, members in slot order while
-    the link's budget lasts.
+  ``tile_budget``: the most tiles of ``TILE`` entries of ``indices`` read
+    a link. A member at most ``hub_width`` wide is *read*: its whole row,
+    in ``ceil`` of its span over ``TILE`` tiles, members in slot order
+    while the link's budget lasts. The budget bounds a link and sizes no
+    read: the extraction gathers and matches the tiles the batch's links
+    hold (``tiles_read``, in whole chunks: ``tiles_matched``), all ``L x
+    tile_budget`` of them only when every link fills its own.
   ``hub_width``: a member wider than this is never read (a row of the
     benchmark's graph is up to 40 k wide).
   ``hub_pairs``: pairs of members of one link that were both not read
@@ -158,6 +163,113 @@ def pad_to_tiles(indices: jax.Array) -> jax.Array:
       else indices
 
 
+#: tiles of the batch-wide list one trip of the induction's loop gathers
+#: and matches. ``benchmarks/bench_enclose_match.py`` on a v5e, the
+#: library's form at the SEAL cell's shapes (PERF.md section 6, PR 42):
+#: 5.84 / 5.84 / 5.84 / 5.82 / 6.06 / 6.20 ms at 512 / 1,024 / 2,048 /
+#: 4,096 / 8,192 / 16,384 (the dense form 22.7): flat until the last
+#: chunk's idle slots show, so the small end, which rounds up least
+MATCH_CHUNK = 1024
+#: tiles a link's live tiles are rounded up to in that list, so that a
+#: block has one link, reads one row of its link's members and is one
+#: write into the plane. The same probe: 6.16 / 5.85 / 5.87 / 6.29 ms at 8
+#: / 16 / 32 / 64 (a block costs a write of 0.2 us, a link 16 idle tiles
+#: of 60 ns at 32); a flat list of tiles (a block of 1) 8.5 to 22 by the
+#: way its matches reach their owners
+MATCH_BLOCK = 32
+
+
+def live_tiles_seen(indices: jax.Array, start: jax.Array, deg: jax.Array,
+                    lo: jax.Array, hi: jax.Array, member: jax.Array,
+                    tile_budget: int):
+  """``(seen [L, S, S] float32, tiles matched)``: ``seen[l, i, j]`` counts
+  the tiles of member ``i``'s row in which member ``j`` of the same link
+  stands, over the tiles the batch holds and no other.
+
+  ``start``, ``deg`` ``[L, S]``: the members' rows; ``lo``, ``hi`` ``[L,
+  S]``: the tiles ``lo .. hi - 1`` of its link's budget that a read member
+  owns (0, 0 for a member not read), so a link's live tiles are the prefix
+  ``0 .. hi.max() - 1`` of its budget; ``member`` ``[L, S]``: the node
+  ids, a dead slot below -1.
+
+  Which tile of ``indices`` a budgeted slot reads and which span of it is
+  its owner's row is arithmetic over every slot (masked sums over ``[L,
+  TL, S]``: cheap). The gather and the match are not, and run over the
+  batch's live tiles alone: one list in (link, tile) order, a link's tiles
+  rounded up to whole blocks of ``MATCH_BLOCK`` so that a block has one
+  link (a running sum of the links' blocks gives a link's base, a block
+  finds its link by counting bases). One ``lax.while_loop`` over the
+  list's chunks of ``MATCH_CHUNK`` tiles gathers a chunk's tiles, masks
+  them to their owners' rows, matches them against their links' members
+  (the members in the lanes, a tile's entries OR-ed over a major axis)
+  and puts each block's matches at its link's place in a zero ``[L, TL,
+  S]`` plane, a block a write. The owners' sums are then one product on
+  the matrix unit: 0/1 products, sums of at most the budget, exact in
+  float32. A slot with no owner (a link's last block, the list's last
+  chunk) reads a tile of its own, the slots' tiles spread evenly over
+  ``indices``, and matches nothing."""
+  num_links, s = start.shape
+  b = MATCH_BLOCK
+  per_link = -(-tile_budget // b)
+  tl = per_link * b
+  c = min(MATCH_CHUNK // b, num_links * per_link) * b
+  g = c // b
+  num_tiles = indices.shape[0] // TILE
+  t = jnp.arange(tl, dtype=jnp.int32)[None, :, None]
+  owner = (lo[:, None, :] <= t) & (t < hi[:, None, :])       # [L, TL, S]
+  of_owner = lambda a: jnp.sum(
+      jnp.where(owner, a[:, None, :], 0), axis=-1)          # [L, TL]
+  # a budgeted slot's tile and its owner's row, a block a row; one more
+  # row, of slots with no owner, for the blocks past the list's end
+  slots = jnp.concatenate(
+      [a.reshape(-1, b) for a in (of_owner(start // TILE - lo) + t[:, :, 0],
+                                  of_owner(start), of_owner(start + deg))],
+      axis=1)                                               # [L TL / B, 3 B]
+  slots = jnp.concatenate([slots, jnp.zeros((1, 3 * b), jnp.int32)])
+  blocks = (hi.max(axis=1) + (b - 1)) // b           # a link's live blocks
+  upto = jnp.cumsum(blocks)
+  chunks = (upto[-1] + (g - 1)) // g
+  cap = -(-num_links * per_link // g) * g    # the most blocks the list holds
+  # block q of the list: its link by counting the links that end before
+  # it (a block past the list's end falls behind the last link's), its
+  # place among its link's blocks
+  q = jnp.arange(cap, dtype=jnp.int32)
+  before = upto[None, :-1] <= q[:, None]                     # [cap, L - 1]
+  link = before.sum(-1).astype(jnp.int32)
+  first = q - jnp.sum(jnp.where(before, blocks[None, :-1], 0), axis=-1)
+  rows = indices.reshape(num_tiles, TILE)
+  stride = max(num_tiles // (cap * b), 1)
+  lane = jnp.arange(TILE, dtype=jnp.int32)
+
+  def match_chunk(carry):
+    k, plane = carry
+    of = jax.lax.dynamic_slice(link, (k * g,), (g,))
+    at = jax.lax.dynamic_slice(first, (k * g,), (g,))
+    got = jnp.take(slots, of * per_link + at, axis=0, mode='clip')
+    tile, row_lo, row_hi = got[:, :b], got[:, b:2 * b], got[:, 2 * b:]
+    spare = ((k * c + jnp.arange(c, dtype=jnp.int32)) * stride
+             % num_tiles).reshape(g, b)
+    tile = jnp.where(row_hi > row_lo, tile, spare)
+    vals = jnp.take(rows, tile.reshape(-1), axis=0,
+                    mode='clip').reshape(g, b, TILE)
+    pos = tile[..., None] * TILE + lane
+    vals = jnp.where((pos >= row_lo[..., None]) & (pos < row_hi[..., None]),
+                     vals, -1)
+    mine = jnp.take(member, of, axis=0, mode='clip')        # [G, S]
+    match = (vals[..., None] == mine[:, None, None, :]).any(2)
+    # a block past the list's end has no place: dropped
+    return k + 1, plane.at[of, at].set(
+        match.astype(jnp.bfloat16), mode='drop', unique_indices=True)
+
+  _, plane = jax.lax.while_loop(
+      lambda carry: carry[0] < chunks, match_chunk,
+      (jnp.int32(0), jnp.zeros((num_links, per_link, b, s), jnp.bfloat16)))
+  seen = jnp.einsum('lti,ltj->lij', owner.astype(jnp.bfloat16),
+                    plane.reshape(num_links, tl, s),
+                    preferred_element_type=jnp.float32)
+  return seen, chunks * c
+
+
 def enclosing_subgraphs(indptr: jax.Array, indices: jax.Array,
                         ends: jax.Array, nbrs: jax.Array,
                         nbr_mask: jax.Array, link_mask: jax.Array,
@@ -181,10 +293,13 @@ def enclosing_subgraphs(indptr: jax.Array, indices: jax.Array,
   ``adj [L, S, S]`` bool (symmetric, no loops; every edge of the graph
   between two nodes of a link, less the link itself in both directions),
   and the scalar int32 counts ``subgraph_nodes``, ``subgraph_edges``
-  (directed), ``tiles_read``, ``hub_members`` (live members not read),
-  ``hub_pairs_probed`` and ``edges_dropped`` (pairs of unread members
-  past ``hub_pairs``: each may be an edge that ``adj`` lacks; 0 means
-  ``adj`` is exact).
+  (directed), ``tiles_read`` (the tiles the read members' rows span: a
+  link's are a prefix of its budget), ``tiles_matched`` (the tiles
+  gathered and matched for them: the batch's live tiles in whole chunks
+  of :func:`live_tiles_seen`'s loop, never the budget's idle rest),
+  ``hub_members`` (live members not read), ``hub_pairs_probed`` and
+  ``edges_dropped`` (pairs of unread members past ``hub_pairs``: each may
+  be an edge that ``adj`` lacks; 0 means ``adj`` is exact).
   """
   num_links, s = ends.shape[1], spec.node_slots
   tl, cap = spec.tile_budget, spec.hub_pairs
@@ -211,26 +326,9 @@ def enclosing_subgraphs(indptr: jax.Array, indices: jax.Array,
     read = (span > 0) & (hi <= tl)
     hi = jnp.where(read, hi, 0)
     lo = jnp.where(read, hi - span, 0)
-    t = jnp.arange(tl, dtype=jnp.int32)[None, :, None]
-    owner = (lo[:, None, :] <= t) & (t < hi[:, None, :])     # [L, TL, S]
-    of_owner = lambda a: jnp.sum(
-        jnp.where(owner, a[:, None, :], 0), axis=-1)        # [L, TL]
-    tile = of_owner(start // TILE - lo) + t[:, :, 0]
-    row_lo, row_hi = of_owner(start), of_owner(start + deg)
-    tiles = jnp.take(
-        indices.reshape(-1, TILE),
-        jnp.clip(tile, 0, indices.shape[0] // TILE - 1).reshape(-1),
-        axis=0).reshape(num_links, tl, TILE)
-    pos = tile[..., None] * TILE + jnp.arange(TILE, dtype=jnp.int32)
-    vals = jnp.where((pos >= row_lo[..., None]) & (pos < row_hi[..., None]),
-                     tiles, -1)
     member = jnp.where(live, nodes, -2)
-    match = (vals[:, :, None, :] == member[:, None, :, None]).any(-1)
-    # a member's tiles summed into its row of the block, on the matrix
-    # unit: 0/1 products, sums of at most TL, exact in float32
-    seen = jnp.einsum('lti,ltj->lij', owner.astype(jnp.bfloat16),
-                      match.astype(jnp.bfloat16),
-                      preferred_element_type=jnp.float32)
+    seen, tiles_matched = live_tiles_seen(indices, start, deg, lo, hi,
+                                          member, tl)
     unread = live & (deg > 0) & ~read
     with jax.named_scope('hub_pairs'):
       ranks = jnp.cumsum(unread, axis=1, dtype=jnp.int32)    # [L, S]
@@ -280,6 +378,7 @@ def enclosing_subgraphs(indptr: jax.Array, indices: jax.Array,
       subgraph_nodes=count.sum(dtype=jnp.int32),
       subgraph_edges=adj.sum(dtype=jnp.int32),
       tiles_read=hi.max(axis=1).sum(dtype=jnp.int32),
+      tiles_matched=tiles_matched,
       hub_members=unread.sum(dtype=jnp.int32),
       hub_pairs_probed=probed.astype(jnp.int32),
       edges_dropped=(total - probed).astype(jnp.int32))

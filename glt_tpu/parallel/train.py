@@ -42,7 +42,10 @@ small graphs, one a link. In the same one program: the link step's
 positives and strict negatives, one hop from the ``4B`` endpoints, then
 for every link on its own the dedup into ``S`` node slots, the exact
 induced edges among them as a dense ``[S, S]`` block with the link itself
-taken out (``ops/subgraph.py::enclosing_subgraphs``), DRNL
+taken out (``ops/subgraph.py::enclosing_subgraphs``: the members' rows
+read tile by tile in one loop over the tiles the batch's links hold, not
+the tiles they are budgeted; the step counts both, ``tiles_read`` and
+``tiles_matched``), DRNL
 (``ops/drnl.py::drnl_dense``), the store's gather of the live slots, a
 model that reads out a graph (``models/dgcnn.py``) and a loss a link.
 
@@ -246,7 +249,9 @@ class SPMDSageTrainStep(StepCounters):
       (endpoint slots, fringe nodes), ``subgraph_nodes``,
       ``subgraph_edges`` (directed), ``edges_dropped``, ``links_capped``
       (links with an endpoint wider than the fanout), ``tiles_read``,
-      ``hub_members``, ``hub_pairs_probed``, ``drnl_rounds``,
+      ``tiles_matched`` (the tiles the induction's loop gathered and
+      matched: ``tiles_read`` in whole chunks), ``hub_members``,
+      ``hub_pairs_probed``, ``drnl_rounds``,
       ``drnl_unreachable`` and the store's.
     keep_sample: an enclosing-subgraph step also hands back what it
       extracted, among its counters: ``nodes [2B, S]`` (-1 padded), ``z
@@ -541,7 +546,8 @@ class SPMDSageTrainStep(StepCounters):
             drnl_rounds=rounds, drnl_unreachable=unreachable,
             **{name: out[name] for name in (
                 'subgraph_nodes', 'subgraph_edges', 'edges_dropped',
-                'tiles_read', 'hub_members', 'hub_pairs_probed')})
+                'tiles_read', 'tiles_matched', 'hub_members',
+                'hub_pairs_probed')})
         if self._keep_sample:
           counted.update(nodes=out['nodes'], z=z,
                          adj_bits=jnp.packbits(out['adj'], axis=-1))
@@ -978,7 +984,8 @@ class SPMDSageTrainStep(StepCounters):
       slots = dict(
           nodes_by_hop=[2 * links, b - 2 * links], subgraph_nodes=b,
           subgraph_edges=links * (s * (s - 1) - 2), links_capped=links,
-          tiles_read=links * spec.tile_budget, hub_members=b,
+          tiles_read=links * spec.tile_budget,
+          tiles_matched=links * spec.tile_budget, hub_members=b,
           hub_pairs_probed=spec.hub_pairs, drnl_unreachable=b)
     else:
       edges = np.diff(static['edge_hop_offsets'])

@@ -263,6 +263,132 @@ def test_the_dropped_edges_counter(budget, dropped):
   assert int(out['tiles_read']) <= 8 * spec.tile_budget
 
 
+RING, REACH = 300, 64
+
+
+def ring_csr():
+  """Node ``i`` joined to ``i - 64 .. i + 64`` around a ring: every row is
+  128 entries at a multiple of 128, exactly one tile, so a link's tiles
+  are its members and any 12 nodes in a row are all joined."""
+  cols = (np.arange(RING)[:, None]
+          + np.r_[-REACH:0, 1:REACH + 1][None, :]) % RING
+  return ((2 * REACH * np.arange(RING + 1)).astype(np.int32),
+          np.sort(cols, axis=1).reshape(-1).astype(np.int32))
+
+
+def ring_links(fringe):
+  """Link ``l`` joins nodes ``20 l`` and ``20 l + 1``; ``fringe[l] = (a,
+  b)``: its source brings the ``a`` nodes under it, its destination the
+  ``b`` over it: ``2 + a + b`` members, distinct."""
+  ends = np.stack([20 * np.arange(len(fringe)),
+                   20 * np.arange(len(fringe)) + 1])
+  nbrs = np.zeros((2, len(fringe), SPEC.fanout), np.int64)
+  mask = np.zeros(nbrs.shape, bool)
+  for l, (a, b) in enumerate(fringe):
+    nbrs[0, l, :a] = ends[0, l] - 1 - np.arange(a)
+    nbrs[1, l, :b] = ends[1, l] + 1 + np.arange(b)
+    mask[0, l, :a], mask[1, l, :b] = True, True
+  return (ends.astype(np.int32), (nbrs % RING).astype(np.int32), mask)
+
+
+def dense_counts(indptr, nodes, spec):
+  """What the dense form (every budgeted tile gathered and matched: the
+  extraction before its loop over live tiles) counted, link by link in
+  numpy: a member's row spans whole tiles, members are read in slot order
+  while the link's budget lasts, pairs of unread members are probed."""
+  tiles, hubs, pairs = [], 0, 0
+  for row in np.asarray(nodes):
+    used = upto = unread = 0
+    for u in row[row >= 0]:
+      start, deg = int(indptr[u]), int(indptr[u + 1] - indptr[u])
+      span = (-(-(start % TILE + deg) // TILE)
+              if 0 < deg <= spec.hub_width else 0)
+      upto += span
+      if span and upto <= spec.tile_budget:
+        used = upto
+      elif deg:
+        unread += 1
+    tiles.append(used)
+    hubs += unread
+    pairs += unread * (unread - 1) // 2
+  return dict(tiles=tiles, tiles_read=sum(tiles), hub_members=hubs,
+              hub_pairs_probed=min(pairs, spec.hub_pairs),
+              edges_dropped=max(pairs - spec.hub_pairs, 0))
+
+
+FULL = [(5, 5)] * 8
+LIVE_TILE_CASES = {
+    # every member is wider than hub_width: no row is read, every edge
+    # comes from a probe
+    'no_live_tile_every_member_a_hub': dict(
+        fringe=FULL, spec=dict(hub_width=100, hub_pairs=1024), chunks=0),
+    'no_live_tile_every_link_masked': dict(
+        fringe=FULL, links=[], chunks=0),
+    'one_live_tile': dict(
+        fringe=FULL, links=[3], spec=dict(tile_budget=1), chunks=1),
+    # 8 links of 8 tiles, a block each: two chunks of 32 to the tile; a
+    # ninth tile in the last link is one past the edge
+    'the_list_ends_at_a_chunks_edge': dict(fringe=[(3, 3)] * 8, chunks=2),
+    'the_list_ends_one_past_a_chunks_edge': dict(
+        fringe=[(3, 3)] * 7 + [(3, 4)], chunks=3),
+    # the worst case: the loop serves all the budget holds, exactly
+    'every_tile_of_every_link_live': dict(fringe=FULL, chunks=4),
+    'a_link_past_its_budget_beside_links_under_it': dict(
+        fringe=[(5, 5), (1, 1), (3, 3), (5, 5), (0, 0), (2, 2), (3, 4),
+                (5, 5)], spec=dict(tile_budget=8), chunks=None),
+    # the constant as it stands: the whole budget is under one chunk
+    'the_chunk_as_it_stands': dict(fringe=FULL, chunk=None, chunks=1),
+}
+
+
+@pytest.mark.parametrize('case', sorted(LIVE_TILE_CASES))
+def test_the_induction_reads_the_live_tiles_only(case, monkeypatch):
+  """The loop over the batch's live tiles, at every shape of its list:
+  the blocks are the reference's entry by entry, the counters the dense
+  form's, and ``tiles_matched`` is the chunks run times the chunk."""
+  from glt_tpu.ops import subgraph
+  given = LIVE_TILE_CASES[case]
+  spec = SPEC._replace(**dict(dict(tile_budget=12, hub_width=128,
+                                   hub_pairs=1024), **given.get('spec', {})))
+  if given.get('chunk', 32) is not None:
+    # blocks of 8 tiles, 4 to a chunk: the loop takes trips at this size
+    monkeypatch.setattr(subgraph, 'MATCH_BLOCK', 8)
+    monkeypatch.setattr(subgraph, 'MATCH_CHUNK', given.get('chunk', 32))
+  indptr, indices = ring_csr()
+  ends, nbrs, mask = ring_links(given['fringe'])
+  links = np.isin(np.arange(8), given.get('links', np.arange(8)))
+  out = jax.jit(lambda *a: enclosing_subgraphs(*a, spec))(
+      jnp.asarray(indptr), pad_to_tiles(jnp.asarray(indices)),
+      jnp.asarray(ends), jnp.asarray(nbrs), jnp.asarray(mask),
+      jnp.asarray(links))
+  nodes = np.asarray(out['nodes'])
+  assert ((nodes >= 0).sum(1) == [
+      (2 + a + b) * on for (a, b), on in zip(given['fringe'], links)]).all()
+  adj, _, _, _ = seal.blocks(indptr, indices, nodes, MAX_Z)
+  assert np.array_equal(np.asarray(out['adj']), adj)
+  # any 12 nodes in a row of the ring are all joined: the link alone is out
+  n = (nodes >= 0).sum(1)
+  assert adj.sum() == (n * (n - 1) - 2 * links).sum()
+  want = dense_counts(indptr, nodes, spec)
+  for name in ('tiles_read', 'hub_members', 'hub_pairs_probed',
+               'edges_dropped'):
+    assert int(out[name]) == want[name], name
+  assert want['edges_dropped'] == 0
+  if case == 'every_tile_of_every_link_live':
+    assert want['tiles_read'] == 8 * spec.tile_budget
+  if case.startswith('a_link_past'):
+    assert want['hub_members'] > 0 and 0 < want['tiles_read'] < 64
+    assert sorted(set(want['tiles'])) == [2, 4, 6, 8]
+  # the list holds a link's tiles in whole blocks, the loop whole chunks
+  block = subgraph.MATCH_BLOCK
+  chunk = min(subgraph.MATCH_CHUNK // block,
+              8 * -(-spec.tile_budget // block)) * block
+  chunks = -(-sum(-(-n // block) for n in want['tiles']) * block // chunk)
+  assert int(out['tiles_matched']) == chunks * chunk
+  if given['chunks'] is not None:
+    assert chunks == given['chunks']
+
+
 def test_a_negative_that_is_an_edge_loses_its_link_too():
   """A padded (non-strict) negative may be an edge of the graph: its
   link is taken out of its subgraph as a positive's is."""
@@ -394,6 +520,9 @@ def test_the_step_says_what_it_counts_and_refuses_what_it_cannot(run):
   assert slots['nodes_by_hop'].tolist() == [2 * links, links * (s - 2)]
   assert slots['subgraph_nodes'] == links * s
   assert slots['tiles_read'] == links * SPEC.tile_budget
+  assert slots['tiles_matched'] == links * SPEC.tile_budget
+  for counted in run['counted']:
+    assert 0 < counted['tiles_read'] <= counted['tiles_matched']
   assert slots['hub_pairs_probed'] == SPEC.hub_pairs
   for name in ('negatives_rejected', 'negatives_padded', 'seed_unique',
                'links_capped', 'hub_members', 'drnl_unreachable',

@@ -59,8 +59,14 @@ PARENT_STABLEHLO = {
     # the enclosing-subgraph step, pinned by PR 41 at its parent's text
     # (read on both trees): its body walks no hop loop and is left as it
     # is, and its one hop's 4B endpoints are one chunk, so
-    # ``sample_neighbors`` traces to the plain read it was
-    'seal': '8dee044f57434574f2adc83bd2d6c587889c3ed69de7e9633534485804858d05',
+    # ``sample_neighbors`` traces to the plain read it was. Read anew by
+    # PR 42, the one PR whose change is this step's: the induction of
+    # ``ops/subgraph.py::enclosing_subgraphs`` is a loop over the batch's
+    # live tiles and the step counts ``tiles_matched``; before it
+    # 8dee044f...04858d05. The five above are that PR's parent's, and so
+    # are the HGT and user-item cells' tiny steps (hashed on both trees by
+    # a scratch script: CHANGES.md)
+    'seal': '68a54cdfc18c55f45a2297f9eb9e0f71b2ab883bc5432e9e54d51adb6ac22cf7',
 }
 
 
