@@ -47,13 +47,6 @@ def check(ok, *why):
     raise AssertionError(*why)
 
 
-def engines():
-  """The dedup knobs as they resolve in this process, read the way the
-  samplers read them at trace time."""
-  from glt_tpu.ops.pipeline import dedup_engine, fused_hops
-  return {'dedup_engine': dedup_engine(), 'fused_hops': fused_hops()}
-
-
 def bytes_in_use():
   import jax
   return [d.memory_stats()['bytes_in_use'] for d in jax.local_devices()]
@@ -136,7 +129,7 @@ def loader_phase(ds, num_classes):
   check((sampler.num_compiled_fns, step._cache_size()) == compiled,
         'recompiled after step 1', sampler.num_compiled_fns,
         step._cache_size())
-  say(phase='loader', steps=LOADER_STEPS, **engines(),
+  say(phase='loader', steps=LOADER_STEPS,
       first_dispatch_s=round(first_s, 1), **steady,
       loss_first5=round(float(losses[:5].mean()), 4),
       loss_last5=round(float(losses[-5:].mean()), 4),
@@ -152,7 +145,7 @@ def step_hlo(step, params, opt, seeds, n_valid, keys):
   from glt_tpu.parallel import row_sharded
   sh = row_sharded(step.mesh, step.axis)
   return step._step_fn.lower(
-      params, opt, step.tables, step.scratches,
+      params, opt,
       jax.device_put(jnp.asarray(seeds, jnp.int32), sh),
       jax.device_put(jnp.asarray(n_valid, jnp.int32), sh), keys,
       step.feature.array, step.labels, step._indptr,
@@ -230,8 +223,6 @@ def fused_phase(ds, feats, num_classes):
                              rtol=1e-3, atol=1e-4)
 
   say(phase='fused', n_dev=n_dev, batch_size_per_device=BATCH,
-      # parallel/train.py reads hops through sample_neighbors directly
-      **engines(),
       per_batch=dict(steps=FUSED_STEPS,
                      first_dispatch_s=round(pb_first_s, 1), **pb_steady),
       superstep=dict(k=SUPERSTEP_K, supersteps=SUPERSTEPS,

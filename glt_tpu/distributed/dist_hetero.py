@@ -4,11 +4,10 @@ Reference: the hetero paths of dist_neighbor_sampler.py (per-etype
 concurrent rpc tasks, :315-347) and dist_dataset/dist_graph hetero
 handling; the deployment target is examples/igbh/dist_train_rgnn.py
 (billion-edge hetero training). TPU design: one DistGraph-style sharded
-store per edge type (all on the same mesh), per-node-type dense inducer
-tables, and a shard_map hop loop that issues the collective one-hop of
-every edge type then merges each destination type once — the same
-structure as the single-device hetero engine with the one-hop swapped
-for the all_to_all version.
+store per edge type (all on the same mesh) and a shard_map hop loop that
+issues the collective one-hop of every edge type then merges each
+destination type once — the same structure as the single-device hetero
+engine with the one-hop swapped for the all_to_all version.
 """
 from __future__ import annotations
 
@@ -23,7 +22,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..obs.device import StepCounters, register_step_program, scope
 from ..ops.negative import random_negative_sample
 from ..ops.pipeline import (hetero_edge_hop_offsets, hetero_hop_fanouts,
-                            make_dedup_tables,
                             multihop_sample_hetero)
 from ..parallel.mesh import replicate
 from ..typing import EdgeType, NodeType, as_str, reverse_edge_type
@@ -348,17 +346,6 @@ class DistHeteroNeighborSampler:
         else RandomSeedManager.getInstance().getSeed())
     self._step = 0
     self._fn_cache = {}
-    n_dev = self.mesh.shape[self.axis]
-    shard = NamedSharding(self.mesh, P(self.axis))
-    self.tables = {}
-    for t, n in graph.node_counts.items():
-      table, scratch = make_dedup_tables(n)
-      self.tables[t] = (
-          jax.device_put(jnp.broadcast_to(table, (n_dev,) + table.shape),
-                         shard),
-          jax.device_put(
-              jnp.broadcast_to(scratch, (n_dev,) + scratch.shape),
-              shard))
 
   def _next_key(self):
     self._step += 1
@@ -387,8 +374,8 @@ class DistHeteroNeighborSampler:
     return caps, budgets
 
   def _make_device_core(self, batch_size: int, seed_type):
-    """Returns device_core(shards, seeds, n_valid_scalar, key, flat_tables)
-    -> (result dict, out_tables) with NO leading shard dims — reusable by
+    """Returns device_core(shards, seeds, n_valid_scalar, key) -> result
+    dict with NO leading shard dims — reusable by
     the train step. ``seed_type`` a tuple of node types (the ends of a
     seed relation, ``batch_size`` slots each): ``seeds`` and the last
     argument ``seed_mask`` are dicts by type, and so are the result's
@@ -407,7 +394,7 @@ class DistHeteroNeighborSampler:
               if any(caps[h][trav[e][0]] * abs(self.num_neighbors[e][h])
                      > 0 for h in range(self.num_hops))]
 
-    def device_core(shards, seeds, n_valid, key, tables, seed_mask=None):
+    def device_core(shards, seeds, n_valid, key, seed_mask=None):
       one_hops = {}
       for e in etypes:
         sh = shards[e]
@@ -429,17 +416,16 @@ class DistHeteroNeighborSampler:
         return multihop_sample_hetero(
             one_hops, trav_active, self.num_neighbors, self.num_hops,
             caps, budgets, seeds, {t: n_valid for t in seeds}, key,
-            tables, with_edge=self.with_edge, seed_mask=seed_mask)
-      result, out_tables = multihop_sample_hetero(
+            with_edge=self.with_edge, seed_mask=seed_mask)
+      result = multihop_sample_hetero(
           one_hops, trav_active, self.num_neighbors, self.num_hops,
           caps, budgets, {seed_type: seeds},
-          {seed_type: n_valid}, key, tables,
-          with_edge=self.with_edge)
+          {seed_type: n_valid}, key, with_edge=self.with_edge)
       # flatten the per-seed-type dicts to the flat fields dist callers
       # consume (single seed type in dist mode)
       result['batch'] = result['batch'][seed_type]
       result['seed_labels'] = result['seed_labels'][seed_type]
-      return result, out_tables
+      return result
 
     return device_core, caps, budgets, etypes
 
@@ -449,7 +435,7 @@ class DistHeteroNeighborSampler:
     device_core, caps, budgets, etypes = self._make_device_core(
         batch_size, seed_type)
 
-    def device_fn(shards, seeds, n_valid, key, tables):
+    def device_fn(shards, seeds, n_valid, key):
       def unpack(sh):
         d = dict(indptr=sh['indptr'][0], indices=sh['indices'][0],
                  edge_ids=sh['edge_ids'][0],
@@ -459,14 +445,8 @@ class DistHeteroNeighborSampler:
         return d
       shards_in = {e: unpack(sh) for e, sh in shards.items()}
       key = jax.random.fold_in(key[0], jax.lax.axis_index(self.axis))
-      flat_tables = {t: (tables[t][0][0], tables[t][1][0])
-                     for t in tables}
-      result, out_tables = device_core(shards_in, seeds, n_valid[0], key,
-                                       flat_tables)
-      result = jax.tree_util.tree_map(lambda a: a[None], result)
-      out_tables = {t: (tb[None], sc[None])
-                    for t, (tb, sc) in out_tables.items()}
-      return result, out_tables
+      result = device_core(shards_in, seeds, n_valid[0], key)
+      return jax.tree_util.tree_map(lambda a: a[None], result)
 
     sp = P(self.axis)
     def etype_spec(e):
@@ -488,15 +468,14 @@ class DistHeteroNeighborSampler:
     }
     if self.with_edge:
       out_elem['edge'] = {e: sp for e in etypes}
-    table_specs = {t: (sp, sp) for t in types}
 
     fn = jax.shard_map(
         device_fn, mesh=self.mesh,
-        in_specs=(shard_specs, sp, sp, sp, table_specs),
-        out_specs=(out_elem, table_specs), check_vma=False)
+        in_specs=(shard_specs, sp, sp, sp),
+        out_specs=out_elem, check_vma=False)
 
-    @functools.partial(jax.jit, donate_argnums=(3,))
-    def step(seeds, n_valid, keys, tables):
+    @jax.jit
+    def step(seeds, n_valid, keys):
       def etype_payload(e):
         d = dict(indptr=g.graphs[e].indptr, indices=g.graphs[e].indices,
                  edge_ids=g.graphs[e].edge_ids,
@@ -506,7 +485,7 @@ class DistHeteroNeighborSampler:
           d['edge_weights'] = g.graphs[e].edge_weights
         return d
       shards = {e: etype_payload(e) for e in etypes}
-      return fn(shards, seeds, n_valid, keys, tables)
+      return fn(shards, seeds, n_valid, keys)
 
     return step
 
@@ -526,10 +505,10 @@ class DistHeteroNeighborSampler:
     if key is None:
       key = self._next_key()
     shard = NamedSharding(self.mesh, P(self.axis))
-    out, self.tables = self._fn_cache[cache_key](
+    out = self._fn_cache[cache_key](
         jax.device_put(jnp.asarray(seeds, jnp.int32), shard),
         jax.device_put(jnp.asarray(n_valid_per_device, jnp.int32), shard),
-        jax.random.split(key, n_dev), self.tables)
+        jax.random.split(key, n_dev))
 
     def final_key(e):
       return reverse_edge_type(e) if self.g.edge_dir == 'out' else e
@@ -927,7 +906,7 @@ class DistHeteroTrainStep(StepCounters):
     """Shared device-batch assembly for the train and eval programs:
     returns (device_batch, specs, payloads) where ``device_batch(...)``
     runs sampling + feature/efeat collate inside shard_map and yields
-    (batch, y, out_tables, counters): ``counters`` is what the sampler
+    (batch, y, counters): ``counters`` is what the sampler
     counted, ``nodes_by_hop`` ``[T, H + 1]``, ``edges_by_hop`` and
     ``hop_rows_read`` ``[R, H]`` in the order of ``counter_node_types``
     and ``counter_edge_types``, and where every node store serves in place
@@ -951,7 +930,7 @@ class DistHeteroTrainStep(StepCounters):
     efeats = {e: v for e, v in self.edge_features.items() if e in etypes}
 
     def device_batch(shards, feat_shards, efeat_shards, labels, seeds,
-                     n_valid, key, tables):
+                     n_valid, key):
       def unpack(sh):
         d = dict(indptr=sh['indptr'][0], indices=sh['indices'][0],
                  edge_ids=sh['edge_ids'][0],
@@ -961,8 +940,6 @@ class DistHeteroTrainStep(StepCounters):
         return d
       shards_in = {e: unpack(sh) for e, sh in shards.items()}
       my_key = jax.random.fold_in(key[0], jax.lax.axis_index(axis))
-      flat_tables = {t: (tables[t][0][0], tables[t][1][0])
-                     for t in tables}
       fk = self._final_key
       with scope('sampler'):
         seed_mask, counted, meta = None, {}, None
@@ -970,8 +947,8 @@ class DistHeteroTrainStep(StepCounters):
           kneg, my_key = jax.random.split(my_key)
           seeds, seed_mask, edge_label, counted = self._link_seeds(
               shards_in[seed_type], seeds, n_valid[0], kneg)
-        out, out_tables = device_core(shards_in, seeds, n_valid[0],
-                                      my_key, flat_tables, seed_mask)
+        out = device_core(shards_in, seeds, n_valid[0], my_key,
+                          seed_mask)
         by_relation = lambda counted: self._by_relation_and_hop(
             {fk(e): v for e, v in out[counted].items()})
         counters = dict(
@@ -1041,9 +1018,7 @@ class DistHeteroTrainStep(StepCounters):
         with scope('sampler'):
           counters['embedding_rows'] = self._embedding_rows(
               slots_of(batch), out)
-      out_tables = {t: (tb[None], sc[None])
-                    for t, (tb, sc) in out_tables.items()}
-      return batch, y, out_tables, counters
+      return batch, y, counters
 
     sp = P(self.axis)
     def etype_spec(e):
@@ -1061,7 +1036,6 @@ class DistHeteroTrainStep(StepCounters):
         shards={e: etype_spec(e) for e in etypes},
         feats={t: store_spec(st) for t, st in feats.items()},
         efeats={e: store_spec(efeats[e]) for e in efeats},
-        tables={t: (sp, sp) for t in types},
         labels={t: P() for t in self.labels},
         sp=sp)
 
@@ -1092,13 +1066,12 @@ class DistHeteroTrainStep(StepCounters):
     device_batch, specs, payloads = self._assembly()
 
     def device_step(params, opt_state, shards, feat_shards, efeat_shards,
-                    labels, seeds, n_valid, key, tables):
-      batch, y, out_tables, counters = device_batch(
-          shards, feat_shards, efeat_shards, labels, seeds, n_valid,
-          key, tables)
+                    labels, seeds, n_valid, key):
+      batch, y, counters = device_batch(
+          shards, feat_shards, efeat_shards, labels, seeds, n_valid, key)
       params, opt_state, loss = _hetero_update(
           model, tx, axis, bs, params, opt_state, batch, y, n_valid[0])
-      out = (params, opt_state, out_tables,
+      out = (params, opt_state,
              (loss[None], jax.tree.map(lambda a: a[None], counters)))
       if self.keep_sample:
         out += (jax.tree_util.tree_map(lambda a: a[None], dict(
@@ -1108,35 +1081,33 @@ class DistHeteroTrainStep(StepCounters):
       return out
 
     sp = specs['sp']
-    out_specs = (P(), P(), specs['tables'], sp)
+    out_specs = (P(), P(), sp)
     fn = jax.shard_map(
         device_step, mesh=self.mesh,
         in_specs=(P(), P(), specs['shards'], specs['feats'],
-                  specs['efeats'], specs['labels'], sp, sp, sp,
-                  specs['tables']),
+                  specs['efeats'], specs['labels'], sp, sp, sp),
         out_specs=out_specs + ((sp,) if self.keep_sample else ()),
         check_vma=False)
 
     # an edge-seeded step owns its state (tables of parameters with their
     # moments: a second copy live across the update is gigabytes): the
     # caller steps on with what a call returns. The node-seeded drivers
-    # hold on to what they passed in, so that program donates its dedup
-    # tables alone
+    # hold on to what they passed in, so that program donates nothing
     import functools
     @functools.partial(jax.jit,
-                       donate_argnums=(0, 1, 9) if self._link else (9,))
+                       donate_argnums=(0, 1) if self._link else ())
     def step(params, opt_state, shards, feat_shards, efeat_shards,
-             labels, seeds, n_valid, keys, tables):
+             labels, seeds, n_valid, keys):
       self.step_traces += 1  # trace-time side effect only
       from ..obs.perf import count_compile
       count_compile('train.hetero_step')
       return fn(params, opt_state, shards, feat_shards, efeat_shards,
-                labels, seeds, n_valid, keys, tables)
+                labels, seeds, n_valid, keys)
 
-    def run(params, opt_state, tables, seeds, n_valid, keys):
+    def run(params, opt_state, seeds, n_valid, keys):
       shards, feat_shards, efeat_shards = payloads()
       return step(params, opt_state, shards, feat_shards, efeat_shards,
-                  self.labels, seeds, n_valid, keys, tables)
+                  self.labels, seeds, n_valid, keys)
 
     run.jitted = step   # the compiled program, for its cache's size
     return run
@@ -1147,57 +1118,54 @@ class DistHeteroTrainStep(StepCounters):
     """The fused hetero superstep program (ISSUE 14 tentpole, first
     move): lax.scan of the per-batch hetero body — per-edge-type
     collective sampling + per-type feature all_to_all + RGNN
-    forward/backward + pmean'd update — with params/opt-state/per-type
-    dedup tables threaded through the carry
-    (ops/superstep.py::superstep_hetero). K batches then cost ONE
+    forward/backward + pmean'd update — with params/opt-state threaded
+    through the carry (ops/superstep.py::superstep). K batches then cost ONE
     donated dispatch: the per-batch train loop's host round-trip, seed
     transfer, and dispatch latency amortize 1/K — exactly the homo
     superstep's collapse (parallel/train.py), now on the per-edge-type
     dispatch train VERDICT round 5 measured at 174 seeds/s."""
     model, tx, axis, bs = self.model, self.tx, self.axis, self.bs
     device_batch, specs, payloads = self._assembly()
-    from ..ops.superstep import superstep_hetero
+    from ..ops.superstep import superstep
 
     def device_superstep(params, opt_state, shards, feat_shards,
                          efeat_shards, labels, seeds_stack,
-                         n_valid_stack, keys, tables):
-      def body(params, opt_state, tables, seeds, n_valid, key):
+                         n_valid_stack, keys):
+      def body(params, opt_state, seeds, n_valid, key):
         # a scanned batch keeps its loss and drops what it counted
-        batch, y, out_tables, _ = device_batch(
+        batch, y, _ = device_batch(
             shards, feat_shards, efeat_shards, labels, seeds, n_valid,
-            key, tables)
+            key)
         params, opt_state, loss = _hetero_update(
             model, tx, axis, bs, params, opt_state, batch, y, n_valid[0])
-        return params, opt_state, out_tables, loss[None]
+        return params, opt_state, loss[None]
 
-      run = superstep_hetero(body)
-      return run(params, opt_state, tables, seeds_stack, n_valid_stack,
-                 keys)
+      run = superstep(body)
+      return run(params, opt_state, seeds_stack, n_valid_stack, keys)
 
     stacked = P(None, self.axis)
     fn = jax.shard_map(
         device_superstep, mesh=self.mesh,
         in_specs=(P(), P(), specs['shards'], specs['feats'],
                   specs['efeats'], specs['labels'], stacked, stacked,
-                  stacked, specs['tables']),
-        out_specs=(P(), P(), specs['tables'], stacked),
+                  stacked),
+        out_specs=(P(), P(), stacked),
         check_vma=False)
 
     import functools
-    @functools.partial(jax.jit, donate_argnums=(0, 1, 9))
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
     def step(params, opt_state, shards, feat_shards, efeat_shards,
-             labels, seeds_stack, n_valid_stack, keys, tables):
+             labels, seeds_stack, n_valid_stack, keys):
       self.superstep_traces += 1  # trace-time side effect only
       from ..obs.perf import count_compile
       count_compile('train.hetero_superstep')
       return fn(params, opt_state, shards, feat_shards, efeat_shards,
-                labels, seeds_stack, n_valid_stack, keys, tables)
+                labels, seeds_stack, n_valid_stack, keys)
 
-    def run(params, opt_state, tables, seeds_stack, n_valid_stack,
-            keys):
+    def run(params, opt_state, seeds_stack, n_valid_stack, keys):
       shards, feat_shards, efeat_shards = payloads()
       return step(params, opt_state, shards, feat_shards, efeat_shards,
-                  self.labels, seeds_stack, n_valid_stack, keys, tables)
+                  self.labels, seeds_stack, n_valid_stack, keys)
 
     return run
 
@@ -1232,9 +1200,8 @@ class DistHeteroTrainStep(StepCounters):
     _synced = {}
     with tracer.span('train.hetero_superstep', k=int(seeds.shape[0]),
                      sync=lambda: _synced.get('loss')):
-      (params, opt_state, self.sampler.tables,
-       loss) = self._superstep_fn(params, opt_state,
-                                  self.sampler.tables, seeds, nv, keys)
+      params, opt_state, loss = self._superstep_fn(params, opt_state,
+                                                   seeds, nv, keys)
       _synced['loss'] = loss
     if tracer.enabled:
       get_registry().set('train_hetero_superstep_traces',
@@ -1260,12 +1227,10 @@ class DistHeteroTrainStep(StepCounters):
         keys = jax.random.split(key, n_dev)
         params, opt_state = replicate((params, opt_state), self.mesh)
       with tracer.span('train.step/dispatch'):
-        out = self._step_fn(params, opt_state, self.sampler.tables,
-                            seeds, nv, keys)
-        (params, opt_state, self.sampler.tables,
-         (loss, counted)) = out[:4]
+        out = self._step_fn(params, opt_state, seeds, nv, keys)
+        params, opt_state, (loss, counted) = out[:3]
         if self.keep_sample:
-          self.last_sample = out[4]
+          self.last_sample = out[3]
       self._keep_counters(counted)
       _synced['loss'] = loss
     return params, opt_state, loss
@@ -1365,35 +1330,29 @@ class DistHeteroTrainStep(StepCounters):
     device_batch, specs, payloads = self._assembly()
 
     def device_eval(params, shards, feat_shards, efeat_shards, labels,
-                    seeds, n_valid, key, tables):
-      batch, y, out_tables, _ = device_batch(
-          shards, feat_shards, efeat_shards, labels, seeds, n_valid,
-          key, tables)
+                    seeds, n_valid, key):
+      batch, y, _ = device_batch(
+          shards, feat_shards, efeat_shards, labels, seeds, n_valid, key)
       logits = model.apply(params, batch)
       mask = jnp.arange(bs) < n_valid[0]
       correct = jnp.where(mask, jnp.argmax(logits, -1) == y, False)
       correct = jax.lax.psum(correct.sum(), axis)
       total = jax.lax.psum(mask.sum(), axis)
-      return out_tables, correct[None], total[None]
+      return correct[None], total[None]
 
     sp = specs['sp']
     fn = jax.shard_map(
         device_eval, mesh=self.mesh,
         in_specs=(P(), specs['shards'], specs['feats'], specs['efeats'],
-                  specs['labels'], sp, sp, sp, specs['tables']),
-        out_specs=(specs['tables'], sp, sp), check_vma=False)
+                  specs['labels'], sp, sp, sp),
+        out_specs=(sp, sp), check_vma=False)
 
-    import functools
-    @functools.partial(jax.jit, donate_argnums=(8,))
-    def jfn(params, shards, feat_shards, efeat_shards, labels, seeds,
-            n_valid, keys, tables):
-      return fn(params, shards, feat_shards, efeat_shards, labels,
-                seeds, n_valid, keys, tables)
+    jfn = jax.jit(fn)
 
-    def run(params, tables, seeds, n_valid, keys):
+    def run(params, seeds, n_valid, keys):
       shards, feat_shards, efeat_shards = payloads()
       return jfn(params, shards, feat_shards, efeat_shards, self.labels,
-                 seeds, n_valid, keys, tables)
+                 seeds, n_valid, keys)
 
     return run
 
@@ -1409,8 +1368,7 @@ class DistHeteroTrainStep(StepCounters):
     nv = jax.device_put(jnp.asarray(n_valid_per_device, jnp.int32),
                         shard)
     keys = jax.random.split(key, n_dev)
-    self.sampler.tables, correct, total = self._eval_fn(
-        params, self.sampler.tables, seeds, nv, keys)
+    correct, total = self._eval_fn(params, seeds, nv, keys)
     # every lane carries the same psum; read a process-LOCAL shard so
     # multihost runs (where the global array spans other processes)
     # can fetch it

@@ -28,7 +28,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..ops.pipeline import edge_hop_offsets, multihop_sample
 from ..ops.sample import sample_neighbors
-from ..ops.pipeline import make_dedup_tables
 from ..parallel.collectives import all_to_all, bucket_by_owner, unbucket
 from ..utils import as_numpy
 from ..utils.rng import RandomSeedManager
@@ -142,13 +141,6 @@ class DistNeighborSampler:
         else RandomSeedManager.getInstance().getSeed())
     self._step = 0
     self._fn_cache = {}
-    n_dev = self.mesh.shape[self.axis]
-    table, scratch = make_dedup_tables(dist_graph.num_nodes)
-    shard = NamedSharding(self.mesh, P(self.axis))
-    self.tables = jax.device_put(
-        jnp.broadcast_to(table, (n_dev,) + table.shape), shard)
-    self.scratches = jax.device_put(
-        jnp.broadcast_to(scratch, (n_dev,) + scratch.shape), shard)
 
   def _next_key(self):
     self._step += 1
@@ -162,7 +154,7 @@ class DistNeighborSampler:
     with_edge = self.with_edge
 
     def device_fn(indptr, indices, eids, weights, local_row, node_pb,
-                  seeds, n_valid, key, table, scratch):
+                  seeds, n_valid, key):
       shards = dict(indptr=indptr[0], indices=indices[0],
                     edge_ids=eids[0], local_row=local_row[0],
                     node_pb=node_pb)
@@ -173,33 +165,25 @@ class DistNeighborSampler:
           with_weight=self.with_weight,
           max_weighted_degree=self.max_weighted_degree)
       my_key = jax.random.fold_in(key[0], jax.lax.axis_index(axis))
-      out, table_o, scratch_o = multihop_sample(
-          one_hop, seeds, n_valid[0], fanouts, my_key, table[0],
-          scratch[0], with_edge=with_edge)
-      out = {k: v[None] for k, v in out.items()}
-      return out, table_o[None], scratch_o[None]
+      out = multihop_sample(one_hop, seeds, n_valid[0], fanouts, my_key,
+                            with_edge=with_edge)
+      return {k: v[None] for k, v in out.items()}
 
     sp = P(self.axis)
     w_spec = sp if g.edge_weights is not None else None
     fn = jax.shard_map(
         device_fn, mesh=self.mesh,
-        in_specs=(sp, sp, sp, w_spec, sp, P(), sp, sp, sp, sp, sp),
-        out_specs=({k: sp for k in self._out_keys()}, sp, sp),
+        in_specs=(sp, sp, sp, w_spec, sp, P(), sp, sp, sp),
+        out_specs={k: sp for k in self._out_keys()},
         check_vma=False)
 
-    import functools
     # graph arrays enter as ARGUMENTS (closure capture would embed them
     # as jit constants, which cannot span processes in multi-host runs)
-    @functools.partial(jax.jit, donate_argnums=(9, 10))
-    def step(indptr, indices, edge_ids, edge_weights, local_row, node_pb,
-             seeds, n_valid, keys, tables, scratches):
-      return fn(indptr, indices, edge_ids, edge_weights, local_row,
-                node_pb, seeds, n_valid, keys, tables, scratches)
+    step = jax.jit(fn)
 
-    def run(seeds, n_valid, keys, tables, scratches):
+    def run(seeds, n_valid, keys):
       return step(g.indptr, g.indices, g.edge_ids, g.edge_weights,
-                  g.local_row, g.node_pb, seeds, n_valid, keys, tables,
-                  scratches)
+                  g.local_row, g.node_pb, seeds, n_valid, keys)
 
     return run
 
@@ -214,8 +198,7 @@ class DistNeighborSampler:
   def sample_from_nodes(self, seeds_per_device: np.ndarray,
                         n_valid_per_device=None, key=None):
     """seeds_per_device: [P, B] or [P*B] shard-major. Returns a dict of
-    stacked arrays [P, ...] (one SamplerOutput per device) plus updated
-    internal tables."""
+    stacked arrays [P, ...] (one SamplerOutput per device)."""
     seeds = as_numpy(seeds_per_device)
     n_dev = self.mesh.shape[self.axis]
     if seeds.ndim == 2:
@@ -229,10 +212,10 @@ class DistNeighborSampler:
       key = self._next_key()
     keys = jax.random.split(key, n_dev)
     shard = NamedSharding(self.mesh, P(self.axis))
-    out, self.tables, self.scratches = self._fn_cache[batch_size](
+    out = self._fn_cache[batch_size](
         jax.device_put(jnp.asarray(seeds, jnp.int32), shard),
         jax.device_put(jnp.asarray(n_valid_per_device, jnp.int32), shard),
-        keys, self.tables, self.scratches)
+        keys)
     out['edge_hop_offsets'] = edge_hop_offsets(batch_size, fanouts=
                                                self.num_neighbors)
     return out

@@ -10,7 +10,6 @@ collectives, feature all_to_all, gradient pmean all riding ICI.
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional, Sequence
 
 import jax
@@ -21,7 +20,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..loader.transform import Batch
 from ..ops.pipeline import edge_hop_offsets, multihop_sample
-from ..ops.pipeline import make_dedup_tables
 from ..parallel.mesh import replicate
 from .dist_feature import DistFeature
 from .dist_graph import DistGraph
@@ -61,13 +59,6 @@ class DistTrainStep:
     self.axis = dist_graph.axis
     self.labels = jax.device_put(
         np.asarray(labels), NamedSharding(self.mesh, P()))
-    n_dev = self.mesh.shape[self.axis]
-    table, scratch = make_dedup_tables(dist_graph.num_nodes)
-    shard = NamedSharding(self.mesh, P(self.axis))
-    self.tables = jax.device_put(
-        jnp.broadcast_to(table, (n_dev,) + table.shape), shard)
-    self.scratches = jax.device_put(
-        jnp.broadcast_to(scratch, (n_dev,) + scratch.shape), shard)
     self._step_fn = self._build()
 
   def _dummy_batch(self) -> Batch:
@@ -106,7 +97,7 @@ class DistTrainStep:
 
     def device_step(params, opt_state, indptr, indices, geids, local_row,
                     node_pb, feats, id2index, feat_pb, labels, seeds,
-                    n_valid, key, table, scratch, *rest):
+                    n_valid, key, *rest):
       rest = list(rest)
       fcold = rest.pop(0) if f_off else None
       efeats, eid2index, efeat_pb = \
@@ -118,9 +109,8 @@ class DistTrainStep:
       one_hop = make_dist_one_hop(shards, g.num_nodes, n_parts,
                                   g.max_rows, axis)
       my_key = jax.random.fold_in(key[0], jax.lax.axis_index(axis))
-      out, table_o, scratch_o = multihop_sample(
-          one_hop, seeds, n_valid[0], fanouts, my_key, table[0],
-          scratch[0], with_edge=with_edge)
+      out = multihop_sample(one_hop, seeds, n_valid[0], fanouts, my_key,
+                            with_edge=with_edge)
       node_valid = jnp.arange(out['node'].shape[0]) < out['node_count']
       x = f.lookup_local(feats[0], id2index[0], feat_pb[0],
                          jnp.maximum(out['node'], 0), node_valid,
@@ -155,7 +145,7 @@ class DistTrainStep:
       loss = jax.lax.pmean(loss, axis)
       updates, opt_state = tx.update(grads, opt_state, params)
       params = optax.apply_updates(params, updates)
-      return params, opt_state, table_o[None], scratch_o[None], loss[None]
+      return params, opt_state, loss[None]
 
     sp = P(self.axis)
     extra = ((sp,) if f_off else ()) \
@@ -164,28 +154,21 @@ class DistTrainStep:
     fn = jax.shard_map(
         device_step, mesh=self.mesh,
         in_specs=(P(), P(), sp, sp, sp, sp, P(), sp, sp, sp, P(), sp, sp,
-                  sp, sp, sp) + extra,
-        out_specs=(P(), P(), sp, sp, sp),
+                  sp) + extra,
+        out_specs=(P(), P(), sp),
         check_vma=False)
 
     # global arrays enter as jit ARGUMENTS (closure constants cannot
     # span processes in multi-host runs)
-    @functools.partial(jax.jit, donate_argnums=(14, 15))
-    def step(params, opt_state, indptr, indices, geids, local_row,
-             node_pb, feats, id2index, feat_pb, labels, seeds, n_valid,
-             keys, tables, scratches, *eargs):
-      return fn(params, opt_state, indptr, indices, geids, local_row,
-                node_pb, feats, id2index, feat_pb, labels, seeds,
-                n_valid, keys, tables, scratches, *eargs)
+    step = jax.jit(fn)
 
-    def run(params, opt_state, tables, scratches, seeds, n_valid, keys):
+    def run(params, opt_state, seeds, n_valid, keys):
       eargs = ((f.cold_array,) if f_off else ()) \
           + ((ef.array, ef.id2index, ef.feat_pb) if with_edge else ()) \
           + ((ef.cold_array,) if ef_off else ())
       return step(params, opt_state, g.indptr, g.indices, g.edge_ids,
                   g.local_row, g.node_pb, f.array, f.id2index,
-                  f.feat_pb, self.labels, seeds, n_valid, keys, tables,
-                  scratches, *eargs)
+                  f.feat_pb, self.labels, seeds, n_valid, keys, *eargs)
 
     return run
 
@@ -198,6 +181,6 @@ class DistTrainStep:
         jnp.asarray(n_valid_per_device, jnp.int32), shard)
     keys = jax.random.split(key, n_dev)
     params, opt_state = replicate((params, opt_state), self.mesh)
-    params, opt_state, self.tables, self.scratches, loss = self._step_fn(
-        params, opt_state, self.tables, self.scratches, seeds, nv, keys)
+    params, opt_state, loss = self._step_fn(params, opt_state, seeds, nv,
+                                            keys)
     return params, opt_state, loss

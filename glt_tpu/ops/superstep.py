@@ -6,16 +6,16 @@ parallel/train.py). :func:`glt_tpu.ops.pipeline.multihop_sample_many`
 already shows that scanning K *sampling* batches in one dispatch
 amortizes that overhead; this module generalizes the same lax.scan
 pattern to the WHOLE training step — sample -> feature gather ->
-forward/backward -> optimizer update — with the dedup tables, params and
-optimizer state threaded through the carry. Seed batches are staged on
-device up front as a [T, B] stack (loader.DeviceEpochLoader), so steady
-state is one dispatch per T batches and zero host round-trips on the hot
-path. PyTorch-Direct (arxiv 2101.07956) and GPU-initiated direct-storage
+forward/backward -> optimizer update — with params and optimizer state
+threaded through the carry. Seed batches are staged on device up front
+as a [T, B] stack (loader.DeviceEpochLoader), so steady state is one
+dispatch per T batches and zero host round-trips on the hot path.
+PyTorch-Direct (arxiv 2101.07956) and GPU-initiated direct-storage
 sampling (arxiv 2306.16384) teach the same lesson on GPUs.
 
-The per-batch body must return its dedup tables RESET (the
-:func:`~glt_tpu.ops.pipeline.multihop_sample` contract), which makes
-scan iterations independent: a T-step superstep is bit-identical to T
+The hop loop carries no state from one batch to the next
+(:func:`~glt_tpu.ops.pipeline.multihop_sample`), which makes scan
+iterations independent: a T-step superstep is bit-identical to T
 sequential calls of the same body with the same key stream.
 """
 from __future__ import annotations
@@ -25,8 +25,7 @@ from typing import Callable, Tuple
 import jax
 
 # body of one training step:
-#   (params, opt_state, table, scratch, seeds, n_valid, key)
-#     -> (params, opt_state, table, scratch, aux)
+#   (params, opt_state, seeds, n_valid, key) -> (params, opt_state, aux)
 BatchStepFn = Callable[..., Tuple]
 
 
@@ -35,63 +34,31 @@ def superstep(batch_step: BatchStepFn, unroll: int = 1):
 
   Args:
     batch_step: one full training step (sample -> gather -> grad ->
-      update). Tables must come back reset so iterations stay
-      independent. ``aux`` is any pytree (typically the loss).
+      update). ``aux`` is any pytree (typically the loss). Seeds may be
+      one array or a per-type dict (the hetero step's), stacked per
+      leaf.
     unroll: forwarded to ``lax.scan`` (TPU sampling A/Bs found modest
       unrolling neutral; the knob exists for re-measurement).
 
-  Returns ``run(params, opt_state, table, scratch, seeds_stack [T, B],
-  n_valid_stack [T, ...], keys [T, ...]) -> (params, opt_state, table,
-  scratch, aux_stack)`` where ``aux_stack`` carries the per-batch aux
-  values stacked on a leading [T] axis. The leading axis of the three
-  stacked inputs must agree; each scan iteration consumes one slice.
+  Returns ``run(params, opt_state, seeds_stack [T, B], n_valid_stack
+  [T, ...], keys [T, ...]) -> (params, opt_state, aux_stack)`` where
+  ``aux_stack`` carries the per-batch aux values stacked on a leading
+  [T] axis. The leading axis of the three stacked inputs must agree;
+  each scan iteration consumes one slice.
   """
 
-  def body(params, opt_state, state, seeds, n_valid, key):
-    table, scratch = state
-    params, opt_state, table, scratch, aux = batch_step(
-        params, opt_state, table, scratch, seeds, n_valid, key)
-    return params, opt_state, (table, scratch), aux
-
-  # the homo (table, scratch) pair is a special case of the pytree-
-  # state lift below — ONE scan implementation serves both engines
-  run_tree = superstep_hetero(body, unroll)
-
-  def run(params, opt_state, table, scratch, seeds_stack, n_valid_stack,
-          keys):
-    params, opt_state, (table, scratch), aux = run_tree(
-        params, opt_state, (table, scratch), seeds_stack,
-        n_valid_stack, keys)
-    return params, opt_state, table, scratch, aux
-
-  return run
-
-
-def superstep_hetero(batch_step: Callable, unroll: int = 1):
-  """Hetero variant of :func:`superstep`: the dedup state is one opaque
-  pytree (the hetero engine's per-type table dict — or, on the fused
-  hetero engine, pass-through placeholders) instead of the homo
-  ``(table, scratch)`` pair. Everything else is the same lax.scan
-  lift: K hetero training batches (per-edge-type collective sampling +
-  per-type feature exchange + RGNN update) run as ONE donated dispatch,
-  bit-identical to K sequential per-batch calls on the same key stream.
-
-  ``batch_step(params, opt_state, tables, seeds, n_valid, key) ->
-  (params, opt_state, tables, aux)``; seeds/n_valid/keys carry a
-  leading [T] axis (per-type seed dicts stack per leaf)."""
-
-  def run(params, opt_state, tables, seeds_stack, n_valid_stack, keys):
+  def run(params, opt_state, seeds_stack, n_valid_stack, keys):
     def step(carry, x):
-      params, opt_state, tables = carry
+      params, opt_state = carry
       seeds, n_valid, key = x
-      params, opt_state, tables, aux = batch_step(
-          params, opt_state, tables, seeds, n_valid, key)
-      return (params, opt_state, tables), aux
+      params, opt_state, aux = batch_step(params, opt_state, seeds,
+                                          n_valid, key)
+      return (params, opt_state), aux
 
-    (params, opt_state, tables), aux = jax.lax.scan(
-        step, (params, opt_state, tables),
-        (seeds_stack, n_valid_stack, keys), unroll=unroll)
-    return params, opt_state, tables, aux
+    (params, opt_state), aux = jax.lax.scan(
+        step, (params, opt_state), (seeds_stack, n_valid_stack, keys),
+        unroll=unroll)
+    return params, opt_state, aux
 
   return run
 
@@ -101,8 +68,7 @@ def scan_consume(consume_step: Callable, unroll: int = 1):
   (carry, aux)`` over stacked inputs whose sampling already ran (the
   cold-row streaming pipeline stages sampler outputs and cold feature
   rows for superstep N+1 while the chip executes superstep N; the
-  consume scan then holds no dedup state — only params/opt ride the
-  carry)."""
+  consume scan's carry is params/opt alone)."""
 
   def run(carry, xs):
     return jax.lax.scan(consume_step, carry, xs, unroll=unroll)

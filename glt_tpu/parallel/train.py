@@ -18,11 +18,9 @@ Two execution modes share one batch body:
     DeviceEpochLoader staged on device once per epoch. Bit-identical to
     K sequential per-batch calls (same RNG stream, same op sequence) —
     the scan only amortizes the per-batch host round-trips. The hetero
-    sibling — per-edge-type collective sampling + RGNN update, same
-    scan lift with the per-type table dict as the dedup state — is
-    ``glt_tpu.distributed.DistHeteroTrainStep.superstep``
-    (ops/superstep.py::superstep_hetero, which this trainer's homo
-    ``(table, scratch)`` superstep is now a special case of).
+    sibling — per-edge-type collective sampling + RGNN update, the same
+    scan lift (ops/superstep.py) — is
+    ``glt_tpu.distributed.DistHeteroTrainStep.superstep``.
 
 The per-batch body has two fronts and two losses, chosen when the step
 is built. Given labelled node seeds it trains a classifier. Given a
@@ -76,7 +74,6 @@ from ..ops.pipeline import edge_hop_offsets, hop_fanouts, \
     multihop_sample, node_hop_offsets, sample_budget
 from ..ops.sample import sample_neighbors
 from ..ops.subgraph import EncloseSpec, enclosing_subgraphs, pad_to_tiles
-from ..ops.pipeline import make_dedup_tables
 from ..ops.superstep import scan_consume, superstep as build_superstep
 from ..loader.transform import Batch
 from ..obs.device import StepCounters, register_step_program, scope
@@ -345,15 +342,6 @@ class SPMDSageTrainStep(StepCounters):
     self._indices = jax.device_put(
         graph.indices if enclose is None else pad_to_tiles(graph.indices),
         NamedSharding(mesh, P()))
-    n_dev = mesh.shape[axis]
-    # per-device inducer tables, stacked on the mesh axis
-    table, scratch = make_dedup_tables(graph.num_nodes)
-    self.tables = jax.device_put(
-        jnp.broadcast_to(table, (n_dev,) + table.shape),
-        NamedSharding(mesh, P(axis)))
-    self.scratches = jax.device_put(
-        jnp.broadcast_to(scratch, (n_dev,) + scratch.shape),
-        NamedSharding(mesh, P(axis)))
     #: times each program was TRACED (trace-time side effect; executions
     #: never bump these) — zero-steady-state-recompile assertions read
     #: them. A fresh T (e.g. an epoch's ragged tail superstep) traces
@@ -462,7 +450,7 @@ class SPMDSageTrainStep(StepCounters):
     one_hop = lambda ids, fanout, k, mask: sample_neighbors(
         indptr, indices, ids, fanout, k, seed_mask=mask)
 
-    def body(params, opt_state, table, scratch, seeds, n_valid, key):
+    def body(params, opt_state, seeds, n_valid, key):
       counted = {}
       with scope('sampler'):
         key = jax.random.fold_in(key[0], jax.lax.axis_index(axis))
@@ -471,8 +459,8 @@ class SPMDSageTrainStep(StepCounters):
           kneg, key = jax.random.split(key)
           seeds, seed_mask, edge_label, counted = self._link_seeds(
               indptr, indices, seeds, n_valid[0], kneg)
-        out, table, scratch = multihop_sample(
-            one_hop, seeds, n_valid[0], fanouts, key, table, scratch,
+        out = multihop_sample(
+            one_hop, seeds, n_valid[0], fanouts, key,
             with_edge=with_edge, seed_mask=seed_mask)
         counted.update(nodes_by_hop=out['num_sampled_nodes'],
                        edges_by_hop=out['num_sampled_edges'],
@@ -501,21 +489,21 @@ class SPMDSageTrainStep(StepCounters):
       self._note_layer_rows(batch)
       params, opt_state, loss = _sage_update(
           model, tx, axis, loss_of, params, opt_state, batch, n_valid[0])
-      return params, opt_state, table, scratch, (loss, counted)
+      return params, opt_state, (loss, counted)
 
     return body
 
   def _make_enclose_body(self, feat_shard, indptr, indices, cold_shard):
     """``_make_batch_body`` for an enclosing-subgraph step: the link
     step's front as it is, then a batch of ``2B`` graphs where the
-    fan-out tree stood (the module's text). The dedup tables pass
-    through untouched: every link is deduped on its own."""
+    fan-out tree stood (the module's text): every link is deduped on
+    its own."""
     feature, model, tx, axis = self.feature, self.model, self.tx, self.axis
     spec, bs = self._enclose, self.bs
     links, s, k = 2 * bs, spec.node_slots, spec.fanout
     loss_of = _graph_loss(bs)
 
-    def body(params, opt_state, table, scratch, pairs, n_valid, key):
+    def body(params, opt_state, pairs, n_valid, key):
       with scope('sampler'):
         key = jax.random.fold_in(key[0], jax.lax.axis_index(axis))
         kneg, key = jax.random.split(key)
@@ -573,35 +561,32 @@ class SPMDSageTrainStep(StepCounters):
           has_aux=True)
       if self._keep_sample:
         counted['pool_order'] = order
-      return params, opt_state, table, scratch, (loss, counted)
+      return params, opt_state, (loss, counted)
 
     return body
 
   def _build(self):
-    def device_step(params, opt_state, table, scratch, seeds, n_valid,
-                    key, feat_shard, labels, indptr, indices,
-                    *cold_shard):
+    def device_step(params, opt_state, seeds, n_valid, key, feat_shard,
+                    labels, indptr, indices, *cold_shard):
       body = self._make_batch_body(
           feat_shard, labels, indptr, indices,
           cold_shard[0] if cold_shard else None)
-      params, opt_state, table, scratch, aux = body(
-          params, opt_state, table[0], scratch[0], seeds, n_valid, key)
-      return (params, opt_state, table[None], scratch[None],
-              jax.tree.map(lambda a: a[None], aux))
+      params, opt_state, aux = body(params, opt_state, seeds, n_valid,
+                                    key)
+      return params, opt_state, jax.tree.map(lambda a: a[None], aux)
 
     offloaded = self.feature.cold_array is not None
     fn = jax.shard_map(
         device_step, mesh=self.mesh,
         in_specs=(P(), P(), P(self.axis), P(self.axis), P(self.axis),
-                  P(self.axis), P(self.axis), P(self.axis), P(), P(),
-                  P())
+                  P(self.axis), P(), P(), P())
         + ((P(self.axis),) if offloaded else ()),
-        out_specs=(P(), P(), P(self.axis), P(self.axis), P(self.axis)),
+        out_specs=(P(), P(), P(self.axis)),
         check_vma=False)
 
-    @functools.partial(jax.jit, donate_argnums=(2, 3))
-    def step(params, opt_state, tables, scratches, seeds, n_valid, keys,
-             feat_array, labels, indptr, indices, *cold):
+    @jax.jit
+    def step(params, opt_state, seeds, n_valid, keys, feat_array, labels,
+             indptr, indices, *cold):
       # feat/cold/labels/topology ride as explicit args: (a) committed
       # shardings — incl. the cold block's pinned_host memory kind —
       # are preserved (a closed-over array would be re-laid-out as a
@@ -611,8 +596,8 @@ class SPMDSageTrainStep(StepCounters):
       self.step_traces += 1  # trace-time side effect only
       from ..obs.perf import count_compile
       count_compile('train.step')
-      return fn(params, opt_state, tables, scratches, seeds, n_valid,
-                keys, feat_array, labels, indptr, indices, *cold)
+      return fn(params, opt_state, seeds, n_valid, keys, feat_array,
+                labels, indptr, indices, *cold)
 
     return step
 
@@ -620,18 +605,18 @@ class SPMDSageTrainStep(StepCounters):
 
   def _build_superstep(self):
     """The fused superstep program: lax.scan of the per-batch body with
-    params/opt-state/dedup-tables in the carry. Unsupported for
+    params/opt-state in the carry. Unsupported for
     streaming stores (cold rows are not in-program resolvable there);
     ``superstep()`` routes those through sample+stage+consume."""
     if self._streaming or self._link:
       return None
     axis = self.axis
 
-    def device_superstep(params, opt_state, tables, scratches,
-                         seeds_stack, n_valid_stack, keys, feat_shard,
-                         labels, indptr, indices, *cold_shard):
+    def device_superstep(params, opt_state, seeds_stack, n_valid_stack,
+                         keys, feat_shard, labels, indptr, indices,
+                         *cold_shard):
       # per-device views: seeds_stack [T, bs], n_valid_stack [T, 1],
-      # keys [T, 1], tables [1, ...]
+      # keys [T, 1]
       body = self._make_batch_body(
           feat_shard, labels, indptr, indices,
           cold_shard[0] if cold_shard else None)
@@ -642,32 +627,28 @@ class SPMDSageTrainStep(StepCounters):
         return (*state, loss)
 
       run = build_superstep(loss_alone)
-      params, opt_state, table, scratch, losses = run(
-          params, opt_state, tables[0], scratches[0], seeds_stack,
-          n_valid_stack, keys)
-      return (params, opt_state, table[None], scratch[None],
-              losses[:, None])
+      params, opt_state, losses = run(params, opt_state, seeds_stack,
+                                      n_valid_stack, keys)
+      return params, opt_state, losses[:, None]
 
     offloaded = self.feature.cold_array is not None
     stacked = P(None, self.axis)
     fn = jax.shard_map(
         device_superstep, mesh=self.mesh,
-        in_specs=(P(), P(), P(self.axis), P(self.axis), stacked,
-                  stacked, stacked, P(self.axis), P(), P(), P())
+        in_specs=(P(), P(), stacked, stacked, stacked, P(self.axis), P(),
+                  P(), P())
         + ((P(self.axis),) if offloaded else ()),
-        out_specs=(P(), P(), P(self.axis), P(self.axis), stacked),
+        out_specs=(P(), P(), stacked),
         check_vma=False)
 
-    @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
-    def step(params, opt_state, tables, scratches, seeds_stack,
-             n_valid_stack, keys, feat_array, labels, indptr, indices,
-             *cold):
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    def step(params, opt_state, seeds_stack, n_valid_stack, keys,
+             feat_array, labels, indptr, indices, *cold):
       self.superstep_traces += 1  # trace-time side effect only
       from ..obs.perf import count_compile
       count_compile('train.superstep')
-      return fn(params, opt_state, tables, scratches, seeds_stack,
-                n_valid_stack, keys, feat_array, labels, indptr,
-                indices, *cold)
+      return fn(params, opt_state, seeds_stack, n_valid_stack, keys,
+                feat_array, labels, indptr, indices, *cold)
 
     return step
 
@@ -711,11 +692,9 @@ class SPMDSageTrainStep(StepCounters):
     _synced = {}
     with tracer.span('train.superstep', k=int(seeds.shape[0]),
                      sync=lambda: _synced.get('loss')):
-      (params, opt_state, self.tables, self.scratches,
-       loss) = self._superstep_fn(
-           params, opt_state, self.tables, self.scratches, seeds,
-           n_valid, keys, self.feature.array, self.labels, self._indptr,
-           self._indices, *extra)
+      params, opt_state, loss = self._superstep_fn(
+          params, opt_state, seeds, n_valid, keys, self.feature.array,
+          self.labels, self._indptr, self._indices, *extra)
       _synced['loss'] = loss
     if tracer.enabled:
       # re-trace visibility on the shared surface: the zero-steady-
@@ -734,47 +713,40 @@ class SPMDSageTrainStep(StepCounters):
     axis, fanouts, bs = self.axis, self.fanouts, self.bs
     with_edge = self.with_edge
 
-    def device_sample(tables, scratches, seeds_stack, n_valid_stack,
-                      keys, indptr, indices):
+    def device_sample(seeds_stack, n_valid_stack, keys, indptr, indices):
       one_hop = lambda ids, fanout, k, mask: sample_neighbors(
           indptr, indices, ids, fanout, k, seed_mask=mask)
 
       def body(carry, x):
-        table, scratch = carry
         seeds, n_valid, key = x
         with scope('sampler'):
           key = jax.random.fold_in(key[0], jax.lax.axis_index(axis))
-          out, table, scratch = multihop_sample(
-              one_hop, seeds, n_valid[0], fanouts, key, table, scratch,
-              with_edge=with_edge)
+          out = multihop_sample(one_hop, seeds, n_valid[0], fanouts, key,
+                                with_edge=with_edge)
         keep = dict(node=out['node'], node_count=out['node_count'][None],
                     row=out['row'], col=out['col'],
                     edge_mask=out['edge_mask'])
         if with_edge:
           keep['edge'] = out['edge']
-        return (table, scratch), keep
+        return carry, keep
 
-      (table, scratch), outs = jax.lax.scan(
-          body, (tables[0], scratches[0]),
-          (seeds_stack, n_valid_stack, keys))
-      return table[None], scratch[None], outs
+      _, outs = jax.lax.scan(body, None,
+                             (seeds_stack, n_valid_stack, keys))
+      return outs
 
     stacked = P(None, self.axis)
     fn = jax.shard_map(
         device_sample, mesh=self.mesh,
-        in_specs=(P(self.axis), P(self.axis), stacked, stacked, stacked,
-                  P(), P()),
-        out_specs=(P(self.axis), P(self.axis), stacked),
+        in_specs=(stacked, stacked, stacked, P(), P()),
+        out_specs=stacked,
         check_vma=False)
 
-    @functools.partial(jax.jit, donate_argnums=(0, 1))
-    def sample(tables, scratches, seeds_stack, n_valid_stack, keys,
-               indptr, indices):
+    @jax.jit
+    def sample(seeds_stack, n_valid_stack, keys, indptr, indices):
       self.superstep_traces += 1  # trace-time side effect only
       from ..obs.perf import count_compile
       count_compile('train.sample_superstep')
-      return fn(tables, scratches, seeds_stack, n_valid_stack, keys,
-                indptr, indices)
+      return fn(seeds_stack, n_valid_stack, keys, indptr, indices)
 
     return sample
 
@@ -841,9 +813,8 @@ class SPMDSageTrainStep(StepCounters):
     rows for every sampled node stack. run_epoch calls this from the
     prefetch thread so the host gather for superstep N+1 overlaps the
     chip executing superstep N."""
-    self.tables, self.scratches, outs = self._sample_fn(
-        self.tables, self.scratches, seeds, n_valid, keys,
-        self._indptr, self._indices)
+    outs = self._sample_fn(seeds, n_valid, keys, self._indptr,
+                           self._indices)
     cold = self.feature.stage_cold_rows(
         np.asarray(outs['node']), np.asarray(outs['node_count']))
     cold_x = jax.device_put(
@@ -954,11 +925,9 @@ class SPMDSageTrainStep(StepCounters):
       extra = ((self.feature.cold_array,)
                if self.feature.cold_array is not None else ())
       with tracer.span('train.step/dispatch'):
-        (params, opt_state, self.tables, self.scratches,
-         (loss, counted)) = self._step_fn(
-             params, opt_state, self.tables, self.scratches, seeds,
-             n_valid, keys, self.feature.array, self.labels,
-             self._indptr, self._indices, *extra)
+        params, opt_state, (loss, counted) = self._step_fn(
+            params, opt_state, seeds, n_valid, keys, self.feature.array,
+            self.labels, self._indptr, self._indices, *extra)
       self._keep_counters(counted)
       _synced['loss'] = loss
     if tracer.enabled:

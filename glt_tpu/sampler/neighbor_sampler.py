@@ -6,8 +6,9 @@ runs a Python hop loop issuing CUDA kernels. Here the *entire multi-hop
 walk* — sampling, dedup/relabel, frontier advance — is one jitted XLA
 program per (batch_size,) shape: static padded frontiers per hop (capacity
 ``B·Πfanouts``, the same bound the reference sizes its inducer with,
-neighbor_sampler.py:660-677), with the dense-table inducer threading its
-tables through the jit via donation so there is no per-batch allocation.
+neighbor_sampler.py:660-677), deduped by the sort-merge inducer over
+batch-sized arrays (ops/pipeline.py), so a program holds no per-graph
+state.
 
 Orientation contract (verified against the reference, see
 neighbor_sampler.py:186-320): for every output edge key, ``row`` holds
@@ -25,9 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..data import Graph
-from ..ops.pipeline import dedup_engine, edge_hop_offsets, \
-    hetero_edge_hop_offsets, hop_fanouts, make_dedup_tables, \
-    multihop_sample, multihop_sample_hetero, node_hop_offsets
+from ..ops.pipeline import edge_hop_offsets, hetero_edge_hop_offsets, \
+    hop_fanouts, multihop_sample, multihop_sample_hetero, node_hop_offsets
 from ..ops.sample import (
     neighbor_probs, sample_full_neighbors, sample_neighbors,
     sample_neighbors_weighted,
@@ -40,10 +40,6 @@ from ..utils.rng import RandomSeedManager
 from .base import (
     BaseSampler, HeteroSamplerOutput, NodeSamplerInput, SamplerOutput,
 )
-
-#: above this column-space size the dense label table is considered too
-#: expensive (2 × 4 bytes per node in HBM)
-DENSE_TABLE_NODE_LIMIT = 256_000_000
 
 
 class NeighborSampler(BaseSampler):
@@ -127,7 +123,6 @@ class NeighborSampler(BaseSampler):
       self._node_counts = None
 
     self._fn_cache = {}
-    self._tables = {}   # key: ntype or '' -> (table, scratch)
 
   # -- helpers -----------------------------------------------------------
 
@@ -164,16 +159,6 @@ class NeighborSampler(BaseSampler):
     self._step += 1
     return jax.random.fold_in(self._base_key, self._step)
 
-  def _get_tables(self, ntype: str, num_nodes: int):
-    if ntype not in self._tables:
-      assert (dedup_engine() == 'sort'
-              or num_nodes <= DENSE_TABLE_NODE_LIMIT), (
-          f'node space {num_nodes} exceeds dense-table limit; '
-          'shard the graph (distributed sampler) or use the sort-merge '
-          'inducer (GLT_DEDUP=sort) instead')
-      self._tables[ntype] = make_dedup_tables(num_nodes)
-    return self._tables[ntype]
-
   def _one_hop(self, g: Graph, frontier, fanout, key, mask):
     """Dispatch full/uniform/weighted one-hop sampling on graph ``g``."""
     eids = g.edge_ids if self.with_edge else None
@@ -198,17 +183,16 @@ class NeighborSampler(BaseSampler):
     one_hop = lambda ids, fanout, key, mask: self._one_hop(
         g, ids, fanout, key, mask)
 
-    def fn(seeds, n_valid, key, table, scratch):
+    def fn(seeds, n_valid, key):
       # trace-time side effect: one compiles_total{fn=...} tick per
       # compiled seed-shape program (the registry counterpart of
       # num_compiled_fns — executions never bump it)
       from ..obs.perf import count_compile
       count_compile('sampler.homo')
       return multihop_sample(one_hop, seeds, n_valid, self.num_neighbors,
-                             key, table, scratch,
-                             with_edge=self.with_edge)
+                             key, with_edge=self.with_edge)
 
-    return jax.jit(fn, donate_argnums=(3, 4))
+    return jax.jit(fn)
 
   def _edge_hop_offsets(self, batch_size: int) -> List[int]:
     return edge_hop_offsets(batch_size, self.num_neighbors)
@@ -230,7 +214,6 @@ class NeighborSampler(BaseSampler):
     cache_key = ('homo', batch_size)
     if cache_key not in self._fn_cache:
       self._fn_cache[cache_key] = self._build_homo_fn(batch_size)
-    table, scratch = self._get_tables('', self.graph.num_nodes)
     # dispatch is async: the sync closure hands the output back to the
     # span so sampled device-syncs (GLT_OBS_TRACE_SAMPLE) measure real
     # compute, not just dispatch
@@ -238,11 +221,10 @@ class NeighborSampler(BaseSampler):
     with get_tracer().span('sample.multihop', batch=batch_size,
                            hops=len(self.num_neighbors),
                            sync=lambda: _synced.get('out')):
-      out, table, scratch = self._fn_cache[cache_key](
+      out = self._fn_cache[cache_key](
           jnp.asarray(seeds.astype(np.int32)), jnp.asarray(n_valid),
-          kwargs.get('key', self._next_key()), table, scratch)
+          kwargs.get('key', self._next_key()))
       _synced['out'] = out['num_sampled_edges']
-    self._tables[''] = (table, scratch)
     return SamplerOutput(
         node=out['node'], node_count=out['node_count'],
         row=out['row'], col=out['col'], edge_mask=out['edge_mask'],
@@ -295,15 +277,14 @@ class NeighborSampler(BaseSampler):
             self.graph[_e], ids, fanout, key, mask))
         for e in self.edge_types}
 
-    def fn(seeds, n_valid, key, tables):
+    def fn(seeds, n_valid, key):
       from ..obs.perf import count_compile
       count_compile('sampler.hetero')  # trace-time only, like homo
       return multihop_sample_hetero(
           one_hops, trav, self.num_neighbors, self.num_hops, caps,
-          budgets, seeds, n_valid, key, tables,
-          with_edge=self.with_edge)
+          budgets, seeds, n_valid, key, with_edge=self.with_edge)
 
-    return jax.jit(fn, donate_argnums=(3,))
+    return jax.jit(fn)
 
   def _hetero_sample_from_nodes(self, inputs, **kwargs) \
       -> HeteroSamplerOutput:
@@ -325,15 +306,12 @@ class NeighborSampler(BaseSampler):
     cache_key = ('hetero', tuple(sorted(batch_sizes.items())))
     if cache_key not in self._fn_cache:
       self._fn_cache[cache_key] = self._build_hetero_fn(batch_sizes)
-    tables = {t: self._get_tables(t, n)
-              for t, n in self._node_counts.items()}
     key = kwargs.pop('key', None)
-    out, new_tables = self._fn_cache[cache_key](
+    out = self._fn_cache[cache_key](
         {t: jnp.asarray(s.astype(np.int32))
          for t, s in seed_dict.items()},
         {t: jnp.asarray(v) for t, v in n_valid.items()},
-        key if key is not None else self._next_key(), tables)
-    self._tables.update(new_tables)
+        key if key is not None else self._next_key())
 
     # final keys: 'out' reverses the traversal type, 'in' keeps it; row
     # must carry child labels (= our cols), col parent labels (= our rows)

@@ -18,9 +18,10 @@ are all cached skips sampling and the forward entirely, and partial
 hits shrink the computed batch to the missing unique ids.
 
 The engine is intentionally NOT thread-safe per call (``infer`` takes an
-internal lock): the donated dedup tables inside the sampler's jitted
-programs make it non-reentrant. Put the :class:`MicroBatcher` in front
-of it — that is also where cross-request batching happens.
+internal lock): the sampler's key counter and program cache and the
+embedding cache are shared, unguarded state. Put the
+:class:`MicroBatcher` in front of it — that is also where cross-request
+batching happens.
 """
 from __future__ import annotations
 

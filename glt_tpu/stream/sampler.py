@@ -36,8 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.delta import delta_one_hop
-from ..ops.pipeline import edge_hop_offsets, make_dedup_tables, \
-    multihop_sample
+from ..ops.pipeline import edge_hop_offsets, multihop_sample
 from ..sampler.base import BaseSampler, NodeSamplerInput, SamplerOutput
 from ..utils import as_numpy
 from ..utils.rng import RandomSeedManager, make_key
@@ -119,7 +118,6 @@ class StreamSampler(BaseSampler):
         else RandomSeedManager.getInstance().getSeed())
     self._step = 0
     self._fn_cache = {}
-    self._tables = {}
     #: times any multihop program was traced (trace-time side effect;
     #: flat in steady state even across snapshot swaps)
     self.trace_count = 0
@@ -154,16 +152,11 @@ class StreamSampler(BaseSampler):
     self._step += 1
     return jax.random.fold_in(self._base_key, self._step)
 
-  def _get_tables(self, num_nodes: int):
-    if '' not in self._tables:
-      self._tables[''] = make_dedup_tables(num_nodes)
-    return self._tables['']
-
   def _build_fn(self, batch_size: int):
     eff = list(self.num_neighbors)
     base = list(self._base_fanouts)
 
-    def fn(arrays, seeds, n_valid, key, table, scratch):
+    def fn(arrays, seeds, n_valid, key):
       self.trace_count += 1  # trace-time only; executions never bump
       from ..obs.perf import count_compile
       count_compile('stream.sample')  # compiles_total{fn=...}
@@ -182,9 +175,9 @@ class StreamSampler(BaseSampler):
             replace=self.replace)
 
       return multihop_sample(one_hop, seeds, n_valid, eff, key,
-                             table, scratch, with_edge=False)
+                             with_edge=False)
 
-    return jax.jit(fn, donate_argnums=(4, 5))
+    return jax.jit(fn)
 
   def sample_from_nodes(self, inputs, **kwargs) -> SamplerOutput:
     """Delta-merged multi-hop sampling from seed nodes; same output
@@ -195,7 +188,6 @@ class StreamSampler(BaseSampler):
       seeds = as_numpy(inputs)
     n_valid = kwargs.get('n_valid', seeds.shape[0])
     batch_size = seeds.shape[0]
-    table, scratch = self._get_tables(self.manager.num_nodes)
     snap = self.manager.acquire()
     try:
       cache_key = ('homo', batch_size)
@@ -212,13 +204,11 @@ class StreamSampler(BaseSampler):
             snap.version, snap.max_degree, self._full_cap)
       arrays = dict(snap.arrays)
       arrays.update(self._overlay)
-      out, table, scratch = self._fn_cache[cache_key](
+      out = self._fn_cache[cache_key](
           arrays, jnp.asarray(seeds.astype(np.int32)),
-          jnp.asarray(n_valid),
-          kwargs.get('key', self._next_key()), table, scratch)
+          jnp.asarray(n_valid), kwargs.get('key', self._next_key()))
     finally:
       self.manager.release(snap)
-    self._tables[''] = (table, scratch)
     return SamplerOutput(
         node=out['node'], node_count=out['node_count'],
         row=out['row'], col=out['col'], edge_mask=out['edge_mask'],

@@ -12,22 +12,12 @@ import threading
 
 import jax
 
-from .env import knob
-
 
 def make_key(seed: int) -> jax.Array:
-  """Typed PRNG key honoring ``GLT_PRNG`` (e.g. ``rbg``).
-
-  threefry (jax default) is counter-based and bit-reproducible across
-  backends — the right default for tests and parity. ``GLT_PRNG=rbg``
-  selects the XLA RngBitGenerator implementation, which generates bits
-  several times faster on TPU (benchmarks/microbench_prims.py
-  uniform_15x153k A/B) at the cost of cross-backend reproducibility.
-  The impl travels inside the typed key, so every ``jax.random.split``
-  / ``fold_in`` downstream inherits it.
-  """
-  impl = knob('GLT_PRNG', None) or None
-  return jax.random.key(int(seed), impl=impl)
+  """Typed threefry PRNG key (jax's default): counter-based and
+  bit-reproducible across backends. Every ``jax.random.split`` /
+  ``fold_in`` downstream inherits it."""
+  return jax.random.key(int(seed))
 
 
 class RandomSeedManager:

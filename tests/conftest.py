@@ -18,9 +18,13 @@ os.environ.pop('GLT_HOST_OFFLOAD', None)
 # Must run before jax initializes its backend: the platform and the
 # virtual device count are fixed there. The shared guard owns that
 # rule: glt_tpu/utils/backend.py
-from glt_tpu.utils.backend import force_backend
+from glt_tpu.utils.backend import configure_compile_cache, force_backend
 
 force_backend('cpu', host_devices=8)
+# many files compile the same step programs (the tiny cells, the typed
+# steps): the entry points' persistent cache, keyed on metadata too, lets
+# the session's workers compile each once
+configure_compile_cache()
 
 import jax
 
@@ -127,3 +131,21 @@ def recount_as_the_enclosing_step_counts_now(request, monkeypatch):
                 tiles_matched=-(-held // chunk) * chunk)
 
   monkeypatch.setattr(seal_fused, 'recount', with_tiles_matched)
+
+
+@pytest.fixture(autouse=True)
+def rehearsal_windows_a_loaded_host_can_fill(request, monkeypatch):
+  """Each cell's ``tests/chipbench/test_<cell>.py::
+  test_rehearsal_of_a_run_comes_out_correct`` drives its tiny cell for a
+  0.3 s window on the CPU and asks for more than 3 steps; with six test
+  workers on a host that steals a fifth of the CPU, the HGT cell's counted
+  3 (PR 43). In those tests the window is 1 s; what the test holds is
+  unchanged. A ``benchmark`` PR can widen the window there and drop this
+  fixture."""
+  if request.node.name != 'test_rehearsal_of_a_run_comes_out_correct':
+    return
+  from chipbench import run
+  run_cell = run.run_cell
+  monkeypatch.setattr(
+      run, 'run_cell', lambda name, seed, seconds, trace: run_cell(
+          name, seed, max(seconds, 1.0), trace))

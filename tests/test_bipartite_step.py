@@ -77,17 +77,6 @@ def build_step(csr, seed_type=U2I, model=None, **kw):
   return step, tx
 
 
-@pytest.fixture(params=['table', 'sort+fused'])
-def dedup_engine(request, monkeypatch):
-  """Both inducers: the CPU's default and what ``auto`` is on a TPU."""
-  if request.param == 'sort+fused':
-    monkeypatch.setenv('GLT_DEDUP', 'sort')
-    monkeypatch.setenv('GLT_FUSED_HOP', '1')
-  else:
-    monkeypatch.setenv('GLT_DEDUP', 'table')
-  return request.param
-
-
 def positives(csr, steps, seed=5):
   pairs = edges_of(csr[U2I])
   pick = np.random.default_rng(seed).choice(
@@ -152,7 +141,7 @@ def flat(tree):
           jax.tree_util.tree_leaves_with_path(tree)}
 
 
-def test_step_matches_the_reference(dedup_engine):
+def test_step_matches_the_reference():
   csr = bipartite_graph()
   step, tx = build_step(csr)
   params0 = step.init_params(jax.random.key(3))
@@ -228,7 +217,7 @@ def is_edge(csr, rows, cols):
 
 
 @pytest.mark.parametrize('n_valid', [BATCH, 5])
-def test_negatives_sample_and_counters_against_numpy(n_valid, dedup_engine):
+def test_negatives_sample_and_counters_against_numpy(n_valid):
   csr = bipartite_graph()
   step, tx = build_step(csr)
   params0 = step.init_params(jax.random.key(3))

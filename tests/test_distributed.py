@@ -909,42 +909,42 @@ def test_dist_subgraph_loader_edge_features(mesh, part_dir_ef,
   assert saw > 0
 
 
-# -- sort-merge inducer inside the SPMD program --------------------------
-# On real TPU hardware GLT_DEDUP=auto resolves to 'sort', so the
-# collective one-hop is fed the sorted engine's permuted, _BIG-padded
-# frontier. These force that engine on the CPU mesh and re-assert the
-# exactness the table-engine tests above establish.
+# -- repeated and masked seed slots through the collective one-hop -------
+# The hop loop feeds the collective one-hop a frontier in no slot order,
+# with _BIG in the slots of nodes seen before: two seed slots of one node
+# and a masked slot on every device must come out as one label and none.
 
-def test_dist_sampler_sort_engine_exact(mesh, part_dir, monkeypatch):
-  monkeypatch.setenv('GLT_DEDUP', 'sort')
+def test_dist_sampler_repeated_and_masked_seeds(mesh, part_dir):
   dg = DistGraph.from_dataset_partitions(mesh, part_dir)
   s = DistNeighborSampler(dg, [2, 2], with_edge=True, seed=1)
-  seeds = np.arange(N_PARTS)[:, None]
-  out = s.sample_from_nodes(seeds)
+  seeds = np.stack([[p, p, (p + 9) % N_NODES, (p + 5) % N_NODES]
+                    for p in range(N_PARTS)])
+  out = s.sample_from_nodes(seeds, np.full(N_PARTS, 3))
   nodes = np.asarray(out['node'])
   counts = np.asarray(out['node_count'])
   for p in range(N_PARTS):
+    live = (p, (p + 9) % N_NODES)
+    np.testing.assert_array_equal(np.asarray(out['seed_labels'])[p],
+                                  [0, 0, 1, -1])
     got = set(nodes[p][:counts[p]].tolist())
-    expect = {p, (p + 1) % N_NODES, (p + 2) % N_NODES,
-              (p + 3) % N_NODES, (p + 4) % N_NODES}
-    assert got == expect
+    assert got == {(v + d) % N_NODES for v in live for d in range(5)}
     em = np.asarray(out['edge_mask'])[p]
     child = nodes[p][np.asarray(out['row'])[p][em]]
     parent = nodes[p][np.asarray(out['col'])[p][em]]
     for pp, cc in zip(parent, child):
       assert cc in ((pp + 1) % N_NODES, (pp + 2) % N_NODES)
-    # hop-0 edge ids are the seed's out-edges {2p, 2p+1}
+    # hop-0 edge ids are the live seeds' out-edges {2v, 2v+1}
     offs = out['edge_hop_offsets']
     em0 = em[offs[0]:offs[1]]
     eids0 = np.asarray(out['edge'])[p][offs[0]:offs[1]][em0]
-    assert set(eids0.tolist()) == {2 * p, 2 * p + 1}
+    assert sorted(eids0.tolist()) == sorted(
+        e for v in live for e in (2 * v, 2 * v + 1))
 
 
-def test_dist_hetero_sampler_sort_engine(tmp_path_factory, mesh,
-                                         monkeypatch):
-  monkeypatch.setenv('GLT_DEDUP', 'sort')
+def test_dist_hetero_sampler_repeated_and_masked_seeds(tmp_path_factory,
+                                                       mesh):
   from glt_tpu.distributed import DistHeteroGraph, DistHeteroNeighborSampler
-  root = str(tmp_path_factory.mktemp('hetero_parts_sort'))
+  root = str(tmp_path_factory.mktemp('hetero_parts_repeated'))
   u2i = ('user', 'u2i', 'item')
   i2i = ('item', 'i2i', 'item')
   nu, ni = 16, 32
@@ -959,18 +959,23 @@ def test_dist_hetero_sampler_sort_engine(tmp_path_factory, mesh,
                     edge_index={u2i: u2i_ei, i2i: i2i_ei}).partition()
   dg = DistHeteroGraph.from_dataset_partitions(mesh, root)
   s = DistHeteroNeighborSampler(dg, {u2i: [2, 2], i2i: [2, 2]}, seed=0)
-  seeds = (np.arange(N_PARTS) % nu)[:, None]
-  out = s.sample_from_nodes('user', seeds)
+  seeds = np.stack([[p % nu, p % nu, (p + 5) % nu, (p + 11) % nu]
+                    for p in range(N_PARTS)])
+  out = s.sample_from_nodes('user', seeds, np.full(N_PARTS, 3))
   items = np.asarray(out['node']['item'])
   icount = np.asarray(out['node_count']['item'])
+  users = np.asarray(out['node']['user'])
+  ucount = np.asarray(out['node_count']['user'])
   for p in range(N_PARTS):
-    uu = p % nu
-    expect = {2*uu % ni, (2*uu+1) % ni}
+    live = (p % nu, (p + 5) % nu)
+    np.testing.assert_array_equal(users[p][:ucount[p]], live)
+    np.testing.assert_array_equal(np.asarray(out['seed_labels'])[p],
+                                  [0, 0, 1, -1])
+    expect = {(2 * uu + d) % ni for uu in live for d in (0, 1)}
     for v in list(expect):
       expect |= {(v+1) % ni, (v+2) % ni}
     got = set(items[p][:icount[p]].tolist())
     assert got == expect, f'dev {p}: {got} != {expect}'
-  assert ('item', 'rev_u2i', 'user') in out['row']
 
 
 def test_dist_feature_lookup_serves_rows_and_zero_rows(mesh,

@@ -50,24 +50,13 @@ def build_step(edges, feats, labels, layers, hops, **model_kw):
   return step, tx
 
 
-@pytest.fixture(params=['table', 'sort+fused'])
-def dedup_engine(request, monkeypatch):
-  """Both inducers: the CPU's default and what ``auto`` is on a TPU."""
-  if request.param == 'sort+fused':
-    monkeypatch.setenv('GLT_DEDUP', 'sort')
-    monkeypatch.setenv('GLT_FUSED_HOP', '1')
-  else:
-    monkeypatch.setenv('GLT_DEDUP', 'table')
-  return request.param
-
-
 def flat(tree):
   return {jax.tree_util.keystr(k): np.asarray(v) for k, v in
           jax.tree_util.tree_leaves_with_path(tree)}
 
 
 @pytest.mark.parametrize('layers,remat', [(2, False), (3, True)])
-def test_step_matches_the_reference(layers, remat, dedup_engine):
+def test_step_matches_the_reference(layers, remat):
   edges, feats, labels = typed_graph()
   step, tx = build_step(edges, feats, labels, layers, layers, remat=remat)
   params0 = step.init_params(jax.random.key(3))
@@ -135,16 +124,12 @@ def _loss_and_grads(model, params, batch):
   return logits, grads
 
 
-@pytest.mark.parametrize('remat,engine', [(False, 'table'),
-                                          (True, 'sort+fused')])
-def test_hgt_with_the_promise_and_with_it_withheld(remat, engine,
-                                                   monkeypatch):
-  """One batch, the promise on and withheld: the grouped form and the
-  segment form give the same logits and the same gradients for the
-  parameters and the features, to float32 rounding, and the counter
-  says which form ran."""
-  monkeypatch.setenv('GLT_DEDUP', engine.split('+')[0])
-  monkeypatch.setenv('GLT_FUSED_HOP', str(int('fused' in engine)))
+@pytest.mark.parametrize('remat', [False, True])
+def test_hgt_with_the_promise_and_with_it_withheld(remat):
+  """One batch, the promise on and withheld (``hop_fanouts_dict=None``):
+  the grouped form and the segment form give the same logits and the
+  same gradients for the parameters and the features, to float32
+  rounding, and the counter says which form ran."""
   edges, feats, labels = typed_graph()
   step, _ = build_step(edges, feats, labels, 2, 2)
   batch = padded_batch(step, feats, labels)
@@ -163,7 +148,7 @@ def test_hgt_with_the_promise_and_with_it_withheld(remat, engine,
                                err_msg=k)
 
 
-def test_the_relations_into_a_type_share_their_parents(dedup_engine):
+def test_the_relations_into_a_type_share_their_parents():
   """What the joint softmax leans on: for every parent type and hop the
   relations into it carry equal ``S`` in ``hop_fanouts_dict`` and the
   same parent, group by group; a group is live in none of them or heads
@@ -339,7 +324,7 @@ def test_blocks_of_one_hop_with_other_parents_are_refused():
 
 
 @pytest.mark.parametrize('layers,hops', [(2, 2), (3, 3), (3, 2)])
-def test_node_trim_matches_untrimmed(layers, hops, dedup_engine):
+def test_node_trim_matches_untrimmed(layers, hops):
   """Trimmed and ``return_all`` runs agree on the seeds' rows, and
   ``layer_rows`` reads what the plan says."""
   edges, feats, labels = typed_graph(1)

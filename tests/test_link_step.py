@@ -2,8 +2,7 @@
 ``NegativeSampling``. Held to the plain reference
 (``models/reference/sage_link.py``) and to the loader path
 (``LinkNeighborLoader`` + ``sample_from_edges`` + ``GraphSAGE.embed``) on
-seeded weights, at sizes the CPU holds, with the sampler's engines as the
-chip resolves them."""
+seeded weights, at sizes the CPU holds."""
 import os
 
 import jax
@@ -24,13 +23,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FANOUT = [3, 2, 2]
 BINARY = NegativeSampling('binary', 1, strict=True)
 LR = 1e-3
-
-
-@pytest.fixture(autouse=True)
-def tpu_sampler(monkeypatch):
-  """The sampler's engines as ``auto`` resolves them on a TPU."""
-  monkeypatch.setenv('GLT_DEDUP', 'sort')
-  monkeypatch.setenv('GLT_FUSED_HOP', '1')
 
 
 def dataset(num_nodes, num_edges, seed, dim=16):
@@ -345,8 +337,7 @@ def test_the_scopes_of_the_link_step():
                         np.full((1,), batch, np.int32), keys)
   rows = NamedSharding(step.mesh, P(step.axis))
   text = step._step_fn.lower(
-      params, opt, step.tables, step.scratches,
-      jax.device_put(pairs, rows),
+      params, opt, jax.device_put(pairs, rows),
       jax.device_put(np.full((1,), batch, np.int32), rows), keys,
       step.feature.array, step.labels, step._indptr,
       step._indices).compile().as_text()
@@ -375,11 +366,10 @@ def test_a_node_seeded_step_carries_nothing_of_the_link_front(chips):
   rows = NamedSharding(t.mesh, P(t.axis))
   seeds, keys = fused.feed(s, 0)
   out = t._step_fn.lower(
-      s.params, s.opt, t.tables, t.scratches,
-      jax.device_put(np.asarray(seeds, np.int32), rows),
+      s.params, s.opt, jax.device_put(np.asarray(seeds, np.int32), rows),
       jax.device_put(s.n_valid, rows), keys, t.feature.array, t.labels,
       t._indptr, t._indices).out_info
-  # (params, opt_state, tables, scratches, (loss, counters)): every step
+  # (params, opt_state, (loss, counters)): every step
   # counts its nodes and edges by hop; over more than one shard the
   # exchanging store's three counters ride beside them; nothing of the
   # link front

@@ -263,13 +263,3 @@ def test_full_neighborhood_mixed_with_sampled_hop():
   for p in parents2.tolist():
     cnt[p] = cnt.get(p, 0) + 1
   assert all(c == 1 for c in cnt.values())
-
-
-def test_rbg_prng_sampler(monkeypatch, ring):
-  # GLT_PRNG=rbg swaps the PRNG implementation inside the typed key;
-  # sampling semantics (exhaustive when deg <= fanout) are unchanged
-  monkeypatch.setenv('GLT_PRNG', 'rbg')
-  s = NeighborSampler(ring.get_graph(), [2], seed=7)
-  out = s.sample_from_nodes(np.array([0, 5]))
-  nodes = np.asarray(out.node)[:int(out.node_count)]
-  assert set(nodes.tolist()) == {0, 1, 2, 5, 6, 7}

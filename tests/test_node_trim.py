@@ -2,14 +2,16 @@
 
 Layer i of L computes output rows only for the nodes within
 min(num_hops, L-1-i) hops of a seed: a static prefix of the node buffer,
-because every engine hands labels out hop by hop. Under test: (a) the
+because the inducer hands labels out hop by hop. Under test: (a) the
 trimmed model equals the untrimmed one on seed logits and on every
-parameter's gradient, (b) the label property the slice rests on, per
-engine and on the loader path, (c) a batch without the field and
+parameter's gradient, (b) the label property the slice rests on, on
+several seed sets and on the loader path, (c) a batch without the field and
 ``return_all=True`` compute what they computed before, bit for bit, (d)
 the SPMD trainer records the rows it computes and trains to the same
 loss.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -25,7 +27,6 @@ from glt_tpu.ops.pipeline import (edge_hop_offsets, hop_fanouts,
                                   multihop_sample, node_hop_offsets,
                                   sample_budget)
 from glt_tpu.ops.sample import sample_neighbors
-from glt_tpu.ops.unique import dense_make_tables
 from glt_tpu.parallel import ShardedFeature, SPMDSageTrainStep, make_mesh
 
 N = 96
@@ -59,17 +60,27 @@ def hub_graph():
           np.stack([src, dst]))
 
 
-def _sample(hub_graph, fanouts, n_valid=BS, key=0):
-  indptr, indices, _ = hub_graph
+#: seed sets of BS slots: 'ragged' is a ragged last batch (a duplicate
+#: seed, a hub, a node of degree 0, padded slots beyond n_valid); in
+#: 'hubs' every slot holds one of the three hubs; in 'one_seed' every
+#: slot holds hub 1
+SEEDS = {'ragged': [0, 17, 17, N - 1, 40, 2, 63, 5],
+         'hubs': [0, 1, 2, 0, 1, 2, 0, 1],
+         'one_seed': [1] * BS}
+
+
+@functools.partial(jax.jit, static_argnums=(2,))
+def _hop_loop(indptr, indices, fanouts, seeds, n_valid, key):
   one_hop = lambda ids, f, k, m: sample_neighbors(
       indptr, indices, ids, f, k, seed_mask=m)
-  # a ragged last batch: a duplicate seed, a hub, a node of degree 0,
-  # and padded slots beyond n_valid
-  seeds = jnp.asarray([0, 17, 17, N - 1, 40, 2, 63, 5], jnp.int32)
-  table, scratch = dense_make_tables(N)
-  out, _, _ = multihop_sample(one_hop, seeds, jnp.asarray(n_valid),
-                              fanouts, jax.random.key(key), table, scratch)
-  return out
+  return multihop_sample(one_hop, seeds, n_valid, fanouts, key)
+
+
+def _sample(hub_graph, fanouts, n_valid=BS, key=0, seeds='ragged'):
+  indptr, indices, _ = hub_graph
+  return _hop_loop(indptr, indices, tuple(fanouts),
+                   jnp.asarray(SEEDS[seeds], jnp.int32),
+                   jnp.asarray(n_valid), jax.random.key(key))
 
 
 def _batch(hub_graph, fanouts, n_valid=BS):
@@ -115,7 +126,7 @@ def test_node_trim_matches_untrimmed(hub_graph, conv, num_layers, fanouts):
   plain = batch.replace(node_hop_offsets=None)
   model = GraphSAGE(hidden_features=16, out_features=5,
                     num_layers=num_layers, conv=conv, trim=True)
-  params = model.init(jax.random.key(0), batch)
+  params = jax.jit(model.init)(jax.random.key(0), batch)
   y = jnp.arange(BS) % 5
 
   def loss_and_logits(p, b):
@@ -123,10 +134,9 @@ def test_node_trim_matches_untrimmed(hub_graph, conv, num_layers, fanouts):
     loss = optax.softmax_cross_entropy_with_integer_labels(logits, y)
     return loss.mean(), logits
 
-  (_, lo_t), g_t = jax.value_and_grad(loss_and_logits, has_aux=True)(
-      params, batch)
-  (_, lo_p), g_p = jax.value_and_grad(loss_and_logits, has_aux=True)(
-      params, plain)
+  grad = jax.jit(jax.value_and_grad(loss_and_logits, has_aux=True))
+  (_, lo_t), g_t = grad(params, batch)
+  (_, lo_p), g_p = grad(params, plain)
   assert lo_t.shape == (BS, 5)
   rows = model.layer_rows(batch)
   noffs = node_hop_offsets(BS, fanouts)
@@ -147,23 +157,21 @@ def test_node_trim_matches_untrimmed(hub_graph, conv, num_layers, fanouts):
 
 # -- (a') grouped aggregation == segment aggregation, end to end ----------
 
-@pytest.mark.parametrize('engine,fused', [('table', '0'), ('sort', '1')],
-                         ids=['table', 'sort_fused'])
+@pytest.mark.parametrize('n_valid', [6, 1], ids=['ragged', 'one_live_seed'])
 @pytest.mark.parametrize('num_layers,fanouts', [
     (3, (5, 3, 2)), (2, (10, 2)), (3, (3, 2))])
-def test_grouped_aggregation_matches_segment(hub_graph, monkeypatch, engine,
-                                             fused, num_layers, fanouts):
+def test_grouped_aggregation_matches_segment(hub_graph, n_valid,
+                                             num_layers, fanouts):
   """A sampled batch with ``hop_fanouts`` against the same batch with
   the field cleared: seed logits, every row (``return_all``) and every
-  gradient to float32 rounding, on the chip's hop loop and the table's."""
-  monkeypatch.setenv('GLT_DEDUP', engine)
-  monkeypatch.setenv('GLT_FUSED_HOP', fused)
-  plain = _batch(hub_graph, fanouts, n_valid=6)
+  gradient to float32 rounding, on a ragged batch and on a batch whose
+  one live seed leaves every other group of each hop masked."""
+  plain = _batch(hub_graph, fanouts, n_valid=n_valid)
   assert plain.hop_fanouts is None and hop_fanouts(fanouts) == fanouts
   batch = plain.replace(hop_fanouts=hop_fanouts(fanouts))
   model = GraphSAGE(hidden_features=16, out_features=5,
                     num_layers=num_layers)
-  params = model.init(jax.random.key(0), batch)
+  params = jax.jit(model.init)(jax.random.key(0), batch)
   y = jnp.arange(BS) % 5
   eoffs = edge_hop_offsets(BS, fanouts)
   hops = tuple((eoffs[h], (eoffs[h + 1] - eoffs[h]) // k, k)
@@ -181,10 +189,9 @@ def test_grouped_aggregation_matches_segment(hub_graph, monkeypatch, engine,
     loss = optax.softmax_cross_entropy_with_integer_labels(logits, y)
     return loss.mean(), logits
 
-  (_, lo_g), g_g = jax.value_and_grad(loss_and_logits, has_aux=True)(
-      params, batch)
-  (_, lo_p), g_p = jax.value_and_grad(loss_and_logits, has_aux=True)(
-      params, plain)
+  grad = jax.jit(jax.value_and_grad(loss_and_logits, has_aux=True))
+  (_, lo_g), g_g = grad(params, batch)
+  (_, lo_p), g_p = grad(params, plain)
   np.testing.assert_allclose(np.asarray(lo_g), np.asarray(lo_p),
                              rtol=1e-5, atol=1e-6)
   for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(g_g),
@@ -192,25 +199,10 @@ def test_grouped_aggregation_matches_segment(hub_graph, monkeypatch, engine,
     assert np.abs(np.asarray(b)).max() > 0, path
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
                                atol=1e-6, err_msg=str(path))
-  np.testing.assert_allclose(
-      np.asarray(model.apply(params, batch, return_all=True)),
-      np.asarray(model.apply(params, plain, return_all=True)),
-      rtol=1e-5, atol=1e-6)
-
-
-def test_unfused_sort_loop_gives_no_promise(hub_graph, monkeypatch):
-  """``sorted_hop_dedup`` permutes a hop's edges, so the helper gives
-  ``None`` for that loop and its batches aggregate over segments."""
-  monkeypatch.setenv('GLT_DEDUP', 'sort')
-  monkeypatch.setenv('GLT_FUSED_HOP', '0')
-  fanouts = (3, 2, 2)
-  assert hop_fanouts(fanouts) is None
-  out = _sample(hub_graph, fanouts)
-  col = np.asarray(out['col'])[:BS * 3].reshape(BS, 3)
-  assert (col != col[:, :1]).any()    # a group holds several parents
-  batch = _batch(hub_graph, fanouts).replace(
-      hop_fanouts=hop_fanouts(fanouts))
-  assert GraphSAGE(16, 5, num_layers=3).layer_groups(batch) == ((),) * 3
+  every_row = jax.jit(functools.partial(model.apply, return_all=True))
+  np.testing.assert_allclose(np.asarray(every_row(params, batch)),
+                             np.asarray(every_row(params, plain)),
+                             rtol=1e-5, atol=1e-6)
 
 
 def test_hop_fanouts_must_divide_the_hop_blocks(hub_graph):
@@ -221,15 +213,12 @@ def test_hop_fanouts_must_divide_the_hop_blocks(hub_graph):
 
 # -- (b) the property the slice rests on ---------------------------------
 
-@pytest.mark.parametrize('engine,fused', [
-    ('table', '0'), ('sort', '0'), ('sort', '1')])
+@pytest.mark.parametrize('seeds', sorted(SEEDS))
 @pytest.mark.parametrize('fanouts', [(3, 2, 2), (4, 3)])
-def test_labels_are_hop_compact(hub_graph, monkeypatch, engine, fused,
-                                fanouts):
-  monkeypatch.setenv('GLT_DEDUP', engine)
-  monkeypatch.setenv('GLT_FUSED_HOP', fused)
+def test_labels_are_hop_compact(hub_graph, seeds, fanouts):
   for n_valid, key in ((BS, 0), (5, 1), (1, 2)):
-    out = _sample(hub_graph, fanouts, n_valid=n_valid, key=key)
+    out = _sample(hub_graph, fanouts, n_valid=n_valid, key=key,
+                  seeds=seeds)
     _assert_hop_compact(out['row'], out['col'], out['edge_mask'],
                         out['node_count'], BS, fanouts)
     # the hubs and the duplicates make the hops overlap, or the test
@@ -386,8 +375,7 @@ def test_trainer_records_layer_rows_and_trains_alike(mesh, hub_graph):
   got = _three_steps(step, params, opt)
   assert step.layer_rows == (bs + bs * k1 + bs * k1 * k2, bs + bs * k1, bs)
   # groups each layer aggregates by the grouped reduce: one a frontier
-  # slot of the hops it keeps (off the TPU the table loop runs, and it
-  # gives the promise)
+  # slot of the hops it keeps
   assert step.layer_groups == step.layer_rows
   assert step.step_traces == 1
   gauges = get_registry().snapshot()['gauges']
@@ -407,15 +395,13 @@ def test_trainer_records_layer_rows_and_trains_alike(mesh, hub_graph):
   np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
 
 
-def test_trainer_without_the_promise_counts_no_groups(mesh, hub_graph,
-                                                     monkeypatch):
-  """The unfused sort loop gives no promise: the step's batches carry
-  ``hop_fanouts=None`` and the counter reads 0 for every layer."""
-  monkeypatch.setenv('GLT_DEDUP', 'sort')
-  monkeypatch.setenv('GLT_FUSED_HOP', '0')
+def test_trainer_without_the_promise_counts_no_groups(mesh, hub_graph):
+  """A batch that makes no promise (``hop_fanouts=None``, as a loader
+  that gives none hands over) counts 0 groups for every layer."""
   step, _, _ = _trainer(mesh, hub_graph, trim=True)
   batch = step._dummy_batch()
-  assert batch.hop_fanouts is None
+  assert batch.hop_fanouts == (3, 2, 2)
+  batch = batch.replace(hop_fanouts=None)
   step._note_layer_rows(batch)        # what a trace of the step does
   assert step.layer_groups == (0, 0, 0)
   gauges = get_registry().snapshot()['gauges']

@@ -145,16 +145,14 @@ def test_reduce_scopes_on_a_hand_made_event_list():
 
 # -- the step program itself ----------------------------------------------
 
-def _tiny_trainer(chips, monkeypatch):
+def _tiny_trainer(chips):
   """``tests/chipbench``'s tiny cell: the benchmark's trainer at a size
-  the CPU holds, with the sampler's engines as a TPU resolves them."""
+  the CPU holds."""
   from chipbench import graphgen
   from glt_tpu.data import Graph
   from glt_tpu.models import GraphSAGE
   from glt_tpu.parallel import (ShardedFeature, SPMDSageTrainStep,
                                 make_mesh)
-  monkeypatch.setenv('GLT_DEDUP', 'sort')
-  monkeypatch.setenv('GLT_FUSED_HOP', '1')
   n, seed = 20000, 7
   indptr, indices = graphgen.csr(n, 291000, seed)
   feats = graphgen.Features(n, 16, 7, seed)
@@ -173,17 +171,15 @@ def _tiny_trainer(chips, monkeypatch):
 
 
 @pytest.mark.parametrize('chips', [1, 4])
-def test_every_instruction_of_the_step_maps_to_a_layer(chips, monkeypatch):
+def test_every_instruction_of_the_step_maps_to_a_layer(chips):
   from jax.sharding import NamedSharding, PartitionSpec as P
-  trainer, params, opt, (seeds, n_valid, keys) = _tiny_trainer(
-      chips, monkeypatch)
+  trainer, params, opt, (seeds, n_valid, keys) = _tiny_trainer(chips)
   params, opt, _ = trainer(params, opt, seeds, n_valid, keys)
   rows = NamedSharding(trainer.mesh, P(trainer.axis))
   text = trainer._step_fn.lower(
-      params, opt, trainer.tables, trainer.scratches,
-      jax.device_put(seeds, rows), jax.device_put(n_valid, rows), keys,
-      trainer.feature.array, trainer.labels, trainer._indptr,
-      trainer._indices).compile().as_text()
+      params, opt, jax.device_put(seeds, rows),
+      jax.device_put(n_valid, rows), keys, trainer.feature.array,
+      trainer.labels, trainer._indptr, trainer._indices).compile().as_text()
   seen, lost = {}, []
   for line in text.split('\n'):
     instr = re.match(r'^\s+(?:ROOT )?%?([\w.\-]+) = \S+ ([\w\-]+)\(', line)
@@ -213,10 +209,10 @@ def test_every_instruction_of_the_step_maps_to_a_layer(chips, monkeypatch):
   assert want <= stages, want - stages
 
 
-def test_live_step_programs_drops_a_freed_trainer(monkeypatch):
+def test_live_step_programs_drops_a_freed_trainer():
   gc.collect()
   before = len(device.live_step_programs())
-  trainer = _tiny_trainer(1, monkeypatch)[0]
+  trainer = _tiny_trainer(1)[0]
   assert trainer in device.live_step_programs()
   assert len(device.live_step_programs()) == before + 1
   del trainer
@@ -224,8 +220,8 @@ def test_live_step_programs_drops_a_freed_trainer(monkeypatch):
   assert len(device.live_step_programs()) == before
 
 
-def test_the_step_records_spans_only_with_the_tracer_on(monkeypatch):
-  trainer, params, opt, batch = _tiny_trainer(1, monkeypatch)
+def test_the_step_records_spans_only_with_the_tracer_on():
+  trainer, params, opt, batch = _tiny_trainer(1)
   tracer = get_tracer()
   assert not tracer.enabled
   tracer.clear()
@@ -250,7 +246,7 @@ def test_scope_profile_takes_a_session_and_leaves_no_trace(monkeypatch,
   """On the CPU a session holds no TPU plane, so the reduction is handed
   what ``load_profile`` found and answers by hand; everything around it
   is the real thing."""
-  trainer, params, opt, batch = _tiny_trainer(1, monkeypatch)
+  trainer, params, opt, batch = _tiny_trainer(1)
   params, opt, _ = trainer(params, opt, *batch)
   monkeypatch.setenv('TMPDIR', str(tmp_path))
   monkeypatch.setattr('tempfile.tempdir', None)

@@ -1,6 +1,6 @@
-"""Property-based tests (hypothesis): the dense-table inducer must be
-EXACTLY equivalent to the sort-based ordered_unique path on arbitrary
-inputs, and sampling invariants must hold for any degree distribution —
+"""Property-based tests (hypothesis): the hop loop's inducer must give
+the reference inducer's labels on arbitrary inputs, and sampling
+invariants must hold for any degree distribution —
 the randomized counterpart of the fixture-exact tests (reference test
 strategy, SURVEY.md §4)."""
 import jax
@@ -15,8 +15,8 @@ from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from glt_tpu.ops.sample import sample_full_neighbors, sample_neighbors
 from glt_tpu.ops.unique import (
-    dense_assign, dense_init, dense_make_tables, dense_reset,
-    ordered_unique,
+    ordered_unique, sorted_hop_dedup, sorted_hop_dedup_fused,
+    sorted_nodes_by_label,
 )
 
 ids_strategy = st.lists(
@@ -52,35 +52,54 @@ def test_ordered_unique_matches_python(pairs):
   np.testing.assert_array_equal(np.asarray(inv), want_inv)
 
 
+@jax.jit
+def _seed_then_fused(a_ids, a_ok, b_ids, b_ok):
+  empty = jnp.zeros((0,), jnp.int32)
+  d = sorted_hop_dedup(empty, empty, jnp.zeros((), jnp.int32), a_ids, a_ok)
+  f = sorted_hop_dedup_fused(d['u_ids2'], d['u_labs2'], d['count2'], b_ids,
+                             b_ok)
+  f['nodes'] = sorted_nodes_by_label(f['u_ids2'], f['u_labs2'], f['count2'],
+                                     a_ids.shape[0] + b_ids.shape[0])
+  return d, f
+
+
 @settings(max_examples=40, deadline=None)
 @given(ids_strategy, ids_strategy)
-def test_dense_assign_matches_ordered_unique_two_rounds(pairs_a, pairs_b):
-  """Two consecutive dense_assign rounds = ordered_unique over the
-  concatenation: same first-occurrence labels, same node list."""
-  a_ids = np.array([p[0] for p in pairs_a], np.int32)
-  a_ok = np.array([p[1] for p in pairs_a])
-  b_ids = np.array([p[0] for p in pairs_b], np.int32)
-  b_ok = np.array([p[1] for p in pairs_b])
-  cap = a_ids.shape[0] + b_ids.shape[0]
+def test_seed_hop_then_fused_hop_match_python(pairs_a, pairs_b):
+  """The hop loop's two dedups in their order (ops/pipeline.py): the
+  exact seed hop, then a fused hop over the seen-set it left. The seed
+  hop's labels are first-occurrence; the fused hop keeps every seen id's
+  label and hands the new ids ``count..`` in ascending id order; the node
+  list is the one bijection behind both."""
+  def pad(pairs):
+    # to the strategy's 40 slots with invalid ones: one program for
+    # every example
+    pairs = pairs + [(0, False)] * (40 - len(pairs))
+    return (np.array([p[0] for p in pairs], np.int32),
+            np.array([p[1] for p in pairs]))
 
-  table, scratch = dense_make_tables(20)
-  state = dense_init(table, scratch, cap)
-  state, lab_a = dense_assign(state, jnp.asarray(a_ids),
-                              jnp.asarray(a_ok))
-  state, lab_b = dense_assign(state, jnp.asarray(b_ids),
-                              jnp.asarray(b_ok))
+  (a_ids, a_ok), (b_ids, b_ok) = pad(pairs_a), pad(pairs_b)
+  d, f = _seed_then_fused(jnp.asarray(a_ids), jnp.asarray(a_ok),
+                          jnp.asarray(b_ids), jnp.asarray(b_ok))
+  lab_a = np.full(a_ids.shape[0], -1)
+  lab_a[np.asarray(d['pos3'])] = np.asarray(d['labels3'])
+  want_uniq, want_inv = _py_ordered_unique(a_ids.tolist(), a_ok.tolist())
+  np.testing.assert_array_equal(np.where(a_ok, lab_a, -1), want_inv)
+  assert int(d['count2']) == len(want_uniq)
 
-  cat_ids = np.concatenate([a_ids, b_ids]).tolist()
-  cat_ok = np.concatenate([a_ok, b_ok]).tolist()
-  want_uniq, want_inv = _py_ordered_unique(cat_ids, cat_ok)
-  got_inv = np.concatenate([np.asarray(lab_a), np.asarray(lab_b)])
-  np.testing.assert_array_equal(got_inv, want_inv)
-  assert int(state.count) == len(want_uniq)
-  np.testing.assert_array_equal(np.asarray(state.nodes)[:len(want_uniq)],
-                                want_uniq)
-  # reset leaves the tables clean for the next batch
-  table, scratch = dense_reset(state)
-  assert int(np.asarray(table).max()) == -1
+  label = dict(zip(want_uniq, range(len(want_uniq))))
+  new = sorted({int(x) for x, ok in zip(b_ids, b_ok)
+                if ok and int(x) not in label})
+  label.update(zip(new, range(len(want_uniq), len(want_uniq) + len(new))))
+  want_b = [label[int(x)] if ok else -1 for x, ok in zip(b_ids, b_ok)]
+  np.testing.assert_array_equal(np.asarray(f['labels3']), want_b)
+  assert int(f['new_count']) == len(new)
+  heads = np.asarray(f['new_head3'])
+  assert sorted(b_ids[heads].tolist()) == new
+  nodes = np.asarray(f['nodes'])
+  want_nodes = want_uniq + new
+  np.testing.assert_array_equal(nodes[:len(want_nodes)], want_nodes)
+  assert (nodes[len(want_nodes):] == -1).all()
 
 
 @settings(max_examples=25, deadline=None)

@@ -98,19 +98,8 @@ def sampled_batch(step, feats, labels, t):
           'y': labels['a'][seeds], 'seed_type': 'a'}
 
 
-@pytest.fixture(params=['table', 'sort+fused'])
-def dedup_engine(request, monkeypatch):
-  """Both inducers: the CPU's default and what ``auto`` is on a TPU."""
-  if request.param == 'sort+fused':
-    monkeypatch.setenv('GLT_DEDUP', 'sort')
-    monkeypatch.setenv('GLT_FUSED_HOP', '1')
-  else:
-    monkeypatch.setenv('GLT_DEDUP', 'table')
-  return request.param
-
-
 @pytest.mark.parametrize('layers', [2, 3])
-def test_step_matches_the_reference(layers, dedup_engine):
+def test_step_matches_the_reference(layers):
   edges, feats, labels = typed_graph()
   step, tx = build_step(edges, feats, labels, layers, layers, head=True)
   params0 = step.init_params(jax.random.key(3))
@@ -157,7 +146,7 @@ def test_a_planted_fault_is_told_apart(fault):
 
 
 @pytest.mark.parametrize('layers,hops', [(2, 2), (3, 3), (3, 2), (2, 3)])
-def test_node_trim_matches_untrimmed(layers, hops, dedup_engine):
+def test_node_trim_matches_untrimmed(layers, hops):
   """Labels are hop-compact per type, so computing only the rows a later
   layer reads changes neither the loss nor any gradient."""
   edges, feats, labels = typed_graph(1)
@@ -338,17 +327,13 @@ def _loss_and_grads(model, params, batch):
   return logits, grads
 
 
-@pytest.mark.parametrize('conv,remat,engine', [
-    ('rgat', False, 'table'), ('rgat', True, 'sort+fused'),
-    ('rsage', False, 'sort+fused'), ('rsage', True, 'table')])
-def test_rgnn_with_the_promise_and_with_it_withheld(conv, remat, engine,
-                                                    monkeypatch):
-  """One batch, the promise on and withheld: the same logits and the
-  same gradients for the parameters and the features, and the counter
-  says which path ran. The promise holds of the batch (every typed hop
-  loop keeps it)."""
-  monkeypatch.setenv('GLT_DEDUP', engine.split('+')[0])
-  monkeypatch.setenv('GLT_FUSED_HOP', str(int('fused' in engine)))
+@pytest.mark.parametrize('conv,remat', [
+    ('rgat', False), ('rgat', True), ('rsage', False), ('rsage', True)])
+def test_rgnn_with_the_promise_and_with_it_withheld(conv, remat):
+  """One batch, the promise on and withheld (``hop_fanouts_dict=None``):
+  the same logits and the same gradients for the parameters and the
+  features, and the counter says which path ran. The promise holds of
+  the batch (the typed hop loop keeps it)."""
   edges, feats, labels = typed_graph()
   step, _ = build_step(edges, feats, labels, 2, 2, head=True)
   batch = padded_batch(step, feats, labels)

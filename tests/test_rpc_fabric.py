@@ -235,9 +235,11 @@ def _slow_reply_worker(rank: int, world: int, port: int, q) -> None:
       # half a second (rank 0's own goes out at once): what a loaded
       # machine does to them now and then
       send = rpc._send_msg
+      # read once: the hook also sends the replies of the shutdown, after
+      # the fabric's context is gone
+      own = rpc._fabric['ctx'].master._sock.getsockname()
 
       def held(conn, msg):
-        own = rpc._fabric['ctx'].master._sock.getsockname()
         if msg == ('ok', True) and conn.getpeername() != own:
           time.sleep(0.5)
         return send(conn, msg)

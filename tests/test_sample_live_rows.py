@@ -3,8 +3,7 @@
 ``mask`` everywhere and its ``nbrs`` / ``eids`` under it, bit for bit;
 it counts the rows it read; the live count alone chooses between the two
 (one ``lax.cond``); a frontier of one chunk traces to the plain read; and
-the hop loops (one type and typed, on the dedup combination the chip
-runs) hand back the plain read's batch."""
+the hop loops (one type and typed) hand back the plain read's batch."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -177,8 +176,6 @@ BATCH_KEYS = ('node', 'node_count', 'row', 'col', 'edge_mask', 'batch',
 @pytest.mark.parametrize('name', list(homo.MULTIHOP))
 def test_the_hop_loop_hands_back_the_plain_reads_batch(
     monkeypatch, name, with_edge):
-  monkeypatch.setenv('GLT_DEDUP', 'sort')
-  monkeypatch.setenv('GLT_FUSED_HOP', '1')
   make, seeds, n_valid = homo.MULTIHOP[name]
   graph, fanouts = make(), (4, 3, 2)
   key = jax.random.key(5)
@@ -208,8 +205,6 @@ def test_the_hop_loop_hands_back_the_plain_reads_batch(
 @pytest.mark.parametrize('name', list(typed.CASES))
 def test_the_typed_hop_loop_hands_back_the_plain_reads_batch(
     monkeypatch, name):
-  monkeypatch.setenv('GLT_DEDUP', 'sort')
-  monkeypatch.setenv('GLT_FUSED_HOP', '1')
   from glt_tpu.sampler import NeighborSampler
   from glt_tpu.sampler.base import NodeSamplerInput
   make, fanouts, seeds, n_valid = typed.CASES[name]
@@ -240,19 +235,13 @@ def test_the_typed_hop_loop_hands_back_the_plain_reads_batch(
 
 # -- the fused steps --------------------------------------------------------
 
-@pytest.fixture
-def chip_engines(monkeypatch):
-  monkeypatch.setenv('GLT_DEDUP', 'sort')
-  monkeypatch.setenv('GLT_FUSED_HOP', '1')
-
-
 def _bits(tree):
   return [np.asarray(a).view(np.uint32) for a in jax.tree.leaves(tree)]
 
 
 @pytest.mark.parametrize('chips', [1, 2])
 def test_the_sage_step_trains_alike_and_counts_the_rows_it_read(
-    monkeypatch, chip_engines, chips):
+    monkeypatch, chips):
   from test_parallel import _tiny_step   # frontiers of 64 and 192 slots
 
   def two_steps(chunk):
@@ -285,7 +274,7 @@ def test_the_sage_step_trains_alike_and_counts_the_rows_it_read(
 
 
 def test_the_typed_step_trains_alike_and_counts_the_rows_it_read(
-    monkeypatch, chip_engines):
+    monkeypatch):
   import test_typed_build_forms as forms
   from test_rgat_step import train
   edges, feats, labels = forms.typed_graph()

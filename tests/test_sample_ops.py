@@ -143,7 +143,6 @@ def test_neighbor_probs_hotness():
 
 def test_multihop_sample_many_matches_single():
   from glt_tpu.ops.pipeline import multihop_sample, multihop_sample_many
-  from glt_tpu.ops.unique import dense_make_tables
   ei = np.stack([np.repeat(np.arange(30), 2),
                  np.concatenate([(np.arange(30) + 1) % 30,
                                  (np.arange(30) + 2) % 30])])
@@ -156,20 +155,18 @@ def test_multihop_sample_many_matches_single():
       jnp.asarray(t.indices)
   one_hop = lambda ids, f, k, m: sample_neighbors(
       indptr, indices, ids, f, k, seed_mask=m)
-  table, scratch = dense_make_tables(30)
   seeds_stack = jnp.asarray([[0, 5], [10, 15], [20, 25]], jnp.int32)
   nv = jnp.full(3, 2, jnp.int32)
-  outs, table, scratch = multihop_sample_many(
-      one_hop, seeds_stack, nv, (2,), jax.random.key(0), table, scratch)
+  outs = multihop_sample_many(one_hop, seeds_stack, nv, (2,),
+                              jax.random.key(0))
   nodes = np.asarray(outs['node'])          # [3, budget]
   counts = np.asarray(outs['node_count'])
   for i, (a, b) in enumerate([(0, 5), (10, 15), (20, 25)]):
     got = set(nodes[i][:counts[i]].tolist())
     expect = {a, b, (a+1) % 30, (a+2) % 30, (b+1) % 30, (b+2) % 30}
     assert got == expect
-  # tables came back clean: a fresh single batch behaves identically
-  out2, _, _ = multihop_sample(one_hop, jnp.array([7, 8], jnp.int32),
-                               jnp.asarray(2), (2,), jax.random.key(1),
-                               table, scratch)
+  # and a single batch alone samples the same neighbourhood
+  out2 = multihop_sample(one_hop, jnp.array([7, 8], jnp.int32),
+                         jnp.asarray(2), (2,), jax.random.key(1))
   got = set(np.asarray(out2['node'])[:int(out2['node_count'])].tolist())
   assert got == {7, 8, 9, 10}

@@ -6,9 +6,8 @@ The inputs are the ones the deleted engine-parity files carried
 window-DMA tests of test_sample_ops.py): those compared an interpreted
 kernel with the element read, so nothing independent checked the
 element read itself on them. Here every output is checked against the
-edge list. The multi-hop cases force ``GLT_DEDUP=sort GLT_FUSED_HOP=1``,
-the chip's combination (off the TPU ``auto`` picks the table inducer).
-The typed cases live in tests/test_sampler_contract_typed.py.
+edge list, on the one hop loop there is (ops/pipeline.py), the one the
+chip runs. The typed cases live in tests/test_sampler_contract_typed.py.
 """
 import functools
 
@@ -18,8 +17,8 @@ import jax.numpy as jnp
 import pytest
 
 from glt_tpu.data import Dataset, Topology
-from glt_tpu.ops.pipeline import (hop_fanouts, make_dedup_tables,
-                                  multihop_sample, multihop_sample_many)
+from glt_tpu.ops.pipeline import (hop_fanouts, multihop_sample,
+                                  multihop_sample_many)
 from glt_tpu.ops.sample import (sample_full_neighbors, sample_neighbors,
                                 sample_neighbors_weighted)
 
@@ -32,8 +31,7 @@ K = 4
 
 def check_multihop(g, seeds, n_valid, fanouts, out, **kw):
   """The oracle, with every batch held to the promise of parent-major
-  edge slots that the hop loop which ran gives (``Batch.hop_fanouts``;
-  the engine knobs are still set while a case checks its batch)."""
+  edge slots that the hop loop gives (``Batch.hop_fanouts``)."""
   kw.setdefault('hop_fanouts', hop_fanouts(kw.get('widths') or fanouts))
   sampler_oracle.check_multihop(g, seeds, n_valid, fanouts, out, **kw)
 
@@ -131,7 +129,7 @@ def test_one_hop_empty_graph():
   assert (np.asarray(out.eids) == -1).all()
 
 
-# -- multi-hop on the chip's combination --------------------------------
+# -- multi-hop ------------------------------------------------------------
 
 def _hub_graph(n=64, e=600, seed=0):
   rng = np.random.default_rng(seed)
@@ -150,19 +148,31 @@ def _ring_graph(n=30):
       np.arange(2 * n, dtype=np.int32)[::-1].copy()
 
 
+def _star_graph(n=40, sinks=10):
+  """Every node below ``n - sinks`` points at nodes 0, 1 and 2 (self
+  loops among them); the last ``sinks`` nodes have no edge."""
+  src = np.repeat(np.arange(n - sinks), 3)
+  dst = np.tile(np.arange(3), n - sinks)
+  t = Topology(edge_index=np.stack([src, dst]), num_nodes=n)
+  return (t.indptr.astype(np.int32), np.asarray(t.indices),
+          np.arange(src.shape[0], dtype=np.int32) * 5 + 2)
+
+
 MULTIHOP = {
     # name: (graph, seeds, n_valid)
     'hub_graph': (_hub_graph, np.array([5, 0, 5, 17, 63, 2, 2, 9]), 7),
     'ring_duplicate_seeds': (_ring_graph,
                              np.array([3, 3, 29, 0, 3, 29, 12, 12]), 8),
     'n_valid_zero': (_hub_graph, np.array([1, 2, 3, 4, 5, 6, 7, 8]), 0),
+    # live seeds without an edge: every hop's frontier is empty
+    'isolated_seeds': (_star_graph, np.arange(30, 38), 8),
+    # every child of hop 0 is a seed: the later hops find nothing new
+    'children_all_seen': (_star_graph, np.array([0, 1, 2, 5, 6, 7, 8, 9]),
+                          8),
+    # one live slot, the hub: the later hops dedup its fan-out
+    'one_live_hub_seed': (_hub_graph, np.array([5, 5, 0, 1, 2, 3, 4, 6]),
+                          1),
 }
-
-
-@pytest.fixture
-def chip_engines(monkeypatch):
-  monkeypatch.setenv('GLT_DEDUP', 'sort')
-  monkeypatch.setenv('GLT_FUSED_HOP', '1')
 
 
 def _multihop(graph, seeds, n_valid, fanouts, with_edge, key,
@@ -171,15 +181,11 @@ def _multihop(graph, seeds, n_valid, fanouts, with_edge, key,
   one_hop = lambda ids, f, k, m: sample_neighbors(
       indptr, indices, ids, f, k, seed_mask=m,
       edge_ids=eids if with_edge else None, replace=replace)
-  table, scratch = make_dedup_tables(indptr.shape[0] - 1)
-  if many:
-    fn = jax.jit(lambda s, nv, k, t, sc: multihop_sample_many(
-        one_hop, s, nv, fanouts, k, t, sc, with_edge=with_edge))
-  else:
-    fn = jax.jit(lambda s, nv, k, t, sc: multihop_sample(
-        one_hop, s, nv, fanouts, k, t, sc, with_edge=with_edge))
-  out, _, _ = fn(jnp.asarray(seeds, jnp.int32),
-                 jnp.asarray(n_valid, jnp.int32), key, table, scratch)
+  loop = multihop_sample_many if many else multihop_sample
+  fn = jax.jit(lambda s, nv, k: loop(one_hop, s, nv, fanouts, k,
+                                     with_edge=with_edge))
+  out = fn(jnp.asarray(seeds, jnp.int32), jnp.asarray(n_valid, jnp.int32),
+           key)
   return jax.tree.map(np.asarray, out)
 
 
@@ -188,37 +194,48 @@ def _multihop(graph, seeds, n_valid, fanouts, with_edge, key,
 @pytest.mark.parametrize('fanouts', [(3,), (3, 2), (4, 3, 2)],
                          ids=['f3', 'f3_2', 'f4_3_2'])
 @pytest.mark.parametrize('name', list(MULTIHOP))
-def test_multihop_sort_fused(chip_engines, name, fanouts, with_edge):
+def test_multihop_sort_fused(name, fanouts, with_edge):
   make, seeds, n_valid = MULTIHOP[name]
   graph = make()
   out = _multihop(graph, seeds, n_valid, fanouts, with_edge,
                   jax.random.key(0))
   g = EdgeTable.from_csr(*graph)
   # the seed hop is exact (slot order); later hops hand new labels out
-  # in value order on this engine
+  # in value order
   check_multihop(g, seeds, n_valid, fanouts, out,
                  new_label_order='value')
   if n_valid == 0:
     assert int(out['node_count']) == 0
     assert not out['edge_mask'].any()
+  if name == 'isolated_seeds':
+    assert int(out['node_count']) == 8 and not out['edge_mask'].any()
+  if name == 'children_all_seen':
+    np.testing.assert_array_equal(out['num_sampled_nodes'][1:], 0)
 
 
-@pytest.mark.parametrize('engine,fused,order', [
-    ('table', '0', 'slot'), ('sort', '0', 'slot'), ('sort', '1', 'value')],
-    ids=['table', 'sort', 'sort_fused'])
-def test_multihop_with_replacement_each_dedup(monkeypatch, engine, fused,
-                                              order):
-  monkeypatch.setenv('GLT_DEDUP', engine)
-  monkeypatch.setenv('GLT_FUSED_HOP', fused)
-  graph = _hub_graph(seed=5)
-  seeds = np.array([1, 2, 3, 4])
-  out = _multihop(graph, seeds, 4, (4, 2), True, jax.random.key(2),
+REPLACE_CASES = {
+    # name: (graph, seeds, n_valid)
+    'distinct_seeds': (functools.partial(_hub_graph, seed=5),
+                       np.array([1, 2, 3, 4]), 4),
+    # the hub's repeats, a ragged tail: picks of one row repeat children
+    'hub_seed_repeated_ragged': (functools.partial(_hub_graph, seed=5),
+                                 np.array([5, 5, 0, 5]), 3),
+    # degree 2 under fanout 4: every row draws its two edges again
+    'rows_below_the_fanout': (_ring_graph, np.array([0, 7, 7, 20]), 4),
+}
+
+
+@pytest.mark.parametrize('name', list(REPLACE_CASES))
+def test_multihop_with_replacement(name):
+  make, seeds, n_valid = REPLACE_CASES[name]
+  graph = make()
+  out = _multihop(graph, seeds, n_valid, (4, 2), True, jax.random.key(2),
                   replace=True)
-  check_multihop(EdgeTable.from_csr(*graph), seeds, 4, (4, 2), out,
-                 replace=True, new_label_order=order)
+  check_multihop(EdgeTable.from_csr(*graph), seeds, n_valid, (4, 2), out,
+                 replace=True, new_label_order='value')
 
 
-def test_multihop_many_sort_fused(chip_engines):
+def test_multihop_many_sort_fused():
   graph = _hub_graph(seed=7)
   seeds = np.array([[1, 2, 3, 4], [9, 9, 10, 11], [5, 0, 63, 5]])
   n_valid = np.array([4, 3, 4])
@@ -302,15 +319,14 @@ def _sampler_output(out):
       'seed_labels': np.asarray(out.metadata['seed_labels'])}
 
 
-@pytest.mark.parametrize('engine', ['table', 'sort'])
+@pytest.mark.parametrize('with_edge', [False, True],
+                         ids=['no_edge', 'with_edge'])
 @pytest.mark.parametrize('case', ['full_variable', 'weighted_variable',
                                   'full_ring', 'weighted_ring'])
-def test_sampler_weighted_and_full_neighbourhood(monkeypatch, engine,
-                                                 case):
+def test_sampler_weighted_and_full_neighbourhood(case, with_edge):
   """The graphs and fanouts of the deleted window-DMA parity tests,
-  through NeighborSampler, on both dedup engines."""
+  through NeighborSampler, with edge ids and without."""
   from glt_tpu.sampler import NeighborSampler
-  monkeypatch.setenv('GLT_DEDUP', engine)
   weighted = case.startswith('weighted')
   if case.endswith('variable'):
     n, ei, w = _variable_degree_edges()
@@ -328,14 +344,15 @@ def test_sampler_weighted_and_full_neighbourhood(monkeypatch, engine,
                   weights=(eids % 7 + 1) if weighted else None)
     seeds = np.arange(0, 30, 3) if weighted else np.array([0, 7, 13])
     fanouts = [2, 2] if weighted else [-1, -1]
-  s = NeighborSampler(ds.get_graph(), fanouts, with_edge=True,
+  s = NeighborSampler(ds.get_graph(), fanouts, with_edge=with_edge,
                       with_weight=weighted, seed=9)
   out = s.sample_from_nodes(seeds, key=jax.random.key(3))
   internal = [f if f > 0 else -ds.get_graph().topo.max_degree
               for f in fanouts]
-  check_multihop(g, seeds, seeds.shape[0], internal,
-                 _sampler_output(out), weighted=weighted,
-                 new_label_order='slot')
+  got = _sampler_output(out)
+  assert (got['edge'] is None) == (not with_edge)
+  check_multihop(g, seeds, seeds.shape[0], internal, got,
+                 weighted=weighted, new_label_order='value')
   # the static prefix a model trims its nodes by: seeds, then each
   # hop's lane capacity
   want, cap = [seeds.shape[0]], seeds.shape[0]
@@ -343,12 +360,8 @@ def test_sampler_weighted_and_full_neighbourhood(monkeypatch, engine,
     cap *= abs(k)
     want.append(want[-1] + cap)
   assert list(out.node_hop_offsets) == want
-  # and the promise of parent-major slots, which the unfused sort loop
-  # (the sort engine off the TPU) does not give: it permutes a block
-  if engine == 'sort':
-    assert out.hop_fanouts is None and hop_fanouts(internal) is None
-  else:
-    assert out.hop_fanouts == tuple(abs(k) for k in internal)
+  # and the promise of parent-major slots the oracle held the batch to
+  assert out.hop_fanouts == tuple(abs(k) for k in internal)
 
 
 # -- row gathers ---------------------------------------------------------
@@ -383,7 +396,7 @@ def test_sharded_feature_lookup_serves_table_rows_and_zero_rows(shards):
 
 # -- compile discipline ---------------------------------------------------
 
-def test_neighbor_sampler_one_program_a_batch_shape(chip_engines):
+def test_neighbor_sampler_one_program_a_batch_shape():
   from glt_tpu.sampler import NeighborSampler
   ds = ring_dataset(num_nodes=40)
   samp = NeighborSampler(ds.get_graph(), [3, 2], seed=0, with_edge=True)
@@ -402,7 +415,7 @@ def test_neighbor_sampler_one_program_a_batch_shape(chip_engines):
                  new_label_order='value')
 
 
-def test_stream_sampler_no_retrace_across_refresh_and_swap(chip_engines):
+def test_stream_sampler_no_retrace_across_refresh_and_swap():
   from glt_tpu.stream import (EdgeDeltaBuffer, SnapshotManager,
                               StreamSampler)
   n = 24
@@ -502,7 +515,7 @@ def _break_one_head(out):
     _break_seed_label, _break_duplicate_node, _break_group,
     _break_one_head],
     ids=lambda f: f.__name__[len('_break_'):])
-def test_oracle_refuses_a_broken_batch(chip_engines, breakage):
+def test_oracle_refuses_a_broken_batch(breakage):
   g, seeds, out = _good_batch()
   check_multihop(g, seeds, 4, (2, 2), out, new_label_order='value')
   breakage(out)

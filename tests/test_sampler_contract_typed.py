@@ -1,7 +1,6 @@
 """The typed sampler held against the numpy oracle
-(tests/sampler_oracle.py), through ``NeighborSampler`` on the sorted
-path with the fused assign (``GLT_DEDUP=sort GLT_FUSED_HOP=1``: what
-``auto`` resolves to on a TPU, and what the typed cell runs).
+(tests/sampler_oracle.py), through ``NeighborSampler`` on the one typed
+hop loop (ops/pipeline.py), the one the typed cells run.
 
 The cases are the typed inputs of the deleted tests/test_pallas_fused.py,
 which compared an interpreted kernel with this path; several were
@@ -112,9 +111,7 @@ def _traversal_output(out, with_edge):
 @pytest.mark.parametrize('with_edge', [False, True],
                          ids=['no_edge', 'with_edge'])
 @pytest.mark.parametrize('name', list(CASES))
-def test_typed_multihop_sort_fused(monkeypatch, name, with_edge):
-  monkeypatch.setenv('GLT_DEDUP', 'sort')
-  monkeypatch.setenv('GLT_FUSED_HOP', '1')
+def test_typed_multihop_sort_fused(name, with_edge):
   make, fanouts, seeds, n_valid = CASES[name]
   ds, graphs = make()
   seeds = {t: np.asarray(s, np.int64) for t, s in seeds.items()}
@@ -138,41 +135,42 @@ def test_typed_multihop_sort_fused(monkeypatch, name, with_edge):
   assert samp.num_compiled_fns == 1
 
 
-@pytest.mark.parametrize('engine,fused,order', [
-    ('table', '0', 'slot'), ('sort', '0', 'slot')], ids=['table', 'sort'])
-def test_typed_multihop_other_dedups(monkeypatch, engine, fused, order):
-  monkeypatch.setenv('GLT_DEDUP', engine)
-  monkeypatch.setenv('GLT_FUSED_HOP', fused)
-  make, fanouts, seeds, n_valid = CASES['two_seed_types']
+@pytest.mark.parametrize('name', ['two_seed_types', 'hub_rows_in_one_type'])
+def test_typed_multihop_with_replacement(name):
+  """Draws with replacement: ``k`` lanes a row of positive degree,
+  children repeating inside a group, and the dedup across them."""
+  make, fanouts, seeds, n_valid = CASES[name]
   ds, graphs = make()
   seeds = {t: np.asarray(s, np.int64) for t, s in seeds.items()}
-  out = NeighborSampler(ds.graph, fanouts, seed=4, with_edge=True
-                        ).sample_from_nodes(seeds, n_valid=n_valid)
+  inputs = (seeds if len(seeds) > 1
+            else NodeSamplerInput(*reversed(next(iter(seeds.items())))))
+  out = NeighborSampler(ds.graph, fanouts, seed=4, with_edge=True,
+                        replace=True).sample_from_nodes(inputs,
+                                                        n_valid=n_valid)
   check_multihop_typed(graphs, {e: (e[0], e[2]) for e in fanouts},
                        fanouts, seeds, {t: n_valid for t in seeds},
-                       _traversal_output(out, True),
-                       new_label_order=order)
+                       _traversal_output(out, True), replace=True,
+                       new_label_order='value')
 
 
-@pytest.mark.parametrize('engine,fused,order', [
-    ('table', '0', 'slot'), ('sort', '0', 'slot'), ('sort', '1', 'value')],
-    ids=['table', 'sort', 'sort_fused'])
-def test_a_permuted_hop_block_breaks_the_promise(monkeypatch, engine, fused,
-                                                 order):
-  """``hop_fanouts_dict``'s promise is held of each typed loop's batch,
+@pytest.mark.parametrize('name', ['two_seed_types',
+                                  'duplicate_seeds_and_padding',
+                                  'three_hops'])
+def test_a_permuted_hop_block_breaks_the_promise(name):
+  """``hop_fanouts_dict``'s promise is held of the typed loop's batch,
   and a batch that is right in all but the order of one hop block's
   lanes (the same edges, rolled by one lane) is refused for it."""
-  monkeypatch.setenv('GLT_DEDUP', engine)
-  monkeypatch.setenv('GLT_FUSED_HOP', fused)
-  make, fanouts, seeds, n_valid = CASES['two_seed_types']
+  make, fanouts, seeds, n_valid = CASES[name]
   ds, graphs = make()
   seeds = {t: np.asarray(s, np.int64) for t, s in seeds.items()}
+  inputs = (seeds if len(seeds) > 1
+            else NodeSamplerInput(*reversed(next(iter(seeds.items())))))
   out = NeighborSampler(ds.graph, fanouts, seed=4, with_edge=True
-                        ).sample_from_nodes(seeds, n_valid=n_valid)
+                        ).sample_from_nodes(inputs, n_valid=n_valid)
   got = _traversal_output(out, True)
   check = lambda: check_multihop_typed(
       graphs, {e: (e[0], e[2]) for e in fanouts}, fanouts, seeds,
-      {t: n_valid for t in seeds}, got, new_label_order=order)
+      {t: n_valid for t in seeds}, got, new_label_order='value')
   check()
   lo, hi = out.metadata['edge_hop_offsets'][reverse_edge_type(I2I)][1:3]
   assert hi - lo > 2 and got['edge_mask'][I2I][lo:hi].any()

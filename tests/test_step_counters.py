@@ -4,8 +4,8 @@ hop read (every slot at these sizes: a frontier is one chunk of
 ``ops/sample.py::HOP_CHUNK``; tests/test_sample_live_rows.py patches the
 chunk small), the store's and the
 link front's counters in the same flat dict, the 128 newest steps held
-on the device, and a read that traces and compiles nothing. On the CPU,
-on the dedup combination the chip runs."""
+on the device, and a read that traces and compiles nothing. On the
+CPU."""
 import os
 import sys
 
@@ -23,13 +23,6 @@ from glt_tpu.parallel.train import LINK_COUNTERS, STORE_COUNTERS
 from test_parallel import _tiny_step   # 64 nodes, 64 seeds a device
 
 HOPS = ['edges_by_hop', 'hop_rows_read', 'nodes_by_hop']
-
-
-@pytest.fixture(autouse=True)
-def tpu_sampler(monkeypatch):
-  """The sampler's engines as ``auto`` resolves them on a TPU."""
-  monkeypatch.setenv('GLT_DEDUP', 'sort')
-  monkeypatch.setenv('GLT_FUSED_HOP', '1')
 
 
 def _sage_cell(chips):

@@ -215,17 +215,16 @@ def test_run_epoch_engines_agree(mesh, setting, resident):
 # -- ops-level builder contract ------------------------------------------
 
 def test_superstep_builder_threads_carry_and_stacks_aux():
-  def body(params, opt, table, scratch, seeds, n_valid, key):
+  def body(params, opt, seeds, n_valid, key):
     params = params + seeds.sum() * n_valid
-    table = table + 1
-    return params, opt, table, scratch, params * 2
+    opt = opt + 1
+    return params, opt, params * 2
 
   run = superstep(body)
-  p, o, t, s, aux = run(jnp.zeros(()), None, jnp.zeros((), jnp.int32),
-                        jnp.zeros(()),
-                        jnp.arange(6).reshape(3, 2).astype(jnp.float32),
-                        jnp.ones((3,)), jnp.zeros((3,)))
-  assert int(t) == 3                       # carry threaded through
+  p, o, aux = run(jnp.zeros(()), jnp.zeros((), jnp.int32),
+                  jnp.arange(6).reshape(3, 2).astype(jnp.float32),
+                  jnp.ones((3,)), jnp.zeros((3,)))
+  assert int(o) == 3                       # carry threaded through
   np.testing.assert_allclose(np.asarray(aux), [2., 12., 30.])
   assert float(p) == 15.                   # 1 + 5 + 9
 

@@ -61,6 +61,12 @@ def _seen_after_seeds(rng, num_ids):
   return d['u_ids2'], d['u_labs2'], d['count2']
 
 
+@jax.jit
+def _both_dedups(*args):
+  return (sorted_hop_dedup_fused(*args),
+          sorted_hop_dedup_fused(*args, fast_compile=True))
+
+
 @pytest.mark.parametrize('seed', range(4))
 @pytest.mark.parametrize('num_ids,m', [(300, 700), (50, 900), (5000, 64)])
 def test_fast_compile_dedup_is_the_dedup_bit_for_bit(seed, num_ids, m):
@@ -72,8 +78,7 @@ def test_fast_compile_dedup_is_the_dedup_bit_for_bit(seed, num_ids, m):
   for _ in range(3):
     ids = jnp.asarray(rng.integers(0, num_ids, m).astype(np.int32))
     ok = jnp.asarray(rng.random(m) < 0.8)
-    slow = sorted_hop_dedup_fused(*seen, ids, ok)
-    fast = sorted_hop_dedup_fused(*seen, ids, ok, fast_compile=True)
+    slow, fast = _both_dedups(*seen, ids, ok)
     assert slow.keys() == fast.keys()
     for k in slow:
       np.testing.assert_array_equal(np.asarray(slow[k]),
@@ -86,17 +91,7 @@ def test_fast_compile_dedup_is_the_dedup_bit_for_bit(seed, num_ids, m):
           np.asarray(sorted_nodes_by_label(*seen, cut, fast_compile=True)))
 
 
-@pytest.fixture(params=['table', 'sort+fused'])
-def engine(request, monkeypatch):
-  if request.param == 'sort+fused':
-    monkeypatch.setenv('GLT_DEDUP', 'sort')
-    monkeypatch.setenv('GLT_FUSED_HOP', '1')
-  else:
-    monkeypatch.setenv('GLT_DEDUP', 'table')
-  return request.param
-
-
-def test_keep_sample_hands_back_the_batch_the_step_trained_on(engine):
+def test_keep_sample_hands_back_the_batch_the_step_trained_on():
   """``last_sample`` is what the sampler's own program draws on the step's
   key, field for field, and a step built without it keeps nothing and
   trains alike."""
@@ -122,7 +117,7 @@ def test_keep_sample_hands_back_the_batch_the_step_trained_on(engine):
                                     err_msg=f'{field} {k}')
 
 
-def test_one_partition_one_hop_draws_edges_of_the_graph(engine):
+def test_one_partition_one_hop_draws_edges_of_the_graph():
   """No bucketing on one partition: every sampled edge is the graph's, no
   parent holds more children than the fanout, the seeds lead."""
   edges, feats, labels = typed_graph(3)
@@ -147,7 +142,7 @@ def test_one_partition_one_hop_draws_edges_of_the_graph(engine):
     assert drawn > 0
 
 
-def test_one_partition_lookup_reads_the_rows(engine):
+def test_one_partition_lookup_reads_the_rows():
   """The lookup on one partition is a plain read: the rows of the ids,
   noughts where a slot is invalid."""
   edges, feats, labels = typed_graph()

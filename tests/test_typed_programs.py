@@ -15,7 +15,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 PARENT_STABLEHLO = {
     # sha256 of the tiny cells' step programs as StableHLO (no locations),
-    # under GLT_DEDUP=sort GLT_FUSED_HOP=1: a PR that means to change one
+    # on the hop loop the chip runs: a PR that means to change one
     # of these programs reads the hash anew and says so. All five were
     # read anew by the PR that gave every per-batch step the counters
     # output: ``(loss, counters)`` with the sampler's ``nodes_by_hop`` and
@@ -50,12 +50,22 @@ PARENT_STABLEHLO = {
     # nothing else). Before it they were c1 77dfcba4...b1c27162, c4
     # d0fff173...424ed731, link 3b9a9823...195b22a2, typed
     # 0b627d6e...c4fa8de0 and withheld 6eb0e928...ec114b53.
-    'c1': 'd8421d94ef828854a54e1566f9ca29868f330c72455d2fe60af2dd58679acddb',
-    'c4': '52c9d8d64d5c450d8d0d4da4944639bb9e52d1fba656699abeff2b54101d017f',
-    'link': '6c3771c000d78e927929dd7ee03d901a34597d309676c6a21adb8b82acf941d0',
-    'typed': '4042ca194a3ca703db26eb53d0ee47bb0597ca927683312e0cdd592016418285',
+    # All six were read anew by PR 43, which took the dedup state out of
+    # every step: the one inducer keeps its seen-set in batch-sized
+    # arrays, so the ``(1,)`` int32 placeholders each program took,
+    # donated and handed back (a pair a device on the SAGE steps, a pair
+    # a node type on the typed steps) are gone. The parent's texts
+    # diffed against these differ by those parameters and results, their
+    # unstacking slices and restacking broadcasts, and the numbering
+    # behind them alone. Before it they were c1 d8421d94...679acddb, c4
+    # 52c9d8d6...4101d017f, link 6c3771c0...acf941d0, typed
+    # 4042ca19...16418285 and withheld 057c64d7...25c3b24d.
+    'c1': 'dce74e7ff2cf5074c5831346f1ba4a4cdb92024bd1aa5f330cbc342f8b7a475e',
+    'c4': 'b63edad1fcbf4a2ad7e39b0066a43cb6feba9344d34b620c74e8f365e5dc9235',
+    'link': '1c1c110293a8a9850b47d426523db8f4ecb44936624c130be98115b31280f9e2',
+    'typed': 'bfb0c9233e41fc3965a2fb0a795e8e8e60c546c56fea4c053b9ab979228f28a6',
     'typed_withheld':
-        '057c64d7fa971a3ad132256880bfb53101ba45d8bb9d1b3b2060994025c3b24d',
+        'c9b3cd74961ff6028d06cf0b6ee05cee865ca10a0702fbcb02cda24f1788e4c4',
     # the enclosing-subgraph step, pinned by PR 41 at its parent's text
     # (read on both trees): its body walks no hop loop and is left as it
     # is, and its one hop's 4B endpoints are one chunk, so
@@ -65,8 +75,9 @@ PARENT_STABLEHLO = {
     # live tiles and the step counts ``tiles_matched``; before it
     # 8dee044f...04858d05. The five above are that PR's parent's, and so
     # are the HGT and user-item cells' tiny steps (hashed on both trees by
-    # a scratch script: CHANGES.md)
-    'seal': '68a54cdfc18c55f45a2297f9eb9e0f71b2ab883bc5432e9e54d51adb6ac22cf7',
+    # a scratch script: CHANGES.md). PR 43 read it anew with the five
+    # above; before it 68a54cdf...ac22cf7.
+    'seal': '2366498f95e06fe7ff95e551a2615b095a9e485e390ab530c565fb9182837665',
 }
 
 
@@ -78,7 +89,7 @@ def _sage_text(driver, cell, chips):
   rows = NamedSharding(t.mesh, P(t.axis))
   seeds, keys = driver.feed(s, 0)
   return t._step_fn.lower(
-      s.params, s.opt, t.tables, t.scratches,
+      s.params, s.opt,
       jax.device_put(np.asarray(seeds, np.int32), rows),
       jax.device_put(s.n_valid, rows), keys, t.feature.array, t.labels,
       t._indptr, t._indices).as_text()
@@ -104,16 +115,14 @@ def _typed_text(withheld):
       s.params, s.opt, shards, feat_shards, efeat_shards, t.labels,
       jax.device_put(np.asarray(seeds, np.int32).reshape(-1), shard),
       jax.device_put(np.asarray(s.n_valid, np.int32), shard),
-      jax.random.split(key, 1), t.sampler.tables).as_text()
+      jax.random.split(key, 1)).as_text()
 
 
 @pytest.mark.parametrize('name', sorted(PARENT_STABLEHLO))
-def test_the_other_cells_tiny_steps_lower_to_the_parents(name, monkeypatch):
+def test_the_other_cells_tiny_steps_lower_to_the_parents(name):
   """R-GAT's typed step, with the promise and with it withheld, the three
   SAGE steps and the enclosing-subgraph step lower to the StableHLO
   pinned above."""
-  monkeypatch.setenv('GLT_DEDUP', 'sort')
-  monkeypatch.setenv('GLT_FUSED_HOP', '1')
   sys.path.insert(0, REPO)
   sys.path.insert(0, os.path.join(REPO, 'tests', 'chipbench'))
   if name.startswith('typed'):

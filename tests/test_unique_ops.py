@@ -2,7 +2,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from glt_tpu.ops import ordered_unique, init_node, induce_next
+from glt_tpu.ops import ordered_unique
 
 
 def test_ordered_unique_first_occurrence():
@@ -47,49 +47,6 @@ def test_ordered_unique_jit_and_big_random():
   np.testing.assert_array_equal(np.asarray(uniq)[np.asarray(inv)], ids)
 
 
-def test_inducer_init_and_induce():
-  # seeds [10, 20, 10] -> labels [0, 1, 0]
-  seeds = jnp.array([10, 20, 10])
-  state, labels = init_node(seeds, jnp.ones(3, bool), capacity=16)
-  np.testing.assert_array_equal(np.asarray(labels), [0, 1, 0])
-  assert int(state.count) == 2
-
-  # frontier = [10, 20] (labels 0, 1); nbrs: 10->{20,30}, 20->{30,40}
-  nbrs = jnp.array([[20, 30], [30, 40]])
-  mask = jnp.ones((2, 2), bool)
-  state2, rows, cols, emask = induce_next(
-      state, jnp.array([0, 1]), nbrs, mask)
-  assert int(state2.count) == 4
-  np.testing.assert_array_equal(np.asarray(state2.nodes)[:4],
-                                [10, 20, 30, 40])
-  np.testing.assert_array_equal(np.asarray(rows), [0, 0, 1, 1])
-  np.testing.assert_array_equal(np.asarray(cols), [1, 2, 2, 3])
-  assert np.asarray(emask).all()
-
-
-def test_inducer_label_stability_across_hops():
-  # previously-seen nodes keep labels when re-encountered in later hops
-  state, _ = init_node(jnp.array([5]), jnp.ones(1, bool), capacity=8)
-  state, _, cols1, _ = induce_next(
-      state, jnp.array([0]), jnp.array([[6, 7]]), jnp.ones((1, 2), bool))
-  # hop 2 from node 6 (label 1) back to 5 and to new node 8
-  state, rows2, cols2, _ = induce_next(
-      state, jnp.array([1]), jnp.array([[5, 8]]), jnp.ones((1, 2), bool))
-  np.testing.assert_array_equal(np.asarray(cols2), [0, 3])  # 5 kept label 0
-  np.testing.assert_array_equal(np.asarray(state.nodes)[:4], [5, 6, 7, 8])
-
-
-def test_inducer_masked_neighbors_ignored():
-  state, _ = init_node(jnp.array([1, 2]), jnp.ones(2, bool), capacity=8)
-  nbrs = jnp.array([[3, 99], [4, 98]])
-  mask = jnp.array([[True, False], [True, False]])
-  state2, rows, cols, emask = induce_next(
-      state, jnp.array([0, 1]), nbrs, mask)
-  assert int(state2.count) == 4
-  np.testing.assert_array_equal(np.asarray(state2.nodes)[:4], [1, 2, 3, 4])
-  np.testing.assert_array_equal(np.asarray(emask), [True, False, True, False])
-
-
 def test_stitch_rows_pad_does_not_clobber_row_zero():
   from glt_tpu.ops import stitch_rows
   # partition A serves positions [0, -1(pad)]; B serves [1]
@@ -98,38 +55,3 @@ def test_stitch_rows_pad_does_not_clobber_row_zero():
       [jnp.array([[42.], [99.]]), jnp.array([[7.]])],
       total=2)
   np.testing.assert_allclose(np.asarray(out), [[42.], [7.]])
-
-
-def test_dense_inducer_matches_sorted_inducer():
-  from glt_tpu.ops.unique import (
-      dense_make_tables, dense_init, dense_assign, dense_reset)
-  n = 100
-  table, scratch = dense_make_tables(n)
-  state = dense_init(table, scratch, capacity=16)
-  seeds = jnp.array([10, 20, 10, 30])
-  state, labels = dense_assign(state, seeds, jnp.ones(4, bool))
-  np.testing.assert_array_equal(np.asarray(labels), [0, 1, 0, 2])
-  assert int(state.count) == 3
-  # second wave: mixes existing (20) and new (40, 50), with invalid slots
-  ids = jnp.array([40, 20, 40, 50, 99])
-  valid = jnp.array([True, True, True, True, False])
-  state, labels = dense_assign(state, ids, valid)
-  np.testing.assert_array_equal(np.asarray(labels), [3, 1, 3, 4, -1])
-  np.testing.assert_array_equal(np.asarray(state.nodes)[:5],
-                                [10, 20, 30, 40, 50])
-  # reset clears only touched entries
-  table, scratch = dense_reset(state)
-  assert int(np.asarray(table).max()) == -1 or np.all(np.asarray(table) == -1)
-  assert np.all(np.asarray(scratch) == np.iinfo(np.int32).max)
-
-
-def test_dense_inducer_reuse_after_reset():
-  from glt_tpu.ops.unique import (
-      dense_make_tables, dense_init, dense_assign, dense_reset)
-  table, scratch = dense_make_tables(50)
-  state = dense_init(table, scratch, capacity=8)
-  state, _ = dense_assign(state, jnp.array([5, 6]), jnp.ones(2, bool))
-  table, scratch = dense_reset(state)
-  state2 = dense_init(table, scratch, capacity=8)
-  state2, labels = dense_assign(state2, jnp.array([7, 5]), jnp.ones(2, bool))
-  np.testing.assert_array_equal(np.asarray(labels), [0, 1])
