@@ -29,7 +29,12 @@ running counts, packs and stitches one by one. Defaults are the
 four-chip cell's shape: b 937,984, P 4, cap 234,496, a 37 % live prefix
 whose ids follow the generator's floor(N u^2) law (shard 0 owns half),
 D 128 float32. One JSON line; every candidate is held to the sorted
-form's buckets and rows bit for bit before it is timed.
+form's buckets and rows bit for bit before it is timed. Beside them, the
+forms the four-chip exchange runs (``collectives.pack_live_chunks`` and
+``stitch_live_chunks``: the pack's scatter and the stitch's gather over
+the chunks of ``SERVE_CHUNK`` request slots that hold a request, the rows
+written into a zero ``[b, D]``), held to the plain library forms bit for
+bit (``live_*``).
 """
 import argparse
 import json
@@ -229,7 +234,7 @@ def bench_forms(args):
   """Time the forms above on one device; one JSON line."""
   import jax
   import jax.numpy as jnp
-  from glt_tpu.parallel import collectives
+  from glt_tpu.parallel import collectives, dist_feature
 
   p, b, d = args.shards, args.requests, args.dim
   cap = args.cap or min(-(-(-(-b // p)) // 128) * 128, b)
@@ -287,6 +292,28 @@ def bench_forms(args):
             collectives.unbucket(r, m, p))
   _, out['ranked_bucket_unbucket_ms'] = ms(ranked_both, ids, owner, resp)
   out['ranked_equals_sorted'] = same((req, rows), (s_req, s_rows))
+
+  # the exchange's forms: the live chunks alone, rows into a zero carry
+  chunk = dist_feature.SERVE_CHUNK
+  (live_req, packed), out['live_pack_ms'] = ms(
+      lambda i, m: collectives.pack_live_chunks(
+          i, m, p, cap, 0, chunk, fill_value=-1), ids, meta)
+  (live_rows, stitched), out['live_stitch_ms'] = ms(
+      lambda r, m: collectives.stitch_live_chunks(
+          jnp.zeros((b, d), r.dtype), r, m, p, 0, chunk), resp, meta)
+
+  def live_both(i, o, r):
+    m = collectives.rank_by_owner(o, p)
+    return (collectives.pack_live_chunks(i, m, p, cap, 0, chunk,
+                                         fill_value=-1)[0],
+            collectives.stitch_live_chunks(jnp.zeros((b, d), r.dtype), r,
+                                           m, p, 0, chunk)[0])
+  _, out['live_bucket_unbucket_ms'] = ms(live_both, ids, owner, resp)
+  out['live_equals_ranked'] = same(
+      (live_req, np.asarray(live_rows).view(np.uint32)),
+      (req, np.asarray(rows).view(np.uint32)))
+  out['live_chunks'] = {'pack': int(packed), 'stitch': int(stitched),
+                        'of': dist_feature.serve_chunks(b)}
 
   # the candidates, one stage at a time, each held to the sorted form
   want_rank = np.asarray(meta.rank)

@@ -17,6 +17,14 @@ is where a stable argsort by owner would put it, so the buckets are such
 a sort's, with no sort: the pack scatters a payload straight to
 ``(owner, rank)`` and the stitch is one gather from there, already in
 request order.
+
+The four-chip feature store's exchange runs the pack and the stitch (and
+its owner's serve, ``parallel/dist_feature.py``) over the chunks of
+request or bucket slots that hold a request and over no other
+(``live_chunks``, ``over_chunks``): ``pack_live_chunks`` and
+``stitch_live_chunks``, the rows written straight into the drain's
+carry. The plain ``bucket_payload`` and ``unbucket`` serve every other
+caller.
 """
 from __future__ import annotations
 
@@ -91,14 +99,12 @@ def unbucket(resp: jax.Array, meta: BucketMeta, n_shards: int,
   request reads the slot it was packed to, and a masked slot reads a row
   of its own (on a TPU a gather whose masked slots share a row waits on
   it: 15.3 ms against 10.6 at 937,984 slots of which 63 % are pads,
-  benchmarks/bench_bucket_drain.py --forms)."""
-  cap = resp.shape[1]
-  ok, slot = _round_slots(meta, n_shards, cap, round_offset)
-  lane = jnp.arange(slot.shape[0], dtype=slot.dtype) % (n_shards * cap)
-  gathered = jnp.take(resp.reshape((n_shards * cap,) + resp.shape[2:]),
-                      jnp.where(ok, slot, lane), axis=0)
-  ok = ok.reshape(ok.shape + (1,) * (gathered.ndim - 1))
-  return jnp.where(ok, gathered, invalid_value)
+  benchmarks/bench_bucket_drain.py --forms): :func:`stitch_live_chunks`
+  over rows of ``invalid_value``, in one chunk. The sharded feature
+  store's drain runs that form chunk by chunk, into its own carry."""
+  b = meta.owner.shape[0]
+  rows = jnp.full((b,) + resp.shape[2:], invalid_value, resp.dtype)
+  return stitch_live_chunks(rows, resp, meta, n_shards, round_offset, b)[0]
 
 
 def drain_rounds(meta: BucketMeta, n_shards: int, cap: int,
@@ -126,8 +132,10 @@ def capped_drain(round_out, meta: 'BucketMeta', n_shards: int, cap: int,
   merge with ``|``, everything else with ``+``.
 
   The round count is a pmax'd traced scalar driving one
-  ``lax.while_loop`` (typical skew: one round). One implementation for
-  every capped lookup path (parallel + distributed feature stores).
+  ``lax.while_loop`` (typical skew: one round). The capped lookups of
+  ``distributed.DistFeature`` run it; ``parallel.ShardedFeature``'s
+  exchange writes each round's rows into its carry instead
+  (:func:`stitch_live_chunks`).
   """
   def merge(a, o):
     return a | o if a.dtype == jnp.bool_ else a + o
@@ -142,6 +150,101 @@ def capped_drain(round_out, meta: 'BucketMeta', n_shards: int, cap: int,
   _, acc = jax.lax.while_loop(lambda s: s[0] < rounds, body,
                               (jnp.zeros((), jnp.int32), zeros))
   return acc
+
+
+def live_chunks(mask: jax.Array, chunk: int):
+  """``(starts [n], count)`` of the chunks of ``chunk`` consecutive slots
+  of ``mask`` [B] that hold a True: their first slots in slot order,
+  then filler, and how many they are. The flags come from ``mask``
+  alone, so any mask is served: a scattered one flags more chunks. The
+  last chunk of a ``B`` that ``chunk`` does not divide starts at ``B -
+  chunk`` and covers some of its neighbour's slots again. Needs ``B >
+  chunk``."""
+  b = mask.shape[0]
+  n = -(-b // chunk)
+  flags = jnp.pad(mask, (0, n * chunk - b)).reshape(n, chunk).any(axis=1)
+  count = flags.sum().astype(jnp.int32)
+  live = jnp.nonzero(flags, size=n, fill_value=0)[0].astype(jnp.int32)
+  return jnp.minimum(live * chunk, b - chunk), count
+
+
+def over_chunks(starts: jax.Array, count: jax.Array, body, carry):
+  """``body(lo, carry)`` for the first ``count`` of ``starts`` in turn,
+  in one ``lax.while_loop`` (``live_chunks`` gives both). ``body`` must
+  be idempotent on a slot: two chunks may overlap."""
+  def step(state):
+    k, carry = state
+    return k + 1, body(starts[k], carry)
+
+  _, carry = jax.lax.while_loop(lambda state: state[0] < count, step,
+                                (jnp.int32(0), carry))
+  return carry
+
+
+def pack_live_chunks(values: jax.Array, meta: BucketMeta, n_shards: int,
+                     cap: int, round_offset, chunk: int, fill_value=0):
+  """``(buckets [n_shards, cap, ...], chunks)``: :func:`bucket_payload`'s
+  buckets element for element, scattered chunk by chunk over the chunks
+  of ``chunk`` request slots that hold a request of the round
+  (``live_chunks``), so that a chunk of pads costs nothing; and the
+  count of those chunks. Up to ``chunk`` slots are one plain scatter.
+  The targets of a round are distinct, so the order of the chunks does
+  not matter."""
+  b = values.shape[0]
+  ok, slot = _round_slots(meta, n_shards, cap, round_offset)
+  tail = values.shape[1:]
+
+  def put(values, ok, slot, buckets):
+    return buckets.at[jnp.where(ok, slot, n_shards * cap)].set(
+        values, mode='drop')
+
+  buckets = jnp.full((n_shards * cap,) + tail, fill_value, values.dtype)
+  if b <= chunk:
+    buckets, count = put(values, ok, slot, buckets), ok.any()
+  else:
+    cut = lambda a, lo: jax.lax.dynamic_slice_in_dim(a, lo, chunk)
+    starts, count = live_chunks(ok, chunk)
+    buckets = over_chunks(
+        starts, count, lambda lo, buckets: put(
+            cut(values, lo), cut(ok, lo), cut(slot, lo), buckets),
+        buckets)
+  return (buckets.reshape((n_shards, cap) + tail),
+          count.astype(jnp.int32))
+
+
+def stitch_live_chunks(rows: jax.Array, resp: jax.Array, meta: BucketMeta,
+                       n_shards: int, round_offset, chunk: int):
+  """``(rows, chunks)``: the response ``resp`` [n_shards, C, ...] of the
+  drain round at ``round_offset`` stitched back to request order and
+  written into ``rows`` [B, ...] in place: a request of the round takes
+  the slot it was packed to, as from :func:`unbucket`, and every other
+  slot keeps what ``rows`` held. A request is in one round alone, so a
+  drain over zero ``rows`` gives every request its row bit for bit
+  (``-0.0`` too) and the pads zero, with no add. The row gather runs
+  chunk by chunk over the chunks of ``chunk`` request slots that hold a
+  request of the round (``live_chunks``); up to ``chunk`` slots are
+  one plain gather. A masked slot of a gathered chunk reads a row of its
+  own, as in :func:`unbucket`."""
+  cap = resp.shape[1]
+  flat = resp.reshape((n_shards * cap,) + resp.shape[2:])
+  ok, slot = _round_slots(meta, n_shards, cap, round_offset)
+  b = ok.shape[0]
+  wide = (1,) * (flat.ndim - 1)
+
+  def put(lo, ok, slot, old):
+    lane = (lo + jnp.arange(ok.shape[0], dtype=slot.dtype)) % (
+        n_shards * cap)
+    got = jnp.take(flat, jnp.where(ok, slot, lane), axis=0)
+    return jnp.where(ok.reshape(ok.shape + wide), got, old)
+
+  if b <= chunk:
+    return put(0, ok, slot, rows), ok.any().astype(jnp.int32)
+  cut = lambda a, lo: jax.lax.dynamic_slice_in_dim(a, lo, chunk)
+  starts, count = live_chunks(ok, chunk)
+  return over_chunks(
+      starts, count, lambda lo, rows: jax.lax.dynamic_update_slice_in_dim(
+          rows, put(lo, cut(ok, lo), cut(slot, lo), cut(rows, lo)), lo,
+          axis=0), rows), count
 
 
 def all_to_all(x: jax.Array, axis_name: str) -> jax.Array:
@@ -161,15 +264,12 @@ def bucket_payload(values: jax.Array, meta: BucketMeta, n_shards: int,
   be a traced scalar, e.g. the drain-loop counter times the capacity)
   packs the requests ranked [round_offset, round_offset + cap) within
   each bucket — drain round k of a capped exchange packs offset k*cap.
-  Everything else is sent past the last slot and dropped."""
+  Everything else is sent past the last slot and dropped: it is
+  :func:`pack_live_chunks` in one chunk."""
   b = values.shape[0]
   cap = capacity if capacity and capacity < b else b
-  ok, slot = _round_slots(meta, n_shards, cap, round_offset)
-  buckets = jnp.full((n_shards * cap,) + values.shape[1:], fill_value,
-                     values.dtype)
-  buckets = buckets.at[jnp.where(ok, slot, n_shards * cap)].set(
-      values, mode='drop')
-  return buckets.reshape((n_shards, cap) + values.shape[1:])
+  return pack_live_chunks(values, meta, n_shards, cap, round_offset, b,
+                          fill_value)[0]
 
 
 def sharded_segment_mean(msgs: jax.Array, targets: jax.Array,

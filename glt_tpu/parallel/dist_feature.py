@@ -14,10 +14,13 @@ id // rows_per_shard), and lookup inside shard_map is
 with fixed-capacity buckets so shapes stay static: an even share of the
 request vector a peer (exchange_cap), as many rounds as the fullest one
 needs; a request's slot is its rank among its owner's requests (no sort:
-collectives.rank_by_owner). On one shard there is one owner and the
-exchange would be the identity: lookup_local then serves in place (the
-local gather alone), the same rows bit for bit, and gathers only the
-chunks of request slots that hold a valid request (serve_live_chunks).
+collectives.rank_by_owner). The pack, the owner's local gather and the
+stitch each visit only the chunks of SERVE_CHUNK slots that hold a
+request, and the stitch writes the rows straight into the drain's carry.
+On one shard there is one owner and the exchange would be the identity:
+lookup_local then serves in place (the local gather alone), the same
+rows bit for bit, and gathers only the chunks of request slots that
+hold a valid request (serve_live_chunks).
 """
 from __future__ import annotations
 
@@ -31,6 +34,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs.device import scope
 from ..utils import as_numpy
+from .collectives import live_chunks
 
 
 def _more_rounds_global(more: bool) -> bool:
@@ -68,8 +72,9 @@ def overflow_lanes(owner_key: np.ndarray, n_shards: int, b: int,
   return over
 
 
-#: request slots in one chunk of the in-place ``serve``: the least sum
-#: among 4,096 / 8,192 / 16,384 / 32,768 of a probe of the store alone on
+#: request slots in one chunk of the in-place ``serve``, and of the
+#: exchange's pack, owner's serve and stitch over more shards: the least
+#: sum among 4,096 / 8,192 / 16,384 / 32,768 of a probe of the store alone on
 #: a v5e over the cells' request vectors (PERF.md section 6, PR 36: 18.48
 #: / 18.36 / 18.91 / 22.95 ms where the plain gathers read 67.05). A small
 #: chunk wins where a node type fills one chunk (the pads that share it
@@ -97,13 +102,10 @@ def serve_live_chunks(serve, ids, valid, feature_dim: int, dtype):
   at ``B - SERVE_CHUNK`` and writes its neighbour's rows again. Up to
   ``SERVE_CHUNK`` slots are one chunk and one plain gather."""
   b = ids.shape[0]
-  n, c = serve_chunks(b), SERVE_CHUNK
-  if n == 1:
+  c = SERVE_CHUNK
+  if b <= c:
     return serve(ids, valid), valid.any().astype(jnp.int32)
-  flags = jnp.pad(valid, (0, n * c - b)).reshape(n, c).any(axis=1)
-  count = flags.sum().astype(jnp.int32)
-  live = jnp.nonzero(flags, size=n, fill_value=0)[0].astype(jnp.int32)
-  starts = jnp.minimum(live * c, b - c)
+  starts, count = live_chunks(valid, c)
 
   def gather(carry):
     k, rows = carry
@@ -279,9 +281,12 @@ class ShardedFeature:
         ``SPMDSageTrainStep.store_counters()``): ``store_rounds`` (the
         drain's round count, the same on every device),
         ``store_bucket_max`` (requests in this device's fullest
-        per-owner bucket) and ``store_requests`` (its valid requests).
-        In place ``store_chunks``: the chunks of request slots that
-        held a valid request and were gathered, of ``serve_chunks(B)``.
+        per-owner bucket), ``store_requests`` (its valid requests) and
+        ``store_exchange_chunks`` (the chunks of ``SERVE_CHUNK`` slots
+        the pack, the serve and the stitch gathered, summed over the
+        rounds, of ``exchange_chunks(B)`` a round). In place
+        ``store_chunks``: the chunks of request slots that held a valid
+        request and were gathered, of ``serve_chunks(B)``.
 
     Returns [B, D]; invalid slots are zero. With ``counters``:
     ``(rows, counters)``.
@@ -293,9 +298,9 @@ class ShardedFeature:
     spills, only the chunks of request slots that hold a valid request
     are gathered (``serve_live_chunks``). The rows are the
     exchange's bit for bit, in every form (resident, hot-only spill with
-    cold lanes zero, ``cold_shard``); a capped exchange differs in one
-    bit only: its drain adds rounds up, which turns a stored -0.0 into
-    +0.0. The choice follows from the mesh at trace time; the gauge
+    cold lanes zero, ``cold_shard``) and at any cap: the drain writes
+    each request's row once and adds nothing. The choice follows from
+    the mesh at trace time; the gauge
     ``feature_store_in_place{fn="ShardedFeature.lookup_local"}`` says
     which form the last trace took.
 
@@ -306,7 +311,10 @@ class ShardedFeature:
     the collectives inside the lax.while_loop stay aligned) and round k
     ships the requests ranked [k*cap, (k+1)*cap) within each bucket. No
     host replay, no cross-process agreement round — fused SPMD train
-    steps use capped stores directly.
+    steps use capped stores directly. Each of a round's pack, serve and
+    stitch visits only the chunks of ``SERVE_CHUNK`` slots that hold a
+    request of the round; the loops hold no collective, so their trip
+    counts may differ by device.
     """
     from ..obs.perf import gauge_in_place
     ax = axis_name or self.axis
@@ -331,6 +339,14 @@ class ShardedFeature:
     a step's ``store_chunks`` counter is read against. A store that
     spills gathers them as one."""
     return 1 if self._spill else serve_chunks(b)
+
+  def exchange_chunks(self, b: int) -> int:
+    """Chunks of ``SERVE_CHUNK`` that one drain round's ``b`` request
+    slots (the pack and the stitch) and ``P x exchange_cap(b)`` bucket
+    slots (the serve) are cut into: what a step's
+    ``store_exchange_chunks`` is read against."""
+    return 2 * serve_chunks(b) + self.serve_chunks(
+        self.mesh.shape[self.axis] * self.exchange_cap(b))
 
   def _serve(self, local_shard, req_in, ax, cold_shard):
     """Rows of the local block for the requests ``req_in`` (any shape;
@@ -371,14 +387,19 @@ class ShardedFeature:
                        counters=False):
     """``lookup_local`` over more than one shard: bucket the requests by
     owner, all_to_all, serve, all_to_all back, stitch to request order,
-    as many rounds as the fullest bucket needs. Correct on one shard too
-    (the exchange is then the identity), which is how tests hold the
-    in-place form to it."""
+    as many rounds as the fullest bucket needs. The pack, the serve and
+    the stitch run over the chunks of ``SERVE_CHUNK`` slots that hold a
+    request of the round (``collectives.pack_live_chunks``,
+    ``serve_live_chunks`` where nothing spills,
+    ``collectives.stitch_live_chunks``), and the stitch writes each
+    round's rows into the drain's zero ``[b, D]`` carry. Correct on one
+    shard too (the exchange is then the identity), which is how tests
+    hold the in-place form to it."""
     from ..obs.perf import gauge_bucket_cap
-    from .collectives import (all_to_all, bucket_payload, capped_drain,
-                              drain_rounds, rank_by_owner, unbucket)
+    from .collectives import (all_to_all, drain_rounds, pack_live_chunks,
+                              rank_by_owner, stitch_live_chunks)
     n_shards = self.mesh.shape[self.axis]
-    b = ids.shape[0]
+    b, d = ids.shape[0], self.feature_dim
     store = lambda stage: scope('feature_store', stage)
     with store('bucket'):
       owner = jnp.clip(ids // self.rows_per_shard, 0, n_shards - 1)
@@ -392,34 +413,52 @@ class ShardedFeature:
       with store('bucket'):
         rounds = drain_rounds(meta, n_shards, cap, ax)
 
-    def round_out(base):
-      """One bucket-exchange-serve-unbucket pass over the requests
-      ranked [base, base+cap) per bucket; other lanes come back 0."""
+    def serve(req):
+      if self._spill:
+        return (self._serve(local_shard, req, ax, cold_shard),
+                (req >= 0).any().astype(jnp.int32))
+      return serve_live_chunks(
+          lambda i, v: self._serve(local_shard, i, ax, None), req,
+          req >= 0, d, local_shard.dtype)
+
+    def round_into(base, rows):
+      """One bucket-exchange-serve-stitch pass over the requests ranked
+      [base, base+cap) per bucket, their rows written into ``rows``;
+      and the chunks its three loops gathered."""
       with store('bucket'):
-        req = bucket_payload(ids, meta, n_shards, fill_value=-1,
-                             capacity=cap, round_offset=base)
+        req, packed = pack_live_chunks(ids, meta, n_shards, cap, base,
+                                       SERVE_CHUNK, fill_value=-1)
       with store('exchange'):
         # exchange requests: row p of the result = what peer p asked us
         req_in = all_to_all(req, ax)
       with store('serve'):
-        served = self._serve(local_shard, req_in, ax, cold_shard)
+        served, gathered = serve(req_in.reshape(-1))
       with store('exchange'):
         # responses back; row p now holds our requests served by peer p
-        resp = all_to_all(served, ax)
+        resp = all_to_all(served.reshape(n_shards, cap, d), ax)
       with store('unbucket'):
-        resp = resp.reshape(n_shards, cap, self.feature_dim)
-        # positional stitch back to request order
-        return unbucket(resp, meta, n_shards, round_offset=base)
+        rows, stitched = stitch_live_chunks(rows, resp, meta, n_shards,
+                                            base, SERVE_CHUNK)
+      return rows, packed + gathered + stitched
 
-    rows = capped_drain(
-        round_out, meta, n_shards, cap, ax,
-        jnp.zeros((b, self.feature_dim), local_shard.dtype),
-        rounds=rounds) if capped else round_out(0)
+    rows = jnp.zeros((b, d), local_shard.dtype)
+    if capped:
+      def drain(state):
+        k, rows, chunks = state
+        rows, more = round_into(k * cap, rows)
+        return k + 1, rows, chunks + more
+
+      _, rows, chunks = jax.lax.while_loop(
+          lambda state: state[0] < rounds, drain,
+          (jnp.int32(0), rows, jnp.int32(0)))
+    else:
+      rows, chunks = round_into(0, rows)
     if not counters:
       return rows
     return rows, dict(store_rounds=rounds,
                       store_bucket_max=meta.counts.max().astype(jnp.int32),
-                      store_requests=meta.counts.sum().astype(jnp.int32))
+                      store_requests=meta.counts.sum().astype(jnp.int32),
+                      store_exchange_chunks=chunks)
 
   def _cold_values_host(self, nodes: np.ndarray, valid: np.ndarray):
     """The host cold-row gather core shared by the lookup() host phase

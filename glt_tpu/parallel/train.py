@@ -89,7 +89,8 @@ NEG_TRIALS = 5
 #: ``store_counters``)
 LINK_COUNTERS = ('negatives_rejected', 'negatives_padded', 'seed_unique',
                  'seeds')
-STORE_COUNTERS = ('store_rounds', 'store_bucket_max', 'store_requests')
+STORE_COUNTERS = ('store_rounds', 'store_bucket_max', 'store_requests',
+                  'store_exchange_chunks')
 
 
 def _node_loss(bs):
@@ -941,8 +942,9 @@ class SPMDSageTrainStep(StepCounters):
     batch; a link step's
     ``NEG_TRIALS x B`` proposals, ``B`` negatives and ``4B`` seed slots;
     over more than one shard the drain's most rounds, a per-owner
-    bucket's ``exchange_cap(b)`` slots and the ``b`` request slots; on
-    one shard the chunks the ``b`` request slots are served in."""
+    bucket's ``exchange_cap(b)`` slots, the ``b`` request slots and the
+    chunks one round's pack, serve and stitch are cut into; on one
+    shard the chunks the ``b`` request slots are served in."""
     static = self._batch_static
     if self._enclose is not None:
       # a link's slots: 2 endpoints and the fringe; its block's entries
@@ -971,7 +973,8 @@ class SPMDSageTrainStep(StepCounters):
     else:
       cap = self.feature.exchange_cap(b)
       slots.update(store_rounds=-(-b // cap), store_bucket_max=cap,
-                   store_requests=b)
+                   store_requests=b,
+                   store_exchange_chunks=self.feature.exchange_chunks(b))
     return {k: np.asarray(v, np.int64) for k, v in slots.items()}
 
   def link_counters(self) -> dict:
@@ -995,8 +998,10 @@ class SPMDSageTrainStep(StepCounters):
     ``store_rounds`` (exchange rounds the drain ran, the same on every
     device: ``ceil`` of the mesh's fullest per-owner bucket over the
     bucket's cap), ``store_bucket_max`` (requests in the device's
-    fullest bucket) and ``store_requests`` (its valid requests: the
-    batch's ``node_count``). Only where the store exchanges: on one
+    fullest bucket), ``store_requests`` (its valid requests: the
+    batch's ``node_count``) and ``store_exchange_chunks`` (the chunks
+    of request and bucket slots its pack, serve and stitch gathered,
+    over the rounds). Only where the store exchanges: on one
     shard it serves in place, the step has no such output, and this
     raises."""
     if self.feature.in_place:

@@ -374,7 +374,8 @@ def test_a_node_seeded_step_carries_nothing_of_the_link_front(chips):
   # exchanging store's three counters ride beside them; nothing of the
   # link front
   loss, counted = out[-1]
-  store = ['store_bucket_max', 'store_requests', 'store_rounds']
+  store = ['store_bucket_max', 'store_exchange_chunks', 'store_requests',
+           'store_rounds']
   # ... and on one the chunks it gathered
   assert sorted(counted) == ['edges_by_hop', 'hop_rows_read',
                              'nodes_by_hop'] + (

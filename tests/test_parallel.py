@@ -505,13 +505,14 @@ def test_lookup_local_exchanges_only_over_more_than_one_shard(
   # neither form sorts
   assert 'stablehlo.sort' not in text
   assert _in_place_gauge() == float(in_place)
-  # the chunk loop is the in-place form's alone, past SERVE_CHUNK slots:
-  # an exchange serves its buckets by the plain gather (uncapped, so
-  # with no drain loop either: no loop at all)
+  # up to SERVE_CHUNK slots every stage is one plain pass (uncapped, so
+  # with no drain loop either: no loop at all); past it the in-place
+  # serve is one chunk loop, and an exchange runs three: the pack, the
+  # owner's serve and the stitch
   assert 'stablehlo.while' not in text
   from glt_tpu.parallel import dist_feature
   monkeypatch.setattr(dist_feature, 'SERVE_CHUNK', b // 2)
-  assert ('stablehlo.while' in lowered()) == in_place
+  assert lowered().count('stablehlo.while') == (1 if in_place else 3)
 
 
 # -- more shards: buckets of b / P slots and the drain behind them ---------
@@ -717,29 +718,71 @@ def test_buckets_are_a_stable_sorts_bit_for_bit(case, p, payload):
 
 
 _EXCHANGE_CASES = {
-    # case: (mask, bucket_cap; 0 = exchange_cap's even share, rounds)
-    'live_prefix': ('prefix', 0, 1),
-    'scattered_mask': ('scattered', 0, 1),
-    'all_pads': ('none', 0, 0),
-    'small_cap_several_rounds': ('scattered', 40, None),   # three or more
-    'one_owner_small_cap': ('one_owner', 100, 6),
-    'one_round_holds_all': ('prefix', 10_000, 1),
+    # case: (mask, bucket_cap; 0 = exchange_cap's even share, rounds, b)
+    'live_prefix': ('prefix', 0, 1, 520),   # 128 does not divide b
+    'scattered_mask': ('scattered', 0, 1, 520),
+    'all_pads': ('none', 0, 0, 520),
+    'small_cap_several_rounds': ('scattered', 40, None, 520),  # three +
+    'one_owner_small_cap': ('one_owner', 100, 6, 520),
+    'one_round_holds_all': ('prefix', 10_000, 1, 520),
+    # more than two chunks of SERVE_CHUNK request slots a device, and
+    # more than two of bucket slots: the pack, serve and stitch loops
+    'chunks_live_prefix': ('prefix', 0, 1, 20_000),
+    'chunks_scattered_mask': ('scattered', 0, 1, 20_000),
+    'chunks_all_pads': ('none', 0, 0, 20_000),
+    'chunks_one_owner': ('one_owner', 0, 4, 20_000),
+    'chunks_small_cap_several_rounds': ('prefix', 1_000, None, 20_000),
+    'chunks_prefix_ends_on_a_chunk': ('two_chunks', 0, 2, 20_000),
 }
+
+
+def _chunks_holding(mask, chunk):
+  """Chunks of ``chunk`` consecutive slots of ``mask`` with a True in
+  them; up to one chunk of slots is one chunk."""
+  n = -(-mask.shape[0] // chunk)
+  if n == 1:
+    return int(mask.any())
+  return int(np.pad(mask, (0, n * chunk - mask.shape[0])).reshape(
+      n, chunk).any(axis=1).sum())
+
+
+def _exchange_chunks(owner, p, cap, rounds, chunk):
+  """What a device's pack, serve and stitch gather over the drain, by
+  numpy: ``owner`` [p, b] (``p`` for a pad) of every device."""
+  rank = np.zeros(owner.shape, np.int64)
+  for q in range(p):
+    hit = owner == q
+    rank = np.where(hit, np.cumsum(hit, axis=1) - 1, rank)
+  counts = np.stack([(owner == q).sum(axis=1) for q in range(p)], axis=1)
+  got = np.zeros(p, np.int64)
+  for k in range(rounds):
+    ok = (owner < p) & (rank >= k * cap) & (rank < (k + 1) * cap)
+    for d in range(p):
+      # device d's buckets hold peer q's requests to d, a prefix each
+      held = np.zeros((p, cap), bool)
+      for q in range(p):
+        held[q, :np.clip(counts[q, d] - k * cap, 0, cap)] = True
+      got[d] += (2 * _chunks_holding(ok[d], chunk)
+                 + _chunks_holding(held.reshape(-1), chunk))
+  return got
 
 
 @pytest.mark.parametrize('case', list(_EXCHANGE_CASES))
 def test_four_shard_lookup_rows_and_counters_for_any_mask_and_cap(case):
-  mask, bucket_cap, want_rounds = _EXCHANGE_CASES[case]
-  p, n, d, b = 4, 1000, 8, 520   # 128 does not divide b
+  from glt_tpu.parallel.dist_feature import SERVE_CHUNK
+  mask, bucket_cap, want_rounds, b = _EXCHANGE_CASES[case]
+  p, n, d = 4, 1000, 8
   rng = np.random.default_rng(53)
   feats = rng.normal(size=(n, d)).astype(np.float32)
-  feats[::7, 0] = -0.0   # a stored -0.0 comes back +0.0 from a drain's add
+  feats[::7, 0] = -0.0   # a drain writes rows: a stored -0.0 stays -0.0
   sf = ShardedFeature(feats, make_mesh(p), bucket_cap=bucket_cap)
   cap = sf.exchange_cap(b)
-  assert cap == (min(bucket_cap, b) if bucket_cap else 256)
+  assert cap == (min(bucket_cap, b) if bucket_cap else
+                 -(-(-(-b // p)) // 128) * 128)
   ids = np.floor(n * rng.random(p * b) ** 2).astype(np.int32)
   valid = {
       'prefix': np.tile(np.arange(b) < int(0.37 * b), p),
+      'two_chunks': np.tile(np.arange(b) < 2 * SERVE_CHUNK, p),
       'scattered': rng.random(p * b) < 0.4,
       'none': np.zeros(p * b, bool),
       'one_owner': np.ones(p * b, bool),
@@ -748,9 +791,8 @@ def test_four_shard_lookup_rows_and_counters_for_any_mask_and_cap(case):
     ids = rng.integers(sf.rows_per_shard, 2 * sf.rows_per_shard,
                        size=p * b).astype(np.int32)
   rows, counted = _lookup_counted(sf, ids, valid)
+  # the in-place form's rows: every request's row, the pads zero
   want = np.where(valid[:, None], feats[ids], np.float32(0))
-  if cap < b:
-    want = want + np.float32(0)   # the drain adds rounds up: -0.0 -> +0.0
   np.testing.assert_array_equal(rows.view(np.uint32), want.view(np.uint32))
   per_owner = np.stack([
       np.bincount(ids[lo:lo + b][valid[lo:lo + b]] // sf.rows_per_shard,
@@ -762,6 +804,14 @@ def test_four_shard_lookup_rows_and_counters_for_any_mask_and_cap(case):
   np.testing.assert_array_equal(counted['store_requests'],
                                 per_owner.sum(axis=1))
   assert rounds == want_rounds if want_rounds is not None else rounds >= 3
+  owner = np.where(valid, ids // sf.rows_per_shard, p).reshape(p, b)
+  np.testing.assert_array_equal(
+      counted['store_exchange_chunks'],
+      _exchange_chunks(owner, p, cap, rounds, SERVE_CHUNK))
+  if b > SERVE_CHUNK and mask in ('prefix', 'two_chunks'):
+    # behind a live prefix the loops skip the chunks of pads
+    assert (counted['store_exchange_chunks']
+            < rounds * sf.exchange_chunks(b)).all()
 
 
 def _tiny_step(chips):
@@ -793,12 +843,16 @@ def test_step_hands_back_the_store_counters_over_four_shards():
   out = step(params, opt, seeds, n_valid, keys)
   assert len(out) == 3 and np.isfinite(np.asarray(out[2])).all()
   counted = step.store_counters()
-  assert sorted(counted) == ['store_bucket_max', 'store_requests',
-                             'store_rounds']
+  assert sorted(counted) == ['store_bucket_max', 'store_exchange_chunks',
+                             'store_requests', 'store_rounds']
   assert all(v.shape == (4,) for v in counted.values())
   b = 64 * (1 + 3 + 6)   # sample_budget(64, [3, 2]) slots a device
   cap = step.feature.exchange_cap(b)
   assert cap == 256 < b
+  # one round: the pack, the serve and the stitch, one chunk each here
+  slots = step.counter_slots()['store_exchange_chunks']
+  assert int(slots) == step.feature.exchange_chunks(b) == 3
+  assert (counted['store_exchange_chunks'] == 3).all()
   assert _store_gauge('feature_store_bucket_cap') == 256.0
   # every device found all 64 nodes or fewer, at least its distinct seeds
   assert (counted['store_requests'] <= 64).all()

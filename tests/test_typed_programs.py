@@ -60,8 +60,13 @@ PARENT_STABLEHLO = {
     # behind them alone. Before it they were c1 d8421d94...679acddb, c4
     # 52c9d8d6...4101d017f, link 6c3771c0...acf941d0, typed
     # 4042ca19...16418285 and withheld 057c64d7...25c3b24d.
+    # c4 alone was read anew when the exchange came to pack, serve and
+    # stitch chunk by chunk and to write each drain round's rows into
+    # the drain's carry (no add), with one more counter out,
+    # ``store_exchange_chunks``; before it c4 was b63edad1...5dc9235. The
+    # one-chip programs never bucket: their hashes stand.
     'c1': 'dce74e7ff2cf5074c5831346f1ba4a4cdb92024bd1aa5f330cbc342f8b7a475e',
-    'c4': 'b63edad1fcbf4a2ad7e39b0066a43cb6feba9344d34b620c74e8f365e5dc9235',
+    'c4': '5b94e094cca91237558de9c495441fb416992817e9e831a399aaa1fb81eb8b49',
     'link': '1c1c110293a8a9850b47d426523db8f4ecb44936624c130be98115b31280f9e2',
     'typed': 'bfb0c9233e41fc3965a2fb0a795e8e8e60c546c56fea4c053b9ab979228f28a6',
     'typed_withheld':
